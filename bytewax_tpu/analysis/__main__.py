@@ -31,7 +31,6 @@ _RULE_DOC = {
     "BTX-FRAMES": "control-frame kind inventory is closed",
     "BTX-FAULT": "fault sites pinned; injector silent; fire before mutate",
     "BTX-SNAPSHOT": "device-tier states implement demotion_snapshots()",
-    "BTX-BACKEND": "standalone scripts force a backend before jax init",
     "BTX-DRAIN": "drain-only ops (evict/restore/flush/...) only at drain points",
     "BTX-THREAD": "the pipeline worker lane never reaches main-only state",
     "BTX-KNOB": "every BYTEWAX_TPU_* knob is cataloged + documented",
@@ -56,12 +55,6 @@ def main(argv: Optional[List[str]] = None) -> int:
             "analyze ONLY these files/directories instead of the "
             "installed package + examples/"
         ),
-    )
-    parser.add_argument(
-        "--scripts",
-        action="store_true",
-        help="treat the given paths as standalone scripts "
-        "(BTX-BACKEND applies)",
     )
     parser.add_argument(
         "--rules",
@@ -142,7 +135,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.paths:
         diags, suppressed, _project = api.analyze_paths(
             args.paths,
-            scripts=args.scripts,
             rule_ids=rule_ids,
             # Regenerating a baseline must see ALL findings, or the
             # old baseline would filter them out of the new one.

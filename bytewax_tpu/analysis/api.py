@@ -36,26 +36,25 @@ def default_roots() -> Tuple[Path, Optional[Path]]:
 
 def discover_files(
     pkg_dir: Path, examples_dir: Optional[Path]
-) -> List[Tuple[str, Path, bool]]:
-    """(module_name, path, is_script) for the default scan set: the
-    whole package as importable modules, ``examples/*.py`` as
-    standalone scripts."""
-    files: List[Tuple[str, Path, bool]] = []
+) -> List[Tuple[str, Path]]:
+    """(module_name, path) for the default scan set: the whole
+    package plus ``examples/*.py``."""
+    files: List[Tuple[str, Path]] = []
     pkg_name = pkg_dir.name
     for path in sorted(pkg_dir.rglob("*.py")):
         rel = path.relative_to(pkg_dir)
         parts = [pkg_name] + list(rel.with_suffix("").parts)
         if parts[-1] == "__init__":
             parts = parts[:-1]
-        files.append((".".join(parts), path, False))
+        files.append((".".join(parts), path))
     if examples_dir is not None:
         for path in sorted(examples_dir.glob("*.py")):
-            files.append((f"examples.{path.stem}", path, True))
+            files.append((f"examples.{path.stem}", path))
     return files
 
 
 def _load(
-    files: Sequence[Tuple[str, Path, bool]], rel_root: Optional[Path]
+    files: Sequence[Tuple[str, Path]], rel_root: Optional[Path]
 ) -> Project:
     return Project.load(files, rel_root=rel_root)
 
@@ -96,7 +95,6 @@ def analyze_tree(
 
 def analyze_paths(
     paths: Sequence[Path],
-    scripts: bool = False,
     rule_ids: Optional[Iterable[str]] = None,
     baseline: Optional[Path] = None,
     rel_root: Optional[Path] = None,
@@ -104,15 +102,14 @@ def analyze_paths(
 ) -> Tuple[List[Diagnostic], int, Project]:
     """Analyze an explicit file set (fixtures, one-off checks).
 
-    Directories are globbed recursively; ``scripts=True`` marks every
-    file as a standalone script (BTX-BACKEND applies).  Module names
-    derive from file stems, so allowlist-gated rules treat these
-    files as outside the sanctioned modules — which is the point for
-    positive fixtures.
+    Directories are globbed recursively.  Module names derive from
+    file stems, so allowlist-gated rules treat these files as outside
+    the sanctioned modules — which is the point for positive
+    fixtures.
     """
     from bytewax_tpu.analysis.rules import run_rules
 
-    files: List[Tuple[str, Path, bool]] = []
+    files: List[Tuple[str, Path]] = []
     used: set = set()
     for p in paths:
         p = Path(p)
@@ -125,7 +122,7 @@ def analyze_paths(
                 n += 1
                 name = f"{path.stem}_{n}"
             used.add(name)
-            files.append((name, path, scripts))
+            files.append((name, path))
     project = _load(files, rel_root)
     diags = run_rules(project, rule_ids, timings=timings)
     diags = apply_waivers(diags, _waiver_map(project))

@@ -823,7 +823,6 @@ KNOBS: Dict[str, Tuple[str, str]] = {
     "BYTEWAX_TPU_CKPT_ASYNC": ("0", "docs/recovery.md"),
     "BYTEWAX_TPU_CKPT_COMPACT_EVERY": ("", "docs/recovery.md"),
     "BYTEWAX_TPU_CKPT_DELTA": ("0", "docs/recovery.md"),
-    "BYTEWAX_TPU_COMPILE_CACHE": ("", "docs/performance.md"),
     "BYTEWAX_TPU_COORDINATOR": ("", "docs/deployment.md"),
     "BYTEWAX_TPU_DEMOTE_AFTER": ("3", "docs/recovery.md"),
     "BYTEWAX_TPU_DIAL_TIMEOUT_S": ("30", "docs/deployment.md"),
@@ -885,38 +884,3 @@ KNOB_PREFIX = "BYTEWAX_TPU_"
 #: seen).
 ENV_READ_CALLS = frozenset({"os.environ.get", "os.getenv"})
 ENV_MAPPING = "os.environ"
-
-# ---------------------------------------------------------------------------
-# BTX-BACKEND — standalone scripts must force a backend
-# ---------------------------------------------------------------------------
-
-#: Entry points that start the engine (and therefore initialize jax).
-RUN_ENTRY_POINTS = frozenset(
-    {
-        "bytewax_tpu.engine.driver.run_main",
-        "bytewax_tpu.engine.driver.cluster_main",
-        "bytewax_tpu.testing.run_main",
-        "bytewax_tpu.testing.cluster_main",
-        "bytewax_tpu.run.cli_main",
-    }
-)
-
-#: Bare call names treated as run entry points inside scripts.
-RUN_ENTRY_NAMES = frozenset({"run_main", "cluster_main", "cli_main"})
-
-#: Helpers that force a backend choice.
-FORCE_HELPERS = frozenset(
-    {
-        "bytewax_tpu.utils.force_platform",
-        "bytewax_tpu.utils.force_cpu_mesh",
-    }
-)
-FORCE_HELPER_NAMES = frozenset({"force_platform", "force_cpu_mesh"})
-
-#: Environment keys whose assignment forces a backend before jax
-#: initializes (the driver reads BYTEWAX_TPU_PLATFORM; jax reads
-#: JAX_PLATFORMS).
-FORCE_ENV_KEYS = frozenset({"BYTEWAX_TPU_PLATFORM", "JAX_PLATFORMS"})
-
-#: jax config flags whose update forces a backend.
-FORCE_JAX_FLAGS = frozenset({"jax_platforms", "jax_platform_name"})

@@ -275,7 +275,6 @@ class Module:
         "rel",
         "tree",
         "source",
-        "is_script",
         "bindings",
         "functions",
         "classes",
@@ -284,16 +283,13 @@ class Module:
         "scope_assigns",
     )
 
-    def __init__(
-        self, name: str, path: Path, source: str, is_script: bool
-    ):
+    def __init__(self, name: str, path: Path, source: str):
         self.name = name
         self.path = path
         #: Display path used in diagnostics (set by the loader).
         self.rel = str(path)
         self.source = source
         self.tree = ast.parse(source, filename=str(path))
-        self.is_script = is_script
         #: local name -> dotted target ("jax", "bytewax_tpu.engine.
         #: comm.Comm", ...), collected from every import statement in
         #: the file (function-local imports included).
@@ -338,16 +334,16 @@ class Project:
     @classmethod
     def load(
         cls,
-        files: Iterable[Tuple[str, Path, bool]],
+        files: Iterable[Tuple[str, Path]],
         rel_root: Optional[Path] = None,
     ) -> "Project":
-        """Build a project from ``(module_name, path, is_script)``
-        triples.  Files that fail to parse raise SyntaxError — a
-        contract checker must not skip unparseable engine code."""
+        """Build a project from ``(module_name, path)`` pairs.  Files
+        that fail to parse raise SyntaxError — a contract checker
+        must not skip unparseable engine code."""
         proj = cls()
-        for name, path, is_script in files:
+        for name, path in files:
             source = Path(path).read_text()
-            mod = Module(name, Path(path), source, is_script)
+            mod = Module(name, Path(path), source)
             if rel_root is not None:
                 try:
                     mod.rel = str(

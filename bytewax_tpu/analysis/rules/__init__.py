@@ -17,7 +17,6 @@ from typing import Callable, Dict, List, Optional
 from bytewax_tpu.analysis.diagnostics import Diagnostic
 from bytewax_tpu.analysis.resolver import Project
 from bytewax_tpu.analysis.rules import (
-    backend,
     drain,
     fault,
     frames,
@@ -38,7 +37,6 @@ ALL_RULES: Dict[str, Callable[[Project], List[Diagnostic]]] = {
     frames.RULE_ID: frames.check,
     fault.RULE_ID: fault.check,
     snapshot.RULE_ID: snapshot.check,
-    backend.RULE_ID: backend.check,
     drain.RULE_ID: drain.check,
     thread.RULE_ID: thread.check,
     knobs.RULE_ID: knobs.check,

@@ -322,20 +322,28 @@ class DirSource(FixedPartitionedSource[str, int]):
 class _LineTap:
     """Pass-through line iterator remembering the last line handed
     out — when ``csv`` raises mid-parse, the remembered line is the
-    poison payload for the dead-letter record."""
+    poison payload for the dead-letter record — and whether any line
+    since the last :meth:`take_nul` carried a NUL byte."""
 
-    __slots__ = ("_lines", "last")
+    __slots__ = ("_lines", "last", "_nul")
 
     def __init__(self, lines):
         self._lines = lines
         self.last: Optional[str] = None
+        self._nul = False
 
     def __iter__(self):
         return self
 
     def __next__(self):
         self.last = next(self._lines)
+        if "\x00" in self.last:
+            self._nul = True
         return self.last
+
+    def take_nul(self) -> bool:
+        nul, self._nul = self._nul, False
+        return nul
 
 
 def _read_rows_dlq(
@@ -343,23 +351,28 @@ def _read_rows_dlq(
 ):
     """Pull up to ``limit`` rows (None = all) off a csv reader,
     dead-lettering parser-rejected rows — with the line the parse
-    died on, via ``tap`` — into ``dead`` instead of raising.
-    Returns ``(rows, captured_count)``."""
+    died on, via ``tap`` — into ``dead`` instead of raising.  A row
+    with an embedded NUL is poison by the connector's own contract:
+    the ``csv`` module rejected it until Python 3.11 and accepts it
+    since, so the check lives here.  Returns ``(rows,
+    captured_count)``."""
     out: List[Dict[str, str]] = []
     captured = 0
     while limit is None or len(out) < limit:
+        error = None
         try:
-            out.append(next(reader))
+            row = next(reader)
         except StopIteration:
             break
         except csv.Error as ex:
+            error = f"{type(ex).__name__}: {ex}"
+        if tap.take_nul() and error is None:
+            error = "Error: line contains NUL"
+        if error is None:
+            out.append(row)
+        else:
             captured += 1
-            dead.append(
-                {
-                    "error": f"{type(ex).__name__}: {ex}",
-                    "payload": tap.last,
-                }
-            )
+            dead.append({"error": error, "payload": tap.last})
     return out, captured
 
 

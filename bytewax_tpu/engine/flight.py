@@ -1282,23 +1282,31 @@ _compile_listener_on = False
 
 
 def ensure_compile_listener() -> None:
-    """Register a ``jax.monitoring`` listener (once per process) that
-    counts backend compiles and their seconds.  Safe to call before
+    """Register ``jax.monitoring`` listeners (once per process) that
+    count backend compiles and their seconds.  Safe to call before
     any backend is up — ``jax.monitoring`` imports without
-    initializing devices — and a jax without the monitoring API just
-    leaves the compile families at zero."""
+    initializing devices.  A program loaded from the persistent
+    compilation cache is not a compile: jax reports the cache hit
+    just before the duration event of the same request, on the same
+    thread, and that duration (the load) is left out."""
     global _compile_listener_on
     if _compile_listener_on:
         return
-    try:
-        from jax import monitoring
-    except ImportError:  # pragma: no cover - jax is a hard dep here
-        return
+    from jax import monitoring
 
     from bytewax_tpu._metrics import xla_compile_count, xla_compile_seconds
 
+    cache_hit = threading.local()
+
+    def _on_event(name: str, **_kw: Any) -> None:
+        if name.endswith("compilation_cache/cache_hits"):
+            cache_hit.pending = True
+
     def _on_duration(name: str, secs: float, **_kw: Any) -> None:
         if not name.endswith("backend_compile_duration"):
+            return
+        if getattr(cache_hit, "pending", False):
+            cache_hit.pending = False
             return
         xla_compile_count.inc()
         xla_compile_seconds.inc(secs)
@@ -1306,5 +1314,6 @@ def ensure_compile_listener() -> None:
         RECORDER.count("xla_compile_seconds", secs)
         RECORDER.record("xla_compile", seconds=round(secs, 6))
 
+    monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
     _compile_listener_on = True

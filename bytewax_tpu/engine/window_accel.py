@@ -688,32 +688,43 @@ class DeviceWindowAggState:
             _WindowSnapshot,
         )
 
+        # One pass over the open-window table and ONE device fetch
+        # for all requested keys: a scan and a fetch per key is
+        # O(keys x open windows) host work plus a whole-table
+        # readback per key, which an epoch close over 10^5 touched
+        # keys never finishes.
+        wanted = {self.key_ids.get(key) for key in keys}
+        open_of: Dict[int, List[Tuple[int, float]]] = {}
+        for (kid, wid), close_us in self.open_close_us.items():
+            if kid in wanted:
+                open_of.setdefault(kid, []).append((wid, close_us))
+        state_of = dict(
+            self.agg.snapshots_for(
+                [
+                    f"{self.keys[kid]}\x00{wid}"
+                    for kid, wins in open_of.items()
+                    for wid, _close_us in wins
+                ]
+            )
+        )
         out = []
         for key in keys:
             kid = self.key_ids.get(key)
-            if kid is None or not any(
-                k2 == kid for (k2, _w) in self.open_close_us
-            ):
+            if kid not in open_of:
                 out.append((key, None))
                 continue
             opened = {}
-            comps = []
-            wids = []
-            for (k2, wid), close_us in self.open_close_us.items():
-                if k2 == kid:
-                    open_dt = datetime.fromtimestamp(
-                        (close_us - self.spec.length_us) / _US,
-                        tz=timezone.utc,
-                    )
-                    close_dt = datetime.fromtimestamp(
-                        close_us / _US, tz=timezone.utc
-                    )
-                    opened[wid] = WindowMetadata(open_dt, close_dt)
-                    comps.append(f"{key}\x00{wid}")
-                    wids.append(wid)
-            states = dict(
-                zip(wids, (s for _c, s in self.agg.snapshots_for(comps)))
-            )
+            states = {}
+            for wid, close_us in open_of[kid]:
+                open_dt = datetime.fromtimestamp(
+                    (close_us - self.spec.length_us) / _US,
+                    tz=timezone.utc,
+                )
+                close_dt = datetime.fromtimestamp(
+                    close_us / _US, tz=timezone.utc
+                )
+                opened[wid] = WindowMetadata(open_dt, close_dt)
+                states[wid] = state_of[f"{key}\x00{wid}"]
             base = self.base_us[kid]
             clock_state = _EventClockState(
                 system_time_of_max_event=datetime.fromtimestamp(
@@ -778,15 +789,36 @@ class DeviceWindowAggState:
 
     def load(self, key: str, snap: Any) -> None:
         """Resume from a host-tier ``_WindowSnapshot``."""
-        kids = self._key_ids_for([key])
-        kid = int(kids[0])
-        self._load_clock(kid, snap)
+        self.load_many([(key, snap)])
+
+    def load_many(self, items: List[Tuple[str, Any]]) -> None:
+        """Batched resume: the per-key bookkeeping stays host Python,
+        the fold states of the whole page install with ONE scatter
+        per field (a device dispatch per window per field does not
+        finish at 10^5 keys)."""
+        slot_states: List[Tuple[str, Any]] = []
+        # One id allocation for the page: the clock arrays grow once.
+        kids = self._key_ids_for([key for key, _snap in items]).tolist()
+        for kid, (key, snap) in zip(kids, items):
+            self._load_clock(kid, snap)
+            slot_states.extend(self._load_windows(key, kid, snap))
+        self._open_cache = None
+        self.agg.load_many(slot_states)
+        # Queued values fold ON TOP of the installed states.
+        for kid, (_key, snap) in zip(kids, items):
+            self._replay_queue(kid, snap)
+
+    def _load_windows(
+        self, key: str, kid: int, snap: Any
+    ) -> List[Tuple[str, Any]]:
+        """Reopen one key's windows; returns the ``(slot key, fold
+        state)`` pairs to install on device."""
         for wid, meta in snap.windower_state.opened.items():
             self.open_close_us[(kid, wid)] = _to_us(meta.close_time)
-        self._open_cache = None
-        for wid, state in snap.logic_states.items():
-            self.agg.load(f"{key}\x00{wid}", state)
-        self._replay_queue(kid, snap)
+        return [
+            (f"{key}\x00{wid}", state)
+            for wid, state in snap.logic_states.items()
+        ]
 
     # -- residency (engine/residency.py) ------------------------------------
     #
@@ -820,8 +852,7 @@ class DeviceWindowAggState:
     def inject_keys(self, items: List[Tuple[str, Any]]) -> None:
         """Reinstate previously-extracted keys from their host-tier
         ``_WindowSnapshot``s."""
-        for key, snap in items:
-            self.load(key, snap)
+        self.load_many(items)
 
 
 class DeviceSessionAggState(DeviceWindowAggState):
@@ -1000,16 +1031,28 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 )
         return acc
 
-    def _session_acc(self, kid: int, wid: int, discard: bool) -> Any:
-        slot_keys = self.session_slots[(kid, wid)]
-        acc = self._combine(
-            [s for _k, s in self.agg.snapshots_for(slot_keys)]
+    def _session_accs(
+        self, sessions: List[Tuple[int, int]], discard: bool
+    ) -> List[Any]:
+        """Accumulators of the given ``(kid, wid)`` sessions, in
+        order, from ONE device fetch over all their slots (a fetch
+        per session reads the whole table back each time)."""
+        slot_lists = [self.session_slots[kw] for kw in sessions]
+        snap_of = dict(
+            self.agg.snapshots_for(
+                [sk for slot_keys in slot_lists for sk in slot_keys]
+            )
         )
+        accs = [
+            self._combine([snap_of[sk] for sk in slot_keys])
+            for slot_keys in slot_lists
+        ]
         if discard:
-            for sk in slot_keys:
-                self.agg.discard(sk)
-            del self.session_slots[(kid, wid)]
-        return acc
+            for kw, slot_keys in zip(sessions, slot_lists):
+                for sk in slot_keys:
+                    self.agg.discard(sk)
+                del self.session_slots[kw]
+        return accs
 
     def _close_due(
         self, now_us: float, clock=None
@@ -1029,11 +1072,11 @@ class DeviceSessionAggState(DeviceWindowAggState):
             return []
         from bytewax_tpu.operators.windowing import WindowMetadata
 
+        due = [(int(kids_arr[i]), int(wids_arr[i])) for i in due_rows]
         events = []
-        for i in due_rows:
-            kid, wid = int(kids_arr[i]), int(wids_arr[i])
+        accs = self._session_accs(due, discard=True)
+        for (kid, wid), acc in zip(due, accs):
             key = self.keys[kid]
-            acc = self._session_acc(kid, wid, discard=True)
             s = self.sessions[kid].pop(wid)
             del self.open_close_us[(kid, wid)]
             events.append((key, (wid, "E", self._finalize_one(acc))))
@@ -1059,6 +1102,18 @@ class DeviceSessionAggState(DeviceWindowAggState):
             _WindowSnapshot,
         )
 
+        open_sessions = [
+            (kid, wid)
+            for kid in (self.key_ids.get(key) for key in keys)
+            if kid is not None
+            for wid in self.sessions.get(kid, {})
+        ]
+        acc_of = dict(
+            zip(
+                open_sessions,
+                self._session_accs(open_sessions, discard=False),
+            )
+        )
         out = []
         for key in keys:
             kid = self.key_ids.get(key)
@@ -1074,10 +1129,7 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 )
                 for wid, s in sess.items()
             }
-            states = {
-                wid: self._session_acc(kid, wid, discard=False)
-                for wid in sess
-            }
+            states = {wid: acc_of[(kid, wid)] for wid in sess}
             base = self.base_us[kid]
             clock_state = _EventClockState(
                 system_time_of_max_event=datetime.fromtimestamp(
@@ -1106,10 +1158,11 @@ class DeviceSessionAggState(DeviceWindowAggState):
             )
         return out
 
-    def load(self, key: str, snap: Any) -> None:
-        """Resume from a host-tier session ``_WindowSnapshot``."""
-        kid = int(self._key_ids_for([key])[0])
-        self._load_clock(kid, snap)
+    def _load_windows(
+        self, key: str, kid: int, snap: Any
+    ) -> List[Tuple[str, Any]]:
+        """Session variant: reopen one key's sessions from a
+        host-tier session ``_WindowSnapshot``."""
         st = snap.windower_state
         self.next_wid[kid] = st.next_id
         sess = self.sessions.setdefault(kid, {})
@@ -1122,12 +1175,12 @@ class DeviceSessionAggState(DeviceWindowAggState):
             ]
             self.session_slots[(kid, wid)] = []
             self.open_close_us[(kid, wid)] = _to_us(meta.close_time) + gap
-        self._open_cache = None
         # A snapshot taken between a windower merge and the logic
         # merge has the sessions dict merged but logic states still
         # split per pre-merge id; resolve each state to its surviving
         # session (chasing chained merges).
         into = dict(st.merge_queue)
+        slot_states = []
         for wid, state in snap.logic_states.items():
             target = wid
             seen = set()
@@ -1138,9 +1191,9 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 continue
             slot_key = f"{key}\x00{target}\x00{self._slot_seq}"
             self._slot_seq += 1
-            self.agg.load(slot_key, state)
+            slot_states.append((slot_key, state))
             self.session_slots[(kid, target)].append(slot_key)
-        self._replay_queue(kid, snap)
+        return slot_states
 
     def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
         """Session variant of the residency extract: open sessions

@@ -156,9 +156,13 @@ class DeviceAggState:
         grown = {}
         for name, (init, _op) in self.kind.fields.items():
             old = self._fields[name]
+            # Identities in the accumulator dtype (see
+            # segment.update_fields): a float identity does not cast
+            # safely into an integer table.
+            ident = identity_for(init, old.dtype)
             # The old scratch slot becomes a real slot: clear it.
-            old = old.at[self.capacity - 1].set(init)
-            pad = jnp.full((new_cap - self.capacity,), init, dtype=old.dtype)
+            old = old.at[self.capacity - 1].set(ident)
+            pad = jnp.full((new_cap - self.capacity,), ident, dtype=old.dtype)
             arr = jnp.concatenate([old, pad])
             if self.sharding is not None:
                 arr = jax.device_put(arr, self.sharding)
@@ -226,7 +230,10 @@ class DeviceAggState:
         slots_np[:n] = self._pending_reset
         slots = jnp.asarray(slots_np)
         for name, (init, _op) in self.kind.fields.items():
-            self._fields[name] = self._fields[name].at[slots].set(init)
+            arr = self._fields[name]
+            self._fields[name] = arr.at[slots].set(
+                identity_for(init, arr.dtype)
+            )
         self._pending_reset.clear()
 
     def update_slots(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
@@ -380,8 +387,8 @@ class DeviceAggState:
         )
 
     def _fetch(self) -> Dict[str, np.ndarray]:
-        """One stacked device→host transfer for all fields (device
-        round-trips dominate over tunneled links)."""
+        """One stacked device→host transfer for all fields (one
+        round-trip instead of one per field)."""
         names = list(self.kind.fields)
         stacked = np.asarray(
             jnp.stack([self._fields[name] for name in names])
@@ -527,11 +534,16 @@ class DeviceAggState:
             return
         self._maybe_lock_int(items[0][1])
         names = list(self.kind.fields)
+        # Pad to a bucket (repeating the first row — set is
+        # idempotent) so pages of any length share a few compiled
+        # shapes.
+        n = len(items)
+        padded = pad_len(n, floor_pow=3)
         cols = {
-            name: np.empty(len(items), dtype=np.dtype(self.dtype))
+            name: np.empty(padded, dtype=np.dtype(self.dtype))
             for name in names
         }
-        slots = np.empty(len(items), dtype=np.int32)
+        slots = np.empty(padded, dtype=np.int32)
         for i, (key, state) in enumerate(items):
             fv = self._field_vals(state)
             # alloc reuses freed (evicted/discarded) slots and grows
@@ -540,6 +552,9 @@ class DeviceAggState:
             slots[i] = self.alloc(key)
             for name in names:
                 cols[name][i] = fv[name]
+        slots[n:] = slots[0]
+        for name in names:
+            cols[name][n:] = cols[name][0]
         self._ensure_fields()
         _flight.note_transfer(
             "h2d",

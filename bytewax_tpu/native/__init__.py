@@ -41,14 +41,33 @@ _host_ops: Any = None
 _host_ops_tried = False
 
 
+def _cpu_features() -> str:
+    """The CPU's feature set as the kernel reports it (``flags`` on
+    x86, ``Features`` on arm); the processor name where there is no
+    ``/proc/cpuinfo``."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line.partition(":")[2].strip()
+    except OSError:
+        pass
+    return platform.processor()
+
+
 def _hashed_out_path(stem: str, src: Path, flags, *extra: str) -> Path:
-    """Cache key = source content + compiler flags + host identity
-    (a stale or foreign binary can SIGILL); binaries are gitignored,
-    never shipped."""
+    """Cache key = source content + compiler flags + host identity;
+    binaries are gitignored, never shipped.  A tree copied to another
+    machine carries them along, and a ``-march=native`` binary uses
+    whatever the building CPU had (it can SIGILL elsewhere), so such
+    a build is also keyed by the CPU's feature set: a binary built on
+    a different CPU is never found by name."""
     h = hashlib.sha256()
     h.update(src.read_bytes())
     h.update(" ".join(flags).encode())
     h.update(platform.machine().encode())
+    if "-march=native" in flags:
+        h.update(_cpu_features().encode())
     for part in extra:
         h.update(part.encode())
     return _HERE / f"{stem}-{h.hexdigest()[:12]}.so"

@@ -7,13 +7,14 @@ whole slot table with a masked VPU reduction (one-hot compare +
 reduce) — collision-free, VMEM-resident, and tiled to the VPU lanes —
 computing every aggregation field of the kind in one pass over a
 single mask, then combines tiles into the accumulator across grid
-steps.
+steps.  The table is reduced one ``_CAP_BLOCK`` of slots at a time so
+the mask's size does not grow with capacity.
 
 Enable with ``BYTEWAX_TPU_PALLAS=1`` (on non-TPU backends the same
 kernel runs in interpret mode, so tests exercise it).  Scope: float32
-accumulators with slot tables up to a few thousand keys (the
-``TILE × capacity`` mask must fit in VMEM); integer states and the
-dictionary-encoded/packed wire paths keep the exact XLA scatter.
+accumulators with slot tables up to a few thousand keys (the work
+is rows × capacity); integer states and the dictionary-encoded/packed
+wire paths keep the exact XLA scatter.
 """
 
 import functools
@@ -28,9 +29,15 @@ from bytewax_tpu.ops.segment import AggKind
 
 __all__ = ["enabled", "fits", "maybe_update_fields", "update_fields_pallas"]
 
+#: Rows reduced per grid step.
 _TILE = 512
-#: Max slot-table size for the one-hot strategy (TILE×CAP f32 mask in
-#: VMEM: 512×4096×4B = 8MB, within a v5e core's 16MB less headroom).
+#: Slots reduced per grid step: the one-hot mask and each field's
+#: masked copy are ``_TILE x _CAP_BLOCK`` f32 temporaries (1 MiB), so
+#: a handful of them stay far inside a core's scoped VMEM whatever
+#: the table's capacity.
+_CAP_BLOCK = 512
+#: Max slot-table size for the one-hot strategy: its work is rows x
+#: capacity, so it is only ever a candidate for small tables.
 _MAX_CAP = 4096
 
 
@@ -42,36 +49,48 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _fold_kernel(field_ops, slots_ref, vals_ref, out_ref):
-    """``field_ops`` is a static tuple of (field_index, op_name,
-    init, is_count); the one-hot mask is built once and reused for
-    every field."""
-    step = pl.program_id(0)
+def _fold_kernel(field_ops, cap_block, slots_ref, vals_ref, out_ref):
+    """One ``(capacity block, row tile)`` grid step.  ``field_ops`` is
+    a static tuple of (field_index, op_name, init, is_count); the
+    one-hot mask is built once and reused for every field.  Rows
+    arrive as ``[TILE, 1]`` columns (sublane-major, as the mask needs
+    them: no relayout in the kernel) and broadcast along the lanes
+    against the block's slot numbers; the row-tile axis is the inner
+    grid axis, so the output block stays resident while every tile
+    folds into it."""
+    tile = pl.program_id(1)
 
-    @pl.when(step == 0)
+    @pl.when(tile == 0)
     def _init():
         for idx, _op, init, _is_count in field_ops:
-            out_ref[idx, :] = jnp.full_like(out_ref[idx, :], init)
+            out_ref[idx : idx + 1, :] = jnp.full(
+                (1, cap_block), init, dtype=jnp.float32
+            )
 
-    slots = slots_ref[:, :]  # [1, TILE] int32
-    vals = vals_ref[:, :]  # [1, TILE] f32
-    cap = out_ref.shape[1]
-    hit = slots.reshape(_TILE, 1) == jax.lax.broadcasted_iota(
-        jnp.int32, (_TILE, cap), 1
+    slots = slots_ref[:, :]  # [TILE, 1] int32
+    vals = vals_ref[:, :]  # [TILE, 1] f32
+    first_slot = pl.program_id(0) * cap_block
+    hit = slots == first_slot + jax.lax.broadcasted_iota(
+        jnp.int32, (_TILE, cap_block), 1
     )
-    contrib = vals.reshape(_TILE, 1)
-    ones = jnp.ones((_TILE, 1), dtype=jnp.float32)
     for idx, op_name, _init, is_count in field_ops:
-        c = ones if is_count else contrib
+        row = out_ref[idx : idx + 1, :]
         if op_name == "add":
-            part = jnp.sum(jnp.where(hit, c, 0.0), axis=0)
-            out_ref[idx, :] += part
+            c = 1.0 if is_count else vals
+            part = jnp.sum(
+                jnp.where(hit, c, 0.0), axis=0, keepdims=True
+            )
+            out_ref[idx : idx + 1, :] = row + part
         elif op_name == "min":
-            part = jnp.min(jnp.where(hit, c, jnp.inf), axis=0)
-            out_ref[idx, :] = jnp.minimum(out_ref[idx, :], part)
+            part = jnp.min(
+                jnp.where(hit, vals, jnp.inf), axis=0, keepdims=True
+            )
+            out_ref[idx : idx + 1, :] = jnp.minimum(row, part)
         else:  # max
-            part = jnp.max(jnp.where(hit, c, -jnp.inf), axis=0)
-            out_ref[idx, :] = jnp.maximum(out_ref[idx, :], part)
+            part = jnp.max(
+                jnp.where(hit, vals, -jnp.inf), axis=0, keepdims=True
+            )
+            out_ref[idx : idx + 1, :] = jnp.maximum(row, part)
 
 
 @functools.partial(jax.jit, static_argnames=("kind",), donate_argnums=(1,))
@@ -95,7 +114,13 @@ def update_fields_pallas(
             [values, jnp.zeros((pad,), dtype=values.dtype)]
         )
     n_padded = slot_ids.shape[0]
-    grid = n_padded // _TILE
+    cap_block = min(capacity, _CAP_BLOCK)
+    if capacity % cap_block:
+        msg = (
+            f"slot-table capacity {capacity} is not a multiple of "
+            f"the kernel's {cap_block}-slot block"
+        )
+        raise ValueError(msg)
 
     names = list(kind.fields)
     field_ops = tuple(
@@ -103,20 +128,20 @@ def update_fields_pallas(
         for i, name in enumerate(names)
     )
     partials = pl.pallas_call(
-        functools.partial(_fold_kernel, field_ops),
+        functools.partial(_fold_kernel, field_ops, cap_block),
         out_shape=jax.ShapeDtypeStruct((len(names), capacity), jnp.float32),
-        grid=(grid,),
+        grid=(capacity // cap_block, n_padded // _TILE),
         in_specs=[
-            pl.BlockSpec((1, _TILE), lambda i: (0, i)),
-            pl.BlockSpec((1, _TILE), lambda i: (0, i)),
+            pl.BlockSpec((_TILE, 1), lambda c, t: (t, 0)),
+            pl.BlockSpec((_TILE, 1), lambda c, t: (t, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (len(names), capacity), lambda i: (0, 0)
+            (len(names), cap_block), lambda c, t: (0, c)
         ),
         interpret=_interpret(),
     )(
-        slot_ids.reshape(1, n_padded).astype(jnp.int32),
-        values.reshape(1, n_padded).astype(jnp.float32),
+        slot_ids.reshape(n_padded, 1).astype(jnp.int32),
+        values.reshape(n_padded, 1).astype(jnp.float32),
     )
 
     out = {}

@@ -252,8 +252,12 @@ def zscore_scan_body(
     prefix sums of *pivot-shifted* values (the segment head's value is
     the pivot, so the ``sumsq - sum²/n`` form stays well-conditioned),
     then merged with each key's persistent table state via Chan's
-    parallel Welford combine — native cumsum lowering, no custom
-    associative-scan combine on the hot path.  Counts ride int32
+    parallel Welford combine.  The prefix sums restart at every
+    segment head (a flagged scan over plain sums): one running total
+    over the whole batch, differenced at the heads, leaves each
+    segment the float32 precision of the batch's total, not of its
+    own rows, and at a few hundred thousand rows that is none.
+    Counts ride int32
     end-to-end (an fp32 count freezes at 2^24 rows; the int path keeps
     parity with the host tier's exact-int Welford state for arbitrary
     stream lengths), cast to float only for the mean/m2 divisions.
@@ -274,16 +278,27 @@ def zscore_scan_body(
     pivot = vals[head_idx]
     d = vals - pivot
 
-    def seg_excl(col):
-        """Exclusive in-segment prefix sum."""
-        c = jnp.cumsum(col)
-        excl = c - col
-        return excl - excl[head_idx]
+    def restart_at_heads(a, b):
+        (fa, sa, qa), (fb, sb, qb) = a, b
+        return (
+            fa | fb,
+            jnp.where(fb, sb, sa + sb),
+            jnp.where(fb, qb, qa + qb),
+        )
 
+    # Inclusive in-segment sums of d and d^2, then the same one row
+    # earlier (zero at the heads) for the pre-update state.
+    _f, si, qi = jax.lax.associative_scan(
+        restart_at_heads, (seg_start, d, d * d)
+    )
+
+    def before_row(incl):
+        prev = jnp.concatenate([jnp.zeros((1,), dtype=f), incl[:-1]])
+        return jnp.where(seg_start, 0.0, prev)
+
+    ps, pq = before_row(si), before_row(qi)
     # Prior rows of this key in the batch — exact int32 arithmetic.
-    pn_i = seg_excl(jnp.ones((n,), dtype=jnp.int32))
-    ps = seg_excl(d)
-    pq = seg_excl(d * d)
+    pn_i = idx - head_idx
 
     def around_pivot(cnt_f, s, q):
         """(mean, m2) of a shifted prefix sum triple."""
@@ -315,9 +330,7 @@ def zscore_scan_body(
     # Segment tails write table carry ⊕ inclusive in-batch state back;
     # every other row is redirected to the scratch slot (arbitrary
     # values there are fine — padding already targets it).
-    mean_i, m2_i = around_pivot(
-        pn_i.astype(f) + 1, ps + d, pq + d * d
-    )
+    mean_i, m2_i = around_pivot(pn_i.astype(f) + 1, si, qi)
     s_n, s_mean, s_m2 = chan_merge(n0_i, mean0, m20, pn_i + 1, mean_i, m2_i)
     seg_end = jnp.concatenate(
         [slots[1:] != slots[:-1], jnp.ones((1,), dtype=bool)]

@@ -15,7 +15,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "SHARD_AXIS",
-    "distributed_is_initialized",
     "key_sharding",
     "make_mesh",
     "replicated",
@@ -23,27 +22,10 @@ __all__ = [
 ]
 
 
-def distributed_is_initialized() -> bool:
-    """Whether the jax distributed runtime is up.
-    ``jax.distributed.is_initialized`` postdates some jax versions
-    this runs on; fall back to the runtime state's client handle."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    from jax._src import distributed as _dist
-
-    return _dist.global_state.client is not None
-
 #: Mesh axis over which keyed state is sharded.
 SHARD_AXIS = "shard"
 
-# ``jax.shard_map`` was promoted out of jax.experimental after 0.4.x;
-# resolve whichever spelling this jax has so the sharded tier runs on
-# both.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 
 def make_mesh(

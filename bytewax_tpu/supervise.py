@@ -61,6 +61,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from bytewax_tpu.engine import flight as _flight
 from bytewax_tpu.engine.backoff import Backoff, seeded_rng
+from bytewax_tpu.utils import chip_env
 
 __all__ = [
     "ClusterSupervisor",
@@ -280,6 +281,9 @@ class ClusterSupervisor:
         self.snapshot_interval_s = snapshot_interval_s
         self.backup_interval_s = backup_interval_s
         self.env_extra = dict(env or {})
+        # Refuse before anything starts when MAX device-tier children
+        # would not each get a chip of their own.
+        chip_env(0, max_procs, dict(os.environ, **self.env_extra))
         self.hint_fn = hint_fn
         self.log_dir = log_dir
         self.workdir = workdir
@@ -391,6 +395,10 @@ class ClusterSupervisor:
     def _child_env(self, proc_id: int) -> Dict[str, str]:
         env = dict(os.environ)
         env.update(self.env_extra)
+        # One chip per child (docs/deployment.md "One process per
+        # chip"); the constructor already refused a MAX the host has
+        # no chips for.
+        env.update(chip_env(proc_id, self.max_procs, env))
         env["BYTEWAX_TPU_REUSEPORT"] = "1"
         if self.addresses:
             env["BYTEWAX_ADDRESSES"] = ";".join(self.addresses)

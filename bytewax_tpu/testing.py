@@ -368,9 +368,21 @@ def _cluster_test_main() -> None:
         holders.append(s)
         addresses.append(f"127.0.0.1:{s.getsockname()[1]}")
 
+    from bytewax_tpu.utils import chip_env
+
+    try:
+        chip_envs = [
+            chip_env(proc_id, args.processes, dict(os.environ))
+            for proc_id in range(args.processes)
+        ]
+    except RuntimeError as ex:
+        # Refuse before anything starts (docs/deployment.md "One
+        # process per chip").
+        parser.exit(2, f"{parser.prog}: {ex}\n")
+
     procs = []
     for proc_id in range(args.processes):
-        env = dict(os.environ)
+        env = dict(os.environ, **chip_envs[proc_id])
         # The children must rebind the ports this parent is holding;
         # production binds stay exclusive (see engine/comm.py).
         env["BYTEWAX_TPU_REUSEPORT"] = "1"

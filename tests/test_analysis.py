@@ -20,10 +20,9 @@ FIXTURES = Path(__file__).resolve().parent / "analysis_fixtures"
 REPO = FIXTURES.parent.parent
 
 
-def _diags(name, rules=None, scripts=False):
+def _diags(name, rules=None):
     diags, _suppressed, _project = analyze_paths(
         [FIXTURES / name],
-        scripts=scripts,
         rule_ids=rules,
         rel_root=REPO,
     )
@@ -259,17 +258,6 @@ def test_new_rule_waiver_round_trip(tmp_path):
             [waived], rule_ids=[rule], rel_root=tmp_path
         )
         assert not after, (name, after)
-
-
-def test_backend_rule_flags_unforced_script():
-    diags = _diags(
-        "fixture_backend_script.py", ["BTX-BACKEND"], scripts=True
-    )
-    assert [d.rule for d in diags] == ["BTX-BACKEND"]
-    assert "run entry point" in diags[0].message
-    # The same file scanned as a library module is exempt: only
-    # standalone execution reaches jax init unforced.
-    assert not _diags("fixture_backend_script.py", ["BTX-BACKEND"])
 
 
 # -- waivers ----------------------------------------------------------------

@@ -604,7 +604,6 @@ def test_knob_catalog_is_pinned():
         "BYTEWAX_TPU_CKPT_ASYNC",
         "BYTEWAX_TPU_CKPT_COMPACT_EVERY",
         "BYTEWAX_TPU_CKPT_DELTA",
-        "BYTEWAX_TPU_COMPILE_CACHE",
         "BYTEWAX_TPU_COORDINATOR",
         "BYTEWAX_TPU_DEMOTE_AFTER",
         "BYTEWAX_TPU_DIAL_TIMEOUT_S",
@@ -654,7 +653,7 @@ def test_knob_catalog_is_pinned():
         "BYTEWAX_TPU_TRACE_DIR",
         "BYTEWAX_TPU_WIRE",
     ]
-    assert len(contracts.KNOBS) == 59
+    assert len(contracts.KNOBS) == 58
     for name, (default, doc) in contracts.KNOBS.items():
         assert isinstance(default, str), name
         assert doc.startswith("docs/") and doc.endswith(".md"), name

@@ -45,7 +45,6 @@ def test_rule_registry_is_complete():
         "BTX-FRAMES",
         "BTX-FAULT",
         "BTX-SNAPSHOT",
-        "BTX-BACKEND",
         "BTX-DRAIN",
         "BTX-THREAD",
         "BTX-KNOB",
@@ -145,7 +144,7 @@ def test_cli_sarif_full_tree_smoke():
     """CI smoke (satellite of the HBM-resident-aggregate PR): the
     code-scanning upload path — ``--output sarif`` over the FULL
     shipped tree (fixtures only exercised it before) — emits one
-    valid SARIF 2.1.0 document: all 11 rules in the driver inventory,
+    valid SARIF 2.1.0 document: all 10 rules in the driver inventory,
     zero results (the tree is clean), exit 0."""
     import json
 
