@@ -28,6 +28,7 @@ from zlib import adler32
 
 import numpy as np
 
+from bytewax_tpu.engine import flight as _flight
 from bytewax_tpu.inputs import (
     ColumnarBatch,
     FixedPartitionedSource,
@@ -561,11 +562,17 @@ class _ColumnarCSVPartition(StatefulSourcePartition[Any, int]):
         return out
 
     def next_batch(self) -> Any:
-        from bytewax_tpu.ops.text import split_fields
-
         out = self._inner.next_batch()
         if not isinstance(out, ColumnarBatch):
             return out
+        # Ledger: the columnar split and casts (or the csv fallback)
+        # are the `parse` phase, inside the driver's `ingest`.
+        with _flight.span("parse", rows=len(out)):
+            return self._parse(out)
+
+    def _parse(self, out: ColumnarBatch) -> Any:
+        from bytewax_tpu.ops.text import split_fields
+
         lines = out.cols["line"]
         n_quotes = self._count_quotes(lines, self._quote)
         cols = None

@@ -12,6 +12,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from bytewax_tpu.engine import flight as _flight
+
 __all__ = ["ArrayBatch", "TsValue", "VocabMap", "column_ts"]
 
 
@@ -91,6 +93,11 @@ class VocabMap:
         spot-check contract the identity fast path always had), so a
         vocabulary grown by passing ever-longer arrays never pays a
         full prefix re-scan per batch."""
+        # Ledger: the `encode` phase of whichever lane calls.
+        with _flight.span("encode", rows=len(ids)):
+            return self._sync(ids, vocab, alloc_many)
+
+    def _sync(self, ids: np.ndarray, vocab: Any, alloc_many) -> np.ndarray:
         same = vocab is self._ref and (
             # Identity only short-circuits full validation for
             # ndarrays (spot-checked below) — a list mutated in place
@@ -321,6 +328,11 @@ class KeyEncoder:
     def encode(self, keys: np.ndarray, alloc_many) -> np.ndarray:
         """Internal id per row; ``alloc_many([key_str, ...]) -> ids``
         assigns ids for keys seen for the first time."""
+        # Ledger: the `encode` phase of whichever lane calls.
+        with _flight.span("encode", rows=len(keys)):
+            return self._encode(keys, alloc_many)
+
+    def _encode(self, keys: np.ndarray, alloc_many) -> np.ndarray:
         keys = np.asarray(keys)
         if not len(keys):
             # Never install from an empty batch: its dtype kind is

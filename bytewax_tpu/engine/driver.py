@@ -715,8 +715,20 @@ def _supervised(
     try:
         while True:
             started = time.monotonic()
+            # Ledger: "startup" runs from here (flatten, plan, the
+            # driver built) to the first pass of the run loop, where
+            # the driver ends it; "teardown" from the loop's exit to
+            # the return.  Both are ended here, whatever unwinds.
+            lifecycle = (
+                _flight.span("startup").begin(),
+                _flight.span("teardown"),
+            )
             try:
-                result = make(generation, reconfig).run()
+                try:
+                    result = make(generation, reconfig).run(lifecycle)
+                finally:
+                    for sp in lifecycle:
+                        sp.end()
                 if isinstance(result, _Reconfigure):
                     if proc_id >= max(len(result.addresses), 1):
                         # This process retires: the agreed close
@@ -882,12 +894,10 @@ class _OpRt:
             return
         # Ledger: everything the main thread does to move this step's
         # queued deliveries (routing, host folds, pipeline submits) is
-        # the "host" phase; nested leaf phases (flush stalls, restores,
-        # evictions, readbacks) subtract so the sums stay disjoint.
-        rec = _flight.RECORDER
-        rec.phase_push()
-        t0 = time.monotonic()
-        try:
+        # the "host" phase; nested phases (flush stalls, restores,
+        # evictions, readbacks, the work spans) subtract so the sums
+        # stay disjoint.
+        with _flight.span("host", self.op.step_id):
             for port, q in self.queues.items():
                 if q:
                     entries, self.queues[port] = q, []
@@ -906,15 +916,6 @@ class _OpRt:
                             self.process(port, entries)
                     else:
                         self.process(port, entries)
-        finally:
-            gross = time.monotonic() - t0
-            _flight.note_phase(
-                "host",
-                self.op.step_id,
-                max(gross - rec.phase_pop(), 0.0),
-                gross=gross,
-                t0=t0,
-            )
 
     def process(self, port: str, entries: List[Entry]) -> None:
         raise NotImplementedError()
@@ -924,6 +925,17 @@ class _OpRt:
 
     def on_upstream_eof(self) -> None:
         """All upstreams are EOF and queues are drained."""
+
+    def upstream_eof(self) -> None:
+        """Run :meth:`on_upstream_eof` on the ledger's ``eof`` lane.
+        End of input was in no phase before the work spans came, so
+        what it does (the last flush is its own line; then the final
+        close: ``eof/close_scan``, ``eof/fetch``, ``eof/close_emit``,
+        ``eof/emit``) keeps the lane's name and joins no fraction
+        bucket: the buckets read what they read."""
+        _flight.lane_run(
+            "eof", self.op.step_id, self.on_upstream_eof, inline=True
+        )
 
     def emit(self, port: str, entry: Entry) -> None:
         if not len(entry[1]):
@@ -1229,15 +1241,16 @@ class _InputRt(_OpRt):
 
     def poll(self, now: datetime) -> bool:
         progressed = False
-        polled = False
-        t0 = time.monotonic()
+        # Ledger: a pass that polls a partition is "ingest", from the
+        # first poll on (the source's own `parse` inside subtracts).
+        ingest = _flight.span("ingest", self.op.step_id)
         try:
             for name in list(self.parts.keys()):
                 part = self.parts[name]
                 na = self.next_awake[name]
                 if na is not None and na > now:
                     continue
-                polled = True
+                ingest.begin()
                 deferred = self._deferred.pop(name, None)
                 if deferred is not None:
                     if isinstance(deferred, StopIteration):
@@ -1334,13 +1347,7 @@ class _InputRt(_OpRt):
                         part_na = now + _EMPTY_COOLDOWN
                 self.next_awake[name] = part_na
         finally:
-            if polled:
-                _flight.note_phase(
-                    "ingest",
-                    self.op.step_id,
-                    time.monotonic() - t0,
-                    t0=t0,
-                )
+            ingest.end()
         if not self.parts:
             self.eof = True
         return progressed
@@ -1882,12 +1889,15 @@ class _StatefulBatchRt(_OpRt):
         return local
 
     def _emit_window_events(self, events: List[Tuple[str, Any]]) -> None:
-        out: Dict[int, List[Any]] = {}
-        w_count = self.driver.worker_count
-        for key, ev in events:
-            out.setdefault(_route_hash(key) % w_count, []).append((key, ev))
-            self.awoken.add(key)
-        self._flush(out)
+        with _flight.span("emit", self.op.step_id, rows=len(events)):
+            out: Dict[int, List[Any]] = {}
+            w_count = self.driver.worker_count
+            for key, ev in events:
+                out.setdefault(_route_hash(key) % w_count, []).append(
+                    (key, ev)
+                )
+                self.awoken.add(key)
+            self._flush(out)
 
     def _wagg_empty(self) -> bool:
         """Whether the device windower holds no state — including
@@ -2485,12 +2495,9 @@ class _StatefulBatchRt(_OpRt):
             if at is not None and at <= now:
                 # Window close is a drain point: quiesce the pipeline,
                 # then scan/close synchronously as before.  Host-phase
-                # ledger time (the flush stall inside subtracts as its
-                # own leaf).
-                rec = _flight.RECORDER
-                rec.phase_push()
-                t0 = time.monotonic()
-                try:
+                # ledger time (the flush stall and the close's work
+                # spans inside subtract as their own lines).
+                with _flight.span("host", self.op.step_id):
                     self.pipeline_flush()
                     try:
                         with self._timer(
@@ -2502,15 +2509,6 @@ class _StatefulBatchRt(_OpRt):
                             self.op.step_id, "the device window fold", ex
                         )
                     self._emit_window_events(events)
-                finally:
-                    gross = time.monotonic() - t0
-                    _flight.note_phase(
-                        "host",
-                        self.op.step_id,
-                        max(gross - rec.phase_pop(), 0.0),
-                        gross=gross,
-                        t0=t0,
-                    )
             return
         due = sorted(
             (key for key, at in self.sched.items() if at <= now)
@@ -2933,6 +2931,7 @@ class _OutputRt(_OpRt):
         name: str,
         worker: Optional[int],
         write: Callable[[], None],
+        rows: int,
     ) -> None:
         """Run one sink ``write_batch`` through the connector-edge
         retry ladder (docs/recovery.md): typed
@@ -2960,10 +2959,11 @@ class _OutputRt(_OpRt):
         while True:
             try:
                 _faults.fire("sink_write", step=step_id, part=name)
-                with self._timer(
+                with _flight.span("sink", step_id) as sp, self._timer(
                     "out_part_write_batch", worker
                 ).time():
                     write()
+                    sp.rows = rows
                 return
             except BaseException as ex:  # noqa: BLE001
                 if not isinstance(ex, TransientIOError):
@@ -3041,6 +3041,7 @@ class _OutputRt(_OpRt):
                         lambda part=self.parts[name], values=values: (
                             part.write_batch(values)
                         ),
+                        len(values),
                     )
         else:
             for w, items in entries:
@@ -3058,7 +3059,7 @@ class _OutputRt(_OpRt):
                     else:
                         part.write_batch(items)
 
-                self._write_retry(f"worker-{w}", w, _write)
+                self._write_retry(f"worker-{w}", w, _write, len(items))
 
     def epoch_snaps(self) -> List[Tuple[str, Optional[Any]]]:
         if not self.stateful:
@@ -3594,24 +3595,12 @@ class _Driver:
         """Time one engine phase into the epoch ledger (exclusive of
         phases nested inside it) — and, when a tracing backend is
         active, as a nested OTLP span on the existing tracing path."""
-        rec = _flight.RECORDER
-        rec.phase_push()
-        t0 = time.monotonic()
-        try:
+        with _flight.span(phase, step_id):
             if self.trace_ops:
                 with _span("epoch_phase", phase=phase):
                     yield
             else:
                 yield
-        finally:
-            gross = time.monotonic() - t0
-            _flight.note_phase(
-                phase,
-                step_id,
-                max(gross - rec.phase_pop(), 0.0),
-                gross=gross,
-                t0=t0,
-            )
 
     def _close_epoch(self, workers: Optional[range] = None) -> None:
         from bytewax_tpu.tracing import span
@@ -4144,7 +4133,7 @@ class _Driver:
         if not rt.eof:
             rt.drain()
             if rt.op.up_streams():
-                rt.on_upstream_eof()
+                rt.upstream_eof()
                 rt.drain()
             rt.eof = True
         if self.comm is not None:
@@ -4838,8 +4827,14 @@ class _Driver:
             "snapshot_lag_epochs": ckpt_lag,
         }
 
-    def run(self) -> Optional[Any]:
+    def run(
+        self, lifecycle: Optional[Tuple[Any, Any]] = None
+    ) -> Optional[Any]:
         clustered = self.comm is not None
+        # The caller's "startup" span ends at the loop's first pass
+        # and its "teardown" span begins at the loop's exit; the
+        # caller ends both (``_supervised``).
+        startup, teardown = lifecycle or (None, None)
 
         # Flight recorder: ring writes on only when someone can look
         # at them; the compile listener is counters-only and always
@@ -4981,6 +4976,9 @@ class _Driver:
 
         try:
             while True:
+                if startup is not None:
+                    startup.end()
+                    startup = None
                 self._progressed = False
                 now = _now()
 
@@ -5032,7 +5030,7 @@ class _Driver:
                         and not isinstance(rt, _InputRt)
                     ):
                         if rt.op.up_streams() and rt.ups_eof():
-                            rt.on_upstream_eof()
+                            rt.upstream_eof()
                             rt.drain()
                             rt.eof = True
 
@@ -5186,6 +5184,8 @@ class _Driver:
             # reconfigure closes already fenced inside the close; a
             # commit fault here propagates restartable like any
             # other).
+            if teardown is not None:
+                teardown.begin()
             self._ckpt_fence()
         except _Abort:
             aborted = True
@@ -5214,6 +5214,8 @@ class _Driver:
                 # handshake and resumes from the last committed epoch.
             raise
         finally:
+            if teardown is not None:
+                teardown.begin()
             if self._gc_managed:
                 gc.enable()
             # Stop pipeline workers before the mesh/store teardown: a

@@ -19,14 +19,18 @@ epoch protocol.  It keeps, per process:
   epoch close (see ``engine/driver.py``), so process 0's ``/status``
   shows every process;
 - the **epoch ledger**: per-epoch, per-step time attribution
-  (always-on dict adds, like the counters).  Instrumented phase
-  boundaries in the driver, the dispatch pipeline, and the residency
-  manager call :func:`note_phase` with *exclusive* durations — a
-  parent phase (an epoch-close sub-phase, a host drain) subtracts the
-  gross time of phases nested inside it via the phase stack, so the
-  per-epoch sums are disjoint main-thread intervals (the ``device``
-  phase is the exception: it is measured on the pipeline worker and
-  overlaps the host phases by design).  ``note_epoch_close`` seals
+  (always-on dict adds, like the counters).  :class:`span` is the
+  one way a timed interval enters it: a span records *exclusive*
+  time — what its nested spans took is taken out of it through the
+  lane's phase stack — so the per-epoch sums are disjoint intervals
+  on each lane.  The main thread is one lane; a pipeline worker's
+  task is another (:func:`lane_run`), recorded as ``device`` (its
+  self time) and ``device/<phase>`` (its children), which overlap the
+  main thread's phases by design.  The work spans
+  (:data:`TRACED_PHASES`) also enter the profiler's trace as
+  ``btx.<phase>``.  Callers that hold a duration already (a stall, a
+  barrier, a sync round) use :func:`note_phase`.
+  ``note_epoch_close`` seals
   the accumulating ledger into a per-epoch record carrying the
   full-epoch phase breakdown, the close-window breakdown (whose sum
   tracks ``epoch_close_duration_seconds``), source-lag samples, and
@@ -57,6 +61,8 @@ __all__ = [
     "FlightRecorder",
     "enabled",
     "ensure_compile_listener",
+    "lane_fold",
+    "lane_run",
     "ledger_fractions",
     "note_autoscale",
     "note_barrier",
@@ -88,6 +94,7 @@ __all__ = [
     "note_transfer",
     "note_unquarantine",
     "note_wire",
+    "span",
     "wire_status",
     "write_postmortem",
 ]
@@ -114,6 +121,38 @@ _OFF_THREAD_PHASES = frozenset(
     {"device", "collective_lane", "snapshot_lane"}
 )
 
+#: The work spans.  None encloses another on the hot path (a
+#: delivery, a close, a job started with nothing to resume), so on
+#: each thread they are disjoint, as the ledger's sums are, and they
+#: alone enter the profiler's trace (``btx.<phase>``): an idle gap of
+#: the device then reads as the work that covers it.  Parent frames
+#: (``host``, ``ingest``, ``device``, ``readback``, the close phases)
+#: and the spans that only wait for another lane go to the ledger
+#: alone.
+TRACED_PHASES = frozenset(
+    {
+        "startup",
+        "teardown",
+        "parse",
+        "encode",
+        "watermark",
+        "prep",
+        "h2d",
+        "dispatch",
+        "close_scan",
+        "fetch",
+        "close_emit",
+        "emit",
+        "sink",
+    }
+)
+
+
+def _lane_of(phase: str) -> str:
+    """``device/prep`` -> ``device``: a lane's children go where the
+    lane goes (fraction bucket, close breakdown, trace track)."""
+    return phase.split("/", 1)[0]
+
 
 def _truthy(name: str) -> bool:
     """Repo convention (matches ``BYTEWAX_TPU_ACCEL``): unset, empty,
@@ -130,6 +169,19 @@ def enabled() -> bool:
     return _truthy("BYTEWAX_FLIGHT_RECORDER") or _truthy(
         "BYTEWAX_DATAFLOW_API_ENABLED"
     )
+
+
+class _Frame:
+    """An open span's place on its lane's phase stack."""
+
+    __slots__ = ("nested", "step_id", "t0")
+
+    def __init__(self, step_id: str):
+        #: Gross seconds of the spans that ended while this one was
+        #: open: what its exclusive time leaves out.
+        self.nested = 0.0
+        self.step_id = step_id
+        self.t0 = 0.0
 
 
 class FlightRecorder:
@@ -158,10 +210,10 @@ class FlightRecorder:
         #: Phase intervals (phase, step, t0_monotonic, gross_s, lane)
         #: for the Perfetto dump; collected only when trace_dir is set.
         self._spans: List[Tuple[str, str, float, float, int]] = []
-        #: Nested-phase accounting: each frame accumulates the gross
-        #: seconds of phases recorded while it was open, so the parent
-        #: records exclusive time.
-        self._phase_stack: List[List[float]] = []
+        #: Nested-phase accounting for the main thread's lane: the
+        #: frame of each open span, so the parent records exclusive
+        #: time.
+        self._phase_stack: List[_Frame] = []
         #: Max pending tasks observed at each step's pipeline drain.
         self._flush_depth: Dict[str, int] = {}
         #: (step_id, kind) -> latest source-lag sample in seconds.
@@ -193,10 +245,18 @@ class FlightRecorder:
         self._ledger = {}
         self._ledger_pre_close = None
         self._spans = []
-        self._phase_stack = []
+        # The phase stack is left as it is: every frame on it belongs
+        # to a span that is still open (``startup`` is, right now)
+        # and takes itself off when it ends, whatever unwinds.  The
+        # first epoch holds that span whole, so its wall clock starts
+        # where the span did.
         self._flush_depth = {}
         self._lag = {}
-        self._epoch_t0 = time.monotonic()
+        self._epoch_t0 = (
+            self._phase_stack[0].t0
+            if self._phase_stack
+            else time.monotonic()
+        )
 
     # -- hot-path writers --------------------------------------------------
 
@@ -213,17 +273,6 @@ class FlightRecorder:
 
     # -- epoch ledger ------------------------------------------------------
 
-    def phase_push(self) -> None:
-        """Open a parent phase frame: nested phases recorded before
-        the matching :meth:`phase_pop` add their gross time here, so
-        the parent can record exclusive (self) time."""
-        self._phase_stack.append([0.0])
-
-    def phase_pop(self) -> float:
-        """Close the innermost parent frame; returns the gross
-        seconds of the phases nested inside it."""
-        return self._phase_stack.pop()[0]
-
     def ledger_add(
         self,
         phase: str,
@@ -234,17 +283,21 @@ class FlightRecorder:
         lane: int = 0,
     ) -> None:
         """Accumulate ``seconds`` (exclusive time) into the current
-        epoch's ledger.  ``gross`` (default: ``seconds``) is the whole
-        interval including nested phases — charged to the enclosing
-        phase frame so parents record self time only.  ``lane`` 0 is
-        the main thread; other lanes (the pipeline worker) overlap it
-        and never charge a parent frame."""
+        epoch's ledger and the lifetime totals.  ``gross`` (default:
+        ``seconds``) is the whole interval including nested phases —
+        charged to the enclosing phase frame so parents record self
+        time only.  ``lane`` 0 is the main thread; other lanes (the
+        pipeline worker) overlap it and never charge a parent
+        frame."""
         key = (phase, step_id)
         self._ledger[key] = self._ledger.get(key, 0.0) + seconds
+        self.phase_totals[phase] = (
+            self.phase_totals.get(phase, 0.0) + seconds
+        )
         if gross is None:
             gross = seconds
         if lane == 0 and self._phase_stack:
-            self._phase_stack[-1][0] += gross
+            self._phase_stack[-1].nested += gross
         if (
             self.trace_dir
             and t0 is not None
@@ -279,13 +332,13 @@ class FlightRecorder:
         self, epoch: int, close_s: float
     ) -> Dict[str, Any]:
         """Turn the accumulating ledger into this epoch's sealed
-        record, roll the phase totals, dump the Perfetto trace when
-        armed, and reset for the next epoch."""
+        record, dump the Perfetto trace when armed, and reset for the
+        next epoch."""
         now = time.monotonic()
         pre = self._ledger_pre_close or {}
         close_phases: Dict[str, float] = {}
         for (phase, step), s in self._ledger.items():
-            if phase in _OFF_THREAD_PHASES:
+            if _lane_of(phase) in _OFF_THREAD_PHASES:
                 continue
             d = s - pre.get((phase, step), 0.0)
             if d > 0:
@@ -301,10 +354,6 @@ class FlightRecorder:
             "lag": self.ledger_lag(),
             "queue_depth_at_drain": dict(self._flush_depth),
         }
-        for (phase, _step), s in self._ledger.items():
-            self.phase_totals[phase] = (
-                self.phase_totals.get(phase, 0.0) + s
-            )
         self.last_ledger = record
         self._ledgers.append(record)
         if self.trace_dir:
@@ -387,9 +436,9 @@ class FlightRecorder:
                     # would render as nonsense nesting.
                     "tid": (
                         3
-                        if phase == "collective_lane"
+                        if _lane_of(phase) == "collective_lane"
                         else 4
-                        if phase == "snapshot_lane"
+                        if _lane_of(phase) == "snapshot_lane"
                         else 1 + lane
                     ),
                     "args": {"step_id": step},
@@ -717,7 +766,6 @@ def note_gsync(tag: Any, seconds: float) -> None:
 
     gsync_round_count.inc()
     RECORDER.count("gsync_round_count")
-    RECORDER.count("gsync_wait_seconds", seconds)
     RECORDER.record(
         "gsync", tag=str(tag), seconds=round(seconds, 6)
     )
@@ -746,13 +794,11 @@ def note_fenced(peer: int, gen: int) -> None:
 
 def note_restart(attempt: int, cause: str, backoff_s: float) -> None:
     """The supervisor is restarting this worker after a restartable
-    fault; also stamps ``restart_at`` so ``bench.py`` can measure
-    kill-to-first-epoch-close recovery latency."""
+    fault."""
     from bytewax_tpu._metrics import worker_restart_count
 
     worker_restart_count.inc()
     RECORDER.count("worker_restart_count")
-    RECORDER.counters["last_restart_at"] = time.time()
     RECORDER.record(
         "restart", attempt=attempt, cause=cause, backoff_s=backoff_s
     )
@@ -763,8 +809,6 @@ def note_stop_requested(source: str) -> None:
     ``http`` for ``POST /stop``, or ``api`` for a direct
     ``request_stop()`` call); the run loop drains to a stop at the
     next epoch close."""
-    RECORDER.count("stop_requested_count")
-    RECORDER.counters["stop_requested_at"] = time.time()
     RECORDER.record("stop_requested", source=source)
 
 
@@ -805,7 +849,6 @@ def note_reconfigure_requested(
     ``request_reconfigure()`` call); the run loop proposes it on the
     next epoch-close sync round (docs/recovery.md "Live partial
     rescale")."""
-    RECORDER.count("reconfigure_requested_count")
     RECORDER.record(
         "reconfigure_requested",
         addresses=n_addresses,
@@ -882,7 +925,6 @@ def note_residency_restore(step_id: str, n: int, seconds: float) -> None:
     """One residency-fault restore: ``n`` evicted/spilled keys
     reinstated on device before a delivery dispatched."""
     RECORDER.count("residency_restore_count", n)
-    RECORDER.count("residency_restore_seconds", seconds)
     RECORDER._restore_s.append(seconds)
     RECORDER.record(
         "restore", step=step_id, keys=n, seconds=round(seconds, 6)
@@ -956,7 +998,6 @@ def note_quarantine(
     parked at its last good offset; ``n_quarantined`` is the step's
     resulting quarantined-partition count."""
     _quarantine_gauge(step_id).set(n_quarantined)
-    RECORDER.count("quarantine_count")
     RECORDER.counters[f"quarantined_partitions[{step_id}]"] = (
         n_quarantined
     )
@@ -975,7 +1016,6 @@ def note_unquarantine(
     """A quarantined partition's re-probe succeeded: it resumes
     polling from the frozen offset."""
     _quarantine_gauge(step_id).set(n_quarantined)
-    RECORDER.count("unquarantine_count")
     RECORDER.counters[f"quarantined_partitions[{step_id}]"] = (
         n_quarantined
     )
@@ -1100,7 +1140,6 @@ def note_pipeline_stall(step_id: str, seconds: float) -> None:
             )
     child.inc(seconds)
     RECORDER.count("pipeline_flush_stall_seconds", seconds)
-    RECORDER.count("pipeline_flush_stall_count")
     note_phase(
         "flush", step_id, seconds, t0=time.monotonic() - seconds
     )
@@ -1127,7 +1166,6 @@ def note_barrier(seconds: float) -> None:
     from bytewax_tpu._metrics import barrier_wait_seconds
 
     barrier_wait_seconds.observe(seconds)
-    RECORDER.count("barrier_count")
     RECORDER.count("barrier_wait_seconds", seconds)
     RECORDER.record("barrier_exit", seconds=round(seconds, 6))
     note_phase(
@@ -1150,7 +1188,10 @@ def note_phase(
     lane: int = 0,
 ) -> None:
     """Attribute ``seconds`` of *exclusive* time to one epoch-ledger
-    phase of one step (``step_id`` ``*`` = process-wide).  ``gross``
+    phase of one step (``step_id`` ``*`` = process-wide): the entry
+    for callers that hold a duration already (a stall, a barrier, a
+    sync round); a timed interval goes through :class:`span`, which
+    ends here.  ``gross``
     is the whole interval including nested phases (charged to the
     enclosing phase frame); ``t0`` (monotonic) keys the Perfetto
     interval; ``lane`` 1 marks off-main-thread time (the pipeline
@@ -1168,6 +1209,205 @@ def note_phase(
     RECORDER.ledger_add(
         phase, step_id, seconds, gross=gross, t0=t0, lane=lane
     )
+
+
+# -- the span primitive ----------------------------------------------------
+
+#: The calling thread's lane (``.lane``); unset on the main thread.
+_tls = threading.local()
+_annotation: Any = None
+
+
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, imported on first use (the
+    import starts no backend), as :func:`ensure_compile_listener`
+    imports ``jax.monitoring``."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class _Lane:
+    """One task's span state on a lane other than the main thread's.
+
+    A worker's spans nest among themselves on the lane's own stack
+    and are gathered in ``spans`` beside the task's result; the main
+    thread folds them into the ledger at finalize
+    (:func:`lane_fold`), so the worker touches no shared recorder
+    state.  A task run inline (pipeline depth 1; a step's end of
+    input) is on the caller's thread: it nests on the caller's stack
+    and records at once (``spans`` is None), under the lane's name
+    all the same."""
+
+    __slots__ = ("phase", "stack", "spans")
+
+    def __init__(self, phase: str, inline: bool):
+        self.phase = phase
+        self.stack: List[_Frame] = (
+            RECORDER._phase_stack if inline else []
+        )
+        self.spans: Optional[List[tuple]] = None if inline else []
+
+
+class span:
+    """Time one interval into the epoch ledger: ``with span("prep",
+    step_id, rows=n):``.
+
+    Records *exclusive* seconds: what nested spans took on the same
+    lane is taken out (each frame on the lane's stack gathers its
+    children's gross time).  Adds 1 to ``counters["<phase>_spans"]``
+    and ``rows`` (settable until the span ends) to
+    ``counters["<phase>_rows"]``; both repeat exactly for a given
+    input.  A work span (:data:`TRACED_PHASES`) also enters
+    ``jax.profiler.TraceAnnotation("btx.<phase>", step_id=...)`` on
+    the thread that does the work, so a profiler session holds it on
+    the device trace's clock.  On a lane (:func:`lane_run`) the span
+    is recorded as ``<lane>/<phase>``; the counters keep the bare
+    name, since the same step runs on the worker in a delivery and on
+    the main thread at a notify or a close.
+
+    :meth:`begin` and :meth:`end` serve the two spans that are not
+    lexical (``startup``, ``teardown``); both do nothing the second
+    time, so the owner can end the span again where a fault unwinds.
+    """
+
+    __slots__ = (
+        "phase",
+        "step_id",
+        "rows",
+        "_lane",
+        "_root",
+        "_frame",
+        "_t0",
+        "_ann",
+    )
+
+    def __init__(
+        self,
+        phase: str,
+        step_id: str = "*",
+        rows: Optional[int] = None,
+    ):
+        self.phase = phase
+        self.step_id = step_id
+        self.rows = rows
+        self._lane: Optional[_Lane] = None
+        self._root = False
+        self._frame: Optional[_Frame] = None
+        self._t0: Optional[float] = None
+        self._ann: Any = None
+
+    def __enter__(self) -> "span":
+        self.begin()
+        return self
+
+    def __exit__(self, *_exc: Any) -> None:
+        self.end()
+
+    def begin(self) -> "span":
+        if self._frame is not None:
+            return self
+        lane = self._lane = getattr(_tls, "lane", None)
+        stack = RECORDER._phase_stack if lane is None else lane.stack
+        if self.step_id == "*" and stack:
+            # A span deep in a state object knows no step: it is the
+            # enclosing span's (the lane's task, the step's drain).
+            self.step_id = stack[-1].step_id
+        frame = self._frame = _Frame(self.step_id)
+        stack.append(frame)
+        if self.phase in TRACED_PHASES:
+            ann = self._ann = _trace_annotation()(
+                "btx." + self.phase, step_id=self.step_id
+            )
+            ann.__enter__()
+        self._t0 = frame.t0 = time.monotonic()
+        return self
+
+    def end(self) -> None:
+        t0 = self._t0
+        if t0 is None:
+            return
+        self._t0 = None
+        gross = time.monotonic() - t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        lane, frame = self._lane, self._frame
+        stack = RECORDER._phase_stack if lane is None else lane.stack
+        if stack and stack[-1] is frame:
+            stack.pop()
+        else:
+            # Not lexical (``startup``), or the stack was replaced
+            # under it: take this span's own frame off, by identity.
+            for i in range(len(stack) - 1, -1, -1):
+                if stack[i] is frame:
+                    del stack[i]
+                    break
+        seconds = max(gross - frame.nested, 0.0)
+        name = (
+            self.phase
+            if lane is None or self._root
+            else lane.phase + "/" + self.phase
+        )
+        if lane is None or lane.spans is None:
+            note_phase(name, self.step_id, seconds, gross=gross, t0=t0)
+            _count_span(self.phase, self.rows)
+            return
+        if stack:
+            stack[-1].nested += gross
+        lane.spans.append(
+            (name, self.step_id, seconds, gross, t0, self.phase, self.rows)
+        )
+
+
+def _count_span(phase: str, rows: Optional[int]) -> None:
+    counters = RECORDER.counters
+    key = phase + "_spans"
+    counters[key] = counters.get(key, 0) + 1
+    if rows is not None:
+        key = phase + "_rows"
+        counters[key] = counters.get(key, 0) + rows
+
+
+def lane_run(
+    phase: str,
+    step_id: str,
+    task: Any,
+    inline: bool = False,
+) -> Tuple[Optional[List[tuple]], Any]:
+    """Run ``task()`` as one task of lane ``phase`` (a pipeline's
+    ``device``, ``collective_lane`` or ``snapshot_lane``; the
+    driver's ``eof``, inline) on the calling thread and return
+    ``(spans, result)``.  The task's whole interval is the
+    lane's own span, recorded under the lane's name with the time no
+    child covers; spans inside it are the lane's children.  On a
+    worker thread the spans come back for the main thread to fold
+    (:func:`lane_fold`); ``inline`` (the task runs on the main
+    thread, pipeline depth 1) records them at once and returns
+    None, and the lane's gross time charges the enclosing frame."""
+    lane = _Lane(phase, inline)
+    outer = getattr(_tls, "lane", None)
+    _tls.lane = lane
+    root = span(phase, step_id)
+    root._root = True
+    try:
+        with root:
+            result = task()
+    finally:
+        _tls.lane = outer
+    return lane.spans, result
+
+
+def lane_fold(spans: List[tuple]) -> None:
+    """Main thread, at finalize: fold one worker task's spans into
+    the ledger with the worker's own timing.  They overlap the main
+    thread's phases and charge no frame of its stack."""
+    for name, step_id, seconds, gross, t0, phase, rows in spans:
+        note_phase(name, step_id, seconds, gross=gross, t0=t0, lane=1)
+        _count_span(phase, rows)
 
 
 def note_source_lag(step_id: str, kind: str, seconds: float) -> None:
@@ -1196,15 +1436,43 @@ def note_flush_depth(step_id: str, depth: int) -> None:
         cur[step_id] = depth
 
 
-#: Ledger phases folded into each reported fraction bucket.
+#: Ledger phases folded into each reported fraction bucket.  A span
+#: takes its seconds out of its parent's exclusive time, so it joins
+#: the bucket its parent is in: the work spans of the main thread go
+#: under ``host``; a lane's children (``device/prep``) go with their
+#: lane, so ``device`` is still the worker's whole busy time.
+#: ``startup`` and ``teardown`` were in no phase before and join no
+#: bucket.
 _FRACTION_BUCKETS = {
-    "host": ("ingest", "host", "readback"),
+    "host": (
+        "ingest",
+        "host",
+        "readback",
+        "parse",
+        "encode",
+        "watermark",
+        "prep",
+        "h2d",
+        "dispatch",
+        "close_scan",
+        "fetch",
+        "close_emit",
+        "emit",
+        "sink",
+    ),
     "device": ("device",),
     "flush": ("flush", "close_flush"),
     "barrier": ("barrier",),
     "gsync": ("gsync", "collective", "collective_lane"),
     "snapshot": ("snapshot", "commit", "snapshot_lane"),
     "residency": ("restore", "evict"),
+}
+
+
+_BUCKET_OF = {
+    phase: name
+    for name, phases in _FRACTION_BUCKETS.items()
+    for phase in phases
 }
 
 
@@ -1219,10 +1487,12 @@ def ledger_fractions(
     hint."""
     if totals is None:
         totals = RECORDER.phase_totals
-    buckets = {
-        name: sum(totals.get(p, 0.0) for p in phases)
-        for name, phases in _FRACTION_BUCKETS.items()
-    }
+    buckets = dict.fromkeys(_FRACTION_BUCKETS, 0.0)
+    # A copy: the totals move live, and the API thread reads them.
+    for phase, s in list(totals.items()):
+        name = _BUCKET_OF.get(_lane_of(phase))
+        if name is not None:
+            buckets[name] += s
     denom = sum(buckets.values())
     if denom <= 0:
         return None

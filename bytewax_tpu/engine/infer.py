@@ -298,12 +298,22 @@ class DeviceInferState(_ParamsHolder):
         returns host-numpy output columns trimmed back to N."""
         n = len(feats)
         padded = pad_len(n)
-        feats_p = np.zeros((padded,) + feats.shape[1:], dtype=np.float32)
-        feats_p[:n] = feats
-        _flight.note_transfer("h2d", feats_p.nbytes)
-        out = self._apply(self._params, self._jax.device_put(feats_p))
-        host = tuple(np.asarray(col)[:n] for col in _out_columns(out))
-        _flight.note_transfer("d2h", sum(col.nbytes for col in host))
+        with _flight.span("h2d", rows=padded):
+            feats_p = np.zeros(
+                (padded,) + feats.shape[1:], dtype=np.float32
+            )
+            feats_p[:n] = feats
+            _flight.note_transfer("h2d", feats_p.nbytes)
+            feats_d = self._jax.device_put(feats_p)
+        with _flight.span("dispatch"):
+            out = self._apply(self._params, feats_d)
+        with _flight.span("fetch", rows=padded):
+            host = tuple(
+                np.asarray(col)[:n] for col in _out_columns(out)
+            )
+            _flight.note_transfer(
+                "d2h", sum(col.nbytes for col in host)
+            )
         return host
 
     # -- broadcast-state lifecycle -----------------------------------------

@@ -156,13 +156,11 @@ class DevicePipeline:
         multi-entry deliveries that push several phases."""
         if self.depth <= 1:
             t0 = time.monotonic()
-            result = task()
-            dur = time.monotonic() - t0
-            # Inline (lock-step) mode folds ON the main thread: lane 0,
-            # so the seconds charge the enclosing host frame instead of
-            # double-counting against it as overlapped worker time.
-            _flight.note_phase(
-                self.phase, self.step_id, dur, t0=t0, lane=0
+            # Inline (lock-step) mode folds ON the main thread: the
+            # lane's seconds charge the enclosing host frame instead
+            # of double-counting against it as overlapped worker time.
+            _spans, result = _flight.lane_run(
+                self.phase, self.step_id, task, inline=True
             )
             finalize(result)
             _flight.note_source_lag(
@@ -170,17 +168,13 @@ class DevicePipeline:
             )
             return
         self.make_room()
-        fut = self._ensure_pool().submit(self._timed, task)
+        # The worker runs the task as one task of this lane: its
+        # spans come back beside the result and enter the ledger on
+        # the main thread, at finalize, with the worker's real timing.
+        fut = self._ensure_pool().submit(
+            _flight.lane_run, self.phase, self.step_id, task
+        )
         self._pending.append((fut, finalize, time.monotonic()))
-
-    @staticmethod
-    def _timed(task: Callable[[], Any]) -> Tuple[float, float, Any]:
-        """Worker-side wrapper: stamp the device phase's wall
-        interval so the ledger's ``device`` lane is recorded (on the
-        main thread, at finalize) with the worker's real timing."""
-        t0 = time.monotonic()
-        result = task()
-        return t0, time.monotonic() - t0, result
 
     #: ``make_room()`` + append, under one name for direct callers.
     submit = push
@@ -191,7 +185,7 @@ class DevicePipeline:
         fut, finalize, t_submit = self._pending.popleft()
         t0 = time.monotonic()
         try:
-            dev_t0, dev_dur, result = fut.result()
+            spans, result = fut.result()
         finally:
             stalled = time.monotonic() - t0
             if stalled > 0.0005:
@@ -215,20 +209,17 @@ class DevicePipeline:
                     _flight.RECORDER.count(
                         "collective_fence_stall_seconds", stalled
                     )
-        # Ledger: the worker phase's wall interval (worker lane — it
-        # overlaps host time and never charges the enclosing phase),
+        # Ledger: the worker task's spans (worker lane — they
+        # overlap host time and never charge the enclosing phase),
         # then the host-side finalize (emission routing, touched-key
         # absorption: the readback surfacing point).
-        _flight.note_phase(
-            self.phase, self.step_id, dev_dur, t0=dev_t0, lane=1
-        )
-        tf = time.monotonic()
-        finalize(result)
-        now = time.monotonic()
+        _flight.lane_fold(spans)
         if self.phase == "device":
-            _flight.note_phase(
-                "readback", self.step_id, now - tf, t0=tf
-            )
+            with _flight.span("readback", self.step_id):
+                finalize(result)
+        else:
+            finalize(result)
+        now = time.monotonic()
         # Ingest→emit latency of this delivery through the pipeline
         # (submit to finalized emissions).
         _flight.note_source_lag(

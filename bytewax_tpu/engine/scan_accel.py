@@ -256,19 +256,24 @@ class DeviceScanState(ScanUpdates):
         # distinct shapes; padding rows target the scratch slot (the
         # max slot id, so the trailing pad is its own segment).
         padded = pad_len(n)
-        slots_p = np.full(padded, self.capacity - 1, dtype=np.int32)
-        slots_p[:n] = row_slots
-        vals_p = np.zeros(padded, dtype=np.float32)
-        vals_p[:n] = values
-        self._ensure_fields()
-        _flight.note_transfer("h2d", slots_p.nbytes + vals_p.nbytes)
-        outs, self._fields = self.kind.run(
-            self._fields,
-            jax.device_put(slots_p),
-            jax.device_put(vals_p),
-        )
-        host_outs = tuple(np.asarray(o) for o in outs)
-        _flight.note_transfer("d2h", sum(o.nbytes for o in host_outs))
+        with _flight.span("h2d", rows=padded):
+            slots_p = np.full(padded, self.capacity - 1, dtype=np.int32)
+            slots_p[:n] = row_slots
+            vals_p = np.zeros(padded, dtype=np.float32)
+            vals_p[:n] = values
+            self._ensure_fields()
+            _flight.note_transfer("h2d", slots_p.nbytes + vals_p.nbytes)
+            slots_d = jax.device_put(slots_p)
+            vals_d = jax.device_put(vals_p)
+        with _flight.span("dispatch"):
+            outs, self._fields = self.kind.run(
+                self._fields, slots_d, vals_d
+            )
+        with _flight.span("fetch", rows=padded):
+            host_outs = tuple(np.asarray(o) for o in outs)
+            _flight.note_transfer(
+                "d2h", sum(o.nbytes for o in host_outs)
+            )
         return self.kind.post(tuple(o[:n] for o in host_outs))
 
     _dispatch = scan_rows
@@ -276,12 +281,14 @@ class DeviceScanState(ScanUpdates):
     # -- recovery ----------------------------------------------------------
 
     def _fetch(self) -> Dict[str, np.ndarray]:
-        host = {
-            name: np.asarray(arr) for name, arr in self._fields.items()
-        }
-        _flight.note_transfer(
-            "d2h", sum(a.nbytes for a in host.values())
-        )
+        with _flight.span("fetch", rows=self.capacity):
+            host = {
+                name: np.asarray(arr)
+                for name, arr in self._fields.items()
+            }
+            _flight.note_transfer(
+                "d2h", sum(a.nbytes for a in host.values())
+            )
         return host
 
     def load(self, key: str, state: Any) -> None:

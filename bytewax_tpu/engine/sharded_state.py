@@ -555,40 +555,40 @@ class ShardedAggState(_ShardedSlots):
         n = len(kids)
         if n == 0:
             return
-        self._ensure_fields()
-        rows_per_shard = _pow2(
-            -(-n // self.n_shards), int(math.log2(_MIN_ROWS_PER_SHARD))
-        )
-        total = rows_per_shard * self.n_shards
+        with _flight.span("prep", rows=n):
+            self._ensure_fields()
+            rows_per_shard = _pow2(
+                -(-n // self.n_shards),
+                int(math.log2(_MIN_ROWS_PER_SHARD)),
+            )
+            total = rows_per_shard * self.n_shards
 
-        kids_p = np.zeros(total, dtype=np.int32)
-        kids_p[:n] = kids
-        vals_p = np.zeros(total, dtype=np.dtype(self.dtype))
-        vals_p[:n] = values
-        valid_p = np.zeros(total, dtype=bool)
-        valid_p[:n] = True
-
-        # Exact per-(source block, destination shard) bucket maximum:
-        # sized on host so the exchange can never drop rows, however
-        # skewed the key distribution.
-        dest = kids % self.n_shards
-        block_of = np.arange(n) // rows_per_shard
-        pair_counts = np.bincount(
-            block_of * self.n_shards + dest,
-            minlength=self.n_shards * self.n_shards,
-        )
-        capacity = _pow2(int(pair_counts.max()), 4)
-
-        _flight.note_transfer(
-            "h2d", kids_p.nbytes + vals_p.nbytes + valid_p.nbytes
-        )
-        step = self._step_for(total, capacity)
-        self._fields = step(
-            self._fields,
-            jax.device_put(kids_p, self._sharding),
-            jax.device_put(vals_p, self._sharding),
-            jax.device_put(valid_p, self._sharding),
-        )
+            # Exact per-(source block, destination shard) bucket
+            # maximum: sized on host so the exchange can never drop
+            # rows, however skewed the key distribution.
+            dest = kids % self.n_shards
+            block_of = np.arange(n) // rows_per_shard
+            pair_counts = np.bincount(
+                block_of * self.n_shards + dest,
+                minlength=self.n_shards * self.n_shards,
+            )
+            capacity = _pow2(int(pair_counts.max()), 4)
+            step = self._step_for(total, capacity)
+        with _flight.span("h2d", rows=total):
+            kids_p = np.zeros(total, dtype=np.int32)
+            kids_p[:n] = kids
+            vals_p = np.zeros(total, dtype=np.dtype(self.dtype))
+            vals_p[:n] = values
+            valid_p = np.zeros(total, dtype=bool)
+            valid_p[:n] = True
+            _flight.note_transfer(
+                "h2d", kids_p.nbytes + vals_p.nbytes + valid_p.nbytes
+            )
+            kids_d = jax.device_put(kids_p, self._sharding)
+            vals_d = jax.device_put(vals_p, self._sharding)
+            valid_d = jax.device_put(valid_p, self._sharding)
+        with _flight.span("dispatch"):
+            self._fields = step(self._fields, kids_d, vals_d, valid_d)
 
     def update_ids(self, kids: np.ndarray, values: np.ndarray) -> None:
         """Fold rows into pre-allocated wire ids (the id-based fold
@@ -787,10 +787,12 @@ class ShardedAggState(_ShardedSlots):
         import jax.numpy as jnp
 
         names = list(self.kind.fields)
-        stacked = np.asarray(
-            jnp.stack([self._fields[name] for name in names])
-        )
-        _flight.note_transfer("d2h", stacked.nbytes)
+        with _flight.span("fetch") as sp:
+            stacked = np.asarray(
+                jnp.stack([self._fields[name] for name in names])
+            )
+            sp.rows = stacked.shape[1]
+            _flight.note_transfer("d2h", stacked.nbytes)
         return {name: stacked[i] for i, name in enumerate(names)}
 
     def snapshots_for(self, keys: List[str]) -> List[Tuple[str, Any]]:
@@ -799,14 +801,20 @@ class ShardedAggState(_ShardedSlots):
             return [(k, None) for k in keys]
         host = self._fetch()
         out = []
-        for key in keys:
-            kid = self.key_to_kid.get(key)
-            if kid is None:
-                out.append((key, None))
-            else:
-                out.append(
-                    (key, _snap_of(self.kind_name, host, self._global_idx(kid)))
-                )
+        with _flight.span("close_emit"):
+            for key in keys:
+                kid = self.key_to_kid.get(key)
+                if kid is None:
+                    out.append((key, None))
+                else:
+                    out.append(
+                        (
+                            key,
+                            _snap_of(
+                                self.kind_name, host, self._global_idx(kid)
+                            ),
+                        )
+                    )
         return out
 
     # -- finalization --------------------------------------------------------
@@ -818,15 +826,18 @@ class ShardedAggState(_ShardedSlots):
             return []
         self._ensure_fields()
         host = self._fetch()
-        out = [
-            (
-                key,
-                _final_of(
-                    self.kind_name, host, self._global_idx(self.key_to_kid[key])
-                ),
-            )
-            for key in sorted(self.key_to_kid)
-        ]
+        with _flight.span("close_emit", rows=len(self.key_to_kid)):
+            out = [
+                (
+                    key,
+                    _final_of(
+                        self.kind_name,
+                        host,
+                        self._global_idx(self.key_to_kid[key]),
+                    ),
+                )
+                for key in sorted(self.key_to_kid)
+            ]
         self.key_to_kid.clear()
         self._shard_fill = [0] * self.n_shards
         self._free = [[] for _ in range(self.n_shards)]

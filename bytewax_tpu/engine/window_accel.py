@@ -280,7 +280,8 @@ class DeviceWindowAggState:
             iddict = dict(self.key_ids)
             self._item_iddict = iddict
         try:
-            res = wa_encode(items, iddict, ids, ts_us, vals)
+            with _flight.span("encode", rows=n):
+                res = wa_encode(items, iddict, ids, ts_us, vals)
         except (TypeError, AttributeError) as ex:
             # AttributeError: a float-coercible value without the
             # TsValue `.ts` attribute.
@@ -349,7 +350,8 @@ class DeviceWindowAggState:
         ``_WindowLogic`` ("E" emit / "L" late / "M" meta).  Returns
         ``(late_events, device_phase)`` — see :meth:`_ingest`."""
         spec = self.spec
-        kids = self._key_ids_for(keys)
+        with _flight.span("encode", rows=len(keys)):
+            kids = self._key_ids_for(keys)
         ts_us = np.fromiter(
             (_to_us(spec.ts_getter(v)) for v in values),
             dtype=np.float64,
@@ -384,59 +386,62 @@ class DeviceWindowAggState:
         # system time.  Group rows by key with one stable sort, then
         # run one accumulate per contiguous segment — O(n log n), not
         # O(keys × rows).
-        eff = ts_us - spec.wait_us
-        n = len(ts_us)
-        order = np.argsort(kids, kind="stable")
-        kids_sorted = kids[order]
-        eff_sorted = eff[order]
-        seg_kids, seg_starts = np.unique(kids_sorted, return_index=True)
-        seg_counts = np.diff(np.append(seg_starts, n))
-        n_seg = len(seg_kids)
-        carry = self.base_us[seg_kids] + (now_us - self.sys_at_base[seg_kids])
-
-        # Segmented prefix max with no per-key Python: shift each
-        # key's rows into its own disjoint value band (band width >
-        # the value span), run ONE global cummax — later bands
-        # dominate earlier ones, so the running max never leaks
-        # across segments — and shift back.  Exact only in integer
-        # arithmetic below 2^53, which the hot columnar path
-        # (datetime64[us] timestamps) always is; fractional
-        # microseconds or astronomically-spread batches take the
-        # per-segment loop so watermark equality stays bit-exact.
-        lo_val = float(eff_sorted.min()) if n else 0.0
-        band = float(eff_sorted.max()) - lo_val + 1.0 if n else 1.0
-        integral = n == 0 or (
-            band == np.floor(band)
-            and not np.any(eff_sorted % 1.0)
-        )
-        if integral and n_seg * band < float(1 << 53):
-            seg_of_row = np.repeat(
-                np.arange(n_seg, dtype=np.int64), seg_counts
+        with _flight.span("watermark", rows=len(ts_us)):
+            eff = ts_us - spec.wait_us
+            n = len(ts_us)
+            order = np.argsort(kids, kind="stable")
+            kids_sorted = kids[order]
+            eff_sorted = eff[order]
+            seg_kids, seg_starts = np.unique(kids_sorted, return_index=True)
+            seg_counts = np.diff(np.append(seg_starts, n))
+            n_seg = len(seg_kids)
+            carry = self.base_us[seg_kids] + (
+                now_us - self.sys_at_base[seg_kids]
             )
-            off = seg_of_row * band
-            prefix = (
-                np.maximum.accumulate((eff_sorted - lo_val) + off) - off
-            ) + lo_val
-            wm_sorted = np.maximum(prefix, carry[seg_of_row])
-            seg_max = np.maximum.reduceat(eff_sorted, seg_starts)
-        else:
-            seg_ends = np.append(seg_starts[1:], n)
-            wm_sorted = np.empty(n, dtype=np.float64)
-            seg_max = np.empty(n_seg, dtype=np.float64)
-            for j, (lo, hi) in enumerate(
-                zip(seg_starts.tolist(), seg_ends.tolist())
-            ):
-                prefix = np.maximum.accumulate(eff_sorted[lo:hi])
-                np.maximum(prefix, carry[j], out=wm_sorted[lo:hi])
-                seg_max[j] = prefix[-1]
-        advanced = seg_max > self.base_us[seg_kids]
-        if advanced.any():
-            moved = seg_kids[advanced]
-            self.base_us[moved] = seg_max[advanced]
-            self.sys_at_base[moved] = now_us
-        wm_rows = np.empty(n, dtype=np.float64)
-        wm_rows[order] = wm_sorted
-        late_mask = ts_us < wm_rows
+
+            # Segmented prefix max with no per-key Python: shift each
+            # key's rows into its own disjoint value band (band width >
+            # the value span), run ONE global cummax — later bands
+            # dominate earlier ones, so the running max never leaks
+            # across segments — and shift back.  Exact only in integer
+            # arithmetic below 2^53, which the hot columnar path
+            # (datetime64[us] timestamps) always is; fractional
+            # microseconds or astronomically-spread batches take the
+            # per-segment loop so watermark equality stays bit-exact.
+            lo_val = float(eff_sorted.min()) if n else 0.0
+            band = float(eff_sorted.max()) - lo_val + 1.0 if n else 1.0
+            integral = n == 0 or (
+                band == np.floor(band)
+                and not np.any(eff_sorted % 1.0)
+            )
+            if integral and n_seg * band < float(1 << 53):
+                seg_of_row = np.repeat(
+                    np.arange(n_seg, dtype=np.int64), seg_counts
+                )
+                off = seg_of_row * band
+                prefix = (
+                    np.maximum.accumulate((eff_sorted - lo_val) + off) - off
+                ) + lo_val
+                wm_sorted = np.maximum(prefix, carry[seg_of_row])
+                seg_max = np.maximum.reduceat(eff_sorted, seg_starts)
+            else:
+                seg_ends = np.append(seg_starts[1:], n)
+                wm_sorted = np.empty(n, dtype=np.float64)
+                seg_max = np.empty(n_seg, dtype=np.float64)
+                for j, (lo, hi) in enumerate(
+                    zip(seg_starts.tolist(), seg_ends.tolist())
+                ):
+                    prefix = np.maximum.accumulate(eff_sorted[lo:hi])
+                    np.maximum(prefix, carry[j], out=wm_sorted[lo:hi])
+                    seg_max[j] = prefix[-1]
+            advanced = seg_max > self.base_us[seg_kids]
+            if advanced.any():
+                moved = seg_kids[advanced]
+                self.base_us[moved] = seg_max[advanced]
+                self.sys_at_base[moved] = now_us
+            wm_rows = np.empty(n, dtype=np.float64)
+            wm_rows[order] = wm_sorted
+            late_mask = ts_us < wm_rows
 
         events: List[Tuple[str, Tuple[int, str, Any]]] = []
         if late_mask.any():
@@ -515,68 +520,72 @@ class DeviceWindowAggState:
     ) -> None:
         """Fold on-time rows into their containing windows (opening
         windows as needed) — the scatter-combine into the slot table."""
-        spec = self.spec
-        hi = np.floor(
-            (ts_ok - spec.align_us) / spec.offset_us
-        ).astype(np.int64)
-        if len(hi) and int(np.abs(hi).max()) >= (1 << 31) - self.expand:
-            msg = (
-                "window ids exceed the composite encoding range; "
-                "move align_to closer to the event times or use a "
-                "larger window offset"
-            )
-            raise ValueError(msg)
-
-        # Expand each row into the (static count of) windows that
-        # contain it, all vectorized.  Tumbling windows (expand == 1)
-        # skip the 2-D broadcast entirely: every row is in exactly its
-        # own window (ts < align + hi*offset + length holds by
-        # construction of hi when offset == length), saving five
-        # row-count-sized materializations per batch on the pipeline
-        # worker.
-        if self.expand == 1 and spec.offset_us == spec.length_us:
-            kid_rep = kids_ok
-            wid_flat = hi
-            val_rep = vals_ok
-        else:
-            e = np.arange(self.expand, dtype=np.int64)
-            wids = hi[:, None] - e[None, :]  # [n, expand]
-            in_window = (
-                ts_ok[:, None]
-                < spec.align_us + wids * spec.offset_us + spec.length_us
-            )
-            kid_rep = np.broadcast_to(kids_ok[:, None], wids.shape)[
-                in_window
-            ]
-            wid_flat = wids[in_window]
-            val_rep = np.broadcast_to(vals_ok[:, None], wids.shape)[
-                in_window
-            ]
-
-        # Composite (key, window) ids; python work only per NEW
-        # composite, per-row mapping is pure numpy.
-        comp = (kid_rep << 32) + (wid_flat + (1 << 31))
-        uniq, inverse = np.unique(comp, return_inverse=True)
-        slot_of_uniq = np.empty(len(uniq), dtype=np.int32)
-        for j, c in enumerate(uniq.tolist()):
-            kid = c >> 32
-            wid = (c & ((1 << 32) - 1)) - (1 << 31)
-            slot_of_uniq[j] = self.agg.alloc(
-                f"{self.keys[kid]}\x00{wid}"
-            )
-            if (kid, wid) not in self.open_close_us:
-                self.open_close_us[(kid, wid)] = (
-                    spec.align_us
-                    + wid * spec.offset_us
-                    + spec.length_us
+        # Ledger: `prep` is the host work up to the fold (composite
+        # ids, the unique pass, a slot per new window).
+        with _flight.span("prep", rows=len(kids_ok)):
+            spec = self.spec
+            hi = np.floor(
+                (ts_ok - spec.align_us) / spec.offset_us
+            ).astype(np.int64)
+            if len(hi) and int(np.abs(hi).max()) >= (1 << 31) - self.expand:
+                msg = (
+                    "window ids exceed the composite encoding range; "
+                    "move align_to closer to the event times or use a "
+                    "larger window offset"
                 )
-                self._open_cache = None
-        if len(comp):
-            _flight.RECORDER.count("window_rows_ingested", len(val_rep))
+                raise ValueError(msg)
+
+            # Expand each row into the (static count of) windows that
+            # contain it, all vectorized.  Tumbling windows (expand == 1)
+            # skip the 2-D broadcast entirely: every row is in exactly its
+            # own window (ts < align + hi*offset + length holds by
+            # construction of hi when offset == length), saving five
+            # row-count-sized materializations per batch on the pipeline
+            # worker.
+            if self.expand == 1 and spec.offset_us == spec.length_us:
+                kid_rep = kids_ok
+                wid_flat = hi
+                val_rep = vals_ok
+            else:
+                e = np.arange(self.expand, dtype=np.int64)
+                wids = hi[:, None] - e[None, :]  # [n, expand]
+                in_window = (
+                    ts_ok[:, None]
+                    < spec.align_us + wids * spec.offset_us + spec.length_us
+                )
+                kid_rep = np.broadcast_to(kids_ok[:, None], wids.shape)[
+                    in_window
+                ]
+                wid_flat = wids[in_window]
+                val_rep = np.broadcast_to(vals_ok[:, None], wids.shape)[
+                    in_window
+                ]
+
+            # Composite (key, window) ids; python work only per NEW
+            # composite, per-row mapping is pure numpy.
+            comp = (kid_rep << 32) + (wid_flat + (1 << 31))
+            uniq, inverse = np.unique(comp, return_inverse=True)
+            slot_of_uniq = np.empty(len(uniq), dtype=np.int32)
+            for j, c in enumerate(uniq.tolist()):
+                kid = c >> 32
+                wid = (c & ((1 << 32) - 1)) - (1 << 31)
+                slot_of_uniq[j] = self.agg.alloc(
+                    f"{self.keys[kid]}\x00{wid}"
+                )
+                if (kid, wid) not in self.open_close_us:
+                    self.open_close_us[(kid, wid)] = (
+                        spec.align_us
+                        + wid * spec.offset_us
+                        + spec.length_us
+                    )
+                    self._open_cache = None
+            if not len(comp):
+                return
             _flight.RECORDER.record(
                 "device_dispatch", tier="window", rows=len(val_rep)
             )
-            self.agg.update_ids(slot_of_uniq[inverse], val_rep)
+            slots_rep = slot_of_uniq[inverse]
+        self.agg.update_ids(slots_rep, val_rep)
 
     def _open_arrays(self):
         """Cached parallel arrays of the open-window table so the
@@ -601,40 +610,50 @@ class DeviceWindowAggState:
     ) -> List[Tuple[str, Tuple[int, str, Any]]]:
         if not self.open_close_us:
             return []
-        kids_arr, wids_arr, closes_arr = self._open_arrays()
-        base, sys_at = clock if clock is not None else (
-            self.base_us,
-            self.sys_at_base,
-        )
-        wm = base[kids_arr] + (now_us - sys_at[kids_arr])
-        due_rows = np.nonzero(closes_arr <= wm)[0]
-        if not len(due_rows):
-            return []
-        due = [
-            (int(kids_arr[i]), int(wids_arr[i]), float(closes_arr[i]))
-            for i in due_rows
-        ]
+        # Ledger: `close_scan` (the due scan over the open windows),
+        # `fetch` (inside ``snapshots_for``), `close_emit` (the loop
+        # over the windows that close).
+        with _flight.span("close_scan") as scan:
+            kids_arr, wids_arr, closes_arr = self._open_arrays()
+            scan.rows = len(closes_arr)
+            base, sys_at = clock if clock is not None else (
+                self.base_us,
+                self.sys_at_base,
+            )
+            wm = base[kids_arr] + (now_us - sys_at[kids_arr])
+            due_rows = np.nonzero(closes_arr <= wm)[0]
+            if not len(due_rows):
+                return []
+            due = [
+                (int(kids_arr[i]), int(wids_arr[i]), float(closes_arr[i]))
+                for i in due_rows
+            ]
+            slot_keys = [
+                f"{self.keys[kid]}\x00{wid}" for kid, wid, _ in due
+            ]
         events = []
         # bytewax: allow[BTX-DRAIN] — the windower's .agg is its own slot table (never residency-wrapped; the driver evicts only the keyed-agg/scan tiers), and this due-window fetch runs inside the deferred device phase the pipeline worker owns
-        snaps = self.agg.snapshots_for(
-            [f"{self.keys[kid]}\x00{wid}" for kid, wid, _ in due]
-        )
+        snaps = self.agg.snapshots_for(slot_keys)
         from bytewax_tpu.operators.windowing import WindowMetadata
 
-        for (kid, wid, close_us), (_ck, snap) in zip(due, snaps):
-            key = self.keys[kid]
-            value = self._finalize_one(snap)
-            del self.open_close_us[(kid, wid)]
-            self.agg.discard(f"{key}\x00{wid}")
-            events.append((key, (wid, "E", value)))
-            open_dt = datetime.fromtimestamp(
-                (close_us - self.spec.length_us) / _US, tz=timezone.utc
-            )
-            close_dt = datetime.fromtimestamp(close_us / _US, tz=timezone.utc)
-            events.append(
-                (key, (wid, "M", WindowMetadata(open_dt, close_dt)))
-            )
-        self._open_cache = None
+        with _flight.span("close_emit", rows=len(due)):
+            for (kid, wid, close_us), (_ck, snap) in zip(due, snaps):
+                key = self.keys[kid]
+                value = self._finalize_one(snap)
+                del self.open_close_us[(kid, wid)]
+                self.agg.discard(f"{key}\x00{wid}")
+                events.append((key, (wid, "E", value)))
+                open_dt = datetime.fromtimestamp(
+                    (close_us - self.spec.length_us) / _US,
+                    tz=timezone.utc,
+                )
+                close_dt = datetime.fromtimestamp(
+                    close_us / _US, tz=timezone.utc
+                )
+                events.append(
+                    (key, (wid, "M", WindowMetadata(open_dt, close_dt)))
+                )
+            self._open_cache = None
         return events
 
     def _finalize_one(self, snap: Any) -> Any:
@@ -798,7 +817,10 @@ class DeviceWindowAggState:
         finish at 10^5 keys)."""
         slot_states: List[Tuple[str, Any]] = []
         # One id allocation for the page: the clock arrays grow once.
-        kids = self._key_ids_for([key for key, _snap in items]).tolist()
+        with _flight.span("encode", rows=len(items)):
+            kids = self._key_ids_for(
+                [key for key, _snap in items]
+            ).tolist()
         for kid, (key, snap) in zip(kids, items):
             self._load_clock(kid, snap)
             slot_states.extend(self._load_windows(key, kid, snap))
@@ -964,46 +986,47 @@ class DeviceSessionAggState(DeviceWindowAggState):
         n = len(ts_ok)
         if not n:
             return
-        order = np.lexsort((ts_ok, kids_ok))
-        k = kids_ok[order]
-        t = ts_ok[order]
-        v = np.asarray(vals_ok)[order]
-        # Runs: maximal (key, ts-sorted) stretches with consecutive
-        # gaps <= gap.  Runs are disjoint and processed in ts order
-        # per key, so a run that bridges two existing sessions via
-        # transitive extension is handled by _place_run seeing the
-        # already-extended interval.
-        new_run = np.empty(n, dtype=bool)
-        new_run[0] = True
-        np.logical_or(
-            k[1:] != k[:-1],
-            (t[1:] - t[:-1]) > self.spec.gap_us,
-            out=new_run[1:],
-        )
-        run_of_row = np.cumsum(new_run) - 1
-        starts = np.nonzero(new_run)[0]
-        ends = np.append(starts[1:], n) - 1
-        slot_of_run = np.empty(len(starts), dtype=np.int32)
-        for r in range(len(starts)):
-            kid = int(k[starts[r]])
-            wid = self._place_run(kid, float(t[starts[r]]), float(t[ends[r]]))
-            # Fold into the session's existing slot when it has one:
-            # a continuously-active session must stay O(1) state, not
-            # accumulate a slot per batch.  (Extra slots only ever
-            # come from merges, which concatenate lists.)
-            slots = self.session_slots[(kid, wid)]
-            if slots:
-                slot_key = slots[0]
-            else:
-                slot_key = f"{self.keys[kid]}\x00{wid}\x00{self._slot_seq}"
-                self._slot_seq += 1
-                slots.append(slot_key)
-            slot_of_run[r] = self.agg.alloc(slot_key)
-        _flight.RECORDER.count("window_rows_ingested", len(v))
-        _flight.RECORDER.record(
-            "device_dispatch", tier="session", rows=len(v)
-        )
-        self.agg.update_ids(slot_of_run[run_of_row], v)
+        with _flight.span("prep", rows=n):
+            order = np.lexsort((ts_ok, kids_ok))
+            k = kids_ok[order]
+            t = ts_ok[order]
+            v = np.asarray(vals_ok)[order]
+            # Runs: maximal (key, ts-sorted) stretches with consecutive
+            # gaps <= gap.  Runs are disjoint and processed in ts order
+            # per key, so a run that bridges two existing sessions via
+            # transitive extension is handled by _place_run seeing the
+            # already-extended interval.
+            new_run = np.empty(n, dtype=bool)
+            new_run[0] = True
+            np.logical_or(
+                k[1:] != k[:-1],
+                (t[1:] - t[:-1]) > self.spec.gap_us,
+                out=new_run[1:],
+            )
+            run_of_row = np.cumsum(new_run) - 1
+            starts = np.nonzero(new_run)[0]
+            ends = np.append(starts[1:], n) - 1
+            slot_of_run = np.empty(len(starts), dtype=np.int32)
+            for r in range(len(starts)):
+                kid = int(k[starts[r]])
+                wid = self._place_run(kid, float(t[starts[r]]), float(t[ends[r]]))
+                # Fold into the session's existing slot when it has one:
+                # a continuously-active session must stay O(1) state, not
+                # accumulate a slot per batch.  (Extra slots only ever
+                # come from merges, which concatenate lists.)
+                slots = self.session_slots[(kid, wid)]
+                if slots:
+                    slot_key = slots[0]
+                else:
+                    slot_key = f"{self.keys[kid]}\x00{wid}\x00{self._slot_seq}"
+                    self._slot_seq += 1
+                    slots.append(slot_key)
+                slot_of_run[r] = self.agg.alloc(slot_key)
+            _flight.RECORDER.record(
+                "device_dispatch", tier="session", rows=len(v)
+            )
+            slots_rep = slot_of_run[run_of_row]
+        self.agg.update_ids(slots_rep, v)
 
     def _combine(self, snaps: List[Any]) -> Any:
         """Combine slot accumulators host-side (kind algebra over a
@@ -1059,34 +1082,38 @@ class DeviceSessionAggState(DeviceWindowAggState):
     ) -> List[Tuple[str, Tuple[int, str, Any]]]:
         if not self.open_close_us:
             return []
-        kids_arr, wids_arr, dues_arr = self._open_arrays()
-        base, sys_at = clock if clock is not None else (
-            self.base_us,
-            self.sys_at_base,
-        )
-        wm = base[kids_arr] + (now_us - sys_at[kids_arr])
-        # Strict: a session closes when the watermark passes close +
-        # gap (host: close_time < watermark - gap), not at equality.
-        due_rows = np.nonzero(dues_arr < wm)[0]
-        if not len(due_rows):
-            return []
+        with _flight.span("close_scan") as scan:
+            kids_arr, wids_arr, dues_arr = self._open_arrays()
+            scan.rows = len(dues_arr)
+            base, sys_at = clock if clock is not None else (
+                self.base_us,
+                self.sys_at_base,
+            )
+            wm = base[kids_arr] + (now_us - sys_at[kids_arr])
+            # Strict: a session closes when the watermark passes close
+            # + gap (host: close_time < watermark - gap), not at
+            # equality.
+            due_rows = np.nonzero(dues_arr < wm)[0]
+            if not len(due_rows):
+                return []
+            due = [(int(kids_arr[i]), int(wids_arr[i])) for i in due_rows]
         from bytewax_tpu.operators.windowing import WindowMetadata
 
-        due = [(int(kids_arr[i]), int(wids_arr[i])) for i in due_rows]
         events = []
         accs = self._session_accs(due, discard=True)
-        for (kid, wid), acc in zip(due, accs):
-            key = self.keys[kid]
-            s = self.sessions[kid].pop(wid)
-            del self.open_close_us[(kid, wid)]
-            events.append((key, (wid, "E", self._finalize_one(acc))))
-            meta = WindowMetadata(
-                datetime.fromtimestamp(s[0] / _US, tz=timezone.utc),
-                datetime.fromtimestamp(s[1] / _US, tz=timezone.utc),
-                set(s[2]),
-            )
-            events.append((key, (wid, "M", meta)))
-        self._open_cache = None
+        with _flight.span("close_emit", rows=len(due)):
+            for (kid, wid), acc in zip(due, accs):
+                key = self.keys[kid]
+                s = self.sessions[kid].pop(wid)
+                del self.open_close_us[(kid, wid)]
+                events.append((key, (wid, "E", self._finalize_one(acc))))
+                meta = WindowMetadata(
+                    datetime.fromtimestamp(s[0] / _US, tz=timezone.utc),
+                    datetime.fromtimestamp(s[1] / _US, tz=timezone.utc),
+                    set(s[2]),
+                )
+                events.append((key, (wid, "M", meta)))
+            self._open_cache = None
         return events
 
     # -- recovery -----------------------------------------------------------

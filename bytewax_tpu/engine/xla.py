@@ -239,9 +239,11 @@ class DeviceAggState:
     def update_slots(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
         """Fold rows into pre-allocated slots (fast path for callers
         managing their own key→slot mapping via :meth:`alloc`)."""
-        self._pick_dtype(values)
-        self._ensure_fields()
-        self._scatter(slot_ids.astype(np.int32), values)
+        with _flight.span("prep", rows=len(values)):
+            self._pick_dtype(values)
+            self._ensure_fields()
+            slot_ids = slot_ids.astype(np.int32)
+        self._scatter(slot_ids, values)
 
     # The id-based fold surface shared with ShardedAggState: ids are
     # whatever :meth:`alloc` returned (slots here, wire kids there).
@@ -300,46 +302,48 @@ class DeviceAggState:
         can't take, with no state mutated."""
         from bytewax_tpu.native import kv_encode as _kv_encode
 
-        n = len(items)
-        ids = np.empty(n, dtype=np.int32)
-        vals = np.empty(n, dtype=np.float64)
-        ivals = np.empty(n, dtype=np.int64)
-        try:
-            res = _kv_encode(items, self._iddict, ids, vals, ivals)
-        except TypeError as ex:
-            raise NonNumericValues(str(ex)) from ex
-        if res is None:
-            return None
-        new_keys, all_int = res
-        if all_int:
-            # Preserve the exact-integer accumulator the per-item
-            # path would have picked: the int64 lane is filled
-            # directly by the C pass (a float64 round-trip would
-            # round integers past 2^53).
-            vals = ivals
-        try:
-            vals = self._pick_dtype(vals)
-        except (NonNumericValues, TypeError):
-            # Undo the C pass's id assignments so a host fallback
-            # (or any caller that survives the error) sees a
-            # genuinely untouched state.
-            for k in new_keys:
-                self._iddict.pop(k, None)
-            raise
-        if new_keys:
-            self._id_keys.extend(new_keys)
-            self._id_to_slot = np.concatenate(
-                [
-                    self._id_to_slot,
-                    np.fromiter(
-                        (self.alloc(k) for k in new_keys),
-                        dtype=np.int32,
-                        count=len(new_keys),
-                    ),
-                ]
-            )
-        self._ensure_fields()
-        self._scatter(self._id_to_slot[ids], vals)
+        with _flight.span("prep", rows=len(items)):
+            n = len(items)
+            ids = np.empty(n, dtype=np.int32)
+            vals = np.empty(n, dtype=np.float64)
+            ivals = np.empty(n, dtype=np.int64)
+            try:
+                res = _kv_encode(items, self._iddict, ids, vals, ivals)
+            except TypeError as ex:
+                raise NonNumericValues(str(ex)) from ex
+            if res is None:
+                return None
+            new_keys, all_int = res
+            if all_int:
+                # Preserve the exact-integer accumulator the per-item
+                # path would have picked: the int64 lane is filled
+                # directly by the C pass (a float64 round-trip would
+                # round integers past 2^53).
+                vals = ivals
+            try:
+                vals = self._pick_dtype(vals)
+            except (NonNumericValues, TypeError):
+                # Undo the C pass's id assignments so a host fallback
+                # (or any caller that survives the error) sees a
+                # genuinely untouched state.
+                for k in new_keys:
+                    self._iddict.pop(k, None)
+                raise
+            if new_keys:
+                self._id_keys.extend(new_keys)
+                self._id_to_slot = np.concatenate(
+                    [
+                        self._id_to_slot,
+                        np.fromiter(
+                            (self.alloc(k) for k in new_keys),
+                            dtype=np.int32,
+                            count=len(new_keys),
+                        ),
+                    ]
+                )
+            self._ensure_fields()
+            slots = self._id_to_slot[ids]
+        self._scatter(slots, vals)
         counts = np.bincount(ids, minlength=len(self._id_keys))
         return [
             self._id_keys[i] for i in np.nonzero(counts)[0].tolist()
@@ -356,44 +360,49 @@ class DeviceAggState:
                 "pass a plain Python reducer for non-numeric data"
             )
             raise NonNumericValues(msg)
-        values = self._pick_dtype(values)
+        with _flight.span("prep", rows=len(values)):
+            values = self._pick_dtype(values)
         row_slots = self._enc.encode(
             keys, lambda ks: [self.alloc(k) for k in ks]
         )
-        self._ensure_fields()
-        self._scatter(row_slots.astype(np.int32, copy=False), values)
+        with _flight.span("prep"):
+            self._ensure_fields()
+            slots = row_slots.astype(np.int32, copy=False)
+        self._scatter(slots, values)
         return [
             self.slot_keys[s] for s in np.unique(row_slots).tolist()
         ]
 
     def _scatter(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
+        from bytewax_tpu.ops.pallas_fold import maybe_update_fields
+
         n = len(values)
         # Bucketed padding (engine/batching.py) so XLA sees few
         # distinct shapes; padding rows target the scratch slot
         # (capacity - 1).
         padded = pad_len(n)
-        slots_p = np.full(padded, self.capacity - 1, dtype=np.int32)
-        slots_p[:n] = slot_ids
-        vals_p = np.zeros(padded, dtype=np.dtype(self.dtype))
-        vals_p[:n] = values
-        _flight.note_transfer("h2d", slots_p.nbytes + vals_p.nbytes)
-        from bytewax_tpu.ops.pallas_fold import maybe_update_fields
-
-        self._fields = maybe_update_fields(
-            self.kind,
-            self._fields,
-            jax.device_put(slots_p),
-            jax.device_put(vals_p),
-        )
+        with _flight.span("h2d", rows=padded):
+            slots_p = np.full(padded, self.capacity - 1, dtype=np.int32)
+            slots_p[:n] = slot_ids
+            vals_p = np.zeros(padded, dtype=np.dtype(self.dtype))
+            vals_p[:n] = values
+            _flight.note_transfer("h2d", slots_p.nbytes + vals_p.nbytes)
+            slots_d = jax.device_put(slots_p)
+            vals_d = jax.device_put(vals_p)
+        with _flight.span("dispatch"):
+            self._fields = maybe_update_fields(
+                self.kind, self._fields, slots_d, vals_d
+            )
 
     def _fetch(self) -> Dict[str, np.ndarray]:
         """One stacked device→host transfer for all fields (one
         round-trip instead of one per field)."""
         names = list(self.kind.fields)
-        stacked = np.asarray(
-            jnp.stack([self._fields[name] for name in names])
-        )
-        _flight.note_transfer("d2h", stacked.nbytes)
+        with _flight.span("fetch", rows=self.capacity):
+            stacked = np.asarray(
+                jnp.stack([self._fields[name] for name in names])
+            )
+            _flight.note_transfer("d2h", stacked.nbytes)
         return {name: stacked[i] for i, name in enumerate(names)}
 
     def _sync_vocab(self, ids: np.ndarray, vocab: np.ndarray) -> np.ndarray:
@@ -411,68 +420,93 @@ class DeviceAggState:
         if had_new or self._dev_map is None:
             # Rebuild the device table: unseen ids and the padding
             # sentinel (index len(vocab)) route to the scratch slot.
-            table = np.append(self._vocab.table, -1)
-            table = np.where(table < 0, self.capacity - 1, table).astype(
-                np.int32
-            )
-            _flight.note_transfer("h2d", table.nbytes)
-            self._dev_map = jax.device_put(table)
+            with _flight.span("h2d") as sp:
+                table = np.append(self._vocab.table, -1)
+                table = np.where(
+                    table < 0, self.capacity - 1, table
+                ).astype(np.int32)
+                sp.rows = len(table)
+                _flight.note_transfer("h2d", table.nbytes)
+                self._dev_map = jax.device_put(table)
         return uniq
 
     def update_batch(self, batch: ArrayBatch) -> List[str]:
         if "key_id" in batch.cols and batch.key_vocab is not None:
-            ids = batch.numpy("key_id")
-            values = batch.numpy("value")
-            quantized = (
-                batch.value_scale is not None
-                and values.dtype == np.int16
-            )
-            if batch.value_scale is not None and self.dtype != jnp.float32:
-                msg = (
-                    "fixed-point (value_scale) batches need a float "
-                    "accumulator, but earlier batches locked this "
-                    "step's state to an integer dtype"
+            # Ledger: `prep` ends before the vocab sync (`encode`) and
+            # begins again after it, so no work span holds another.
+            with _flight.span("prep") as sp:
+                ids = batch.numpy("key_id")
+                values = batch.numpy("value")
+                sp.rows = len(values)
+                quantized = (
+                    batch.value_scale is not None
+                    and values.dtype == np.int16
                 )
-                raise TypeError(msg)
-            if batch.value_scale is not None and not quantized:
-                # Fixed-point values in a non-int16 carrier: dequantize
-                # host-side into the (float) accumulator dtype.
-                values = (values * batch.value_scale).astype(np.float32)
-            elif not quantized:
-                values = self._pick_dtype(values)
+                if (
+                    batch.value_scale is not None
+                    and self.dtype != jnp.float32
+                ):
+                    msg = (
+                        "fixed-point (value_scale) batches need a float "
+                        "accumulator, but earlier batches locked this "
+                        "step's state to an integer dtype"
+                    )
+                    raise TypeError(msg)
+                if batch.value_scale is not None and not quantized:
+                    # Fixed-point values in a non-int16 carrier:
+                    # dequantize host-side into the (float)
+                    # accumulator dtype.
+                    values = (values * batch.value_scale).astype(
+                        np.float32
+                    )
+                elif not quantized:
+                    values = self._pick_dtype(values)
             uniq = self._sync_vocab(ids, batch.key_vocab)
-            self._ensure_fields()
-            n = len(values)
-            sentinel = len(self._vocab.table)
-            padded = pad_len(n)
+            with _flight.span("prep"):
+                self._ensure_fields()
+                n = len(values)
+                sentinel = len(self._vocab.table)
+                padded = pad_len(n)
             if quantized and sentinel < 2**15:
                 # Fixed-point fast path: one int16 [2, n] transfer.
-                packed = np.full((2, padded), sentinel, dtype=np.int16)
-                packed[0, :n] = ids
-                packed[1, :n] = values
-                packed[1, n:] = 0
-                _flight.note_transfer("h2d", packed.nbytes)
-                self._fields = update_fields_packed(
-                    self.kind,
-                    self._fields,
-                    self._dev_map,
-                    jax.device_put(packed),
-                    jnp.float32(batch.value_scale),
-                )
+                with _flight.span("h2d", rows=padded):
+                    packed = np.full(
+                        (2, padded), sentinel, dtype=np.int16
+                    )
+                    packed[0, :n] = ids
+                    packed[1, :n] = values
+                    packed[1, n:] = 0
+                    _flight.note_transfer("h2d", packed.nbytes)
+                    packed_d = jax.device_put(packed)
+                    scale = jnp.float32(batch.value_scale)
+                with _flight.span("dispatch"):
+                    self._fields = update_fields_packed(
+                        self.kind,
+                        self._fields,
+                        self._dev_map,
+                        packed_d,
+                        scale,
+                    )
             else:
-                id_dtype = np.int16 if sentinel < 2**15 else np.int32
-                ids_p = np.full(padded, sentinel, dtype=id_dtype)
-                ids_p[:n] = ids
-                vals_p = np.zeros(padded, dtype=np.dtype(self.dtype))
-                vals_p[:n] = values
-                _flight.note_transfer("h2d", ids_p.nbytes + vals_p.nbytes)
-                self._fields = update_fields_vocab(
-                    self.kind,
-                    self._fields,
-                    self._dev_map,
-                    jax.device_put(ids_p),
-                    jax.device_put(vals_p),
-                )
+                with _flight.span("h2d", rows=padded):
+                    id_dtype = np.int16 if sentinel < 2**15 else np.int32
+                    ids_p = np.full(padded, sentinel, dtype=id_dtype)
+                    ids_p[:n] = ids
+                    vals_p = np.zeros(padded, dtype=np.dtype(self.dtype))
+                    vals_p[:n] = values
+                    _flight.note_transfer(
+                        "h2d", ids_p.nbytes + vals_p.nbytes
+                    )
+                    ids_d = jax.device_put(ids_p)
+                    vals_d = jax.device_put(vals_p)
+                with _flight.span("dispatch"):
+                    self._fields = update_fields_vocab(
+                        self.kind,
+                        self._fields,
+                        self._dev_map,
+                        ids_d,
+                        vals_d,
+                    )
             return [str(self._vocab.vocab[e]) for e in uniq.tolist()]
         if "key" in batch.cols:
             values = batch.numpy("value")
@@ -556,17 +590,18 @@ class DeviceAggState:
         for name in names:
             cols[name][n:] = cols[name][0]
         self._ensure_fields()
-        _flight.note_transfer(
-            "h2d",
-            slots.nbytes + sum(c.nbytes for c in cols.values()),
-        )
-        dev_slots = jax.device_put(slots)
-        for name in names:
-            self._fields[name] = (
-                self._fields[name]
-                .at[dev_slots]
-                .set(jax.device_put(cols[name]))
+        with _flight.span("h2d", rows=padded):
+            _flight.note_transfer(
+                "h2d",
+                slots.nbytes + sum(c.nbytes for c in cols.values()),
             )
+            dev_slots = jax.device_put(slots)
+            for name in names:
+                self._fields[name] = (
+                    self._fields[name]
+                    .at[dev_slots]
+                    .set(jax.device_put(cols[name]))
+                )
 
     def snapshots_for(self, keys: List[str]) -> List[Tuple[str, Any]]:
         """Host-format snapshots of specific keys (one device_get)."""
@@ -574,12 +609,18 @@ class DeviceAggState:
             return [(k, None) for k in keys]
         host = self._fetch()
         out = []
-        for key in keys:
-            slot = self.key_to_slot.get(key)
-            if slot is None:
-                out.append((key, None))
-            else:
-                out.append((key, _snap_of(self.kind_name, host, slot)))
+        # Ledger: turning the fetched slots into host-format states
+        # is part of `close_emit` (its rows are counted by the close
+        # that asked).
+        with _flight.span("close_emit"):
+            for key in keys:
+                slot = self.key_to_slot.get(key)
+                if slot is None:
+                    out.append((key, None))
+                else:
+                    out.append(
+                        (key, _snap_of(self.kind_name, host, slot))
+                    )
         return out
 
     # -- finalization ------------------------------------------------------
@@ -591,10 +632,14 @@ class DeviceAggState:
             return []
         self._ensure_fields()
         host = self._fetch()
-        out = [
-            (key, _final_of(self.kind_name, host, self.key_to_slot[key]))
-            for key in sorted(self.key_to_slot)
-        ]
+        with _flight.span("close_emit", rows=len(self.key_to_slot)):
+            out = [
+                (
+                    key,
+                    _final_of(self.kind_name, host, self.key_to_slot[key]),
+                )
+                for key in sorted(self.key_to_slot)
+            ]
         self.key_to_slot.clear()
         self.slot_keys.clear()
         self._fields = None

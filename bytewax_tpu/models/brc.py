@@ -17,6 +17,7 @@ import numpy as np
 import bytewax_tpu.operators as op
 from bytewax_tpu import xla
 from bytewax_tpu.dataflow import Dataflow
+from bytewax_tpu.engine import flight as _flight
 from bytewax_tpu.engine.arrays import ArrayBatch
 from bytewax_tpu.inputs import (
     DynamicSource,
@@ -103,22 +104,26 @@ class _BrcFilePartition(StatefulSourcePartition):
     def next_batch(self) -> ArrayBatch:
         if self._pos >= self._end and not self._carry:
             raise StopIteration()
-        self._f.seek(self._pos)
-        want = min(self._chunk_bytes, self._end - self._pos)
-        raw = self._carry + self._f.read(want)
-        self._pos += want
-        if not raw:
-            raise StopIteration()
-        if self._pos >= self._end:
-            cut = len(raw)
-            if not raw.endswith(b"\n"):
-                raw += b"\n"
+        # Ledger: read, split, native parse and the vocabulary are the
+        # `parse` phase (inside the driver's `ingest`).
+        with _flight.span("parse") as sp:
+            self._f.seek(self._pos)
+            want = min(self._chunk_bytes, self._end - self._pos)
+            raw = self._carry + self._f.read(want)
+            self._pos += want
+            if not raw:
+                raise StopIteration()
+            if self._pos >= self._end:
                 cut = len(raw)
-        else:
-            cut = self._parser.split_point(raw)
-        chunk, self._carry = raw[:cut], raw[cut:]
-        ids, temps = self._parser.parse(chunk)
-        vocab = self._parser.vocab()
+                if not raw.endswith(b"\n"):
+                    raw += b"\n"
+                    cut = len(raw)
+            else:
+                cut = self._parser.split_point(raw)
+            chunk, self._carry = raw[:cut], raw[cut:]
+            ids, temps = self._parser.parse(chunk)
+            vocab = self._parser.vocab()
+            sp.rows = len(ids)
         return ArrayBatch(
             {"key_id": ids, "value": temps},
             key_vocab=vocab,

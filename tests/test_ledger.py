@@ -31,9 +31,10 @@ ZERO_TD = timedelta(seconds=0)
 
 #: Ledger phases measured on the main thread: disjoint exclusive
 #: intervals, so their per-epoch sum may never exceed the epoch wall
-#: time ("device" runs on the pipeline worker and overlaps).
+#: time ("device" and its children, "device/prep", run on the
+#: pipeline worker and overlap).
 _MAIN_PHASES_ONLY = lambda phases: {  # noqa: E731
-    p: v for p, v in phases.items() if p != "device"
+    p: v for p, v in phases.items() if p.split("/")[0] != "device"
 }
 
 
