@@ -769,17 +769,6 @@ RACE_WORKER_CARVEOUTS: FrozenSet[str] = frozenset(
 #: ``module:Class.attr`` (``module:<globals>.name`` for module
 #: globals).
 SHARED_STATE: Dict[str, str] = {
-    "bytewax_tpu.engine.arrays:KeyEncoder._ids": (
-        "instance-per-owner: source/router encoders mutate on main, "
-        "a device state's encoder mutates only inside its step's "
-        "ordered lane (main touches it at drain points only); the "
-        "attribute-level analysis is instance-insensitive"
-    ),
-    "bytewax_tpu.engine.arrays:KeyEncoder._sorted": (
-        "instance-per-owner: same ownership split as "
-        "KeyEncoder._ids — no encoder instance is ever shared "
-        "between the lane and per-batch main code"
-    ),
     "bytewax_tpu.engine.driver:_OpRt._m_timers": (
         "memoized tracing-timer handles: GIL-atomic dict get/set; a "
         "racy miss creates one duplicate handle and drops it, never "

@@ -306,6 +306,22 @@ def _annotate_accel_bound(plan: Plan) -> None:
         op.conf["_accel_bound"] = bound
 
 
+def _annotate_meta_live(plan: Plan) -> None:
+    """Tell each device-lowered window step whether its ``meta``
+    stream is read: with the ``unwrap_meta`` tap pruned (below) no
+    consumer can see an "M" event, and the device tier builds none
+    (two ``datetime``s and a ``WindowMetadata`` a closed window).
+    Read from the plan, so every cluster process agrees."""
+    for op in plan.ops:
+        spec = op.conf.get("_accel") if op.name == "stateful_batch" else None
+        if hasattr(spec, "meta_live"):
+            spec.meta_live = any(
+                plan.ops[ci].step_id.endswith(".unwrap_meta")
+                for s in op.down_streams()
+                for ci, _port in plan.consumers.get(s.stream_id, [])
+            )
+
+
 def _prune_dead_taps(plan: Plan) -> None:
     """Drop core steps marked ``_prunable`` (pure internal shims —
     the window operator's unwrap taps) whose output streams have no
@@ -338,6 +354,7 @@ def flatten(flow: Dataflow) -> Plan:
         _walk(op, plan)
     _index(plan)
     _prune_dead_taps(plan)
+    _annotate_meta_live(plan)
     _annotate_accel_bound(plan)
     names = {op.name for op in plan.ops}
     if "input" not in names:

@@ -359,6 +359,8 @@ def device_footprint(state: Any) -> Tuple[int, int]:
             m = getattr(obj, attr, None)
             if isinstance(m, dict):
                 keys = max(keys, len(m))
+        # A window state's slots carry no key: it counts them itself.
+        keys = max(keys, getattr(obj, "open_count", 0))
         fields = getattr(obj, "_fields", None)
         if isinstance(fields, dict) and id(fields) not in field_ids:
             field_ids.add(id(fields))

@@ -38,7 +38,11 @@ from bytewax_tpu.engine.xla import (
     DeviceAggState,
     NonNumericValues,
     _final_of,
-    _snap_of,
+    _field_vals,
+    _snaps_for,
+    _snaps_of,
+    _state_columns,
+    _take_free,
 )
 from bytewax_tpu.ops.segment import AGG_KINDS
 
@@ -318,7 +322,55 @@ class _ShardedSlots:
         """Hook: un-map released wire ids from any external-id vocab
         (one vectorized pass per batch of kids)."""
 
-    def _global_idx(self, kid: int) -> int:
+    # The id-based slot surface (see ``xla.DeviceAggState.open_ids``):
+    # the window tier keeps its own table of integer (key, window)
+    # composites and takes, reads and returns wire ids a delivery at
+    # a time.
+
+    def _owners(self, place: np.ndarray) -> np.ndarray:
+        """Owner shard of each integer composite: a multiplicative
+        hash, so neighbouring window ids spread over the shards.
+        Ownership is recomputed at every load and never persisted."""
+        mixed = place.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+        return ((mixed >> np.uint64(33)) % np.uint64(self.n_shards)).astype(
+            np.int64
+        )
+
+    def open_ids(self, place: np.ndarray) -> np.ndarray:
+        """One wire id per composite in ``place``, on the shard that
+        owns it; per shard, freed slots first (in :meth:`alloc`'s
+        order), then fresh ones."""
+        shards = self._owners(place)
+        kids = np.empty(len(place), dtype=np.int32)
+        for shard in range(self.n_shards):
+            rows = np.nonzero(shards == shard)[0]
+            if not len(rows):
+                continue
+            reused = _take_free(self._free[shard], len(rows))
+            fresh = len(rows) - len(reused)
+            start = self._shard_fill[shard]
+            while start + fresh > self.cap_per_shard - 1:
+                self._grow()
+            self._shard_fill[shard] = start + fresh
+            self._pending_reset.extend(
+                shard * self.cap_per_shard + slot for slot in reused
+            )
+            slots = np.empty(len(rows), dtype=np.int64)
+            slots[: len(reused)] = reused
+            slots[len(reused) :] = np.arange(start, start + fresh)
+            kids[rows] = slots * self.n_shards + shard
+        return kids
+
+    def release_ids(self, kids: np.ndarray) -> None:
+        """Take back wire ids :meth:`open_ids` gave out, with one
+        vocab drop for the batch."""
+        shards, slots = kids % self.n_shards, kids // self.n_shards
+        for shard in range(self.n_shards):
+            self._free[shard].extend(slots[shards == shard].tolist())
+        self._drop_vocab_ids(kids.tolist())
+
+    def _global_idx(self, kid):
+        """Row of a wire id (or an array of them) in the flat table."""
         shard, slot = kid % self.n_shards, kid // self.n_shards
         return shard * self.cap_per_shard + slot
 
@@ -702,23 +754,6 @@ class ShardedAggState(_ShardedSlots):
 
     # -- recovery ------------------------------------------------------------
 
-    def _field_vals(self, state: Any):
-        """Decompose a host-format snapshot into per-field scalars."""
-        kind = self.kind_name
-        if kind in ("sum", "min", "max", "count"):
-            name = "count" if kind == "count" else next(iter(self.kind.fields))
-            return {name: float(state)}
-        if kind == "mean":
-            total, count = state
-            return {"sum": float(total), "count": float(count)}
-        mn, mx, total, count = state  # stats
-        return {
-            "min": float(mn),
-            "max": float(mx),
-            "sum": float(total),
-            "count": float(count),
-        }
-
     def _maybe_lock_int(self, state: Any) -> None:
         import jax.numpy as jnp
 
@@ -735,7 +770,7 @@ class ShardedAggState(_ShardedSlots):
         import jax.numpy as jnp
 
         self._maybe_lock_int(state)
-        field_vals = self._field_vals(state)
+        field_vals = _field_vals(self.kind_name, state)
         kid = self.alloc(key)
         self._ensure_fields()
         idx = self._global_idx(kid)
@@ -746,41 +781,39 @@ class ShardedAggState(_ShardedSlots):
 
     def load_many(self, items) -> None:
         """Batched resume: ONE scatter per field per page (mirrors
-        ``DeviceAggState.load_many``).  Wire ids are resolved after
-        every alloc so capacity growth mid-page can't skew the
-        global indices."""
+        ``DeviceAggState.load_many``)."""
+        if not items:
+            return
+        self._maybe_lock_int(items[0][1])
+        kids = np.fromiter(
+            (self.alloc(key) for key, _state in items),
+            dtype=np.int32,
+            count=len(items),
+        )
+        self.load_ids(kids, [state for _key, state in items])
+
+    def load_ids(self, ids: np.ndarray, states) -> None:
+        """Install host-format snapshots into wire ids already given
+        out (:meth:`alloc`, :meth:`open_ids`).  Rows are resolved
+        here, after every alloc, so capacity growth mid-page can't
+        skew the global indices."""
         import jax
 
         from bytewax_tpu.engine.batching import pad_len
 
-        if not items:
+        n = len(states)
+        if not n:
             return
-        self._maybe_lock_int(items[0][1])
-        names = list(self.kind.fields)
-        # Pad to a bucket (repeating the first row — set is
-        # idempotent) so pages of any length share a few compiled
-        # shapes.
-        n = len(items)
+        self._maybe_lock_int(states[0])
         padded = pad_len(n, floor_pow=3)
-        cols = {
-            name: np.empty(padded, dtype=np.dtype(self.dtype))
-            for name in names
-        }
-        kids = []
-        for i, (key, state) in enumerate(items):
-            fv = self._field_vals(state)
-            kids.append(self.alloc(key))
-            for name in names:
-                cols[name][i] = fv[name]
-        for name in names:
-            cols[name][n:] = cols[name][0]
+        cols = _state_columns(self.kind, self.dtype, states, padded)
         self._ensure_fields()
         idxs = np.empty(padded, dtype=np.int64)
-        idxs[:n] = [self._global_idx(k) for k in kids]
+        idxs[:n] = self._global_idx(ids.astype(np.int64))
         idxs[n:] = idxs[0]
-        for name in names:
+        for name, col in cols.items():
             self._fields[name] = (
-                self._fields[name].at[idxs].set(jax.device_put(cols[name]))
+                self._fields[name].at[idxs].set(jax.device_put(col))
             )
 
     def _fetch(self) -> Dict[str, np.ndarray]:
@@ -800,22 +833,26 @@ class ShardedAggState(_ShardedSlots):
         if self._fields is None or not keys:
             return [(k, None) for k in keys]
         host = self._fetch()
-        out = []
         with _flight.span("close_emit"):
-            for key in keys:
-                kid = self.key_to_kid.get(key)
-                if kid is None:
-                    out.append((key, None))
-                else:
-                    out.append(
-                        (
-                            key,
-                            _snap_of(
-                                self.kind_name, host, self._global_idx(kid)
-                            ),
-                        )
-                    )
-        return out
+            kids = [self.key_to_kid.get(key) for key in keys]
+            return _snaps_for(
+                self.kind_name,
+                host,
+                [None if k is None else self._global_idx(k) for k in kids],
+                keys,
+            )
+
+    def states_of(self, kids: np.ndarray) -> List[Any]:
+        """Host-format snapshots of the given wire ids, in order (one
+        device_get)."""
+        self._ensure_fields()
+        host = self._fetch()
+        with _flight.span("close_emit"):
+            return _snaps_of(
+                self.kind_name,
+                host,
+                self._global_idx(kids.astype(np.int64)),
+            )
 
     # -- finalization --------------------------------------------------------
 

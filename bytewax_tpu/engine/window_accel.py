@@ -21,6 +21,18 @@ watermark``; a row exactly at the watermark is on time).
 ``tests/test_window_accel.py::test_window_accel_lateness_boundary``
 pins this.
 
+Slot note: a tumbling/sliding step keeps its open (key, window)s
+under integer composites (``kid << 32 | wid + 2**31``) in
+:class:`_OpenWindows` and opens, reads and releases their device
+slots a delivery at a time through the aggregate state's id-based
+surface (``open_ids`` / ``states_of`` / ``release_ids``): nothing runs
+once per (key, window) but the construction of the result tuples and
+``_finalize_one``.  "M" events (two ``datetime``s and a
+``WindowMetadata`` a window) are built only while the plan keeps the
+step's ``meta`` tap (``WindowAccelSpec.meta_live``, set at flatten
+time).  The session tier keeps string slot keys and the scalar
+``alloc`` / ``discard`` surface.
+
 Pipeline note (docs/performance.md): each ``on_batch*`` call returns
 ``(late_events, device_phase)`` — the host phase (vocab sync,
 watermark math, late classification) runs on the caller's thread and
@@ -34,6 +46,7 @@ remain synchronous and may only run with the pipeline drained.
 """
 
 from datetime import datetime, timedelta, timezone
+from itertools import repeat
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -48,6 +61,70 @@ _US = 1_000_000.0
 
 def _to_us(dt: datetime) -> float:
     return dt.timestamp() * _US
+
+
+# A (key, window) of a tumbling/sliding step as one integer:
+# ``kid << 32 | wid + 2**31``.
+_WID_BIAS = 1 << 31
+_WID_MASK = (1 << 32) - 1
+
+
+class _OpenWindows:
+    """The open (key, window)s of a tumbling/sliding step: parallel
+    arrays sorted by composite, so a delivery's windows are looked
+    up with one ``searchsorted`` and opened, scanned and closed as
+    arrays — no Python per window.
+
+    ``ids`` are the aggregate state's slot ids; ``seq`` numbers the
+    windows in the order they were opened, which is the order closes
+    and snapshots list them in.  A window's key id, window id and
+    close time are arithmetic on its composite and are not stored.
+    """
+
+    __slots__ = ("comp", "ids", "seq", "_next_seq")
+
+    def __init__(self):
+        self.comp = np.empty(0, dtype=np.int64)
+        self.ids = np.empty(0, dtype=np.int32)
+        self.seq = np.empty(0, dtype=np.int64)
+        self._next_seq = 0
+
+    def __len__(self) -> int:
+        return len(self.comp)
+
+    def ids_for(self, uniq: np.ndarray, agg) -> np.ndarray:
+        """Slot ids of sorted unique composites; those not yet open
+        are given slots by ``agg`` in one call and join the table."""
+        pos = np.searchsorted(self.comp, uniq)
+        found = np.zeros(len(uniq), dtype=bool)
+        inside = pos < len(self.comp)
+        found[inside] = self.comp[pos[inside]] == uniq[inside]
+        out = np.empty(len(uniq), dtype=np.int32)
+        out[found] = self.ids[pos[found]]
+        if not found.all():
+            new = ~found
+            opened = agg.open_ids(uniq[new])
+            _flight.RECORDER.count("window_opens", len(opened))
+            out[new] = opened
+            seq = np.arange(
+                self._next_seq, self._next_seq + len(opened), dtype=np.int64
+            )
+            self._next_seq += len(opened)
+            self.comp = np.insert(self.comp, pos[new], uniq[new])
+            self.ids = np.insert(self.ids, pos[new], opened)
+            self.seq = np.insert(self.seq, pos[new], seq)
+        return out
+
+    def in_order(self, rows: np.ndarray) -> np.ndarray:
+        """``rows`` in the order their windows were opened."""
+        return rows[np.argsort(self.seq[rows], kind="stable")]
+
+    def remove(self, rows: np.ndarray) -> None:
+        keep = np.ones(len(self.comp), dtype=bool)
+        keep[rows] = False
+        self.comp = self.comp[keep]
+        self.ids = self.ids[keep]
+        self.seq = self.seq[keep]
 
 
 class _LateTs:
@@ -94,6 +171,11 @@ class WindowAccelSpec:
         self.length_us = length.total_seconds() * _US
         self.offset_us = offset.total_seconds() * _US
         self.wait_us = wait.total_seconds() * _US
+
+    #: Whether anything reads the step's ``meta`` stream: the flatten
+    #: pass clears it when the ``unwrap_meta`` tap was pruned from the
+    #: plan, and the tumbling/sliding tier then builds no "M" events.
+    meta_live = True
 
     def make_state(self) -> "DeviceWindowAggState":
         return DeviceWindowAggState(self)
@@ -156,14 +238,11 @@ class DeviceWindowAggState:
         self.key_ids: Dict[str, int] = {}
         self.base_us = np.empty(0, dtype=np.float64)  # watermark base
         self.sys_at_base = np.empty(0, dtype=np.float64)
-        # Open windows: composite "k\x00wid" -> True (slot table lives
-        # in self.agg keyed by the same composite).
-        self.open_close_us: Dict[Tuple[int, int], float] = {}
+        # Open windows by integer composite, with the slot self.agg
+        # gave each (the session tier keeps a table of its own).
+        self.open = _OpenWindows()
         #: Keys touched since the last epoch snapshot.
         self.touched: set = set()
-        # Cached (kids, wids, closes) arrays over open_close_us;
-        # invalidated whenever the open-window set changes.
-        self._open_cache = None
         # Dictionary-encoded fast path: external id -> internal kid.
         self._vocab = VocabMap(dtype=np.int64)
         # Automatic encoder for plain string key columns.
@@ -245,8 +324,13 @@ class DeviceWindowAggState:
             vals = (vals * batch.value_scale).astype(np.float32)
         return self._ingest(kids, ts_us, vals)
 
+    @property
+    def open_count(self) -> int:
+        """Open (key, window)s, each holding a slot of ``self.agg``."""
+        return len(self.open)
+
     def is_empty(self) -> bool:
-        return not self.open_close_us and not self.keys and not self.touched
+        return not self.open_count and not self.keys and not self.touched
 
     def on_batch_items(self, items: List[Any]):
         """Itemized promotion: one native pass dictionary-encodes the
@@ -561,58 +645,35 @@ class DeviceWindowAggState:
                     in_window
                 ]
 
-            # Composite (key, window) ids; python work only per NEW
-            # composite, per-row mapping is pure numpy.
-            comp = (kid_rep << 32) + (wid_flat + (1 << 31))
-            uniq, inverse = np.unique(comp, return_inverse=True)
-            slot_of_uniq = np.empty(len(uniq), dtype=np.int32)
-            for j, c in enumerate(uniq.tolist()):
-                kid = c >> 32
-                wid = (c & ((1 << 32) - 1)) - (1 << 31)
-                slot_of_uniq[j] = self.agg.alloc(
-                    f"{self.keys[kid]}\x00{wid}"
-                )
-                if (kid, wid) not in self.open_close_us:
-                    self.open_close_us[(kid, wid)] = (
-                        spec.align_us
-                        + wid * spec.offset_us
-                        + spec.length_us
-                    )
-                    self._open_cache = None
+            # Composite (key, window) ids: one unique pass, one table
+            # lookup, one batched open for the windows that are new.
+            comp = (kid_rep << 32) + (wid_flat + _WID_BIAS)
             if not len(comp):
                 return
+            uniq, inverse = np.unique(comp, return_inverse=True)
+            slots_rep = self.open.ids_for(uniq, self.agg)[inverse]
             _flight.RECORDER.record(
                 "device_dispatch", tier="window", rows=len(val_rep)
             )
-            slots_rep = slot_of_uniq[inverse]
         self.agg.update_ids(slots_rep, val_rep)
 
     def _open_arrays(self):
-        """Cached parallel arrays of the open-window table so the
-        per-batch due check is vectorized (a Python loop here is
-        O(keys × windows) per batch at high cardinality)."""
-        if self._open_cache is None:
-            items = list(self.open_close_us.items())
-            kids = np.fromiter(
-                (k for (k, _w), _c in items), dtype=np.int64, count=len(items)
-            )
-            wids = np.fromiter(
-                (w for (_k, w), _c in items), dtype=np.int64, count=len(items)
-            )
-            closes = np.fromiter(
-                (c for _kw, c in items), dtype=np.float64, count=len(items)
-            )
-            self._open_cache = (kids, wids, closes)
-        return self._open_cache
+        """Parallel ``(kids, wids, closes)`` arrays over the open
+        windows (table order) for the vectorized due check."""
+        spec = self.spec
+        comp = self.open.comp
+        wids = (comp & _WID_MASK) - _WID_BIAS
+        closes = spec.align_us + wids * spec.offset_us + spec.length_us
+        return comp >> 32, wids, closes
 
     def _close_due(
         self, now_us: float, clock=None
     ) -> List[Tuple[str, Tuple[int, str, Any]]]:
-        if not self.open_close_us:
+        if not self.open_count:
             return []
         # Ledger: `close_scan` (the due scan over the open windows),
-        # `fetch` (inside ``snapshots_for``), `close_emit` (the loop
-        # over the windows that close).
+        # `fetch` (inside ``states_of``), `close_emit` (columns to
+        # events for the windows that close).
         with _flight.span("close_scan") as scan:
             kids_arr, wids_arr, closes_arr = self._open_arrays()
             scan.rows = len(closes_arr)
@@ -621,42 +682,48 @@ class DeviceWindowAggState:
                 self.sys_at_base,
             )
             wm = base[kids_arr] + (now_us - sys_at[kids_arr])
-            due_rows = np.nonzero(closes_arr <= wm)[0]
-            if not len(due_rows):
+            due = np.nonzero(closes_arr <= wm)[0]
+            if not len(due):
                 return []
-            due = [
-                (int(kids_arr[i]), int(wids_arr[i]), float(closes_arr[i]))
-                for i in due_rows
-            ]
-            slot_keys = [
-                f"{self.keys[kid]}\x00{wid}" for kid, wid, _ in due
-            ]
-        events = []
+            due = self.open.in_order(due)
+            ids = self.open.ids[due]
         # bytewax: allow[BTX-DRAIN] — the windower's .agg is its own slot table (never residency-wrapped; the driver evicts only the keyed-agg/scan tiers), and this due-window fetch runs inside the deferred device phase the pipeline worker owns
-        snaps = self.agg.snapshots_for(slot_keys)
-        from bytewax_tpu.operators.windowing import WindowMetadata
-
+        states = self.agg.states_of(ids)
         with _flight.span("close_emit", rows=len(due)):
-            for (kid, wid, close_us), (_ck, snap) in zip(due, snaps):
-                key = self.keys[kid]
-                value = self._finalize_one(snap)
-                del self.open_close_us[(kid, wid)]
-                self.agg.discard(f"{key}\x00{wid}")
-                events.append((key, (wid, "E", value)))
-                open_dt = datetime.fromtimestamp(
-                    (close_us - self.spec.length_us) / _US,
-                    tz=timezone.utc,
-                )
-                close_dt = datetime.fromtimestamp(
-                    close_us / _US, tz=timezone.utc
-                )
-                events.append(
-                    (key, (wid, "M", WindowMetadata(open_dt, close_dt)))
-                )
-            self._open_cache = None
+            self.open.remove(due)
+            self.agg.release_ids(ids)
+            keys = list(map(self.keys.__getitem__, kids_arr[due].tolist()))
+            wids = wids_arr[due].tolist()
+            values = map(self._finalize_one, states)
+            events = list(zip(keys, zip(wids, repeat("E"), values)))
+            if self.spec.meta_live:
+                metas = self._metas(closes_arr[due].tolist())
+                _flight.RECORDER.count("window_meta_events", len(metas))
+                # "E" then "M" per window, as the host tier emits.
+                both = [None] * (2 * len(events))
+                both[0::2] = events
+                both[1::2] = zip(keys, zip(wids, repeat("M"), metas))
+                events = both
         return events
 
+    def _metas(self, closes_us: List[float]) -> List[Any]:
+        """``WindowMetadata`` per close time."""
+        from bytewax_tpu.operators.windowing import WindowMetadata
+
+        length_us = self.spec.length_us
+        return [
+            WindowMetadata(
+                datetime.fromtimestamp(
+                    (close_us - length_us) / _US, tz=timezone.utc
+                ),
+                datetime.fromtimestamp(close_us / _US, tz=timezone.utc),
+            )
+            for close_us in closes_us
+        ]
+
     def _finalize_one(self, snap: Any) -> Any:
+        """A closed window's emitted value from its host-format fold
+        state: where a window's answer is produced."""
         kind = self.spec.kind
         if snap is None:
             return 0 if kind == "count" else None
@@ -678,7 +745,7 @@ class DeviceWindowAggState:
     def notify_at(self, clock=None) -> Optional[datetime]:
         """System time of the earliest window close: the instant the
         key's watermark reaches the close time."""
-        if not self.open_close_us:
+        if not self.open_count:
             return None
         kids_arr, _wids_arr, closes_arr = self._open_arrays()
         base, sys_at = clock if clock is not None else (
@@ -701,7 +768,6 @@ class DeviceWindowAggState:
         with no open windows snapshots as a discard (the host tier
         discards empty window logics the same way)."""
         from bytewax_tpu.operators.windowing import (
-            WindowMetadata,
             _EventClockState,
             _SlidingWindowerState,
             _WindowSnapshot,
@@ -712,38 +778,25 @@ class DeviceWindowAggState:
         # O(keys x open windows) host work plus a whole-table
         # readback per key, which an epoch close over 10^5 touched
         # keys never finishes.
-        wanted = {self.key_ids.get(key) for key in keys}
-        open_of: Dict[int, List[Tuple[int, float]]] = {}
-        for (kid, wid), close_us in self.open_close_us.items():
-            if kid in wanted:
-                open_of.setdefault(kid, []).append((wid, close_us))
-        state_of = dict(
-            self.agg.snapshots_for(
-                [
-                    f"{self.keys[kid]}\x00{wid}"
-                    for kid, wins in open_of.items()
-                    for wid, _close_us in wins
-                ]
-            )
-        )
+        rows = self._rows_of(keys)
+        kids_arr, wids_arr, closes_arr = self._open_arrays()
+        # bytewax: allow[BTX-DRAIN] — snapshots run with the pipeline drained (module docstring); the windower's .agg is its own slot table
+        states = self.agg.states_of(self.open.ids[rows]) if len(rows) else []
+        metas = self._metas(closes_arr[rows].tolist())
+        open_of: Dict[int, Tuple[dict, dict]] = {}
+        for kid, wid, meta, state in zip(
+            kids_arr[rows].tolist(), wids_arr[rows].tolist(), metas, states
+        ):
+            opened, folded = open_of.setdefault(kid, ({}, {}))
+            opened[wid] = meta
+            folded[wid] = state
         out = []
         for key in keys:
             kid = self.key_ids.get(key)
             if kid not in open_of:
                 out.append((key, None))
                 continue
-            opened = {}
-            states = {}
-            for wid, close_us in open_of[kid]:
-                open_dt = datetime.fromtimestamp(
-                    (close_us - self.spec.length_us) / _US,
-                    tz=timezone.utc,
-                )
-                close_dt = datetime.fromtimestamp(
-                    close_us / _US, tz=timezone.utc
-                )
-                opened[wid] = WindowMetadata(open_dt, close_dt)
-                states[wid] = state_of[f"{key}\x00{wid}"]
+            opened, folded = open_of[kid]
             base = self.base_us[kid]
             clock_state = _EventClockState(
                 system_time_of_max_event=datetime.fromtimestamp(
@@ -761,12 +814,21 @@ class DeviceWindowAggState:
                     _WindowSnapshot(
                         clock_state,
                         _SlidingWindowerState(opened=opened),
-                        states,
+                        folded,
                         [],
                     ),
                 )
             )
         return out
+
+    def _rows_of(self, keys: List[str]) -> np.ndarray:
+        """Table rows of the given keys' open windows, in the order
+        the windows were opened."""
+        wanted = [
+            kid for kid in map(self.key_ids.get, keys) if kid is not None
+        ]
+        held = np.isin(self.open.comp >> 32, np.asarray(wanted, dtype=np.int64))
+        return self.open.in_order(np.nonzero(held)[0])
 
     def demotion_snapshots(self):
         """Full-state drain for device→host demotion: host-format
@@ -815,32 +877,35 @@ class DeviceWindowAggState:
         the fold states of the whole page install with ONE scatter
         per field (a device dispatch per window per field does not
         finish at 10^5 keys)."""
-        slot_states: List[Tuple[str, Any]] = []
         # One id allocation for the page: the clock arrays grow once.
         with _flight.span("encode", rows=len(items)):
             kids = self._key_ids_for(
                 [key for key, _snap in items]
             ).tolist()
-        for kid, (key, snap) in zip(kids, items):
+        for kid, (_key, snap) in zip(kids, items):
             self._load_clock(kid, snap)
-            slot_states.extend(self._load_windows(key, kid, snap))
-        self._open_cache = None
-        self.agg.load_many(slot_states)
+        self._load_windows(kids, items)
         # Queued values fold ON TOP of the installed states.
         for kid, (_key, snap) in zip(kids, items):
             self._replay_queue(kid, snap)
 
     def _load_windows(
-        self, key: str, kid: int, snap: Any
-    ) -> List[Tuple[str, Any]]:
-        """Reopen one key's windows; returns the ``(slot key, fold
-        state)`` pairs to install on device."""
-        for wid, meta in snap.windower_state.opened.items():
-            self.open_close_us[(kid, wid)] = _to_us(meta.close_time)
-        return [
-            (f"{key}\x00{wid}", state)
-            for wid, state in snap.logic_states.items()
-        ]
+        self, kids: List[int], items: List[Tuple[str, Any]]
+    ) -> None:
+        """Reopen the page's windows (one batched open) and install
+        their fold states on device."""
+        comps, states = [], []
+        for kid, (_key, snap) in zip(kids, items):
+            folded = snap.logic_states
+            for wid in snap.windower_state.opened:
+                comps.append((kid << 32) + wid + _WID_BIAS)
+                states.append(folded[wid])
+        if not comps:
+            return
+        uniq, inverse = np.unique(
+            np.asarray(comps, dtype=np.int64), return_inverse=True
+        )
+        self.agg.load_ids(self.open.ids_for(uniq, self.agg)[inverse], states)
 
     # -- residency (engine/residency.py) ------------------------------------
     #
@@ -854,21 +919,18 @@ class DeviceWindowAggState:
 
     def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
         """Snapshot AND release the given keys: open windows close
-        their device slots; the per-key clock entries stay (a later
-        ``inject_keys`` restores the snapshotted clock)."""
-        out = []
-        for key, snap in self.snapshots_for(keys):
-            if snap is None:
-                continue
-            kid = self.key_ids[key]
-            for k2, wid in [
-                kw for kw in self.open_close_us if kw[0] == kid
-            ]:
-                del self.open_close_us[(k2, wid)]
-                self.agg.discard(f"{key}\x00{wid}")
-            self._open_cache = None
-            self.touched.discard(key)
-            out.append((key, snap))
+        their device slots (one release for the batch); the per-key
+        clock entries stay (a later ``inject_keys`` restores the
+        snapshotted clock)."""
+        out = [
+            (key, snap)
+            for key, snap in self.snapshots_for(keys)
+            if snap is not None
+        ]
+        rows = self._rows_of([key for key, _snap in out])
+        self.agg.release_ids(self.open.ids[rows])
+        self.open.remove(rows)
+        self.touched.difference_update(key for key, _snap in out)
         return out
 
     def inject_keys(self, items: List[Tuple[str, Any]]) -> None:
@@ -917,10 +979,36 @@ class DeviceSessionAggState(DeviceWindowAggState):
         #: session's accumulator
         self.session_slots: Dict[Tuple[int, int], List[str]] = {}
         self._slot_seq = 0
-        # For sessions, ``open_close_us`` holds each session's DUE
-        # time (close + gap) so the base class's vectorized due scan
-        # and ``notify_at`` apply unchanged; emission recovers the
-        # close time by subtracting the gap.
+        #: (kid, wid) -> the session's DUE time (close + gap), which
+        #: moves as the session grows and so is stored, unlike a
+        #: sliding window's; ``notify_at`` reads it through
+        #: :meth:`_open_arrays` as it reads the base class's table.
+        self.open_close_us: Dict[Tuple[int, int], float] = {}
+        # Cached (kids, wids, dues) arrays over open_close_us;
+        # invalidated whenever the open-session set changes.
+        self._open_cache = None
+
+    @property
+    def open_count(self) -> int:
+        return len(self.open_close_us)
+
+    def _open_arrays(self):
+        """Cached parallel arrays of the open-session table so the
+        per-batch due check is vectorized (a Python loop here is
+        O(keys × sessions) per batch at high cardinality)."""
+        if self._open_cache is None:
+            items = list(self.open_close_us.items())
+            kids = np.fromiter(
+                (k for (k, _w), _c in items), dtype=np.int64, count=len(items)
+            )
+            wids = np.fromiter(
+                (w for (_k, w), _c in items), dtype=np.int64, count=len(items)
+            )
+            dues = np.fromiter(
+                (c for _kw, c in items), dtype=np.float64, count=len(items)
+            )
+            self._open_cache = (kids, wids, dues)
+        return self._open_cache
 
     # -- session bookkeeping (per run, host Python) ------------------------
 
@@ -1186,41 +1274,45 @@ class DeviceSessionAggState(DeviceWindowAggState):
         return out
 
     def _load_windows(
-        self, key: str, kid: int, snap: Any
-    ) -> List[Tuple[str, Any]]:
-        """Session variant: reopen one key's sessions from a
-        host-tier session ``_WindowSnapshot``."""
-        st = snap.windower_state
-        self.next_wid[kid] = st.next_id
-        sess = self.sessions.setdefault(kid, {})
+        self, kids: List[int], items: List[Tuple[str, Any]]
+    ) -> None:
+        """Session variant: reopen the page's sessions from host-tier
+        session ``_WindowSnapshot``s, one install for the page."""
+        slot_states: List[Tuple[str, Any]] = []
         gap = self.spec.gap_us
-        for wid, meta in st.sessions.items():
-            sess[wid] = [
-                _to_us(meta.open_time),
-                _to_us(meta.close_time),
-                set(meta.merged_ids),
-            ]
-            self.session_slots[(kid, wid)] = []
-            self.open_close_us[(kid, wid)] = _to_us(meta.close_time) + gap
-        # A snapshot taken between a windower merge and the logic
-        # merge has the sessions dict merged but logic states still
-        # split per pre-merge id; resolve each state to its surviving
-        # session (chasing chained merges).
-        into = dict(st.merge_queue)
-        slot_states = []
-        for wid, state in snap.logic_states.items():
-            target = wid
-            seen = set()
-            while target in into and target not in seen:
-                seen.add(target)
-                target = into[target]
-            if target not in sess:
-                continue
-            slot_key = f"{key}\x00{target}\x00{self._slot_seq}"
-            self._slot_seq += 1
-            slot_states.append((slot_key, state))
-            self.session_slots[(kid, target)].append(slot_key)
-        return slot_states
+        for kid, (key, snap) in zip(kids, items):
+            st = snap.windower_state
+            self.next_wid[kid] = st.next_id
+            sess = self.sessions.setdefault(kid, {})
+            for wid, meta in st.sessions.items():
+                sess[wid] = [
+                    _to_us(meta.open_time),
+                    _to_us(meta.close_time),
+                    set(meta.merged_ids),
+                ]
+                self.session_slots[(kid, wid)] = []
+                self.open_close_us[(kid, wid)] = (
+                    _to_us(meta.close_time) + gap
+                )
+            # A snapshot taken between a windower merge and the logic
+            # merge has the sessions dict merged but logic states
+            # still split per pre-merge id; resolve each state to its
+            # surviving session (chasing chained merges).
+            into = dict(st.merge_queue)
+            for wid, state in snap.logic_states.items():
+                target = wid
+                seen = set()
+                while target in into and target not in seen:
+                    seen.add(target)
+                    target = into[target]
+                if target not in sess:
+                    continue
+                slot_key = f"{key}\x00{target}\x00{self._slot_seq}"
+                self._slot_seq += 1
+                slot_states.append((slot_key, state))
+                self.session_slots[(kid, target)].append(slot_key)
+        self._open_cache = None
+        self.agg.load_many(slot_states)
 
     def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
         """Session variant of the residency extract: open sessions

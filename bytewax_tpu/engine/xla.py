@@ -20,8 +20,10 @@ from bytewax_tpu.engine.arrays import ArrayBatch, KeyEncoder, VocabMap
 from bytewax_tpu.engine.batching import pad_len
 from bytewax_tpu.ops.segment import (
     AGG_KINDS,
+    AggKind,
     identity_for,
     init_fields,
+    reset_fields,
     update_fields,
     update_fields_packed,
     update_fields_vocab,
@@ -76,23 +78,75 @@ def _final_of(kind: str, fields: Dict[str, np.ndarray], i: int):
     raise AssertionError(kind)
 
 
-def _snap_of(kind: str, fields: Dict[str, np.ndarray], i: int):
-    # Single-field kinds snapshot the bare scalar so host-tier logics
-    # can resume from device snapshots and vice versa.
+def _snaps_of(kind: str, fields: Dict[str, np.ndarray], idx: np.ndarray):
+    """Host-format snapshots of the table rows ``idx``: one gather
+    and one ``tolist()`` a field, no Python per row but the tuples
+    themselves.  Single-field kinds snapshot the bare scalar (floats;
+    an ``int`` count) so host-tier logics can resume from device
+    snapshots and vice versa."""
+
+    def col(name: str) -> list:
+        return fields[name][idx].tolist()
+
     if kind in ("sum", "min", "max"):
-        return fields[next(iter(fields))][i].item()
+        return col(kind)
+    count = fields["count"][idx].astype(np.int64).tolist()
     if kind == "count":
-        return int(fields["count"][i].item())
+        return count
     if kind == "mean":
-        return (fields["sum"][i].item(), int(fields["count"][i].item()))
+        return list(zip(col("sum"), count))
     if kind == "stats":
-        return (
-            fields["min"][i].item(),
-            fields["max"][i].item(),
-            fields["sum"][i].item(),
-            int(fields["count"][i].item()),
-        )
+        return list(zip(col("min"), col("max"), col("sum"), count))
     raise AssertionError(kind)
+
+
+def _snaps_for(kind: str, fields, idx_of_key: List[Optional[int]], keys):
+    """``(key, snapshot)`` per key, ``None`` where a key has no row."""
+    have = [i for i in idx_of_key if i is not None]
+    snaps = iter(_snaps_of(kind, fields, np.asarray(have, dtype=np.int64)))
+    return [
+        (key, None if i is None else next(snaps))
+        for key, i in zip(keys, idx_of_key)
+    ]
+
+
+def _field_vals(kind: str, state: Any) -> Dict[str, float]:
+    """Decompose a host-format snapshot into per-field scalars."""
+    if kind in ("sum", "min", "max", "count"):
+        return {kind: float(state)}
+    if kind == "mean":
+        total, count = state
+        return {"sum": float(total), "count": float(count)}
+    mn, mx, total, count = state  # stats
+    return {
+        "min": float(mn),
+        "max": float(mx),
+        "sum": float(total),
+        "count": float(count),
+    }
+
+
+def _state_columns(kind: AggKind, dtype, states, padded: int):
+    """Host-format snapshots as one column a field, padded to a
+    bucket (repeating the first row — set is idempotent) so pages of
+    any length share a few compiled shapes."""
+    rows = [_field_vals(kind.name, state) for state in states]
+    cols = {}
+    for name in kind.fields:
+        col = np.empty(padded, dtype=np.dtype(dtype))
+        col[: len(rows)] = [row[name] for row in rows]
+        col[len(rows) :] = col[0]
+        cols[name] = col
+    return cols
+
+
+def _take_free(free: List[int], n: int) -> List[int]:
+    """Up to ``n`` slots off the end of a free list, in the order as
+    many ``pop()``s would give them."""
+    keep = max(len(free) - n, 0)
+    taken = free[keep:][::-1]
+    del free[keep:]
+    return taken
 
 
 class DeviceAggState:
@@ -152,7 +206,12 @@ class DeviceAggState:
         # The scratch slot moves to the new last index; any device
         # id→slot table pointing at the old scratch is stale.
         self._dev_map = None
-        self._ensure_fields()
+        if self._fields is None:
+            # Nothing folded yet: the table is made at its final size
+            # (and dtype) by the first update or load.
+            self.capacity = new_cap
+            return
+        self._apply_resets()
         grown = {}
         for name, (init, _op) in self.kind.fields.items():
             old = self._fields[name]
@@ -216,6 +275,48 @@ class DeviceAggState:
                 self._id_to_slot = np.empty(0, dtype=np.int32)
         return slot
 
+    # The id-based slot surface for a caller that keeps its own table
+    # of what a slot holds (the window tier: integer (key, window)
+    # composites): a whole delivery's slots are given out, read and
+    # taken back in one call each, and carry no key here.  Shared
+    # with ShardedAggState, where the ids are wire ids.
+
+    def open_ids(self, place: np.ndarray) -> np.ndarray:
+        """One slot per entry of ``place`` (only its length matters
+        on one device), in the order that many :meth:`alloc`s would
+        take them: freed slots from the end of the free list, then
+        fresh ones with one growth."""
+        n = len(place)
+        reused = _take_free(self._free, n)
+        self._pending_reset.extend(reused)
+        fresh = n - len(reused)
+        start = len(self.slot_keys)
+        if fresh:
+            self._grow_to(start + fresh + 1)
+            self.slot_keys.extend([None] * fresh)  # type: ignore[list-item]
+        slots = np.empty(n, dtype=np.int32)
+        slots[: len(reused)] = reused
+        slots[len(reused) :] = np.arange(start, start + fresh)
+        return slots
+
+    def release_ids(self, slots: np.ndarray) -> None:
+        """Take back slots :meth:`open_ids` gave out (reset when they
+        are given out again), with one vocab drop for the batch."""
+        freed = slots.tolist()
+        self._free.extend(freed)
+        if self._vocab.drop_ids(freed):
+            self._dev_map = None
+
+    def states_of(self, slots: np.ndarray) -> List[Any]:
+        """Host-format snapshots of the given slots, in order (one
+        device_get)."""
+        self._ensure_fields()
+        host = self._fetch()
+        # Ledger: fetched columns to host-format states is part of
+        # `close_emit` (its rows are counted by the close that asked).
+        with _flight.span("close_emit"):
+            return _snaps_of(self.kind_name, host, slots)
+
     def _apply_resets(self) -> None:
         if self._fields is None:
             self._pending_reset.clear()
@@ -228,12 +329,9 @@ class DeviceAggState:
         padded = pad_len(n, floor_pow=3)
         slots_np = np.full(padded, self._pending_reset[0], dtype=np.int32)
         slots_np[:n] = self._pending_reset
-        slots = jnp.asarray(slots_np)
-        for name, (init, _op) in self.kind.fields.items():
-            arr = self._fields[name]
-            self._fields[name] = arr.at[slots].set(
-                identity_for(init, arr.dtype)
-            )
+        self._fields = reset_fields(
+            self.kind, self._fields, jax.device_put(slots_np)
+        )
         self._pending_reset.clear()
 
     def update_slots(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
@@ -521,23 +619,6 @@ class DeviceAggState:
 
     # -- recovery ----------------------------------------------------------
 
-    def _field_vals(self, state: Any) -> Dict[str, float]:
-        """Decompose a host-format snapshot into per-field scalars."""
-        kind = self.kind_name
-        if kind in ("sum", "min", "max", "count"):
-            name = "count" if kind == "count" else next(iter(self.kind.fields))
-            return {name: float(state)}
-        if kind == "mean":
-            total, count = state
-            return {"sum": float(total), "count": float(count)}
-        mn, mx, total, count = state  # stats
-        return {
-            "min": float(mn),
-            "max": float(mx),
-            "sum": float(total),
-            "count": float(count),
-        }
-
     def _maybe_lock_int(self, state: Any) -> None:
         if (
             self.kind_name in ("sum", "min", "max", "count")
@@ -551,7 +632,7 @@ class DeviceAggState:
         Slot assignment goes through :meth:`alloc` so freed (evicted/
         discarded) slots are reused instead of growing the table."""
         self._maybe_lock_int(state)
-        field_vals = self._field_vals(state)
+        field_vals = _field_vals(self.kind_name, state)
         slot = self.alloc(key)
         self._ensure_fields()
         for name, val in field_vals.items():
@@ -567,28 +648,29 @@ class DeviceAggState:
         if not items:
             return
         self._maybe_lock_int(items[0][1])
-        names = list(self.kind.fields)
-        # Pad to a bucket (repeating the first row — set is
-        # idempotent) so pages of any length share a few compiled
-        # shapes.
-        n = len(items)
+        # alloc reuses freed (evicted/discarded) slots and grows on
+        # demand.
+        slots = np.fromiter(
+            (self.alloc(key) for key, _state in items),
+            dtype=np.int32,
+            count=len(items),
+        )
+        self.load_ids(slots, [state for _key, state in items])
+
+    def load_ids(self, ids: np.ndarray, states: List[Any]) -> None:
+        """Install host-format snapshots into slots already given out
+        (:meth:`alloc`, :meth:`open_ids`): one scatter per field."""
+        n = len(states)
+        if not n:
+            return
+        self._maybe_lock_int(states[0])
         padded = pad_len(n, floor_pow=3)
-        cols = {
-            name: np.empty(padded, dtype=np.dtype(self.dtype))
-            for name in names
-        }
+        cols = _state_columns(self.kind, self.dtype, states, padded)
         slots = np.empty(padded, dtype=np.int32)
-        for i, (key, state) in enumerate(items):
-            fv = self._field_vals(state)
-            # alloc reuses freed (evicted/discarded) slots and grows
-            # on demand; pending resets apply in _ensure_fields below,
-            # BEFORE the scatter installs the resumed values.
-            slots[i] = self.alloc(key)
-            for name in names:
-                cols[name][i] = fv[name]
+        slots[:n] = ids
         slots[n:] = slots[0]
-        for name in names:
-            cols[name][n:] = cols[name][0]
+        # Pending resets apply here, BEFORE the scatter installs the
+        # resumed values.
         self._ensure_fields()
         with _flight.span("h2d", rows=padded):
             _flight.note_transfer(
@@ -596,11 +678,9 @@ class DeviceAggState:
                 slots.nbytes + sum(c.nbytes for c in cols.values()),
             )
             dev_slots = jax.device_put(slots)
-            for name in names:
+            for name, col in cols.items():
                 self._fields[name] = (
-                    self._fields[name]
-                    .at[dev_slots]
-                    .set(jax.device_put(cols[name]))
+                    self._fields[name].at[dev_slots].set(jax.device_put(col))
                 )
 
     def snapshots_for(self, keys: List[str]) -> List[Tuple[str, Any]]:
@@ -608,20 +688,16 @@ class DeviceAggState:
         if self._fields is None or not keys:
             return [(k, None) for k in keys]
         host = self._fetch()
-        out = []
         # Ledger: turning the fetched slots into host-format states
         # is part of `close_emit` (its rows are counted by the close
         # that asked).
         with _flight.span("close_emit"):
-            for key in keys:
-                slot = self.key_to_slot.get(key)
-                if slot is None:
-                    out.append((key, None))
-                else:
-                    out.append(
-                        (key, _snap_of(self.kind_name, host, slot))
-                    )
-        return out
+            return _snaps_for(
+                self.kind_name,
+                host,
+                [self.key_to_slot.get(key) for key in keys],
+                keys,
+            )
 
     # -- finalization ------------------------------------------------------
 

@@ -83,6 +83,22 @@ def init_fields(kind: AggKind, capacity: int, dtype=jnp.float32):
 
 
 @functools.partial(jax.jit, static_argnames=("kind",), donate_argnums=(1,))
+def reset_fields(
+    kind: AggKind, state: Dict[str, jax.Array], slot_ids: jax.Array
+) -> Dict[str, jax.Array]:
+    """Set the given slots back to every field's fold identity: one
+    program for a batch of released slots and all fields (an eager
+    ``.at[].set`` a field is half a dozen dispatches each).  Padding
+    repeats a slot; set is idempotent."""
+    return {
+        name: state[name]
+        .at[slot_ids]
+        .set(identity_for(init, state[name].dtype))
+        for name, (init, _op) in kind.fields.items()
+    }
+
+
+@functools.partial(jax.jit, static_argnames=("kind",), donate_argnums=(1,))
 def update_fields(
     kind: AggKind,
     state: Dict[str, jax.Array],

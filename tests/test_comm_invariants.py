@@ -507,17 +507,17 @@ def test_lane_catalog_is_pinned():
 
 def test_shared_state_inventory_is_pinned():
     """The shared-state contract (docs/contracts.md BTX-RACE):
-    exactly today's five worker/main shared attributes, each with a
+    exactly today's three worker/main shared attributes, each with a
     synchronization justification, plus the sealed-capture and
     worker-carve-out inventories.  An attribute enters SHARED_STATE
     only with its justification here AND in contracts.py AND a
     re-check of the docs — never silently.  (The HBM-resident-
     aggregate PR REMOVED wire:_Reader.off: peer frames now decode on
-    main at seal time, so no lane task constructs a _Reader.)"""
+    main at seal time, so no lane task constructs a _Reader.  The
+    batched window close REMOVED KeyEncoder._ids / ._sorted: the
+    lane's closes release slot ids, and no longer drop a key from
+    the aggregate state's encoder per window.)"""
     assert set(contracts.SHARED_STATE) == {
-        # instance-per-owner: no KeyEncoder crosses tiers.
-        "bytewax_tpu.engine.arrays:KeyEncoder._ids",
-        "bytewax_tpu.engine.arrays:KeyEncoder._sorted",
         # GIL-atomic memoization; duplicate handles are benign.
         "bytewax_tpu.engine.driver:_OpRt._m_timers",
         # the deliberately-shared lock-free telemetry surface

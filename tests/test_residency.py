@@ -376,9 +376,8 @@ def test_window_extract_inject_round_trip():
     before = dict(st.snapshots_for(["a"]))
     items = st.extract_keys(["a"])
     assert [k for k, _s in items] == ["a"]
-    assert not any(
-        k2 == st.key_ids["a"] for (k2, _w) in st.open_close_us
-    )
+    assert st.key_ids["a"] not in (st.open.comp >> 32).tolist()
+    assert st.open_count == 1
     st.inject_keys(items)
     after = dict(st.snapshots_for(["a"]))
     assert after["a"].logic_states == before["a"].logic_states

@@ -578,3 +578,114 @@ def test_sharded_scan_generic_kinds_match_single_device(kind_name):
     ):
         assert k1 == k2
         np.testing.assert_allclose(s1, s2, rtol=1e-4, atol=1e-5)
+
+
+# -- the id-based slot surface the window tier drives ------------------------
+
+
+def _composites(n, n_keys=5, seed=3):
+    """Integer (key, window) composites as window_accel makes them."""
+    rng = np.random.RandomState(seed)
+    kids = rng.randint(0, n_keys, size=n).astype(np.int64)
+    wids = np.arange(n, dtype=np.int64) - n // 2  # negative ids too
+    return np.unique((kids << 32) + (wids + (1 << 31)))
+
+
+@pytest.mark.parametrize("n_shards", [4, 8])
+@pytest.mark.parametrize(
+    "kind", ["sum", "min", "max", "count", "mean", "stats"]
+)
+def test_sharded_open_read_release_matches_single_device(kind, n_shards):
+    """Batched open / fold / read / release / reopen over a mesh gives
+    what the single-device slot table gives for the same composites,
+    across a capacity growth, and a reopened id reads the identity."""
+    from bytewax_tpu.engine.sharded_state import ShardedAggState
+    from bytewax_tpu.engine.xla import DeviceAggState
+
+    mesh = _mesh(n_shards)
+    sharded = ShardedAggState(kind, mesh, cap_per_shard=16)
+    single = DeviceAggState(kind)
+    comps = _composites(300)
+    rng = np.random.RandomState(8)
+    rows = rng.randint(0, len(comps), size=2000)
+    vals = (
+        np.ones(len(rows))
+        if kind == "count"
+        else rng.randint(-40, 40, size=len(rows)).astype(np.float64)
+    )
+
+    ids_m, ids_1 = sharded.open_ids(comps), single.open_ids(comps)
+    assert len(set(ids_m.tolist())) == len(comps) == len(set(ids_1.tolist()))
+    owners = sharded._owners(comps)
+    assert (ids_m % n_shards == owners).all()
+    assert len(set(owners.tolist())) == n_shards  # every shard takes some
+    assert sharded.cap_per_shard > 16  # grew, in one call
+    sharded.update_ids(ids_m[rows], vals)
+    single.update_ids(ids_1[rows], vals)
+    got, want = sharded.states_of(ids_m), single.states_of(ids_1)
+    assert got == want
+    assert [type(x) for x in np.ravel(got[:3])] == [
+        type(x) for x in np.ravel(want[:3])
+    ]
+
+    # Release every other id; the next open takes exactly those back
+    # (per shard, newest freed first) and they read the identity.
+    freed_m, freed_1 = ids_m[::2], ids_1[::2]
+    sharded.release_ids(freed_m)
+    single.release_ids(freed_1)
+    again = comps[::2] + (1 << 20)
+    re_m, re_1 = sharded.open_ids(again), single.open_ids(again)
+    assert sorted(re_1.tolist()) == sorted(freed_1.tolist())
+    owners_again = sharded._owners(again)
+    for shard in range(n_shards):
+        had = sorted(freed_m[freed_m % n_shards == shard].tolist())
+        now = re_m[owners_again == shard].tolist()
+        reused = [k for k in now if k in set(had)]
+        assert len(reused) == min(len(had), len(now))
+    one = np.array([1.0])
+    sharded.update_ids(re_m[:1], one)
+    single.update_ids(re_1[:1], one)
+    fresh = DeviceAggState(kind)
+    fresh_ids = fresh.open_ids(again)
+    fresh.update_ids(fresh_ids[:1], one)
+    assert (
+        sharded.states_of(re_m)
+        == single.states_of(re_1)
+        == fresh.states_of(fresh_ids)
+    )
+    # The ids that stayed keep their state through all of it.
+    assert sharded.states_of(ids_m[1::2]) == want[1::2]
+
+    # load_ids installs host-format states by id, as load_many by key.
+    loaded = ShardedAggState(kind, mesh, cap_per_shard=16)
+    loaded_ids = loaded.open_ids(comps)
+    loaded.load_ids(loaded_ids, want)
+    assert loaded.states_of(loaded_ids) == want
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["reused_slot", "constant_calls", "snapshot_resumes"],
+)
+def test_window_state_on_four_devices(monkeypatch, case):
+    """The window tier's batched path over a 4-device mesh (the shape
+    of ``chip_smoke.py``'s four-chip stage): the state-level cases of
+    tests/test_window_accel.py with ``make_agg_state`` handing out a
+    ``ShardedAggState``."""
+    from bytewax_tpu.engine.sharded_state import ShardedAggState
+    from tests import test_window_accel as twa
+
+    _mesh(4)
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", "4")
+    st = twa._spec_of("stats", twa.TUMBLING_10S).make_state()
+    assert isinstance(st.agg, ShardedAggState) and st.agg.n_shards == 4
+    if case == "reused_slot":
+        twa.test_reused_slot_starts_from_identity(monkeypatch, "stats", "4")
+    elif case == "constant_calls":
+        twa.test_delivery_makes_constant_calls_into_agg(
+            monkeypatch, "4", True
+        )
+    else:
+        twa.test_snapshot_resumes_to_same_results(
+            monkeypatch, "4", twa.SLIDING_10S_BY_4S
+        )

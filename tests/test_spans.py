@@ -278,7 +278,10 @@ def test_tumbling_flow_counts_rows_windows_and_items(ledger, monkeypatch):
     assert counters["close_emit_rows"] == len(out)
     assert counters["sink_rows"] == len(out)
     assert counters["watermark_rows"] == rows == counters["encode_rows"]
-    assert counters["emit_rows"] == 2 * len(out)  # an "E" and an "M" a window
+    # An "E" a window and no "M": the flow reads `down` only, so the
+    # `unwrap_meta` tap is pruned and the tier builds no metadata.
+    assert counters["emit_rows"] == len(out) == counters["window_opens"]
+    assert "window_meta_events" not in counters
     assert counters["device_spans"] == 3  # deliveries
     # At most 24 spans a delivery, none per row or per window.
     spans = sum(

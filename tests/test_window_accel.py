@@ -1215,3 +1215,317 @@ def test_itemized_tsvalue_flow_device_matches_host(monkeypatch):
     for kw in wd:
         # Device folds in f32; host in f64.
         assert gd[kw] == pytest.approx(wd[kw], abs=1e-4)
+
+
+# -- the batched open / read / release path (integer composites) -----------
+#
+# Shared with tests/test_sharded.py, which runs the state-level cases
+# over several host devices.
+
+TUMBLING_10S = TumblingWindower(length=timedelta(seconds=10), align_to=ALIGN)
+# An offset that does not divide the length: a row is in 2 or 3 windows.
+SLIDING_10S_BY_4S = SlidingWindower(
+    length=timedelta(seconds=10), offset=timedelta(seconds=4), align_to=ALIGN
+)
+
+
+def _spec_of(kind, windower, meta_live=True):
+    offset = getattr(windower, "offset", windower.length)
+    spec = WindowAccelSpec(
+        kind, lambda v: v.ts, ALIGN, windower.length, offset, timedelta(0)
+    )
+    spec.meta_live = meta_live
+    return spec
+
+
+def _deliver(st, keys, secs, vals):
+    """One columnar delivery through the state's public entry point;
+    returns the delivery's late and close events."""
+    from bytewax_tpu.engine.arrays import ArrayBatch
+
+    batch = ArrayBatch(
+        {
+            "key": np.asarray(keys),
+            "ts": np.datetime64(ALIGN.replace(tzinfo=None), "us")
+            + np.asarray(secs).astype("timedelta64[s]"),
+            "value": np.asarray(vals, dtype=np.float64),
+        }
+    )
+    late, phase = st.on_batch_columnar(batch)
+    closes, _hint = phase()
+    return late + closes
+
+
+def _types_of(obj):
+    if isinstance(obj, (tuple, list)):
+        return tuple(_types_of(x) for x in obj)
+    # The host tier's min / max hand back the TsValue (a float that
+    # also carries its row's timestamp) they were given.
+    return float if isinstance(obj, float) else type(obj)
+
+
+def _tap_flow(kind, windower, meta, inp):
+    """A windowed flow over timestamped rows with its ``down`` and
+    ``late`` taps read, and ``meta`` read or left to be pruned."""
+    from bytewax_tpu import xla
+
+    taps = {"down": [], "late": [], "meta": []}
+    flow = Dataflow("test_df")
+    s = op.input("inp", flow, TestingSource(list(inp), batch_size=50))
+    clock = EventClock(
+        ts_getter=(lambda kv: kv[1]) if kind == "count" else xla.column_ts,
+        wait_for_system_duration=timedelta(seconds=20),
+    )
+    if kind == "count":
+        wo = w.count_window("win", s, clock, windower, key=lambda kv: kv[0])
+    else:
+        wo = w.fold_window(
+            "win",
+            s,
+            clock,
+            windower,
+            xla.STATS.make_acc,
+            xla.STATS,
+            xla.STATS.merge,
+        )
+    op.output("down", wo.down, TestingSink(taps["down"]))
+    op.output("late", wo.late, TestingSink(taps["late"]))
+    if meta:
+        op.output("meta", wo.meta, TestingSink(taps["meta"]))
+    return flow, taps
+
+
+def _rows_with_late(n=400, seed=5):
+    """Integer-valued readings a second apart in event time, jittered
+    a few seconds (on time under the 20 s wait) with every 37th row
+    200 s behind (late on both tiers, whatever the wall clock does)."""
+    from bytewax_tpu import xla
+
+    rng = np.random.RandomState(seed)
+    rows = []
+    for i in range(n):
+        sec = 300 + i - int(rng.randint(0, 4))
+        if i % 37 == 36:
+            sec -= 200
+        rows.append(
+            (
+                f"k{rng.randint(0, 3)}",
+                xla.TsValue(
+                    float(rng.randint(-50, 50)),
+                    ALIGN + timedelta(seconds=sec),
+                ),
+            )
+        )
+    return rows
+
+
+@pytest.mark.parametrize("meta", [False, True], ids=["meta_pruned", "meta_read"])
+@pytest.mark.parametrize(
+    "windower", [TUMBLING_10S, SLIDING_10S_BY_4S], ids=["tumbling", "sliding"]
+)
+@pytest.mark.parametrize("kind", ["count", "stats"])
+@pytest.mark.parametrize("shard", ["0", "auto"], ids=["one_device", "mesh"])
+def test_batched_close_matches_host_tier(
+    monkeypatch, shard, kind, windower, meta
+):
+    """Device tier against the host tier, the oracle: the same events
+    on every tap the flow reads, in the same order, with the same
+    Python types (integer-valued readings fold exactly in float32)."""
+    from bytewax_tpu.engine import flight
+
+    inp = _rows_with_late()
+    if kind == "count":
+        inp = [(k, v.ts) for k, v in inp]
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", shard)
+
+    def run(accel):
+        monkeypatch.setenv("BYTEWAX_TPU_ACCEL", accel)
+        flow, taps = _tap_flow(kind, windower, meta, inp)
+        before = dict(flight.RECORDER.counters)
+        run_main(flow)
+        gained = {
+            name: flight.RECORDER.counters.get(name, 0) - before.get(name, 0)
+            for name in ("window_opens", "window_meta_events")
+        }
+        return taps, gained
+
+    (device, counted), (host, _) = run("1"), run("0")
+    assert len(device["late"]) >= 10 and len(device["down"]) > 100
+    for tap in ("down", "late", "meta"):
+        # One key's events keep their order on both tiers; across
+        # keys the host tier goes key by key and the device tier in
+        # the order windows were opened, so those are compared sorted.
+        for key in ("k0", "k1", "k2"):
+            assert [e for e in device[tap] if e[0] == key] == [
+                e for e in host[tap] if e[0] == key
+            ], (tap, key)
+        assert len(device[tap]) == len(host[tap])
+        assert _types_of(sorted(device[tap], key=repr)) == _types_of(
+            sorted(host[tap], key=repr)
+        )
+    assert counted["window_opens"] == len(device["down"])
+    assert counted["window_meta_events"] == len(device["meta"])
+    assert (len(device["meta"]) == len(device["down"])) is meta
+
+
+@pytest.mark.parametrize(
+    "kind", ["sum", "min", "max", "count", "mean", "stats"]
+)
+def test_reused_slot_starts_from_identity(monkeypatch, kind, shard="0"):
+    """A slot released by a close and given to a later window is reset
+    before it folds: nothing of the closed window's state is inherited."""
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", shard)
+    st = _spec_of(kind, TUMBLING_10S).make_state()
+    first = _deliver(st, ["a"] * 3 + ["b"], [1, 2, 3, 4], [100, -100, 7, 9])
+    assert first == []
+    held = sorted(st.open.ids.tolist())
+    # 30 s on: two new windows open, then both old ones close (wait
+    # 0) and hand their slots back.
+    second = _deliver(st, ["a", "b"], [31, 32], [-3, -4])
+    assert sorted(e[1][0] for e in second if e[1][1] == "E") == [0, 0]
+    assert not set(st.open.ids.tolist()) & set(held)
+    # 30 s on again: the next two windows take the freed slots.
+    _deliver(st, ["a", "b"], [61, 62], [5, 6])
+    if shard == "0":  # on a mesh a freed slot serves its own shard only
+        assert sorted(st.open.ids.tolist()) == held, "freed slots are reused"
+    third = [e for e in st.on_eof() if e[1][1] == "E"]
+    want = {
+        "sum": [5.0, 6.0],
+        "min": [5.0, 6.0],
+        "max": [5.0, 6.0],
+        "count": [1, 1],
+        "mean": [(5.0, 1), (6.0, 1)],
+        "stats": [(5.0, 5.0, 5.0, 1), (6.0, 6.0, 6.0, 1)],
+    }[kind]
+    assert [(k, wid, v) for k, (wid, _e, v) in third] == [
+        ("a", 6, want[0]),
+        ("b", 6, want[1]),
+    ]
+    assert _types_of([e[1][2] for e in third]) == _types_of(want)
+    assert st.open_count == 0 and st.is_empty() is False  # keys stay known
+
+
+@pytest.mark.parametrize(
+    "windower", [TUMBLING_10S, SLIDING_10S_BY_4S], ids=["tumbling", "sliding"]
+)
+@pytest.mark.parametrize("shard", ["0", "auto"], ids=["one_device", "mesh"])
+def test_snapshot_resumes_to_same_results(monkeypatch, shard, windower):
+    """A snapshot taken before a restart resumes to the same results,
+    and is the host tier's ``_WindowSnapshot`` as it always was: wid ->
+    ``WindowMetadata`` under the windower state, wid -> host-format
+    fold state, Python floats and an ``int`` count."""
+    from bytewax_tpu.operators.windowing import (
+        WindowMetadata,
+        _SlidingWindowerState,
+        _WindowSnapshot,
+    )
+
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", shard)
+    spec = _spec_of("stats", windower)
+    rng = np.random.RandomState(9)
+
+    def rows(lo, n=200):
+        secs = lo + np.arange(n) // 4
+        return (
+            rng.choice(["a", "b", "c"], size=n),
+            secs,
+            rng.randint(-9, 9, size=n),
+        )
+
+    # 30 s of event time between the two deliveries: the watermark
+    # also moves with the wall clock (wait 0), and no row may turn late.
+    before, after = rows(0), rows(80)
+    straight = spec.make_state()
+    ev_before = _deliver(straight, *before)
+    snaps = straight.snapshots_for(["a", "b", "c", "never_seen"])
+    assert snaps[-1] == ("never_seen", None)
+    for key, snap in snaps[:3]:
+        assert isinstance(snap, _WindowSnapshot), key
+        assert isinstance(snap.windower_state, _SlidingWindowerState)
+        opened = snap.windower_state.opened
+        assert list(opened) == list(snap.logic_states) != []
+        for wid, meta in opened.items():
+            assert type(wid) is int and isinstance(meta, WindowMetadata)
+            assert meta.close_time - meta.open_time == windower.length
+        for state in snap.logic_states.values():
+            assert _types_of(state) == (float, float, float, int)
+        assert snap.queue == []
+    resumed = spec.make_state()
+    resumed.load_many([s for s in snaps if s[1] is not None])
+    assert resumed.open_count == straight.open_count
+    assert dict(resumed.snapshots_for(["a", "b", "c"])) == dict(snaps[:3])
+    ev_straight = _deliver(straight, *after) + straight.on_eof()
+    ev_resumed = _deliver(resumed, *after) + resumed.on_eof()
+    assert len(ev_before) > 0 and len(ev_straight) >= 30
+    # Key ids are given anew at load (in the page's order), so keys
+    # may take turns differently; one key's events keep their order.
+    for key in "abc":
+        assert [e for e in ev_resumed if e[0] == key] == [
+            e for e in ev_straight if e[0] == key
+        ]
+    assert len(ev_resumed) == len(ev_straight)
+
+
+class _CountedCalls:
+    """Wraps an aggregate state and counts the calls made into it."""
+
+    def __init__(self, inner):
+        self._inner = inner
+        self.calls = {}
+
+    def __getattr__(self, name):
+        attr = getattr(self._inner, name)
+        if not callable(attr):
+            return attr
+
+        def counted(*args, **kwargs):
+            self.calls[name] = self.calls.get(name, 0) + 1
+            return attr(*args, **kwargs)
+
+        return counted
+
+
+@pytest.mark.parametrize("meta", [False, True], ids=["meta_pruned", "meta_read"])
+@pytest.mark.parametrize("shard", ["0", "auto"], ids=["one_device", "mesh"])
+def test_delivery_makes_constant_calls_into_agg(monkeypatch, shard, meta):
+    """A delivery that opens and closes N windows makes the same few
+    calls into the aggregate state whatever N is, and builds N "M"
+    events when ``meta`` is read and none when it was pruned."""
+    from bytewax_tpu.engine import flight
+
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", shard)
+
+    def drive(n_windows):
+        st = _spec_of("stats", TUMBLING_10S, meta_live=meta).make_state()
+        st.agg = _CountedCalls(st.agg)
+        secs = np.arange(n_windows) * 10
+        _deliver(st, ["a"] * n_windows, secs, np.ones(n_windows))
+        st.agg.calls.clear()
+        before = dict(flight.RECORDER.counters)
+        # As many windows open as close (the first delivery's last,
+        # then all of this one's but its own last).
+        events = _deliver(
+            st, ["a"] * n_windows, secs + n_windows * 10, np.ones(n_windows)
+        )
+        gained = {
+            name: flight.RECORDER.counters.get(name, 0) - before.get(name, 0)
+            for name in ("window_opens", "window_meta_events")
+        }
+        return events, st.agg.calls, gained
+
+    few, calls_few, _ = drive(8)
+    many, calls_many, gained = drive(1000)
+    assert calls_many == calls_few
+    assert calls_many == {
+        "open_ids": 1,
+        "update_ids": 1,
+        "states_of": 1,
+        "release_ids": 1,
+    }
+    assert gained["window_opens"] == 1000
+    assert [e[1][1] for e in many].count("E") == 1000
+    assert gained["window_meta_events"] == (1000 if meta else 0)
+    assert [e[1][1] for e in many[:4]] == (
+        ["E", "M", "E", "M"] if meta else ["E"] * 4
+    )
+    assert len(few) == (16 if meta else 8)
