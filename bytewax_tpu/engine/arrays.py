@@ -410,6 +410,27 @@ class KeyEncoder:
             self._sorted = np.delete(self._sorted, pos)
             self._ids = np.delete(self._ids, pos)
 
+    def drop_many(self, keys: List[str]) -> None:
+        """Forget a batch of keys with one search and one delete (a
+        :meth:`drop` a key copies the seen set once a key)."""
+        if self._sorted is None or not len(self._sorted) or not keys:
+            return
+        kind = self._sorted.dtype.kind
+        try:
+            probes = np.asarray(keys)
+            if kind in "SU":
+                probes = probes.astype(kind)
+        except (UnicodeEncodeError, ValueError):
+            for key in keys:
+                self.drop(key)
+            return
+        pos = np.searchsorted(self._sorted, probes)
+        inside = pos < len(self._sorted)
+        pos, probes = pos[inside], probes[inside]
+        held = pos[self._sorted[pos] == probes]
+        self._sorted = np.delete(self._sorted, held)
+        self._ids = np.delete(self._ids, held)
+
     def clear(self) -> None:
         self._sorted = None
         self._ids = None

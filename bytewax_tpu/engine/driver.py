@@ -964,6 +964,11 @@ class _OpRt:
         """Return (state_key, state-or-None) changed this epoch."""
         return []
 
+    def epoch_forget(self) -> None:
+        """An epoch closes with no store to write to: forget what the
+        epoch touched."""
+        self.epoch_snaps()
+
     def close(self) -> None:
         """Shutdown cleanup at clean EOF."""
 
@@ -1921,8 +1926,12 @@ class _StatefulBatchRt(_OpRt):
                 _reraise(step_id, "the device window fold", ex)
 
         def finalize(res) -> None:
-            closes, hint = res
+            closes, hint, gone = res
             self._wagg_hint = hint
+            if self.wagg is not None:
+                # Keys the phase's close left without a window give
+                # up their ids here, phases in order.
+                self.wagg.let_go(gone)
             self._emit_window_events(late_events + closes)
 
         if self._pipe is None:
@@ -2044,20 +2053,23 @@ class _StatefulBatchRt(_OpRt):
                 for item in items:
                     k, v = _extract_kv(item, self.op.step_id)
                     groups.setdefault(k, []).append(v)
-            for key, values in groups.items():
-                logic = self.logics.get(key)
-                if logic is None:
-                    logic = self._build(None)
-                    self.logics[key] = logic
-                w_home = _route_hash(key) % self.driver.worker_count
-                try:
-                    with self._timer(
-                        "stateful_batch_on_batch", w_home
-                    ).time():
-                        emits, discard = logic.on_batch(values)
-                except BaseException as ex:  # noqa: BLE001
-                    _reraise(self.op.step_id, "`on_batch`", ex)
-                self._handle(key, emits, discard, out)
+            # Ledger: `logic` is the step's per-key logic calls of
+            # one delivery (build, `on_batch`, reschedule).
+            with _flight.span("logic", self.op.step_id, rows=len(items)):
+                for key, values in groups.items():
+                    logic = self.logics.get(key)
+                    if logic is None:
+                        logic = self._build(None)
+                        self.logics[key] = logic
+                    w_home = _route_hash(key) % self.driver.worker_count
+                    try:
+                        with self._timer(
+                            "stateful_batch_on_batch", w_home
+                        ).time():
+                            emits, discard = logic.on_batch(values)
+                    except BaseException as ex:  # noqa: BLE001
+                        _reraise(self.op.step_id, "`on_batch`", ex)
+                    self._handle(key, emits, discard, out)
         self._flush(out)
 
     def _dispatch_device(self, entries: List[Entry]) -> bool:
@@ -2592,6 +2604,17 @@ class _StatefulBatchRt(_OpRt):
                 return self._wagg_hint
             return self.wagg.notify_at()
         return min(self.sched.values()) if self.sched else None
+
+    def epoch_forget(self) -> None:
+        if self.wagg is None:
+            self.epoch_snaps()
+            return
+        # The window tier reads nothing back: with 10^5 keys touched
+        # an epoch, a snapshot of each that nobody keeps stalls the
+        # run for seconds (PERF.md, PR 27).
+        self.pipeline_flush()
+        self.awoken.clear()
+        self.wagg.touched.clear()
 
     def epoch_snaps(self) -> List[Tuple[str, Optional[Any]]]:
         # Snapshots only ever read post-flush state: the driver
@@ -3846,7 +3869,7 @@ class _Driver:
         if self.store is None:
             with self._ledger_phase("snapshot"):
                 for rt in self.rts:
-                    rt.epoch_snaps()  # still clears awoken sets
+                    rt.epoch_forget()
             return
         snaps: List[Tuple[str, str, Optional[bytes]]] = []
         with self._ledger_phase("snapshot"):

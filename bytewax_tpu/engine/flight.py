@@ -142,7 +142,9 @@ TRACED_PHASES = frozenset(
         "close_scan",
         "fetch",
         "close_emit",
+        "retire",
         "emit",
+        "logic",
         "sink",
     }
 )
@@ -1457,7 +1459,9 @@ _FRACTION_BUCKETS = {
         "close_scan",
         "fetch",
         "close_emit",
+        "retire",
         "emit",
+        "logic",
         "sink",
     ),
     "device": ("device",),

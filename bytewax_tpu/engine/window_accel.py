@@ -27,18 +27,35 @@ under integer composites (``kid << 32 | wid + 2**31``) in
 slots a delivery at a time through the aggregate state's id-based
 surface (``open_ids`` / ``states_of`` / ``release_ids``): nothing runs
 once per (key, window) but the construction of the result tuples and
-``_finalize_one``.  "M" events (two ``datetime``s and a
+``_finalize_one``.  Beside each composite the table keeps the system
+time at which the window falls due under its key's clock
+(``_OpenWindows.at``); a delivery's phase retimes the windows of the
+delivery's own keys (the only clocks it moved), so the due scan and
+the notify hint are one pass over one column each, not a recomputation
+of every open window's watermark.  "M" events (two ``datetime``s and a
 ``WindowMetadata`` a window) are built only while the plan keeps the
 step's ``meta`` tap (``WindowAccelSpec.meta_live``, set at flatten
 time).  The session tier keeps string slot keys and the scalar
 ``alloc`` / ``discard`` surface.
 
+Key note: a tumbling/sliding step holds a key (its id, its clock,
+its encoder entries) only while the key has an open window, as the
+host tier discards a ``_WindowLogic`` that is empty.  The close that
+takes a key's last window finds it (on whichever thread runs the
+close) and hands it back with the close's events; the main thread
+lets it go (:meth:`DeviceWindowAggState.let_go`) and gives its id to
+the next new key, which starts a clock at minus infinity.  A key with
+an on-time row in a delivery taken in after the one whose close found
+it stays, clock and all (it has a window again before anything could
+tell).  The session tier keeps its keys.
+
 Pipeline note (docs/performance.md): each ``on_batch*`` call returns
 ``(late_events, device_phase)`` — the host phase (vocab sync,
 watermark math, late classification) runs on the caller's thread and
 mutates only host clock state; ``device_phase()`` (the fold
-scatter-combine, the due-window scan against a clock snapshot taken
-at ingest, the close snapshot fetch, and window-event construction)
+scatter-combine, the due-window scan against the clocks of the
+delivery's keys as its ingest left them, the close snapshot fetch,
+and window-event construction)
 is safe to defer onto the engine's dispatch-pipeline worker.  The
 driver runs it inline at pipeline depth 1 — byte-identical to the
 pre-pipeline engine.  ``on_notify``/``on_eof``/``snapshots_for``
@@ -67,6 +84,7 @@ def _to_us(dt: datetime) -> float:
 # ``kid << 32 | wid + 2**31``.
 _WID_BIAS = 1 << 31
 _WID_MASK = (1 << 32) - 1
+_NO_KIDS = np.empty(0, dtype=np.int64)
 
 
 class _OpenWindows:
@@ -79,14 +97,23 @@ class _OpenWindows:
     windows in the order they were opened, which is the order closes
     and snapshots list them in.  A window's key id, window id and
     close time are arithmetic on its composite and are not stored.
+
+    ``at`` is the system time (us) at which the window falls due
+    under its key's clock: ``sys_at_base + (close - base)``, the
+    instant the key's watermark reaches the close time.  It moves
+    only when the key's clock does (:meth:`retime`), so the due scan
+    and the notify hint are one pass over one column each, however
+    many windows are open.  A window just opened holds ``inf`` until
+    the delivery that opened it retimes its key.
     """
 
-    __slots__ = ("comp", "ids", "seq", "_next_seq")
+    __slots__ = ("comp", "ids", "seq", "at", "_next_seq")
 
     def __init__(self):
         self.comp = np.empty(0, dtype=np.int64)
         self.ids = np.empty(0, dtype=np.int32)
         self.seq = np.empty(0, dtype=np.int64)
+        self.at = np.empty(0, dtype=np.float64)
         self._next_seq = 0
 
     def __len__(self) -> int:
@@ -113,7 +140,34 @@ class _OpenWindows:
             self.comp = np.insert(self.comp, pos[new], uniq[new])
             self.ids = np.insert(self.ids, pos[new], opened)
             self.seq = np.insert(self.seq, pos[new], seq)
+            self.at = np.insert(self.at, pos[new], np.inf)
         return out
+
+    def retime(
+        self, kids: np.ndarray, base: np.ndarray, sys_at: np.ndarray, closes_of
+    ) -> None:
+        """Set ``at`` of every open window of ``kids`` from the keys'
+        clocks (``base``, ``sys_at``, parallel to ``kids``): a key's
+        composites are one run of the table."""
+        lo = np.searchsorted(self.comp, kids << 32)
+        n = np.searchsorted(self.comp, (kids + 1) << 32) - lo
+        total = int(n.sum())
+        if not total:
+            return
+        rows = np.arange(total) + np.repeat(lo - (np.cumsum(n) - n), n)
+        of_key = np.repeat(np.arange(len(kids)), n)
+        self.at[rows] = sys_at[of_key] + (
+            closes_of(self.comp[rows]) - base[of_key]
+        )
+
+    def without_window(self, kids: np.ndarray) -> np.ndarray:
+        """Those of the sorted unique key ids that have no open
+        window: a key's composites are one run of the table."""
+        at = np.searchsorted(self.comp, kids << 32)
+        held = np.zeros(len(kids), dtype=bool)
+        inside = at < len(self.comp)
+        held[inside] = (self.comp[at[inside]] >> 32) == kids[inside]
+        return kids[~held]
 
     def in_order(self, rows: np.ndarray) -> np.ndarray:
         """``rows`` in the order their windows were opened."""
@@ -125,6 +179,7 @@ class _OpenWindows:
         self.comp = self.comp[keep]
         self.ids = self.ids[keep]
         self.seq = self.seq[keep]
+        self.at = self.at[keep]
 
 
 class _LateTs:
@@ -233,11 +288,18 @@ class DeviceWindowAggState:
         self.agg = make_agg_state(spec.kind)
         # windows_per_ts is static for a sliding windower.
         self.expand = max(1, int(np.ceil(spec.length_us / spec.offset_us)))
-        # Per-key clock state, indexed by key id.
-        self.keys: List[str] = []
+        # Per-key clock state, indexed by key id.  A key holds an id
+        # while it has an open window (:meth:`let_go`); ``keys`` is
+        # None at an id that is free.
+        self.keys: List[Optional[str]] = []
         self.key_ids: Dict[str, int] = {}
+        self._free_kids: List[int] = []
         self.base_us = np.empty(0, dtype=np.float64)  # watermark base
         self.sys_at_base = np.empty(0, dtype=np.float64)
+        # Deliveries taken in so far, and per key id the last one that
+        # held an on-time row of the key.
+        self._seq = 0
+        self._seen = np.empty(0, dtype=np.int64)
         # Open windows by integer composite, with the slot self.agg
         # gave each (the session tier keeps a table of its own).
         self.open = _OpenWindows()
@@ -250,35 +312,87 @@ class DeviceWindowAggState:
         # Sticky marker: itemized promotion failed a deterministic
         # check; stop re-trying it every batch.
         self._promote_failed = False
-        # Deferred device phases read the per-key clock as of their
-        # own ingest, so the ingest snapshots it; at pipeline depth 1
-        # the phase runs inline before the clock can move again and
-        # the copy is skipped.
-        from bytewax_tpu.engine.pipeline import pipeline_depth
-
-        self._clock_copies = pipeline_depth() > 1
+        # Itemized promotion: the native pass numbers keys densely in
+        # a dict of its own; ``_item_kids`` maps those numbers to key
+        # ids.  Both start over when a key is let go.
+        self._item_iddict: Optional[Dict[str, int]] = None
+        self._item_kids = _NO_KIDS
 
     # -- clock -------------------------------------------------------------
 
+    def _phase_clock(self, kids: np.ndarray):
+        """What a delivery's deferred phase needs of the clock as of
+        its own ingest (the next ingest moves the clock on the host
+        thread while the phase may still be in flight): the
+        delivery's keys with their clocks, from which the phase
+        retimes their open windows."""
+        return kids, self.base_us[kids], self.sys_at_base[kids]
+
+    def _closes_of(self, comp: np.ndarray) -> np.ndarray:
+        """Close times (us) of composites."""
+        spec = self.spec
+        wids = (comp & _WID_MASK) - _WID_BIAS
+        return spec.align_us + wids * spec.offset_us + spec.length_us
+
     def _key_ids_for(self, keys: List[str]) -> np.ndarray:
+        """Key ids of ``keys``; a key not held takes a free id (or a
+        new one) and starts a clock at minus infinity."""
         out = np.empty(len(keys), dtype=np.int64)
+        free = self._free_kids
+        fresh = []
         for i, k in enumerate(keys):
             kid = self.key_ids.get(k)
             if kid is None:
-                kid = len(self.keys)
+                if free:
+                    kid = free.pop()
+                    self.keys[kid] = k
+                else:
+                    kid = len(self.keys)
+                    self.keys.append(k)
                 self.key_ids[k] = kid
-                self.keys.append(k)
+                fresh.append(kid)
             out[i] = kid
-        if len(self.keys) > len(self.base_us):
+        if fresh:
+            _flight.RECORDER.count("window_keys_opened", len(fresh))
             grow = len(self.keys) - len(self.base_us)
-            now_us = datetime.now(timezone.utc).timestamp() * _US
-            self.base_us = np.concatenate(
-                [self.base_us, np.full(grow, -np.inf)]
-            )
-            self.sys_at_base = np.concatenate(
-                [self.sys_at_base, np.full(grow, now_us)]
+            if grow > 0:
+                self.base_us = np.concatenate(
+                    [self.base_us, np.empty(grow)]
+                )
+                self.sys_at_base = np.concatenate(
+                    [self.sys_at_base, np.empty(grow)]
+                )
+                self._seen = np.concatenate(
+                    [self._seen, np.zeros(grow, dtype=np.int64)]
+                )
+            self.base_us[fresh] = -np.inf
+            self.sys_at_base[fresh] = (
+                datetime.now(timezone.utc).timestamp() * _US
             )
         return out
+
+    def let_go(self, gone: Tuple[int, np.ndarray]) -> None:
+        """Retire the keys a close left without an open window
+        (``gone``: the delivery the close belongs to and the key ids
+        it found): id, clock, vocabulary and encoder entries, as the
+        host tier discards an empty window logic.  Main thread only,
+        in the order of the closes.  A key with an on-time row in a
+        later delivery stays: its fold is still to come."""
+        seq, kids = gone
+        kids = kids[self._seen[kids] <= seq]
+        if not len(kids):
+            return
+        with _flight.span("retire", rows=len(kids)):
+            ids = kids.tolist()
+            names = [self.keys[kid] for kid in ids]
+            for name, kid in zip(names, ids):
+                del self.key_ids[name]
+                self.keys[kid] = None
+            self._free_kids.extend(ids)
+            self._vocab.drop_ids(ids)
+            self._enc.drop_many(names)
+            self._item_iddict = None
+            _flight.RECORDER.count("window_keys_retired", len(ids))
 
     def _watermarks(self, kids: np.ndarray, now_us: float) -> np.ndarray:
         return self.base_us[kids] + (now_us - self.sys_at_base[kids])
@@ -330,7 +444,7 @@ class DeviceWindowAggState:
         return len(self.open)
 
     def is_empty(self) -> bool:
-        return not self.open_count and not self.keys and not self.touched
+        return not self.open_count and not self.key_ids and not self.touched
 
     def on_batch_items(self, items: List[Any]):
         """Itemized promotion: one native pass dictionary-encodes the
@@ -356,23 +470,48 @@ class DeviceWindowAggState:
         ids = np.empty(n, dtype=np.int32)
         ts_us = np.empty(n, dtype=np.float64)
         vals = np.empty(n, dtype=np.float64)
-        # The native id dict shares the engine's key-id space; resync
-        # when other ingest paths (columnar, per-item) allocated ids
-        # this dict hasn't seen.
-        iddict = getattr(self, "_item_iddict", None)
-        if iddict is None or len(iddict) != len(self.key_ids):
-            iddict = dict(self.key_ids)
-            self._item_iddict = iddict
+        # The native pass numbers keys in a dict of its own (a key's
+        # number is the dict's length when it is first seen);
+        # ``_item_kids`` maps the numbers to key ids, which other
+        # ingest paths give out too and :meth:`let_go` takes back.
+        iddict = self._item_iddict
+        if iddict is None:
+            iddict = self._item_iddict = {}
+            self._item_kids = _NO_KIDS
         try:
             with _flight.span("encode", rows=n):
                 res = wa_encode(items, iddict, ids, ts_us, vals)
         except (TypeError, AttributeError) as ex:
             # AttributeError: a float-coercible value without the
-            # TsValue `.ts` attribute.
+            # TsValue `.ts` attribute.  (The native pass has taken
+            # its new keys out of the dict again.)
             raise NonNumericValues(str(ex)) from ex
         if res is None:
             return None
         new_keys, mode = res
+        try:
+            self._check_promotion(items, ts_us, mode)
+        except NonNumericValues:
+            # The native pass numbered keys that the map will not hold.
+            self._item_iddict = None
+            raise
+        if new_keys:
+            self._item_kids = np.concatenate(
+                [self._item_kids, self._key_ids_for(new_keys)]
+            )
+        kids = self._item_kids[ids]
+        if self.spec.kind == "count":
+            return self._ingest(kids, ts_us, _LateTs(ts_us))
+        # Late events carry the original value objects (a TsValue
+        # keeps its .ts); the fold consumes the encoded column.
+        return self._ingest(kids, ts_us, _ItemVals(items), fold_vals=vals)
+
+    def _check_promotion(self, items: List[Any], ts_us, mode: int) -> None:
+        """The promotion's deterministic checks; raises
+        :class:`NonNumericValues` where the rows cannot promote."""
+        from bytewax_tpu.engine.xla import NonNumericValues
+
+        n = len(items)
         if mode == 1 and self.spec.kind != "count":
             # Bare datetimes carry no foldable value; the numeric
             # fold must see the rows itemized (and will raise the
@@ -405,29 +544,6 @@ class DeviceWindowAggState:
                     "reading the row's own datetime/TsValue.ts"
                 )
                 raise NonNumericValues(msg)
-        if new_keys:
-            kids_new = self._key_ids_for(new_keys)
-            # wa_encode assigned len(iddict)-ordered ids; they must
-            # line up with the engine's first-seen allocation.  Not an
-            # assert: under ``python -O`` a desync would silently
-            # misattribute every subsequent window fold to the wrong
-            # keys instead of failing the step.
-            if int(kids_new[-1]) != len(self.keys) - 1:
-                self._promote_failed = True
-                msg = (
-                    "itemized windowing promotion desynchronized from "
-                    "the engine key-id space (native id "
-                    f"{int(kids_new[-1])} vs engine id "
-                    f"{len(self.keys) - 1}); this is an engine "
-                    "invariant violation — please report it"
-                )
-                raise RuntimeError(msg)
-        kids = ids.astype(np.int64)
-        if self.spec.kind == "count":
-            return self._ingest(kids, ts_us, _LateTs(ts_us))
-        # Late events carry the original value objects (a TsValue
-        # keeps its .ts); the fold consumes the encoded column.
-        return self._ingest(kids, ts_us, _ItemVals(items), fold_vals=vals)
 
     def on_batch(self, keys: List[str], values: List[Any]):
         """Fold a batch; window events are tagged like the host tier's
@@ -454,12 +570,16 @@ class DeviceWindowAggState:
         column when ``values`` is a lazy view rather than an array.
         ``device_phase()`` — the fold, the due-window scan (against
         the clock as of THIS ingest), and window-event construction —
-        returns ``(close_events, notify_hint)`` and may run deferred
-        on the dispatch pipeline's worker; it touches only the
-        fold/open-window state the pipeline owns between submit and
-        finalize."""
+        returns ``(close_events, notify_hint, gone)`` and may run
+        deferred on the dispatch pipeline's worker; it touches only
+        the fold/open-window state the pipeline owns between submit
+        and finalize.  ``gone`` (the keys its close left without a
+        window) goes to :meth:`let_go` on the main thread, phases in
+        order."""
         spec = self.spec
         now_us = datetime.now(timezone.utc).timestamp() * _US
+        self._seq += 1
+        seq = self._seq
         self.touched.update(
             self.keys[int(k)] for k in np.unique(kids)
         )
@@ -539,6 +659,7 @@ class DeviceWindowAggState:
         kids_ok = ts_ok = vals_ok = None
         if ok.any():
             kids_ok = kids[ok]
+            self._seen[kids_ok] = seq
             ts_ok = ts_us[ok]
             if spec.kind == "count":
                 vals_ok = np.ones(int(ok.sum()), dtype=np.float64)
@@ -548,20 +669,14 @@ class DeviceWindowAggState:
                 vals_ok = np.asarray(values)[ok]  # keep dtype for exact ints
 
         # The deferred phase judges window dues by the watermark as of
-        # THIS ingest: snapshot the clock (the next ingest mutates it
-        # in place on the host thread while the phase may still be in
-        # flight on the pipeline worker).
-        clock = (
-            (self.base_us.copy(), self.sys_at_base.copy())
-            if self._clock_copies
-            else None
-        )
+        # THIS ingest.
+        clock = self._phase_clock(seg_kids)
 
         def device_phase():
             if kids_ok is not None:
                 self._absorb(kids_ok, ts_ok, vals_ok)
-            closes = self._close_due(now_us, clock=clock)
-            return closes, self.notify_at(clock=clock)
+            closes, gone = self._close_due(now_us, clock=clock)
+            return closes, self.notify_at(clock=clock), (seq, gone)
 
         return events, device_phase
 
@@ -659,52 +774,53 @@ class DeviceWindowAggState:
 
     def _open_arrays(self):
         """Parallel ``(kids, wids, closes)`` arrays over the open
-        windows (table order) for the vectorized due check."""
-        spec = self.spec
+        windows (table order), for snapshots."""
         comp = self.open.comp
-        wids = (comp & _WID_MASK) - _WID_BIAS
-        closes = spec.align_us + wids * spec.offset_us + spec.length_us
-        return comp >> 32, wids, closes
+        return comp >> 32, (comp & _WID_MASK) - _WID_BIAS, self._closes_of(comp)
 
     def _close_due(
         self, now_us: float, clock=None
-    ) -> List[Tuple[str, Tuple[int, str, Any]]]:
+    ) -> Tuple[List[Tuple[str, Tuple[int, str, Any]]], np.ndarray]:
+        """Close the windows that are due: their events, and the ids
+        of the keys this leaves without an open window (for
+        :meth:`let_go`).  ``clock`` (:meth:`_phase_clock`, from a
+        delivery's own phase) first retimes the delivery's keys."""
         if not self.open_count:
-            return []
+            return [], _NO_KIDS
         # Ledger: `close_scan` (the due scan over the open windows),
         # `fetch` (inside ``states_of``), `close_emit` (columns to
-        # events for the windows that close).
-        with _flight.span("close_scan") as scan:
-            kids_arr, wids_arr, closes_arr = self._open_arrays()
-            scan.rows = len(closes_arr)
-            base, sys_at = clock if clock is not None else (
-                self.base_us,
-                self.sys_at_base,
-            )
-            wm = base[kids_arr] + (now_us - sys_at[kids_arr])
-            due = np.nonzero(closes_arr <= wm)[0]
+        # events for the windows that close), `retire` (which keys
+        # the close left without a window).
+        with _flight.span("close_scan", rows=len(self.open)):
+            if clock is not None:
+                self.open.retime(*clock, self._closes_of)
+            due = np.nonzero(self.open.at <= now_us)[0]
             if not len(due):
-                return []
+                return [], _NO_KIDS
             due = self.open.in_order(due)
             ids = self.open.ids[due]
+            comp_due = self.open.comp[due]
+            kids_due = comp_due >> 32
         # bytewax: allow[BTX-DRAIN] — the windower's .agg is its own slot table (never residency-wrapped; the driver evicts only the keyed-agg/scan tiers), and this due-window fetch runs inside the deferred device phase the pipeline worker owns
         states = self.agg.states_of(ids)
         with _flight.span("close_emit", rows=len(due)):
             self.open.remove(due)
             self.agg.release_ids(ids)
-            keys = list(map(self.keys.__getitem__, kids_arr[due].tolist()))
-            wids = wids_arr[due].tolist()
+            keys = list(map(self.keys.__getitem__, kids_due.tolist()))
+            wids = ((comp_due & _WID_MASK) - _WID_BIAS).tolist()
             values = map(self._finalize_one, states)
             events = list(zip(keys, zip(wids, repeat("E"), values)))
             if self.spec.meta_live:
-                metas = self._metas(closes_arr[due].tolist())
+                metas = self._metas(self._closes_of(comp_due).tolist())
                 _flight.RECORDER.count("window_meta_events", len(metas))
                 # "E" then "M" per window, as the host tier emits.
                 both = [None] * (2 * len(events))
                 both[0::2] = events
                 both[1::2] = zip(keys, zip(wids, repeat("M"), metas))
                 events = both
-        return events
+        with _flight.span("retire", rows=len(due)):
+            gone = self.open.without_window(np.unique(kids_due))
+        return events, gone
 
     def _metas(self, closes_us: List[float]) -> List[Any]:
         """``WindowMetadata`` per close time."""
@@ -736,30 +852,27 @@ class DeviceWindowAggState:
         return snap
 
     def on_notify(self) -> List[Tuple[str, Tuple[int, str, Any]]]:
-        now_us = datetime.now(timezone.utc).timestamp() * _US
-        return self._close_due(now_us)
+        return self._close_now(datetime.now(timezone.utc).timestamp() * _US)
 
     def on_eof(self) -> List[Tuple[str, Tuple[int, str, Any]]]:
-        return self._close_due(np.inf)
+        return self._close_now(np.inf)
+
+    def _close_now(self, now_us: float):
+        """A close on the main thread with the pipeline drained: the
+        keys it leaves without a window go at once."""
+        events, gone = self._close_due(now_us)
+        self.let_go((self._seq, gone))
+        return events
 
     def notify_at(self, clock=None) -> Optional[datetime]:
         """System time of the earliest window close: the instant the
         key's watermark reaches the close time."""
         if not self.open_count:
             return None
-        kids_arr, _wids_arr, closes_arr = self._open_arrays()
-        base, sys_at = clock if clock is not None else (
-            self.base_us,
-            self.sys_at_base,
-        )
-        bases = base[kids_arr]
-        finite = np.isfinite(bases)
-        if not finite.any():
+        at = float(self.open.at.min())
+        if not np.isfinite(at):
             return None
-        ats = sys_at[kids_arr][finite] + (
-            closes_arr[finite] - bases[finite]
-        )
-        return datetime.fromtimestamp(float(ats.min()) / _US, tz=timezone.utc)
+        return datetime.fromtimestamp(at / _US, tz=timezone.utc)
 
     # -- recovery ----------------------------------------------------------
 
@@ -844,6 +957,11 @@ class DeviceWindowAggState:
             self.base_us[kid] = _to_us(cs.watermark_base)
             self.sys_at_base[kid] = _to_us(cs.system_time_of_max_event)
 
+    def _retime(self, kids: np.ndarray) -> None:
+        """Due instants of the keys' open windows from the live clock
+        (main thread, pipeline drained)."""
+        self.open.retime(*self._phase_clock(kids), self._closes_of)
+
     def _replay_queue(self, kid: int, snap: Any) -> None:
         """A host-tier ordered=True logic keeps on-time values whose
         ts is still ahead of the watermark in ``queue``, to apply in
@@ -888,6 +1006,7 @@ class DeviceWindowAggState:
         # Queued values fold ON TOP of the installed states.
         for kid, (_key, snap) in zip(kids, items):
             self._replay_queue(kid, snap)
+        self._retime(np.unique(np.asarray(kids, dtype=np.int64)))
 
     def _load_windows(
         self, kids: List[int], items: List[Tuple[str, Any]]
@@ -964,6 +1083,13 @@ class DeviceSessionAggState(DeviceWindowAggState):
       the host tier's can differ when a single value extends several
       sessions downward at once.
 
+    Keys are kept: a session key's id, clock and ``next_wid`` stay
+    after its last session closes (session ids must never be reused,
+    so the key's state is never empty), where the tumbling/sliding
+    tier lets a key go with its last window
+    (:meth:`DeviceWindowAggState.let_go`); its closes hand no key
+    back.
+
     Reference session semantics:
     ``/root/reference/pysrc/bytewax/operators/windowing.py:688-806``.
     """
@@ -987,6 +1113,13 @@ class DeviceSessionAggState(DeviceWindowAggState):
         # Cached (kids, wids, dues) arrays over open_close_us;
         # invalidated whenever the open-session set changes.
         self._open_cache = None
+        # Deferred device phases read the per-key clock as of their
+        # own ingest, so the ingest snapshots it; at pipeline depth 1
+        # the phase runs inline before the clock can move again and
+        # the copy is skipped.
+        from bytewax_tpu.engine.pipeline import pipeline_depth
+
+        self._clock_copies = pipeline_depth() > 1
 
     @property
     def open_count(self) -> int:
@@ -1009,6 +1142,36 @@ class DeviceSessionAggState(DeviceWindowAggState):
             )
             self._open_cache = (kids, wids, dues)
         return self._open_cache
+
+    def _phase_clock(self, kids: np.ndarray):
+        """The whole clock as of this ingest (a session's due time is
+        stored, not its due instant: the scan reads every key's
+        clock)."""
+        if not self._clock_copies:
+            return None
+        return self.base_us.copy(), self.sys_at_base.copy()
+
+    def _retime(self, kids: np.ndarray) -> None:
+        """Nothing to retime: the scan reads the clock itself."""
+
+    def notify_at(self, clock=None) -> Optional[datetime]:
+        """System time of the earliest session close: the instant the
+        key's watermark reaches the due time."""
+        if not self.open_count:
+            return None
+        kids_arr, _wids_arr, closes_arr = self._open_arrays()
+        base, sys_at = clock if clock is not None else (
+            self.base_us,
+            self.sys_at_base,
+        )
+        bases = base[kids_arr]
+        finite = np.isfinite(bases)
+        if not finite.any():
+            return None
+        ats = sys_at[kids_arr][finite] + (
+            closes_arr[finite] - bases[finite]
+        )
+        return datetime.fromtimestamp(float(ats.min()) / _US, tz=timezone.utc)
 
     # -- session bookkeeping (per run, host Python) ------------------------
 
@@ -1167,9 +1330,9 @@ class DeviceSessionAggState(DeviceWindowAggState):
 
     def _close_due(
         self, now_us: float, clock=None
-    ) -> List[Tuple[str, Tuple[int, str, Any]]]:
+    ) -> Tuple[List[Tuple[str, Tuple[int, str, Any]]], np.ndarray]:
         if not self.open_close_us:
-            return []
+            return [], _NO_KIDS
         with _flight.span("close_scan") as scan:
             kids_arr, wids_arr, dues_arr = self._open_arrays()
             scan.rows = len(dues_arr)
@@ -1183,7 +1346,7 @@ class DeviceSessionAggState(DeviceWindowAggState):
             # equality.
             due_rows = np.nonzero(dues_arr < wm)[0]
             if not len(due_rows):
-                return []
+                return [], _NO_KIDS
             due = [(int(kids_arr[i]), int(wids_arr[i])) for i in due_rows]
         from bytewax_tpu.operators.windowing import WindowMetadata
 
@@ -1202,7 +1365,7 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 )
                 events.append((key, (wid, "M", meta)))
             self._open_cache = None
-        return events
+        return events, _NO_KIDS
 
     # -- recovery -----------------------------------------------------------
 

@@ -1091,7 +1091,7 @@ def test_itemized_promotion_unit_matches_per_item_path():
         # on_batch* return (late_events, device_phase); materialize
         # the deferred phase to get the full event stream.
         late, phase = ingest
-        closes, _hint = phase()
+        closes, _hint, _gone = phase()
         return late + closes
 
     # Count shape: values ARE the timestamps.
@@ -1252,7 +1252,8 @@ def _deliver(st, keys, secs, vals):
         }
     )
     late, phase = st.on_batch_columnar(batch)
-    closes, _hint = phase()
+    closes, _hint, gone = phase()
+    st.let_go(gone)  # as the driver does where the phase is finalized
     return late + closes
 
 
@@ -1402,7 +1403,8 @@ def test_reused_slot_starts_from_identity(monkeypatch, kind, shard="0"):
         ("b", 6, want[1]),
     ]
     assert _types_of([e[1][2] for e in third]) == _types_of(want)
-    assert st.open_count == 0 and st.is_empty() is False  # keys stay known
+    # Every key went with its last window; both are still to snapshot.
+    assert st.open_count == 0 and not st.key_ids and st.is_empty() is False
 
 
 @pytest.mark.parametrize(
