@@ -27,12 +27,16 @@ under integer composites (``kid << 32 | wid + 2**31``) in
 slots a delivery at a time through the aggregate state's id-based
 surface (``open_ids`` / ``states_of`` / ``release_ids``): nothing runs
 once per (key, window) but the construction of the result tuples and
-``_finalize_one``.  Beside each composite the table keeps the system
-time at which the window falls due under its key's clock
-(``_OpenWindows.at``); a delivery's phase retimes the windows of the
-delivery's own keys (the only clocks it moved), so the due scan and
-the notify hint are one pass over one column each, not a recomputation
-of every open window's watermark.  "M" events (two ``datetime``s and a
+``_finalize_one``.  The table appends opens to an arena in open
+order, marks closes dead and finds composites through a two-level
+sorted index; it copies itself only in a rebuild, when its own sizes
+say so, so a delivery costs what it opens, closes and retimes, not
+what is open.  Beside each composite the table keeps the system
+time at which the window falls due under its key's clock (``at``); a
+delivery's phase retimes the windows of the delivery's own keys (the
+only clocks it moved), so the due scan and the notify hint are one
+pass over one column each, not a recomputation of every open
+window's watermark.  "M" events (two ``datetime``s and a
 ``WindowMetadata`` a window) are built only while the plan keeps the
 step's ``meta`` tap (``WindowAccelSpec.meta_live``, set at flatten
 time).  The session tier keeps string slot keys and the scalar
@@ -87,99 +91,261 @@ _WID_MASK = (1 << 32) - 1
 _NO_KIDS = np.empty(0, dtype=np.int64)
 
 
+# When :class:`_OpenWindows` rebuilds, as ratios of the sizes it
+# observes: the recent opens outgrow this share of the large sorted
+# run, or the closed rows this share of the open ones.
+_RECENT_SHARE = 1 / 4
+_DEAD_SHARE = 1 / 2
+
+
+def _find(run: np.ndarray, wanted: np.ndarray):
+    """Where each of ``wanted`` lies in the sorted ``run``, and
+    whether it is there."""
+    pos = np.searchsorted(run, wanted)
+    if not len(run):
+        return pos, np.zeros(len(wanted), dtype=bool)
+    return pos, run[np.minimum(pos, len(run) - 1)] == wanted
+
+
 class _OpenWindows:
-    """The open (key, window)s of a tumbling/sliding step: parallel
-    arrays sorted by composite, so a delivery's windows are looked
-    up with one ``searchsorted`` and opened, scanned and closed as
-    arrays — no Python per window.
+    """The open (key, window)s of a tumbling/sliding step.  What a
+    delivery costs here follows what the delivery opens, closes and
+    retimes, not what the table holds: no Python per window, and no
+    copy of a whole column but in a rebuild, whose cost is spread
+    over the deliveries between two of them.
 
-    ``ids`` are the aggregate state's slot ids; ``seq`` numbers the
-    windows in the order they were opened, which is the order closes
-    and snapshots list them in.  A window's key id, window id and
-    close time are arithmetic on its composite and are not stored.
+    **The arena** holds a row per window in the order the windows
+    were opened, which is the order closes and snapshots list them
+    in: the composite, the aggregate state's slot id and ``at``, the
+    system time (us) at which the window falls due under its key's
+    clock (``sys_at_base + (close - base)``, the instant the key's
+    watermark reaches the close time).  ``at`` moves only when the
+    key's clock does (:meth:`retime`), so the due scan and the notify
+    hint are one pass over one column each; a window just opened
+    holds ``inf`` until the delivery that opened it retimes its key.
+    Opens append (the columns are allocated with room to spare); a
+    close marks its rows dead (slot id -1, ``at`` inf) and moves
+    nothing.  A window's key id, window id and close time are
+    arithmetic on its composite and are not stored.
 
-    ``at`` is the system time (us) at which the window falls due
-    under its key's clock: ``sys_at_base + (close - base)``, the
-    instant the key's watermark reaches the close time.  It moves
-    only when the key's clock does (:meth:`retime`), so the due scan
-    and the notify hint are one pass over one column each, however
-    many windows are open.  A window just opened holds ``inf`` until
-    the delivery that opened it retimes its key.
+    **The index** finds a composite's row: two sorted runs of
+    (composite, arena row), a large one that only a rebuild writes
+    and a small one of the opens since, which takes each delivery's
+    new composites with one ``np.insert``.  A composite has at most
+    one entry; the entry of a closed window stays until the next
+    rebuild (a lookup skips it: its row is dead) and is pointed at
+    the new row if the window is opened again before that.  A key's
+    windows are one range of each run.
+
+    **A rebuild** drops the dead rows from the arena, merges the two
+    runs into the large one and renumbers its rows, in one pass.  It
+    runs before an open that finds the small run grown past
+    ``_RECENT_SHARE`` of the large one or the arena full, and after a
+    close that leaves more than ``_DEAD_SHARE`` as many dead rows as
+    live ones: counters
+    ``window_table_rebuilds`` and ``window_table_rebuild_rows`` (the
+    live rows each carried over).  Arena rows handed out
+    (:meth:`due`, :meth:`rows_of`) hold until the next call that
+    opens or removes.
     """
 
-    __slots__ = ("comp", "ids", "seq", "at", "_next_seq")
+    __slots__ = (
+        "_comp", "_ids", "_at", "_n", "_live",
+        "_big", "_big_rows", "_small", "_small_rows",
+    )  # fmt: skip
 
     def __init__(self):
-        self.comp = np.empty(0, dtype=np.int64)
-        self.ids = np.empty(0, dtype=np.int32)
-        self.seq = np.empty(0, dtype=np.int64)
-        self.at = np.empty(0, dtype=np.float64)
-        self._next_seq = 0
+        self._comp = np.empty(0, dtype=np.int64)
+        self._ids = np.empty(0, dtype=np.int32)
+        self._at = np.empty(0, dtype=np.float64)
+        self._n = 0  # arena rows in use, the dead among them
+        self._live = 0
+        self._big = self._small = np.empty(0, dtype=np.int64)
+        self._big_rows = self._small_rows = np.empty(0, dtype=np.int64)
 
     def __len__(self) -> int:
-        return len(self.comp)
+        return self._live
+
+    def _column(self, col: np.ndarray) -> np.ndarray:
+        col = col[: self._n]
+        if self._live < self._n:
+            col = col[self._ids[: self._n] >= 0]
+        col = col.view()
+        col.flags.writeable = False
+        return col
+
+    @property
+    def comp(self) -> np.ndarray:
+        """Composites of the open windows in the order they were
+        opened (read-only, like :attr:`ids` and :attr:`at`)."""
+        return self._column(self._comp)
+
+    @property
+    def ids(self) -> np.ndarray:
+        return self._column(self._ids)
+
+    @property
+    def at(self) -> np.ndarray:
+        return self._column(self._at)
 
     def ids_for(self, uniq: np.ndarray, agg) -> np.ndarray:
         """Slot ids of sorted unique composites; those not yet open
-        are given slots by ``agg`` in one call and join the table."""
-        pos = np.searchsorted(self.comp, uniq)
-        found = np.zeros(len(uniq), dtype=bool)
-        inside = pos < len(self.comp)
-        found[inside] = self.comp[pos[inside]] == uniq[inside]
-        out = np.empty(len(uniq), dtype=np.int32)
-        out[found] = self.ids[pos[found]]
-        if not found.all():
-            new = ~found
-            opened = agg.open_ids(uniq[new])
-            _flight.RECORDER.count("window_opens", len(opened))
-            out[new] = opened
-            seq = np.arange(
-                self._next_seq, self._next_seq + len(opened), dtype=np.int64
-            )
-            self._next_seq += len(opened)
-            self.comp = np.insert(self.comp, pos[new], uniq[new])
-            self.ids = np.insert(self.ids, pos[new], opened)
-            self.seq = np.insert(self.seq, pos[new], seq)
-            self.at = np.insert(self.at, pos[new], np.inf)
+        are given slots by ``agg`` in one call (in ascending
+        composite order) and join the table."""
+        if (
+            self._n + len(uniq) > len(self._comp)
+            or len(self._small) > _RECENT_SHARE * len(self._big)
+        ):
+            self._rebuild(room=len(uniq))
+        pos_big, in_big = _find(self._big, uniq)
+        pos_small, in_small = _find(self._small, uniq)
+        row_of = np.full(len(uniq), -1, dtype=np.int64)
+        row_of[in_big] = self._big_rows[pos_big[in_big]]
+        row_of[in_small] = self._small_rows[pos_small[in_small]]
+        # (A composite in neither run reads row -1: whatever that
+        # row holds, ``new`` does not trust it.)
+        fresh = row_of < 0
+        out = self._ids[row_of]
+        new = fresh | (out < 0)
+        if not new.any():
+            return out
+        opened = agg.open_ids(uniq[new])
+        _flight.RECORDER.count("window_opens", len(opened))
+        out[new] = opened
+        rows = np.arange(self._n, self._n + len(opened))
+        self._comp[rows] = uniq[new]
+        self._ids[rows] = opened
+        self._at[rows] = np.inf
+        self._n += len(opened)
+        self._live += len(opened)
+        row_of[new] = rows
+        # An entry left by a window closed since the last rebuild is
+        # pointed at the new row; a composite with no entry joins the
+        # small run.
+        for run_rows, pos, held in (
+            (self._big_rows, pos_big, in_big),
+            (self._small_rows, pos_small, in_small),
+        ):
+            again = new & held
+            if again.any():
+                run_rows[pos[again]] = row_of[again]
+        self._small = np.insert(self._small, pos_small[fresh], uniq[fresh])
+        self._small_rows = np.insert(
+            self._small_rows, pos_small[fresh], row_of[fresh]
+        )
         return out
+
+    def _of_keys(self, kids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """Arena rows of the open windows of ``kids``, and for each
+        the index into ``kids`` of its key: a key's composites are
+        one range of each run, the dead among them skipped."""
+        rows, of_key = [], []
+        for run, run_rows in (
+            (self._big, self._big_rows),
+            (self._small, self._small_rows),
+        ):
+            lo = np.searchsorted(run, kids << 32)
+            n = np.searchsorted(run, (kids + 1) << 32) - lo
+            total = int(n.sum())
+            if not total:
+                continue
+            found = run_rows[
+                np.arange(total) + np.repeat(lo - (np.cumsum(n) - n), n)
+            ]
+            live = self._ids[found] >= 0
+            rows.append(found[live])
+            of_key.append(np.repeat(np.arange(len(kids)), n)[live])
+        if not rows:
+            return _NO_KIDS, _NO_KIDS
+        return np.concatenate(rows), np.concatenate(of_key)
 
     def retime(
         self, kids: np.ndarray, base: np.ndarray, sys_at: np.ndarray, closes_of
     ) -> None:
         """Set ``at`` of every open window of ``kids`` from the keys'
-        clocks (``base``, ``sys_at``, parallel to ``kids``): a key's
-        composites are one run of the table."""
-        lo = np.searchsorted(self.comp, kids << 32)
-        n = np.searchsorted(self.comp, (kids + 1) << 32) - lo
-        total = int(n.sum())
-        if not total:
-            return
-        rows = np.arange(total) + np.repeat(lo - (np.cumsum(n) - n), n)
-        of_key = np.repeat(np.arange(len(kids)), n)
-        self.at[rows] = sys_at[of_key] + (
-            closes_of(self.comp[rows]) - base[of_key]
+        clocks (``base``, ``sys_at``, parallel to ``kids``)."""
+        rows, of_key = self._of_keys(kids)
+        self._at[rows] = sys_at[of_key] + (
+            closes_of(self._comp[rows]) - base[of_key]
         )
 
+    def shift(self, delta_us: float) -> None:
+        """Move every due instant by ``delta_us``, as if the system
+        clock had (tests age a table with it)."""
+        self._at[: self._n] += delta_us
+
+    def due(self, now_us: float) -> np.ndarray:
+        """Arena rows of the windows due by ``now_us``, in the order
+        they were opened."""
+        rows = np.nonzero(self._at[: self._n] <= now_us)[0]
+        if now_us == np.inf:  # end of input: the dead hold inf too
+            rows = rows[self._ids[rows] >= 0]
+        return rows
+
+    def next_due(self) -> float:
+        """The earliest due instant (inf where none is known)."""
+        return float(self._at[: self._n].min()) if self._n else np.inf
+
+    def read(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """Composites and slot ids at arena rows."""
+        return self._comp[rows], self._ids[rows]
+
+    def rows_of(self, kids: np.ndarray) -> np.ndarray:
+        """Arena rows of the keys' open windows, in the order the
+        windows were opened."""
+        return np.sort(self._of_keys(kids)[0])
+
     def without_window(self, kids: np.ndarray) -> np.ndarray:
-        """Those of the sorted unique key ids that have no open
-        window: a key's composites are one run of the table."""
-        at = np.searchsorted(self.comp, kids << 32)
+        """Those of the key ids that have no open window."""
         held = np.zeros(len(kids), dtype=bool)
-        inside = at < len(self.comp)
-        held[inside] = (self.comp[at[inside]] >> 32) == kids[inside]
+        held[self._of_keys(kids)[1]] = True
         return kids[~held]
 
-    def in_order(self, rows: np.ndarray) -> np.ndarray:
-        """``rows`` in the order their windows were opened."""
-        return rows[np.argsort(self.seq[rows], kind="stable")]
-
     def remove(self, rows: np.ndarray) -> None:
-        keep = np.ones(len(self.comp), dtype=bool)
-        keep[rows] = False
-        self.comp = self.comp[keep]
-        self.ids = self.ids[keep]
-        self.seq = self.seq[keep]
-        self.at = self.at[keep]
+        self._ids[rows] = -1
+        self._at[rows] = np.inf
+        self._live -= len(rows)
+        if self._n - self._live > _DEAD_SHARE * self._live:
+            self._rebuild()
+
+    def _rebuild(self, room: int = 0) -> None:
+        """Drop the dead rows, merge the small run into the large one
+        and leave the arena with room for as many rows again and for
+        ``room`` more (and no smaller than it was filled: a table
+        that turns over whole keeps its room)."""
+        n, live = self._n, self._live
+        keep = self._ids[:n] >= 0
+        row_now = np.cumsum(keep)
+        row_now -= 1
+        runs = []
+        for run, run_rows in (
+            (self._big, self._big_rows),
+            (self._small, self._small_rows),
+        ):
+            held = keep[run_rows]
+            runs.append((run[held], row_now[run_rows[held]]))
+        (big, big_rows), (small, small_rows) = runs
+        pos = np.searchsorted(big, small)
+        self._big = np.insert(big, pos, small)
+        self._big_rows = np.insert(big_rows, pos, small_rows)
+        self._small = small[:0]
+        self._small_rows = small_rows[:0]
+        kept = np.flatnonzero(keep)
+        size = max(2 * (live + room), n)
+
+        def carried(old: np.ndarray) -> np.ndarray:
+            col = np.empty(size, dtype=old.dtype)
+            # ("clip" writes straight into ``out``; the default mode
+            # goes through a buffer.)
+            np.take(old, kept, out=col[:live], mode="clip")
+            return col
+
+        self._comp, self._ids, self._at = map(
+            carried, (self._comp, self._ids, self._at)
+        )
+        self._n = live
+        _flight.RECORDER.count("window_table_rebuilds")
+        _flight.RECORDER.count("window_table_rebuild_rows", live)
 
 
 class _LateTs:
@@ -772,11 +938,14 @@ class DeviceWindowAggState:
             )
         self.agg.update_ids(slots_rep, val_rep)
 
+    def _split(self, comp: np.ndarray):
+        """Parallel ``(kids, wids, closes)`` arrays of composites."""
+        return comp >> 32, (comp & _WID_MASK) - _WID_BIAS, self._closes_of(comp)
+
     def _open_arrays(self):
         """Parallel ``(kids, wids, closes)`` arrays over the open
-        windows (table order), for snapshots."""
-        comp = self.open.comp
-        return comp >> 32, (comp & _WID_MASK) - _WID_BIAS, self._closes_of(comp)
+        windows (table order: the order they were opened in)."""
+        return self._split(self.open.comp)
 
     def _close_due(
         self, now_us: float, clock=None
@@ -794,12 +963,10 @@ class DeviceWindowAggState:
         with _flight.span("close_scan", rows=len(self.open)):
             if clock is not None:
                 self.open.retime(*clock, self._closes_of)
-            due = np.nonzero(self.open.at <= now_us)[0]
+            due = self.open.due(now_us)
             if not len(due):
                 return [], _NO_KIDS
-            due = self.open.in_order(due)
-            ids = self.open.ids[due]
-            comp_due = self.open.comp[due]
+            comp_due, ids = self.open.read(due)
             kids_due = comp_due >> 32
         # bytewax: allow[BTX-DRAIN] — the windower's .agg is its own slot table (never residency-wrapped; the driver evicts only the keyed-agg/scan tiers), and this due-window fetch runs inside the deferred device phase the pipeline worker owns
         states = self.agg.states_of(ids)
@@ -869,7 +1036,7 @@ class DeviceWindowAggState:
         key's watermark reaches the close time."""
         if not self.open_count:
             return None
-        at = float(self.open.at.min())
+        at = self.open.next_due()
         if not np.isfinite(at):
             return None
         return datetime.fromtimestamp(at / _US, tz=timezone.utc)
@@ -891,14 +1058,14 @@ class DeviceWindowAggState:
         # O(keys x open windows) host work plus a whole-table
         # readback per key, which an epoch close over 10^5 touched
         # keys never finishes.
-        rows = self._rows_of(keys)
-        kids_arr, wids_arr, closes_arr = self._open_arrays()
+        comp, ids = self.open.read(self._rows_of(keys))
+        kids_arr, wids_arr, closes_arr = self._split(comp)
         # bytewax: allow[BTX-DRAIN] — snapshots run with the pipeline drained (module docstring); the windower's .agg is its own slot table
-        states = self.agg.states_of(self.open.ids[rows]) if len(rows) else []
-        metas = self._metas(closes_arr[rows].tolist())
+        states = self.agg.states_of(ids) if len(ids) else []
+        metas = self._metas(closes_arr.tolist())
         open_of: Dict[int, Tuple[dict, dict]] = {}
         for kid, wid, meta, state in zip(
-            kids_arr[rows].tolist(), wids_arr[rows].tolist(), metas, states
+            kids_arr.tolist(), wids_arr.tolist(), metas, states
         ):
             opened, folded = open_of.setdefault(kid, ({}, {}))
             opened[wid] = meta
@@ -940,8 +1107,7 @@ class DeviceWindowAggState:
         wanted = [
             kid for kid in map(self.key_ids.get, keys) if kid is not None
         ]
-        held = np.isin(self.open.comp >> 32, np.asarray(wanted, dtype=np.int64))
-        return self.open.in_order(np.nonzero(held)[0])
+        return self.open.rows_of(np.unique(np.asarray(wanted, dtype=np.int64)))
 
     def demotion_snapshots(self):
         """Full-state drain for device→host demotion: host-format
@@ -1047,7 +1213,7 @@ class DeviceWindowAggState:
             if snap is not None
         ]
         rows = self._rows_of([key for key, _snap in out])
-        self.agg.release_ids(self.open.ids[rows])
+        self.agg.release_ids(self.open.read(rows)[1])
         self.open.remove(rows)
         self.touched.difference_update(key for key, _snap in out)
         return out

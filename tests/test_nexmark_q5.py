@@ -250,7 +250,7 @@ def _age(st, seconds):
     """As if ``seconds`` of system time had passed since every key's
     newest event was taken in."""
     st.sys_at_base -= seconds * 1e6
-    st.open.at -= seconds * 1e6
+    st.open.shift(-seconds * 1e6)
 
 
 @pytest.mark.parametrize("windower", [TUMBLING, SLIDING], ids=["tumbling", "sliding"])

@@ -1531,3 +1531,295 @@ def test_delivery_makes_constant_calls_into_agg(monkeypatch, shard, meta):
         ["E", "M", "E", "M"] if meta else ["E"] * 4
     )
     assert len(few) == (16 if meta else 8)
+
+
+# -- the open-window table against a plain dict model -----------------------
+
+
+class _FakeSlots:
+    """``open_ids`` as the aggregate state gives slots out: freed
+    ones from the end of the free list, then fresh ones."""
+
+    def __init__(self):
+        self.free, self.fresh, self.asked = [], 0, []
+
+    def open_ids(self, place):
+        self.asked.append(place.copy())
+        reused = [self.free.pop() for _ in range(min(len(place), len(self.free)))]
+        fresh = np.arange(self.fresh, self.fresh + len(place) - len(reused))
+        self.fresh += len(fresh)
+        return np.concatenate([reused, fresh]).astype(np.int32)
+
+
+def _comp_of(kid, wid):
+    from bytewax_tpu.engine.window_accel import _WID_BIAS
+
+    return (int(kid) << 32) + int(wid) + _WID_BIAS
+
+
+def _model_closes_of(comp):
+    from bytewax_tpu.engine.window_accel import _WID_BIAS, _WID_MASK
+
+    return ((comp & _WID_MASK) - _WID_BIAS) * 5.0 + 10.0
+
+
+class _TableModel:
+    """The table as a dict: composite -> [slot, due instant, open
+    order], with the clock each key was last retimed from."""
+
+    def __init__(self):
+        self.rows, self.clock, self.opened = {}, {}, 0
+
+    def in_order(self):
+        return sorted(self.rows, key=lambda c: self.rows[c][2])
+
+    def open(self, uniq, table, slots):
+        before = len(slots.asked)
+        got = table.ids_for(uniq, slots)
+        new = [c for c in uniq.tolist() if c not in self.rows]
+        if new:  # one call, in ascending composite order
+            assert len(slots.asked) == before + 1
+            assert slots.asked[-1].tolist() == new
+        else:
+            assert len(slots.asked) == before
+        it = iter(got[[c not in self.rows for c in uniq.tolist()]].tolist())
+        for c in new:
+            self.rows[c] = [next(it), np.inf, self.opened]
+            self.opened += 1
+        assert got.tolist() == [self.rows[c][0] for c in uniq.tolist()]
+
+    def retime(self, kids, base, sys_at, table):
+        table.retime(kids, base, sys_at, _model_closes_of)
+        of = dict(zip(kids.tolist(), zip(base.tolist(), sys_at.tolist())))
+        self.clock.update(of)
+        for c, row in self.rows.items():
+            if c >> 32 in of:
+                b, s = of[c >> 32]
+                row[1] = s + (float(_model_closes_of(np.int64(c))) - b)
+
+    def close(self, now, table, slots):
+        due = table.due(now)
+        comp, ids = table.read(due)
+        want = [c for c in self.in_order() if self.rows[c][1] <= now]
+        assert comp.tolist() == want  # in the order they were opened
+        assert ids.tolist() == [self.rows[c][0] for c in want]
+        table.remove(due)
+        slots.free.extend(ids.tolist())
+        for c in want:
+            del self.rows[c]
+        kids = np.unique(comp >> 32)
+        held = {c >> 32 for c in self.rows}
+        assert table.without_window(kids).tolist() == [
+            k for k in kids.tolist() if k not in held
+        ]
+
+    def check(self, table):
+        order = self.in_order()
+        assert len(table) == len(order)
+        assert table.comp.tolist() == order
+        assert table.ids.tolist() == [self.rows[c][0] for c in order]
+        assert table.at.tolist() == [self.rows[c][1] for c in order]
+        ats = [row[1] for row in self.rows.values()]
+        assert table.next_due() == (min(ats) if ats else np.inf)
+
+
+def _rebuilds():
+    from bytewax_tpu.engine import flight
+
+    return flight.RECORDER.counters.get("window_table_rebuilds", 0)
+
+
+@pytest.mark.parametrize("keys, per_step", [(6, 4), (40, 30), (400, 300), (3000, 1500)])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_open_window_table_matches_dict_model(seed, keys, per_step):
+    """Random opens, retimes, due scans, closes, whole-clock shifts,
+    ends of input and resumes leave the table and a plain dict model
+    with the same slots, the same close order, the same keys without
+    a window and the same notify minimum after every step, through
+    many rebuilds (sizes swing from empty to thousands and back)."""
+    from bytewax_tpu.engine.window_accel import _OpenWindows
+
+    rng = np.random.default_rng([seed, keys])
+    table, slots, model = _OpenWindows(), _FakeSlots(), _TableModel()
+    started, now, wid = _rebuilds(), 0.0, 0
+    for step in range(120):
+        growing = (step // 15) % 2 == 0  # fill, drain, fill, ...
+        what = rng.choice(
+            ["open", "close", "retime", "shift", "eof", "resume"],
+            p=[0.5, 0.2, 0.2, 0.04, 0.02, 0.04] if growing
+            else [0.2, 0.5, 0.2, 0.04, 0.02, 0.04],
+        )  # fmt: skip
+        now += 1.0
+        if what == "open":
+            wid += int(rng.integers(0, 2))
+            kids = rng.integers(0, keys, size=int(rng.integers(1, per_step + 1)))
+            wids = wid - rng.integers(0, 3, size=len(kids))
+            comps = [_comp_of(k, w) for k, w in zip(kids, wids)]
+            model.open(np.unique(np.asarray(comps, dtype=np.int64)), table, slots)
+            what = "retime" if rng.random() < 0.8 else what
+        if what == "retime":
+            kids = np.unique(rng.integers(0, keys, size=int(rng.integers(1, per_step + 1))))
+            base = rng.integers(0, 50, size=len(kids)).astype(np.float64)
+            model.retime(kids, base, np.full(len(kids), now), table)
+        elif what == "close" and model.rows:
+            ats = sorted(row[1] for row in model.rows.values())
+            model.close(ats[int(rng.integers(0, len(ats)))], table, slots)
+        elif what == "eof":
+            model.close(np.inf, table, slots)
+            assert len(table) == 0
+        elif what == "shift":
+            delta = float(rng.integers(-20, 20))
+            table.shift(delta)
+            for row in model.rows.values():
+                row[1] += delta
+            model.clock = {k: (b, s + delta) for k, (b, s) in model.clock.items()}
+        elif what == "resume":
+            # As ``load_many`` does: a page's windows reopen in
+            # composite order, then every key is retimed.
+            table, slots = _OpenWindows(), _FakeSlots()
+            live, clock = sorted(model.rows), model.clock
+            model = _TableModel()
+            model.open(np.asarray(live, dtype=np.int64), table, slots)
+            kids = np.asarray(sorted({c >> 32 for c in live} & set(clock)), dtype=np.int64)
+            if len(kids):
+                base, sys_at = map(np.asarray, zip(*(clock[k] for k in kids.tolist())))
+                model.retime(kids, base.astype(np.float64), sys_at.astype(np.float64), table)
+        model.check(table)
+    assert _rebuilds() - started >= 5
+
+
+def test_open_window_table_key_straddles_both_index_levels():
+    """A key with windows in the large run and in the small one is
+    retimed, listed and held as one key."""
+    from bytewax_tpu.engine.window_accel import _OpenWindows
+
+    table, slots, model = _OpenWindows(), _FakeSlots(), _TableModel()
+    old = np.asarray([_comp_of(k, w) for k in range(500) for w in (0, 1)], dtype=np.int64)
+    model.open(old, table, slots)
+    # The next open merges the first into the large run and joins the
+    # small one itself.
+    model.open(np.asarray([_comp_of(7, 2), _comp_of(600, 2)], dtype=np.int64), table, slots)
+    assert (len(table._big), len(table._small)) == (1000, 2)
+    model.retime(np.asarray([7]), np.asarray([3.0]), np.asarray([100.0]), table)
+    model.check(table)
+    comp, _ids = table.read(table.rows_of(np.asarray([7])))
+    assert comp.tolist() == [_comp_of(7, 0), _comp_of(7, 1), _comp_of(7, 2)]
+    assert np.isfinite(table.at[table.comp >> 32 == 7]).all()
+    # Its two old windows close: the key is still held by the new one.
+    model.close(float(model.rows[_comp_of(7, 1)][1]), table, slots)
+    assert _comp_of(7, 2) in model.rows and _comp_of(7, 1) not in model.rows
+    assert (len(table._big), len(table._small)) == (1000, 2)  # no rebuild yet
+    assert table.without_window(np.asarray([7, 8])).tolist() == []
+    model.close(float(model.rows[_comp_of(7, 2)][1]), table, slots)
+    assert table.without_window(np.asarray([7, 8])).tolist() == [7]
+    model.check(table)
+
+
+def test_open_window_table_reopens_a_composite_before_a_rebuild():
+    """A window closed and opened again before the index was rebuilt
+    takes a new slot and a new place in the order; the entry its
+    closed self left behind finds the new one, in either run."""
+    from bytewax_tpu.engine.window_accel import _OpenWindows
+
+    table, slots, model = _OpenWindows(), _FakeSlots(), _TableModel()
+    model.open(np.asarray([_comp_of(k, 0) for k in range(100)], dtype=np.int64), table, slots)
+    model.open(np.asarray([_comp_of(k, 1) for k in range(10)], dtype=np.int64), table, slots)
+    assert (len(table._big), len(table._small)) == (100, 10)
+    in_big, in_small = _comp_of(40, 0), _comp_of(4, 1)
+    kids = np.asarray([4, 40])
+    model.retime(kids, np.asarray([0.0, 0.0]), np.asarray([0.0, 0.0]), table)
+    # Key 4's windows fall due at 10 and 15, key 40's at 10.
+    model.close(12.0, table, slots)
+    assert _comp_of(4, 0) not in model.rows and in_big not in model.rows
+    model.close(15.0, table, slots)
+    assert in_small not in model.rows and table.without_window(kids).tolist() == [4, 40]
+    started = _rebuilds()
+    model.open(np.asarray(sorted([in_big, in_small, _comp_of(50, 0)]), dtype=np.int64), table, slots)
+    assert _rebuilds() == started
+    assert (len(table._big), len(table._small)) == (100, 10)  # entries reused
+    model.check(table)
+    assert table.comp[-2:].tolist() == sorted([in_big, in_small])
+    assert np.isinf(table.at[-2:]).all()
+    assert table.without_window(kids).tolist() == []
+    model.retime(kids, np.asarray([1.0, 2.0]), np.asarray([50.0, 60.0]), table)
+    model.check(table)
+    # Through a rebuild and on: still one live entry a composite.
+    model.open(np.asarray([_comp_of(k, 2) for k in range(60)], dtype=np.int64), table, slots)
+    model.open(np.asarray([_comp_of(k, 3) for k in range(5)], dtype=np.int64), table, slots)
+    assert _rebuilds() > started
+    model.check(table)
+    model.close(np.inf, table, slots)
+    assert len(table) == 0 and table.next_due() == np.inf
+
+
+@pytest.mark.parametrize(
+    "held, per_delivery, deliveries, most",
+    [(1_000_000, 10_000, 20, 2_000_000), (3_334, 3_334, 20, 20 * 2 * 3_334)],
+    ids=["million_open", "flood_sized"],
+)
+def test_open_window_table_copies_are_amortised(held, per_delivery, deliveries, most):
+    """What the table copies follows what deliveries open and close,
+    not what it holds: with a million windows open, twenty deliveries
+    that each open and close ten thousand copy under two million rows
+    between them (a table rewritten at every insert and remove copies
+    eight million a delivery), and at the flood's 3,334 a delivery
+    copies a few thousand; both counters are on ``GET /status``."""
+    from bytewax_tpu.engine import flight
+    from bytewax_tpu.engine.window_accel import _OpenWindows
+
+    table, slots, kid = _OpenWindows(), _FakeSlots(), 0
+
+    def deliver(n):
+        nonlocal kid
+        kids = np.arange(kid, kid + n, dtype=np.int64)
+        kid += n
+        table.ids_for((kids << 32) + (1 << 31), slots)
+        table.retime(kids, np.zeros(n), kids.astype(np.float64), _model_closes_of)
+
+    while len(table) < held:
+        deliver(min(100_000, held - len(table)))
+    before = dict(flight.RECORDER.counters)
+    for _ in range(deliveries):
+        deliver(per_delivery)
+        due = table.due(float(table.at[per_delivery - 1]))
+        assert len(due) == per_delivery
+        table.remove(due)
+        assert len(table) == held
+    counted = flight.RECORDER.snapshot()["counters"]
+    gained = {
+        name: counted[name] - before.get(name, 0)
+        for name in ("window_table_rebuilds", "window_table_rebuild_rows")
+    }
+    assert gained["window_table_rebuild_rows"] <= most
+    assert gained["window_table_rebuilds"] <= deliveries + 1
+    # Further on the rebuilds come, each a copy of the table, and stay
+    # far apart: under a quarter of the table a delivery.
+    for _ in range(4 * deliveries):
+        deliver(per_delivery)
+        table.remove(table.due(float(table.at[per_delivery - 1])))
+    rows = flight.RECORDER.counters["window_table_rebuild_rows"] - before.get(
+        "window_table_rebuild_rows", 0
+    )
+    assert rows > 0
+    if held > 100 * per_delivery - 1:
+        assert rows / (5 * deliveries) < held / 4
+
+
+def test_window_table_counters_are_on_status(monkeypatch):
+    """A window step's deliveries count the table's rebuilds where
+    ``window_opens`` is counted: the recorder section of ``GET
+    /status``."""
+    import json
+
+    from bytewax_tpu.engine import flight
+
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", "0")
+    before = dict(flight.RECORDER.counters)
+    st = _spec_of("count", TUMBLING_10S).make_state()
+    for step in range(4):
+        secs = step * 400 + np.arange(40) * 10
+        _deliver(st, ["a"] * 40, secs, np.ones(40))
+    counted = json.loads(json.dumps(flight.RECORDER.snapshot()))["counters"]
+    assert counted["window_table_rebuilds"] > before.get("window_table_rebuilds", 0)
+    assert counted["window_table_rebuild_rows"] > before.get("window_table_rebuild_rows", 0)
+    assert counted["window_opens"] - before.get("window_opens", 0) == 160
