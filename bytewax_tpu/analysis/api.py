@@ -1,7 +1,6 @@
 """File discovery and the one-call analysis entry points.
 
-Used by the CLI (``__main__``), the tier-1 wrapper test, and
-``bench.py``'s enforcement-status line.
+Used by the CLI (``__main__``) and the tier-1 wrapper test.
 """
 
 from pathlib import Path
@@ -75,7 +74,7 @@ def analyze_tree(
     """Analyze the installed package (+ examples).  Returns
     ``(diagnostics, n_baselined, project)`` after waiver and baseline
     filtering.  Pass a dict as ``timings`` to collect per-rule wall
-    seconds (``bench.py`` feeds these into the perf trajectory)."""
+    seconds (the CLI's ``--timings``)."""
     from bytewax_tpu.analysis.rules import run_rules
 
     pkg_dir, examples = default_roots()

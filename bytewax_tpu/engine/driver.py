@@ -3182,8 +3182,7 @@ class _Driver:
         #: point, so the count-matched barrier sees exactly the
         #: frames that hit the wire.  ``BYTEWAX_TPU_WIRE=pickle``
         #: restores the legacy wire wholesale — whole-frame pickle
-        #: AND one frame per routed slice — which is also the
-        #: comparison baseline bench.py measures.
+        #: AND one frame per routed slice.
         self._ship_acc = (
             _wire.RouteAccumulator()
             if self.comm is not None

@@ -14,7 +14,7 @@ epoch protocol.  It keeps, per process:
   mirrored into the Prometheus families in
   :mod:`bytewax_tpu._metrics` so ``GET /metrics`` exposes them;
 - a bounded buffer of recent **epoch-close durations** for p50/p99
-  reporting (``bench.py`` and the ``/status`` plane);
+  reporting (the ``/status`` plane);
 - the latest **cluster summaries** collected by the gsync piggyback at
   epoch close (see ``engine/driver.py``), so process 0's ``/status``
   shows every process;
@@ -35,8 +35,7 @@ epoch protocol.  It keeps, per process:
   full-epoch phase breakdown, the close-window breakdown (whose sum
   tracks ``epoch_close_duration_seconds``), source-lag samples, and
   drain-point queue depths.  Sealed records feed ``/status``, the
-  epoch-close gsync piggyback, ``bench.py``'s phase fractions, the
-  rescale hint, and — with ``BYTEWAX_TPU_TRACE_DIR`` set — a
+  epoch-close gsync piggyback, the rescale hint, and — with ``BYTEWAX_TPU_TRACE_DIR`` set — a
   Chrome/Perfetto ``trace_event`` JSON dump per completed epoch.
 
 XLA compiles are observed via ``jax.monitoring`` duration events
@@ -193,10 +192,6 @@ class FlightRecorder:
         self._ring: deque = deque(maxlen=max(ring_len, 16))
         self.counters: Dict[str, float] = {}
         self._close_s: deque = deque(maxlen=_CLOSE_BUF)
-        #: Residency-restore durations (always on, like _close_s) so
-        #: bench.py reports restore latency percentiles without the
-        #: ring perturbing the measured loops.
-        self._restore_s: deque = deque(maxlen=_CLOSE_BUF)
         self.active = False
         #: proc_id -> latest piggybacked summary (clustered runs).
         self.cluster: Dict[int, Any] = {}
@@ -220,7 +215,7 @@ class FlightRecorder:
         self._flush_depth: Dict[str, int] = {}
         #: (step_id, kind) -> latest source-lag sample in seconds.
         self._lag: Dict[Tuple[str, str], float] = {}
-        #: Lifetime per-phase totals (rescale hint, bench fractions).
+        #: Lifetime per-phase totals (rescale hint, the benchmark's host_phase_pct).
         self.phase_totals: Dict[str, float] = {}
         #: Latest sealed per-epoch ledger record (also what the
         #: epoch-close gsync piggyback ships).
@@ -518,7 +513,7 @@ class FlightRecorder:
         self.count("epoch_close_count")
         self.count("epoch_close_seconds", seconds)
         # The percentile buffer is always on (one float into a
-        # bounded deque) so readers like bench.py get close latency
+        # bounded deque) so ``GET /status`` has close latency
         # percentiles without turning on ring recording — which would
         # perturb the very hot loops being measured.
         self._close_s.append(seconds)
@@ -548,17 +543,6 @@ class FlightRecorder:
         """``(p50_seconds, p99_seconds, n)`` over the recent closes, or
         None before the first recorded close."""
         xs = sorted(self._copied(lambda: list(self._close_s), []))
-        if not xs:
-            return None
-        n = len(xs)
-        return xs[n // 2], xs[min(n - 1, int(n * 0.99))], n
-
-    def restore_percentiles(
-        self,
-    ) -> Optional[Tuple[float, float, int]]:
-        """``(p50_seconds, p99_seconds, n)`` over recent residency
-        restores, or None before the first restore."""
-        xs = sorted(self._copied(lambda: list(self._restore_s), []))
         if not xs:
             return None
         n = len(xs)
@@ -927,7 +911,6 @@ def note_residency_restore(step_id: str, n: int, seconds: float) -> None:
     """One residency-fault restore: ``n`` evicted/spilled keys
     reinstated on device before a delivery dispatched."""
     RECORDER.count("residency_restore_count", n)
-    RECORDER._restore_s.append(seconds)
     RECORDER.record(
         "restore", step=step_id, keys=n, seconds=round(seconds, 6)
     )
@@ -1486,9 +1469,9 @@ def ledger_fractions(
     """Fold the lifetime per-phase totals into the coarse
     host/device/flush/barrier/gsync/snapshot/residency buckets and
     normalize to fractions of the attributed time; None before any
-    phase was recorded.  Feeds ``bench.py``'s
-    ``epoch_phase_fractions`` and the attribution-backed rescale
-    hint."""
+    phase was recorded.  Feeds the benchmark's ``host_phase_pct``
+    (``benchmark/metrics/host_phase_pct.py``) and the
+    attribution-backed rescale hint."""
     if totals is None:
         totals = RECORDER.phase_totals
     buckets = dict.fromkeys(_FRACTION_BUCKETS, 0.0)

@@ -355,10 +355,9 @@ def device_footprint(state: Any) -> Tuple[int, int]:
         if obj is None or depth > 4 or id(obj) in seen:
             return
         seen.add(id(obj))
-        for attr in ("key_to_slot", "key_to_kid"):
-            m = getattr(obj, attr, None)
-            if isinstance(m, dict):
-                keys = max(keys, len(m))
+        m = getattr(obj, "key_to_slot", None)
+        if isinstance(m, dict):
+            keys = max(keys, len(m))
         # A window state's slots carry no key: it counts them itself.
         keys = max(keys, getattr(obj, "open_count", 0))
         fields = getattr(obj, "_fields", None)

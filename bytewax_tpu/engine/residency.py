@@ -377,11 +377,7 @@ class ResidentKeyState:
     # -- bookkeeping ------------------------------------------------------
 
     def _resident_map(self) -> Optional[Dict[str, int]]:
-        inner = self._inner
-        m = getattr(inner, "key_to_slot", None)
-        if m is None:
-            m = getattr(inner, "key_to_kid", None)
-        return m
+        return getattr(self._inner, "key_to_slot", None)
 
     def _resident_count(self) -> int:
         m = self._resident_map()

@@ -1,11 +1,13 @@
 """Mesh-sharded keyed aggregation state.
 
-The multi-chip sibling of :class:`bytewax_tpu.engine.xla.DeviceAggState`:
-per-key state lives as a slot table sharded over a device mesh
-(``n_shards * cap_per_shard`` slots, block *d* on device *d*), and each
-micro-batch runs ONE compiled program that exchanges rows to their
-owning shard with ``all_to_all`` over ICI and scatter-combines them
-into the local block (:func:`bytewax_tpu.ops.sharded.make_sharded_step`).
+The multi-chip placement of the keyed slot table
+(:class:`bytewax_tpu.engine.xla._AggTable`, whose one-device placement
+is ``DeviceAggState``): per-key state lives as a slot table sharded
+over a device mesh (``n_shards * cap_per_shard`` rows, block *d* on
+device *d*), and each micro-batch runs ONE compiled program that
+exchanges rows to their owning shard with ``all_to_all`` over ICI and
+scatter-combines them into the local block
+(:func:`bytewax_tpu.ops.sharded.make_sharded_step`).
 
 This is the keyed shuffle of the reference collapsed into the compiled
 step: ``hash(key) → worker → routed_exchange → per-key callback``
@@ -32,17 +34,14 @@ import numpy as np
 
 from bytewax_tpu.engine import flight as _flight
 from bytewax_tpu.engine import wire as _wire
-from bytewax_tpu.engine.arrays import ArrayBatch, KeyEncoder, VocabMap
+from bytewax_tpu.engine.arrays import ArrayBatch, VocabMap
 from bytewax_tpu.engine.scan_accel import ScanUpdates
 from bytewax_tpu.engine.xla import (
     DeviceAggState,
     NonNumericValues,
+    _AggTable,
     _final_of,
-    _field_vals,
-    _snaps_for,
-    _snaps_of,
-    _state_columns,
-    _take_free,
+    _SlotLayout,
 )
 from bytewax_tpu.ops.segment import AGG_KINDS
 
@@ -244,145 +243,50 @@ def _pow2(n: int, floor: int) -> int:
     return 1 << max(floor, math.ceil(math.log2(max(n, 1))))
 
 
-class _ShardedSlots:
-    """Key placement shared by the sharded state tiers.
+class _ShardedSlots(_SlotLayout):
+    """The table of a mesh-sharded state: block *d* of
+    ``cap_per_shard`` rows on device *d*, made, reset and grown over
+    the columns :meth:`_iter_fields` names (see ``xla._SlotLayout``
+    for where an id lives).
 
-    A key's owner shard is ``adler32(key) % n_shards`` (the same
-    family of stable hash the host tier routes with); its slot within
-    the owner is assigned densely per shard.  The wire id is
-    ``kid = slot * n_shards + shard`` so a compiled step recovers
-    both with one mod/div.  Each shard's last slot is scratch for
-    padding rows; blocks double on demand (key ids stay stable — only
-    the scratch index moves, and the old scratch is reset to each
-    field's identity), and freed slots reset lazily via the
-    pending-reset list.
-
-    Hosts set ``n_shards`` / ``cap_per_shard`` / ``_sharding``, call
-    :meth:`_init_slots`, and implement :meth:`_iter_fields` yielding
-    ``(name, identity, dtype)`` per state column.
+    Hosts set ``_sharding``, call :meth:`_init_slots`, and implement
+    :meth:`_iter_fields` yielding ``(name, identity, dtype)`` per
+    state column.
     """
-
-    def _init_slots(self) -> None:
-        self.key_to_kid: Dict[str, int] = {}
-        #: per-shard count of assigned slots
-        self._shard_fill = [0] * self.n_shards
-        #: per-shard free (discarded) slot lists
-        self._free: List[List[int]] = [[] for _ in range(self.n_shards)]
-        self._pending_reset: List[int] = []
-        self._fields = None  # lazy until first update/load
 
     def _iter_fields(self):
         """``(name, identity, dtype)`` per state column."""
         raise NotImplementedError
 
-    def _owner(self, key: str) -> int:
-        return zlib.adler32(key.encode()) % self.n_shards
+    def _make_fields(self):
+        import jax
+        import jax.numpy as jnp
 
-    def alloc(self, key: str) -> int:
-        """Assign (or return) the wire key id for a key."""
-        kid = self.key_to_kid.get(key)
-        if kid is not None:
-            return kid
-        shard = self._owner(key)
-        if self._free[shard]:
-            slot = self._free[shard].pop()
-            self._pending_reset.append(shard * self.cap_per_shard + slot)
-        else:
-            slot = self._shard_fill[shard]
-            if slot >= self.cap_per_shard - 1:
-                self._grow()
-            self._shard_fill[shard] += 1
-        kid = slot * self.n_shards + shard
-        self.key_to_kid[key] = kid
-        self._on_alloc(key, kid)
-        return kid
-
-    def _on_alloc(self, key: str, kid: int) -> None:
-        """Hook: bookkeeping for a newly-assigned key."""
-
-    def discard(self, key: str) -> None:
-        kid = self._release(key)
-        if kid is not None:
-            self._drop_vocab_ids([kid])
-
-    def _release(self, key: str) -> Optional[int]:
-        """Free a key's slot WITHOUT the vocab drop (extract_keys
-        batches that into one pass); returns the freed wire id."""
-        kid = self.key_to_kid.pop(key, None)
-        if kid is not None:
-            shard, slot = kid % self.n_shards, kid // self.n_shards
-            self._free[shard].append(slot)
-            self._on_discard(key, kid)
-        return kid
-
-    def _on_discard(self, key: str, kid: int) -> None:
-        """Hook: bookkeeping for a released key."""
-
-    def _drop_vocab_ids(self, kids: List[int]) -> None:
-        """Hook: un-map released wire ids from any external-id vocab
-        (one vectorized pass per batch of kids)."""
-
-    # The id-based slot surface (see ``xla.DeviceAggState.open_ids``):
-    # the window tier keeps its own table of integer (key, window)
-    # composites and takes, reads and returns wire ids a delivery at
-    # a time.
-
-    def _owners(self, place: np.ndarray) -> np.ndarray:
-        """Owner shard of each integer composite: a multiplicative
-        hash, so neighbouring window ids spread over the shards.
-        Ownership is recomputed at every load and never persisted."""
-        mixed = place.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
-        return ((mixed >> np.uint64(33)) % np.uint64(self.n_shards)).astype(
-            np.int64
-        )
-
-    def open_ids(self, place: np.ndarray) -> np.ndarray:
-        """One wire id per composite in ``place``, on the shard that
-        owns it; per shard, freed slots first (in :meth:`alloc`'s
-        order), then fresh ones."""
-        shards = self._owners(place)
-        kids = np.empty(len(place), dtype=np.int32)
-        for shard in range(self.n_shards):
-            rows = np.nonzero(shards == shard)[0]
-            if not len(rows):
-                continue
-            reused = _take_free(self._free[shard], len(rows))
-            fresh = len(rows) - len(reused)
-            start = self._shard_fill[shard]
-            while start + fresh > self.cap_per_shard - 1:
-                self._grow()
-            self._shard_fill[shard] = start + fresh
-            self._pending_reset.extend(
-                shard * self.cap_per_shard + slot for slot in reused
+        return {
+            name: jax.device_put(
+                jnp.full((self.capacity,), ident, dtype=dtype),
+                self._sharding,
             )
-            slots = np.empty(len(rows), dtype=np.int64)
-            slots[: len(reused)] = reused
-            slots[len(reused) :] = np.arange(start, start + fresh)
-            kids[rows] = slots * self.n_shards + shard
-        return kids
+            for name, ident, dtype in self._iter_fields()
+        }
 
-    def release_ids(self, kids: np.ndarray) -> None:
-        """Take back wire ids :meth:`open_ids` gave out, with one
-        vocab drop for the batch."""
-        shards, slots = kids % self.n_shards, kids // self.n_shards
-        for shard in range(self.n_shards):
-            self._free[shard].extend(slots[shards == shard].tolist())
-        self._drop_vocab_ids(kids.tolist())
+    def _reset_rows(self, ids: List[int]) -> None:
+        import jax.numpy as jnp
 
-    def _global_idx(self, kid):
-        """Row of a wire id (or an array of them) in the flat table."""
-        shard, slot = kid % self.n_shards, kid // self.n_shards
-        return shard * self.cap_per_shard + slot
+        idxs = jnp.asarray(
+            self._global_idx(np.asarray(ids, dtype=np.int32))
+        )
+        for name, ident, _dtype in self._iter_fields():
+            self._fields[name] = self._fields[name].at[idxs].set(ident)
 
-    def _grow(self) -> None:
-        """Double every shard's block.  Key ids are unchanged; only
-        the per-shard scratch slot (the block's last) moves, and the
-        old scratch becomes a real slot (reset to identity)."""
+    def _resize(self, new_cap: int) -> None:
+        """Grow every shard's block.  Ids are unchanged; only the
+        per-shard scratch row (the block's last) moves, and the old
+        scratch becomes a real slot (reset to identity)."""
         import jax
         import jax.numpy as jnp
 
         old_cap = self.cap_per_shard
-        new_cap = old_cap * 2
         if self._fields is not None:
             grown = {}
             for name, ident, dtype in self._iter_fields():
@@ -394,124 +298,26 @@ class _ShardedSlots:
                 arr = jnp.concatenate([blocks, pad], axis=1).reshape(-1)
                 grown[name] = jax.device_put(arr, self._sharding)
             self._fields = grown
-        # Remap pending resets (stored as global idx of the OLD
-        # layout; the shard/slot split survives via the old capacity).
-        self._pending_reset = [
-            (idx // old_cap) * new_cap + (idx % old_cap)
-            for idx in self._pending_reset
-        ]
         self.cap_per_shard = new_cap
 
-    def _ensure_fields(self) -> None:
-        import jax
-        import jax.numpy as jnp
 
-        if self._fields is None:
-            self._fields = {
-                name: jax.device_put(
-                    jnp.full(
-                        (self.n_shards * self.cap_per_shard,),
-                        ident,
-                        dtype=dtype,
-                    ),
-                    self._sharding,
-                )
-                for name, ident, dtype in self._iter_fields()
-            }
-            self._pending_reset.clear()
-        elif self._pending_reset:
-            idxs = jnp.asarray(
-                np.asarray(self._pending_reset, dtype=np.int32)
-            )
-            for name, ident, _dtype in self._iter_fields():
-                self._fields[name] = self._fields[name].at[idxs].set(ident)
-            self._pending_reset.clear()
-
-    def keys(self) -> List[str]:
-        return list(self.key_to_kid)
-
-    def flush(self) -> None:
-        """Block until every dispatched exchange step has
-        materialized on the mesh (see ``xla.DeviceAggState.flush``)."""
-        if self._fields is not None:
-            import jax
-
-            jax.block_until_ready(self._fields)
-
-    def demotion_snapshots(self) -> List[Tuple[str, Any]]:
-        """Full-state drain for device→host demotion (subclasses
-        supply ``snapshots_for``); see
-        ``xla.DeviceAggState.demotion_snapshots``."""
-        return self.snapshots_for(self.keys())
-
-    # -- residency (engine/residency.py) ------------------------------------
-
-    def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
-        """Snapshot AND release the given keys — the residency
-        manager's eviction surface (see
-        ``xla.DeviceAggState.extract_keys``).  Freed per-shard slots
-        reset lazily via the pending-reset list on reuse; the vocab
-        drop runs as ONE vectorized pass for the whole victim batch."""
-        snaps = self.snapshots_for(keys)
-        kids = [
-            k for k in (self._release(key) for key in keys)
-            if k is not None
-        ]
-        if kids:
-            self._drop_vocab_ids(kids)
-        return [(k, s) for k, s in snaps if s is not None]
-
-    def inject_keys(self, items: List[Tuple[str, Any]]) -> None:
-        """Reinstall previously-extracted keys (host-format
-        snapshots, one scatter per field) — the residency-fault
-        restore path (subclasses supply ``load_many``)."""
-        self.load_many(items)
-
-
-class ShardedAggState(_ShardedSlots):
-    """Slot-table aggregation state sharded over a device mesh.
-
-    Duck-types the ``DeviceAggState`` surface the engine driver uses
-    (``update`` / ``update_batch`` / ``load`` / ``snapshots_for`` /
-    ``finalize`` / ``keys``).
-
-    Key placement: a key's owner shard is ``adler32(key) % n_shards``
-    (the same family of stable hash the host tier routes with); its
-    slot within the owner is assigned densely per shard.  The wire id
-    is ``key_id = slot * n_shards + shard`` so the compiled step
-    recovers both with one mod/div.  Each shard's last slot is
-    scratch for padding rows, and key ids are stable across capacity
-    growth (only the scratch index moves).
+class ShardedAggState(_ShardedSlots, _AggTable):
+    """Slot-table aggregation state sharded over a device mesh:
+    ``xla._AggTable`` with the placement of ``n_shards`` blocks.  A
+    delivery's rows go to their owning shard in ONE compiled program
+    (``all_to_all`` over ICI, then scatter-combine into the local
+    block: :func:`bytewax_tpu.ops.sharded.make_sharded_step`); a
+    dictionary-encoded batch has its ids looked up on the host first.
     """
 
     def __init__(self, kind: str, mesh, cap_per_shard: int = _MIN_CAP_PER_SHARD):
-        import jax.numpy as jnp
-
         from bytewax_tpu.parallel.mesh import SHARD_AXIS, key_sharding
 
-        self.kind_name = kind
-        self.kind = AGG_KINDS[kind]
+        super().__init__(kind, mesh.shape[SHARD_AXIS], cap_per_shard)
         self.mesh = mesh
-        self.n_shards = mesh.shape[SHARD_AXIS]
-        self.cap_per_shard = cap_per_shard
-        self.dtype = jnp.float32
         # Rows and state blocks use the same leading-axis split.
         self._sharding = key_sharding(mesh)
-        self._init_slots()
         self._steps: Dict[Tuple[int, int, int, Any], Any] = {}
-        # Dictionary-encoded fast path: external id -> wire key id.
-        self._vocab = VocabMap(dtype=np.int32)
-        # Automatic encoder for plain string key columns plus the
-        # kid -> key reverse map it needs for touched-key reporting.
-        self._enc = KeyEncoder()
-        self._kid_key: Dict[int, str] = {}
-        # One-pass itemized promotion (native kv_encode): dense ids
-        # in first-sight order, mapped to wire kids via one gather.
-        self._iddict: Dict[str, int] = {}
-        self._id_keys: List[str] = []
-        self._id_to_kid = np.empty(0, dtype=np.int32)
-
-    # -- key placement hooks (_ShardedSlots) --------------------------------
 
     def _iter_fields(self):
         from bytewax_tpu.ops.segment import identity_for
@@ -520,26 +326,6 @@ class ShardedAggState(_ShardedSlots):
             (name, identity_for(init, self.dtype), self.dtype)
             for name, (init, _op) in self.kind.fields.items()
         ]
-
-    def _on_alloc(self, key: str, kid: int) -> None:
-        self._kid_key[kid] = key
-
-    def _on_discard(self, key: str, kid: int) -> None:
-        self._kid_key.pop(kid, None)
-        self._enc.drop(key)
-        if self._iddict:
-            # Dense ids must stay collision-free (kv_encode assigns
-            # len(dict)): a discard resets the itemized cache (see
-            # DeviceAggState.discard).
-            self._iddict = {}
-            self._id_keys = []
-            self._id_to_kid = np.empty(0, dtype=np.int32)
-
-    def _drop_vocab_ids(self, kids: List[int]) -> None:
-        # The vocab table maps each key's external id to its (now
-        # reusable) wire id; drop them so a post-evict return of the
-        # key re-allocs instead of folding into a reassigned slot.
-        self._vocab.drop_ids(kids)
 
     def _step_for(self, total_rows: int, capacity: int):
         from bytewax_tpu.ops.sharded import make_sharded_step
@@ -557,58 +343,16 @@ class ShardedAggState(_ShardedSlots):
             self._steps[key] = step
         return step
 
-    # -- dtype policy (mirrors DeviceAggState._pick_dtype) -------------------
+    # -- placement -----------------------------------------------------------
 
-    def _pick_dtype(self, values: np.ndarray) -> np.ndarray:
-        import jax.numpy as jnp
-
-        if np.issubdtype(values.dtype, np.integer):
-            if values.dtype.itemsize > 4:
-                if len(values) and (
-                    values.max() > np.iinfo(np.int32).max
-                    or values.min() < np.iinfo(np.int32).min
-                ):
-                    msg = (
-                        "device-accelerated reduction over integers "
-                        "wider than 32 bits is not exact; pass a plain "
-                        "Python reducer"
-                    )
-                    raise NonNumericValues(msg)
-                values = values.astype(np.int32)
-            if self._fields is None:
-                self.dtype = jnp.int32
-        elif self.dtype == jnp.int32 and len(values):
-            # Mirrors the value_scale guard: a float batch after the
-            # accumulator locked to int32 would otherwise be silently
-            # truncated by the host-side cast into the int32 carrier.
-            # Integral in-range floats (e.g. the count path's ones
-            # after resuming an int snapshot) cast losslessly and
-            # pass through.
-            if (
-                np.any(values % 1)
-                or values.max() > np.iinfo(np.int32).max
-                or values.min() < np.iinfo(np.int32).min
-            ):
-                msg = (
-                    "non-integral float values arrived after earlier "
-                    "batches locked this step's device state to an "
-                    "integer dtype; pass a plain Python reducer for "
-                    "mixed int/float streams"
-                )
-                raise TypeError(msg)
-        return values
-
-    # -- updates -------------------------------------------------------------
-
-    def _dispatch(self, kids: np.ndarray, values: np.ndarray) -> None:
+    def _scatter(self, kids: np.ndarray, values: np.ndarray) -> None:
         """Run one compiled exchange + fold over the mesh."""
         import jax
 
         n = len(kids)
         if n == 0:
             return
-        with _flight.span("prep", rows=n):
-            self._ensure_fields()
+        with _flight.span("prep"):
             rows_per_shard = _pow2(
                 -(-n // self.n_shards),
                 int(math.log2(_MIN_ROWS_PER_SHARD)),
@@ -642,250 +386,13 @@ class ShardedAggState(_ShardedSlots):
         with _flight.span("dispatch"):
             self._fields = step(self._fields, kids_d, vals_d, valid_d)
 
-    def update_ids(self, kids: np.ndarray, values: np.ndarray) -> None:
-        """Fold rows into pre-allocated wire ids (the id-based fold
-        surface shared with ``DeviceAggState``: ids are whatever
-        :meth:`alloc` returned)."""
-        values = self._pick_dtype(np.asarray(values))
-        self._dispatch(np.asarray(kids, dtype=np.int32), values)
-
-    def update_items(self, items) -> "List[str]":
-        """One-pass itemized fast path over native ``kv_encode``; see
-        ``DeviceAggState.update_items`` (same contract: returns
-        touched keys, None without the native module, raises
-        NonNumericValues with no state mutated)."""
-        from bytewax_tpu.engine.xla import NonNumericValues as _NNV
-        from bytewax_tpu.native import kv_encode as _kv_encode
-
-        n = len(items)
-        ids = np.empty(n, dtype=np.int32)
-        vals = np.empty(n, dtype=np.float64)
-        ivals = np.empty(n, dtype=np.int64)
-        try:
-            res = _kv_encode(items, self._iddict, ids, vals, ivals)
-        except TypeError as ex:
-            raise _NNV(str(ex)) from ex
-        if res is None:
-            return None
-        new_keys, all_int = res
-        if all_int:
-            # Exact int64 lane from the C pass (no float round-trip).
-            vals = ivals
-        try:
-            vals = self._pick_dtype(vals)
-        except (_NNV, TypeError):
-            for k in new_keys:
-                self._iddict.pop(k, None)
-            raise
-        if new_keys:
-            self._id_keys.extend(new_keys)
-            self._id_to_kid = np.concatenate(
-                [
-                    self._id_to_kid,
-                    np.fromiter(
-                        (self.alloc(k) for k in new_keys),
-                        dtype=np.int32,
-                        count=len(new_keys),
-                    ),
-                ]
-            )
-        self._dispatch(self._id_to_kid[ids], vals)
-        counts = np.bincount(ids, minlength=len(self._id_keys))
-        return [
-            self._id_keys[i] for i in np.nonzero(counts)[0].tolist()
-        ]
-
-    def update(self, keys: np.ndarray, values: np.ndarray) -> List[str]:
-        """Fold ``(key, value)`` rows in; returns the unique keys
-        touched (for epoch snapshot bookkeeping)."""
-        keys = np.asarray(keys)
-        values = np.asarray(values)
-        if values.dtype == object or values.dtype.kind in "US":
-            msg = (
-                "device-accelerated reduction requires numeric values; "
-                "pass a plain Python reducer for non-numeric data"
-            )
-            raise NonNumericValues(msg)
-        values = self._pick_dtype(values)
-        kids = self._enc.encode(
-            keys, lambda ks: [self.alloc(k) for k in ks]
-        )
-        self._dispatch(kids.astype(np.int32, copy=False), values)
-        return [self._kid_key[k] for k in np.unique(kids).tolist()]
-
-    def _sync_vocab(self, ids: np.ndarray, vocab: np.ndarray) -> np.ndarray:
-        """Assign wire ids for newly-seen external vocabulary ids;
-        returns the touched unique external ids (see
-        :class:`VocabMap`)."""
-        return self._vocab.sync(
-            ids, vocab, lambda keys: [self.alloc(k) for k in keys]
-        )
-
-    def update_batch(self, batch: ArrayBatch) -> List[str]:
-        if "key_id" in batch.cols and batch.key_vocab is not None:
-            ids = batch.numpy("key_id")
-            values = batch.numpy("value")
-            if batch.value_scale is not None:
-                import jax.numpy as jnp
-
-                if self.dtype != jnp.float32:
-                    msg = (
-                        "fixed-point (value_scale) batches need a float "
-                        "accumulator, but earlier batches locked this "
-                        "step's state to an integer dtype"
-                    )
-                    raise TypeError(msg)
-                values = (values * batch.value_scale).astype(np.float32)
-            else:
-                values = self._pick_dtype(values)
-            uniq = self._sync_vocab(ids.astype(np.int64), batch.key_vocab)
-            self._dispatch(self._vocab.table[ids], values)
-            return [str(self._vocab.vocab[e]) for e in uniq.tolist()]
-        if "key" in batch.cols:
-            values = batch.numpy("value")
-            if batch.value_scale is not None:
-                values = (values * batch.value_scale).astype(np.float32)
-            return self.update(batch.numpy("key"), values)
-        msg = (
-            "columnar batch feeding an accelerated keyed aggregation "
-            "needs a 'key' or dictionary-encoded 'key_id' column"
-        )
-        raise TypeError(msg)
-
-    # -- recovery ------------------------------------------------------------
-
-    def _maybe_lock_int(self, state: Any) -> None:
-        import jax.numpy as jnp
-
-        if (
-            self.kind_name in ("sum", "min", "max", "count")
-            and isinstance(state, int)
-            and self._fields is None
-        ):
-            self.dtype = jnp.int32
-
-    def load(self, key: str, state: Any) -> None:
-        """Install a resumed snapshot for a key (host-tier format,
-        identical to ``DeviceAggState.load``)."""
-        import jax.numpy as jnp
-
-        self._maybe_lock_int(state)
-        field_vals = _field_vals(self.kind_name, state)
-        kid = self.alloc(key)
-        self._ensure_fields()
-        idx = self._global_idx(kid)
-        for name, val in field_vals.items():
-            self._fields[name] = (
-                self._fields[name].at[idx].set(jnp.asarray(val, self.dtype))
-            )
-
-    def load_many(self, items) -> None:
-        """Batched resume: ONE scatter per field per page (mirrors
-        ``DeviceAggState.load_many``)."""
-        if not items:
-            return
-        self._maybe_lock_int(items[0][1])
-        kids = np.fromiter(
-            (self.alloc(key) for key, _state in items),
-            dtype=np.int32,
-            count=len(items),
-        )
-        self.load_ids(kids, [state for _key, state in items])
-
-    def load_ids(self, ids: np.ndarray, states) -> None:
-        """Install host-format snapshots into wire ids already given
-        out (:meth:`alloc`, :meth:`open_ids`).  Rows are resolved
-        here, after every alloc, so capacity growth mid-page can't
-        skew the global indices."""
-        import jax
-
-        from bytewax_tpu.engine.batching import pad_len
-
-        n = len(states)
-        if not n:
-            return
-        self._maybe_lock_int(states[0])
-        padded = pad_len(n, floor_pow=3)
-        cols = _state_columns(self.kind, self.dtype, states, padded)
-        self._ensure_fields()
-        idxs = np.empty(padded, dtype=np.int64)
-        idxs[:n] = self._global_idx(ids.astype(np.int64))
-        idxs[n:] = idxs[0]
-        for name, col in cols.items():
-            self._fields[name] = (
-                self._fields[name].at[idxs].set(jax.device_put(col))
-            )
-
-    def _fetch(self) -> Dict[str, np.ndarray]:
-        import jax.numpy as jnp
-
-        names = list(self.kind.fields)
-        with _flight.span("fetch") as sp:
-            stacked = np.asarray(
-                jnp.stack([self._fields[name] for name in names])
-            )
-            sp.rows = stacked.shape[1]
-            _flight.note_transfer("d2h", stacked.nbytes)
-        return {name: stacked[i] for i, name in enumerate(names)}
-
-    def snapshots_for(self, keys: List[str]) -> List[Tuple[str, Any]]:
-        """Host-format snapshots of specific keys (one device_get)."""
-        if self._fields is None or not keys:
-            return [(k, None) for k in keys]
-        host = self._fetch()
-        with _flight.span("close_emit"):
-            kids = [self.key_to_kid.get(key) for key in keys]
-            return _snaps_for(
-                self.kind_name,
-                host,
-                [None if k is None else self._global_idx(k) for k in kids],
-                keys,
-            )
-
-    def states_of(self, kids: np.ndarray) -> List[Any]:
-        """Host-format snapshots of the given wire ids, in order (one
-        device_get)."""
-        self._ensure_fields()
-        host = self._fetch()
-        with _flight.span("close_emit"):
-            return _snaps_of(
-                self.kind_name,
-                host,
-                self._global_idx(kids.astype(np.int64)),
-            )
-
-    # -- finalization --------------------------------------------------------
-
-    def finalize(self) -> List[Tuple[str, Any]]:
-        """Emit ``(key, final_value)`` for every live key, sorted by
-        key (matching the host tier's EOF ordering), and clear."""
-        if not self.key_to_kid:
-            return []
-        self._ensure_fields()
-        host = self._fetch()
-        with _flight.span("close_emit", rows=len(self.key_to_kid)):
-            out = [
-                (
-                    key,
-                    _final_of(
-                        self.kind_name,
-                        host,
-                        self._global_idx(self.key_to_kid[key]),
-                    ),
-                )
-                for key in sorted(self.key_to_kid)
-            ]
-        self.key_to_kid.clear()
-        self._shard_fill = [0] * self.n_shards
-        self._free = [[] for _ in range(self.n_shards)]
-        self._fields = None
-        self._vocab = VocabMap(dtype=np.int32)
-        self._enc.clear()
-        self._kid_key.clear()
-        self._iddict = {}
-        self._id_keys = []
-        self._id_to_kid = np.empty(0, dtype=np.int32)
-        return out
+    def _fold_encoded(self, ids, values, scale) -> None:
+        with _flight.span("prep"):
+            if scale is not None:
+                values = (values * scale).astype(np.float32)
+            self._ensure_fields()
+            kids = self._vocab.table[ids]
+        self._scatter(kids, values)
 
 
 class ShardedScanState(_ShardedSlots, ScanUpdates):
@@ -912,10 +419,8 @@ class ShardedScanState(_ShardedSlots, ScanUpdates):
 
         self.kind = scan_kind
         self.mesh = mesh
-        self.n_shards = mesh.shape[SHARD_AXIS]
-        self.cap_per_shard = cap_per_shard
         self._sharding = key_sharding(mesh)
-        self._init_slots()
+        self._init_slots(mesh.shape[SHARD_AXIS], cap_per_shard)
         self._steps: Dict[Tuple[int, int, int], Any] = {}
 
     def _iter_fields(self):
@@ -1024,7 +529,7 @@ class ShardedScanState(_ShardedSlots, ScanUpdates):
         host = {name: np.asarray(self._fields[name]) for name in names}
         out = []
         for key in keys:
-            kid = self.key_to_kid.get(key)
+            kid = self.key_to_slot.get(key)
             if kid is None:
                 out.append((key, None))
             else:
@@ -1112,7 +617,7 @@ class GlobalAggState:
         self.mesh = make_mesh(devices=devices)
         self._sharding = key_sharding(self.mesh)
         #: Full global key→kid map, identical on every process.
-        self.key_to_kid: Dict[str, int] = {}
+        self.key_to_slot: Dict[str, int] = {}
         self._shard_fill = [0] * self.n_shards
         #: Buffered local rows awaiting the next collective flush,
         #: dictionary-encoded: per-row DENSE local ids into
@@ -1315,18 +820,18 @@ class GlobalAggState:
         raise TypeError(msg)
 
     def keys(self) -> List[str]:
-        known = set(self.key_to_kid)
+        known = set(self.key_to_slot)
         known.update(self._dense_keys)
         return sorted(known)
 
     def discard(self, key: str) -> None:  # pragma: no cover - EOF clears
-        self.key_to_kid.pop(key, None)
+        self.key_to_slot.pop(key, None)
 
     # -- the collective flush -------------------------------------------------
 
     def _assign_kids(self, new_keys: List[str]) -> None:
         for k in new_keys:
-            if k in self.key_to_kid:
+            if k in self.key_to_slot:
                 continue
             shard = self._owner_shard(k)
             slot = self._shard_fill[shard]
@@ -1339,7 +844,7 @@ class GlobalAggState:
                 )
                 raise RuntimeError(msg)
             self._shard_fill[shard] = slot + 1
-            self.key_to_kid[k] = slot * self.n_shards + shard
+            self.key_to_slot[k] = slot * self.n_shards + shard
 
     def _ensure_fields(self) -> None:
         import jax
@@ -1470,7 +975,7 @@ class GlobalAggState:
         the metadata round (engine/wire.py) instead of raw rows
         riding the device all_to_all; the merge is sealed on the
         main thread (scatter targets resolved against the main-owned
-        ``key_to_kid``) and folds on device
+        ``key_to_slot``) and folds on device
         (dequant+merge+scatter in HBM, engine/xla.py) — or
         host-side under the ``BYTEWAX_TPU_WIRE=pickle`` fallback."""
         import jax
@@ -1480,7 +985,7 @@ class GlobalAggState:
         self._maybe_replay_resume()
         n_local = int(sum(len(a) for a in self._buf_vals))
         local_new = sorted(
-            k for k in self._dense_keys if k not in self.key_to_kid
+            k for k in self._dense_keys if k not in self.key_to_slot
         )
         quant = self._quant
         frames = (
@@ -1517,7 +1022,7 @@ class GlobalAggState:
             # Quantized exchange: the partial frames already rode the
             # round; seal the (deterministically ordered) merge ON
             # MAIN — frame decode and scatter-target resolution
-            # against the main-owned ``key_to_kid`` — and launch the
+            # against the main-owned ``key_to_slot`` — and launch the
             # fold (device or host per the sealed decision).
             self._buf_ids.clear()
             self._buf_vals.clear()
@@ -1595,7 +1100,7 @@ class GlobalAggState:
         self._buf_ids.clear()
         self._buf_vals.clear()
         # Kid resolution per DISTINCT key, then one gather per row.
-        kid_map = self.key_to_kid
+        kid_map = self.key_to_slot
         kid_of_dense = np.fromiter(
             (kid_map[k] for k in self._dense_keys),
             dtype=np.int32,
@@ -1765,7 +1270,7 @@ class GlobalAggState:
         """Seal one quantized round's merge ON MAIN: decode every
         peer frame's raw parts (engine/wire.py ``decode_agg_parts``)
         and resolve scatter targets against the main-owned
-        ``key_to_kid`` — the sealed task never reads main state
+        ``key_to_slot`` — the sealed task never reads main state
         (BTX-RACE).  Decides device-vs-host per the sticky
         ``_merge_demoted`` flag: an exact integer part that cannot
         ride the device's int32 tables demotes the merge to the host
@@ -1790,7 +1295,7 @@ class GlobalAggState:
                 )
         if not self._merge_demoted and self._needs_host_fold(decoded):
             self._demote_merge()
-        kid_map = self.key_to_kid
+        kid_map = self.key_to_slot
         if self._merge_demoted:
             sealed = []
             for keys, fields in decoded:
@@ -2021,7 +1526,9 @@ class GlobalAggState:
         and replays only the rounds stashed after it."""
         base: Dict[str, Any] = {
             "round": self._data_rounds,
-            "key_to_kid": dict(self.key_to_kid),
+            # The stored name predates the attribute's: baseline rows
+            # outlive the program that wrote them.
+            "key_to_kid": dict(self.key_to_slot),
             "shard_fill": list(self._shard_fill),
             "procs": self.driver.proc_count,
         }
@@ -2067,7 +1574,7 @@ class GlobalAggState:
                 "BYTEWAX_TPU_GLOBAL_EXCHANGE=0"
             )
             raise RuntimeError(msg)
-        self.key_to_kid = dict(base["key_to_kid"])
+        self.key_to_slot = dict(base["key_to_kid"])
         self._shard_fill = list(base["shard_fill"])
         self._data_rounds = base["round"]
         self._base_written = True
@@ -2269,12 +1776,12 @@ class GlobalAggState:
                 # leaves HBM only here (and at baselines/demotion).
                 self._host_fields = self._fetch_dev_fields()
                 self._dev_fields = None
-            if self._host_fields is not None and self.key_to_kid:
+            if self._host_fields is not None and self.key_to_slot:
                 my_shards = set(
                     self._proc_shards[self.driver.proc_id]
                 )
-                for key in sorted(self.key_to_kid):
-                    kid = self.key_to_kid[key]
+                for key in sorted(self.key_to_slot):
+                    kid = self.key_to_slot[key]
                     if kid % self.n_shards not in my_shards:
                         continue  # another process's shard emits it
                     out.append(
@@ -2289,14 +1796,14 @@ class GlobalAggState:
                             ),
                         )
                     )
-        elif self._fields is not None and self.key_to_kid:
+        elif self._fields is not None and self.key_to_slot:
             blocks = self._local_host_fields()
             first_field = next(iter(self.kind.fields))
             #: block start -> membership test happens once per key.
             starts = sorted(blocks[first_field])
 
-            for key in sorted(self.key_to_kid):
-                gidx = self._global_idx(self.key_to_kid[key])
+            for key in sorted(self.key_to_slot):
+                gidx = self._global_idx(self.key_to_slot[key])
                 start = next(
                     (
                         s
@@ -2336,7 +1843,7 @@ class GlobalAggState:
             if self._base_written:
                 self._pending_snap_rows.append((self._base_key(), None))
                 self._base_written = False
-        self.key_to_kid.clear()
+        self.key_to_slot.clear()
         self._shard_fill = [0] * self.n_shards
         self._fields = None
         self._host_fields = None

@@ -63,8 +63,8 @@ BTX-SEND and pinned in ``tests/test_comm_invariants.py``).
 
 ``BYTEWAX_TPU_WIRE=pickle`` restores the legacy wire wholesale —
 whole-frame pickle for every payload AND one frame per routed slice
-(the driver arms no accumulator) — which is both the mixed-version
-rollout mode and the comparison baseline bench.py measures.
+(the driver arms no accumulator) — which is the mixed-version
+rollout mode.
 """
 
 import os
@@ -148,7 +148,7 @@ def wire_mode() -> str:
     """The armed wire: ``"columnar"`` (default) or ``"pickle"``
     (``BYTEWAX_TPU_WIRE=pickle`` — the legacy wire: whole-frame
     pickle, no route accumulation).  Cached; re-read after
-    :func:`reconfigure` (tests/bench)."""
+    :func:`reconfigure` (tests)."""
     global _mode_cache
     if _mode_cache is None:
         raw = os.environ.get("BYTEWAX_TPU_WIRE", "columnar") or "columnar"
@@ -176,7 +176,7 @@ def gsync_quant() -> str:
 
 
 def reconfigure() -> None:
-    """Drop the cached env knobs (tests/bench tweak them
+    """Drop the cached env knobs (tests tweak them
     mid-process)."""
     global _mode_cache, _quant_cache
     _mode_cache = None

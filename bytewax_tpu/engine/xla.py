@@ -2,13 +2,23 @@
 
 Replaces per-key Python logic objects with slot-table device arrays
 for the recognized reduction kinds (see
-:mod:`bytewax_tpu.ops.segment`).  The host keeps the key→slot
-vocabulary; values fold in on device; snapshots `jax.device_get` only
-the slots awoken in the closing epoch, preserving the recovery
-contract of the host tier (states are interchangeable between tiers).
+:mod:`bytewax_tpu.ops.segment`).  The host keeps the key→id
+vocabulary; values fold in on device; snapshots stay in the host
+tier's per-key format, preserving its recovery contract (states are
+interchangeable between tiers and mesh sizes).
+
+The table is written once (docs/xla-tier.md "The slot table"):
+:class:`_SlotLayout` says which row an id lives in, for one block or
+a mesh of them, and :class:`_AggTable` is the ingest-and-recovery
+surface over it.  What differs by device count is the *placement*:
+how the arrays are made, reset and grown, and how a delivery's rows
+are folded into them.  :class:`DeviceAggState` is the placement of
+one device; ``sharded_state.ShardedAggState`` is a mesh's;
+``sharded_state.make_agg_state`` picks from the device count.
 """
 
 import functools
+import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -24,7 +34,6 @@ from bytewax_tpu.ops.segment import (
     identity_for,
     init_fields,
     reset_fields,
-    update_fields,
     update_fields_packed,
     update_fields_vocab,
 )
@@ -100,16 +109,6 @@ def _snaps_of(kind: str, fields: Dict[str, np.ndarray], idx: np.ndarray):
     raise AssertionError(kind)
 
 
-def _snaps_for(kind: str, fields, idx_of_key: List[Optional[int]], keys):
-    """``(key, snapshot)`` per key, ``None`` where a key has no row."""
-    have = [i for i in idx_of_key if i is not None]
-    snaps = iter(_snaps_of(kind, fields, np.asarray(have, dtype=np.int64)))
-    return [
-        (key, None if i is None else next(snaps))
-        for key, i in zip(keys, idx_of_key)
-    ]
-
-
 def _field_vals(kind: str, state: Any) -> Dict[str, float]:
     """Decompose a host-format snapshot into per-field scalars."""
     if kind in ("sum", "min", "max", "count"):
@@ -140,214 +139,271 @@ def _state_columns(kind: AggKind, dtype, states, padded: int):
     return cols
 
 
-def _take_free(free: List[int], n: int) -> List[int]:
-    """Up to ``n`` slots off the end of a free list, in the order as
-    many ``pop()``s would give them."""
-    keep = max(len(free) - n, 0)
-    taken = free[keep:][::-1]
-    del free[keep:]
-    return taken
+class _SlotLayout:
+    """Which row of a slot table a key, or an id a caller asked for,
+    lives in: the host's half of the table, written once for one
+    device and for a mesh.
 
+    A table is ``n_shards`` blocks of ``cap_per_shard`` rows, block
+    *d* on device *d* (one block on one device).  An id is
+    ``slot * n_shards + shard`` — on one device the slot itself — so a
+    compiled step recovers both with one mod/div and ids stay put
+    when blocks grow.  The last row of a block is scratch for padding
+    rows and one more stays spare: a block holds ``cap_per_shard - 2``
+    slots and doubles past that, so XLA sees O(log n) shapes.  A
+    key's shard is ``adler32(key) % n_shards`` (the family of stable
+    hash the host tier routes with).  Freed ids are given out again
+    newest first and reset then, not when freed (``_pending_reset``).
 
-class DeviceAggState:
-    """Slot-table aggregation state for one stateful step.
-
-    The last slot of the table is scratch for masked (padding) rows;
-    keys occupy slots ``0..capacity-2``.  Tables double when full so
-    XLA recompiles only O(log n) shapes.
+    The arrays are the subclass's, the *placement*: ``_make_fields()``
+    makes them at the current size, ``_reset_rows(ids)`` puts the
+    identity back into reused rows, ``_resize(new_cap)`` grows the
+    blocks (if made yet) and sets ``cap_per_shard``.
     """
 
-    def __init__(self, kind: str, sharding: Optional[Any] = None):
+    def _init_slots(self, n_shards: int, cap_per_shard: int) -> None:
+        self.n_shards = n_shards
+        self.cap_per_shard = cap_per_shard
+        self.key_to_slot: Dict[str, int] = {}
+        self._id_key: Dict[int, str] = {}
+        #: per-shard count of slots ever given out
+        self._fill = [0] * n_shards
+        #: per-shard freed ids
+        self._free: List[List[int]] = [[] for _ in range(n_shards)]
+        self._pending_reset: List[int] = []
+        self._fields = None  # lazy until first update/load
+
+    @property
+    def capacity(self) -> int:
+        """Rows of the whole table, scratch rows included."""
+        return self.n_shards * self.cap_per_shard
+
+    def _owner(self, key: str) -> int:
+        return zlib.adler32(key.encode()) % self.n_shards
+
+    def _owners(self, place: np.ndarray) -> np.ndarray:
+        """Owner shard of each integer composite: a multiplicative
+        hash, so neighbouring window ids spread over the shards.
+        Ownership is recomputed at every load and never persisted."""
+        mixed = place.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+        return ((mixed >> np.uint64(33)) % np.uint64(self.n_shards)).astype(
+            np.int64
+        )
+
+    def _reserve(self, fill: int) -> None:
+        """Grow until a block holds ``fill`` slots."""
+        new_cap = self.cap_per_shard
+        while new_cap < fill + 2:
+            new_cap *= 2
+        if new_cap != self.cap_per_shard:
+            self._resize(new_cap)
+
+    def alloc(self, key: str) -> int:
+        """Assign (or return) the id for a key, reusing freed ones."""
+        id_ = self.key_to_slot.get(key)
+        if id_ is not None:
+            return id_
+        shard = self._owner(key)
+        if self._free[shard]:
+            id_ = self._free[shard].pop()
+            self._pending_reset.append(id_)
+        else:
+            slot = self._fill[shard]
+            self._reserve(slot + 1)
+            self._fill[shard] = slot + 1
+            id_ = slot * self.n_shards + shard
+        self.key_to_slot[key] = id_
+        self._id_key[id_] = key
+        return id_
+
+    def _release(self, key: str) -> Optional[int]:
+        """Free a key's id WITHOUT :meth:`_forget_ids` (extract_keys
+        batches that into one pass); returns the freed id."""
+        id_ = self.key_to_slot.pop(key, None)
+        if id_ is not None:
+            del self._id_key[id_]
+            self._free[id_ % self.n_shards].append(id_)
+        return id_
+
+    def _forget_ids(self, ids) -> None:
+        """Hook: a table that maps outside ids to these un-maps them
+        (one vectorized pass per batch of released ids)."""
+
+    def discard(self, key: str) -> None:
+        """Release a key's id for reuse (its state is reset when the
+        id is given out again)."""
+        id_ = self._release(key)
+        if id_ is not None:
+            self._forget_ids([id_])
+
+    # The id-based surface for a caller that keeps its own table of
+    # what an id holds (the window tier: integer (key, window)
+    # composites): a whole delivery's ids are given out, read and
+    # taken back in one call each, and carry no key here.
+
+    def open_ids(self, place: np.ndarray) -> np.ndarray:
+        """One id per composite in ``place``, on the shard that owns
+        it.  One device owns everything and its callers pass
+        ``np.empty(n)`` for ``place``: only the length is read there,
+        which is why this one method looks at the device count."""
+        if self.n_shards == 1:
+            return self._open_on(0, len(place))
+        shards = self._owners(place)
+        ids = np.empty(len(place), dtype=np.int32)
+        for shard in range(self.n_shards):
+            rows = np.nonzero(shards == shard)[0]
+            if len(rows):
+                ids[rows] = self._open_on(shard, len(rows))
+        return ids
+
+    def _open_on(self, shard: int, n: int) -> np.ndarray:
+        """``n`` ids on one shard, in the order that many
+        :meth:`alloc`s would take them: freed ones from the end of
+        the free list, then fresh ones with one growth."""
+        free = self._free[shard]
+        keep = max(len(free) - n, 0)
+        reused = free[keep:][::-1]
+        del free[keep:]
+        self._pending_reset.extend(reused)
+        start = self._fill[shard]
+        end = start + n - len(reused)
+        if end > start:
+            self._reserve(end)
+            self._fill[shard] = end
+        ids = np.empty(n, dtype=np.int32)
+        ids[: len(reused)] = reused
+        ids[len(reused) :] = np.arange(start, end) * self.n_shards + shard
+        return ids
+
+    def release_ids(self, ids: np.ndarray) -> None:
+        """Take back ids :meth:`open_ids` gave out (reset when they
+        are given out again), forgotten in one pass."""
+        shards = ids % self.n_shards
+        for shard, free in enumerate(self._free):
+            free.extend(ids[shards == shard].tolist())
+        self._forget_ids(ids)
+
+    def _global_idx(self, ids):
+        """Row of an id (or an array of them) in the flat table."""
+        shard, slot = ids % self.n_shards, ids // self.n_shards
+        return shard * self.cap_per_shard + slot
+
+    def _ensure_fields(self) -> None:
+        """The table exists and every reused row holds the identity."""
+        if self._fields is None:
+            self._fields = self._make_fields()
+        elif self._pending_reset:
+            self._reset_rows(self._pending_reset)
+        self._pending_reset.clear()
+
+    def keys(self) -> List[str]:
+        return list(self.key_to_slot)
+
+    def flush(self) -> None:
+        """Block until every dispatched fold has materialized on
+        device.  ``update*`` only enqueue under JAX async dispatch;
+        the engine's pipeline (``engine/pipeline.py``) defers all host
+        readbacks to drain points, and this is the state-level wait
+        those drain points (snapshot, demotion, EOF) rest on."""
+        if self._fields is not None:
+            jax.block_until_ready(self._fields)
+
+    def demotion_snapshots(self) -> List[Tuple[str, Any]]:
+        """Every live key's host-format snapshot — the full-state
+        drain the driver uses to demote this step to the host tier
+        after repeated device faults (host logics rebuild from these
+        exactly as a recovery resume would)."""
+        return self.snapshots_for(self.keys())
+
+    # -- residency (engine/residency.py) ------------------------------------
+
+    def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
+        """Snapshot AND release the given keys (one device_get for the
+        batch): the residency manager's eviction surface.  Released
+        ids reset lazily on reuse; keys with no folded state release
+        with no snapshot.  The ids are forgotten in ONE vectorized
+        pass over the whole victim batch (a per-key drop is an
+        O(vocab) scan each).  Callers own the drain-point scheduling
+        — no fold referencing these ids may be in flight."""
+        snaps = self.snapshots_for(keys)
+        ids = [
+            i for i in (self._release(key) for key in keys)
+            if i is not None
+        ]
+        if ids:
+            self._forget_ids(ids)
+        return [(k, s) for k, s in snaps if s is not None]
+
+    def inject_keys(self, items: List[Tuple[str, Any]]) -> None:
+        """Reinstall previously-extracted keys (host-format snapshots,
+        one scatter per field) — the residency-fault restore path."""
+        self.load_many(items)
+
+
+class _AggTable(_SlotLayout):
+    """The keyed aggregation slot table: the ingest-and-recovery
+    surface the driver, the window tier and the residency manager
+    use, written once over :class:`_SlotLayout`.
+
+    Where the rows of a delivery are put is the subclass's, the
+    *placement*, beside the layout's three: ``_scatter(ids, values)``
+    folds rows into ids already given out, and
+    ``_fold_encoded(ext_ids, values, scale)`` folds a
+    dictionary-encoded batch whose vocabulary :meth:`_sync_vocab` has
+    just mapped (``scale`` is set when ``values`` are fixed-point,
+    still to be multiplied by it).  Both find the table made and the reused rows reset
+    only after they call :meth:`_ensure_fields` or a caller has.
+    """
+
+    def __init__(self, kind: str, n_shards: int, cap_per_shard: int):
         self.kind_name = kind
         self.kind = AGG_KINDS[kind]
-        self.sharding = sharding
-        self.capacity = _MIN_CAPACITY
-        self.key_to_slot: Dict[str, int] = {}
-        self.slot_keys: List[Optional[str]] = []
-        self._free: List[int] = []
-        self._pending_reset: List[int] = []
         self.dtype = jnp.float32
-        self._fields = None  # lazy until first update/load
-        # Dictionary-encoded fast path: external id -> slot table,
-        # mirrored on device so raw (id, value) columns are all the
-        # host ships per batch.
+        self._init_slots(n_shards, cap_per_shard)
+        self._reset_ingest()
+
+    def _reset_ingest(self) -> None:
+        # Dictionary-encoded fast path: external id -> id here.
         self._vocab = VocabMap(dtype=np.int32)
-        self._dev_map = None
         # Automatic encoder for plain string key columns: steady
         # state is one searchsorted per batch, no per-row hashing.
         self._enc = KeyEncoder()
+        self._drop_item_ids()
+
+    def _drop_item_ids(self) -> None:
         # One-pass itemized promotion (native kv_encode): dense ids
-        # assigned in first-sight order, mapped to slots via one
+        # assigned in first-sight order, mapped to ids here via one
         # gather per batch.
         self._iddict: Dict[str, int] = {}
         self._id_keys: List[str] = []
         self._id_to_slot = np.empty(0, dtype=np.int32)
 
-    # -- slot management ---------------------------------------------------
+    def _vocab_moved(self) -> None:
+        """Hook: an entry of ``self._vocab.table`` changed (a
+        placement that mirrors the table drops its copy)."""
 
-    def _ensure_fields(self) -> None:
-        if self._fields is None:
-            self._fields = init_fields(self.kind, self.capacity, self.dtype)
-            if self.sharding is not None:
-                self._fields = {
-                    k: jax.device_put(v, self.sharding)
-                    for k, v in self._fields.items()
-                }
-            self._pending_reset.clear()
-        else:
-            self._apply_resets()
-
-    def _grow_to(self, needed: int) -> None:
-        new_cap = self.capacity
-        while new_cap - 1 < needed:
-            new_cap *= 2
-        if new_cap == self.capacity:
-            return
-        # The scratch slot moves to the new last index; any device
-        # id→slot table pointing at the old scratch is stale.
-        self._dev_map = None
-        if self._fields is None:
-            # Nothing folded yet: the table is made at its final size
-            # (and dtype) by the first update or load.
-            self.capacity = new_cap
-            return
-        self._apply_resets()
-        grown = {}
-        for name, (init, _op) in self.kind.fields.items():
-            old = self._fields[name]
-            # Identities in the accumulator dtype (see
-            # segment.update_fields): a float identity does not cast
-            # safely into an integer table.
-            ident = identity_for(init, old.dtype)
-            # The old scratch slot becomes a real slot: clear it.
-            old = old.at[self.capacity - 1].set(ident)
-            pad = jnp.full((new_cap - self.capacity,), ident, dtype=old.dtype)
-            arr = jnp.concatenate([old, pad])
-            if self.sharding is not None:
-                arr = jax.device_put(arr, self.sharding)
-            grown[name] = arr
-        self._fields = grown
-        self.capacity = new_cap
-
-    def alloc(self, key: str) -> int:
-        """Assign (or return) the slot for a key, reusing freed slots."""
-        slot = self.key_to_slot.get(key)
-        if slot is not None:
-            return slot
-        if self._free:
-            slot = self._free.pop()
-            self._pending_reset.append(slot)
-            self.slot_keys[slot] = key
-        else:
-            self._grow_to(len(self.slot_keys) + 2)
-            slot = len(self.slot_keys)
-            self.slot_keys.append(key)
-        self.key_to_slot[key] = slot
-        return slot
-
-    def discard(self, key: str) -> None:
-        """Release a key's slot for reuse (its state is reset when the
-        slot is reallocated)."""
-        slot = self._release(key)
-        if slot is not None and self._vocab.drop_ids([slot]):
-            # The on-device id→slot table still routes the dropped
-            # external id to this (now reusable) slot; rebuild it
-            # on the next vocab sync.
-            self._dev_map = None
+    def _forget_ids(self, ids) -> None:
+        # The vocab table still routes the dropped external ids to
+        # these (now reusable) ids: un-map them, so a key that
+        # returns re-allocs instead of folding into another's row.
+        if self._vocab.drop_ids(ids):
+            self._vocab_moved()
 
     def _release(self, key: str) -> Optional[int]:
-        """Free a key's slot WITHOUT the vocab drop (extract_keys
-        batches that into one pass); returns the freed slot."""
-        slot = self.key_to_slot.pop(key, None)
-        if slot is not None:
-            self.slot_keys[slot] = None  # type: ignore[call-overload]
-            self._free.append(slot)
+        id_ = super()._release(key)
+        if id_ is not None:
             self._enc.drop(key)
             if self._iddict:
                 # Dense ids must stay collision-free (kv_encode
                 # assigns len(dict)), so a discard invalidates the
                 # itemized cache wholesale; keys re-intern to their
-                # existing slots on the next batch.  Callers that
+                # existing ids on the next batch.  Callers that
                 # discard per-close (window accel) never use this
                 # cache, so the reset is effectively free.
-                self._iddict = {}
-                self._id_keys = []
-                self._id_to_slot = np.empty(0, dtype=np.int32)
-        return slot
+                self._drop_item_ids()
+        return id_
 
-    # The id-based slot surface for a caller that keeps its own table
-    # of what a slot holds (the window tier: integer (key, window)
-    # composites): a whole delivery's slots are given out, read and
-    # taken back in one call each, and carry no key here.  Shared
-    # with ShardedAggState, where the ids are wire ids.
-
-    def open_ids(self, place: np.ndarray) -> np.ndarray:
-        """One slot per entry of ``place`` (only its length matters
-        on one device), in the order that many :meth:`alloc`s would
-        take them: freed slots from the end of the free list, then
-        fresh ones with one growth."""
-        n = len(place)
-        reused = _take_free(self._free, n)
-        self._pending_reset.extend(reused)
-        fresh = n - len(reused)
-        start = len(self.slot_keys)
-        if fresh:
-            self._grow_to(start + fresh + 1)
-            self.slot_keys.extend([None] * fresh)  # type: ignore[list-item]
-        slots = np.empty(n, dtype=np.int32)
-        slots[: len(reused)] = reused
-        slots[len(reused) :] = np.arange(start, start + fresh)
-        return slots
-
-    def release_ids(self, slots: np.ndarray) -> None:
-        """Take back slots :meth:`open_ids` gave out (reset when they
-        are given out again), with one vocab drop for the batch."""
-        freed = slots.tolist()
-        self._free.extend(freed)
-        if self._vocab.drop_ids(freed):
-            self._dev_map = None
-
-    def states_of(self, slots: np.ndarray) -> List[Any]:
-        """Host-format snapshots of the given slots, in order (one
-        device_get)."""
-        self._ensure_fields()
-        host = self._fetch()
-        # Ledger: fetched columns to host-format states is part of
-        # `close_emit` (its rows are counted by the close that asked).
-        with _flight.span("close_emit"):
-            return _snaps_of(self.kind_name, host, slots)
-
-    def _apply_resets(self) -> None:
-        if self._fields is None:
-            self._pending_reset.clear()
-            return
-        if not self._pending_reset:
-            return
-        # Pad to a bucket (repeating the first slot — set is
-        # idempotent) so XLA sees few distinct shapes.
-        n = len(self._pending_reset)
-        padded = pad_len(n, floor_pow=3)
-        slots_np = np.full(padded, self._pending_reset[0], dtype=np.int32)
-        slots_np[:n] = self._pending_reset
-        self._fields = reset_fields(
-            self.kind, self._fields, jax.device_put(slots_np)
-        )
-        self._pending_reset.clear()
-
-    def update_slots(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
-        """Fold rows into pre-allocated slots (fast path for callers
-        managing their own key→slot mapping via :meth:`alloc`)."""
-        with _flight.span("prep", rows=len(values)):
-            self._pick_dtype(values)
-            self._ensure_fields()
-            slot_ids = slot_ids.astype(np.int32)
-        self._scatter(slot_ids, values)
-
-    # The id-based fold surface shared with ShardedAggState: ids are
-    # whatever :meth:`alloc` returned (slots here, wire kids there).
-    update_ids = update_slots
-
-    # -- updates -----------------------------------------------------------
+    # -- dtype policy ------------------------------------------------------
 
     def _pick_dtype(self, values: np.ndarray) -> np.ndarray:
         """Choose the accumulator dtype; integer inputs that don't fit
@@ -390,10 +446,30 @@ class DeviceAggState:
                 raise TypeError(msg)
         return values
 
+    def _maybe_lock_int(self, state: Any) -> None:
+        if (
+            self.kind_name in ("sum", "min", "max", "count")
+            and isinstance(state, int)
+            and self._fields is None
+        ):
+            self.dtype = jnp.int32
+
+    # -- updates -----------------------------------------------------------
+
+    def update_ids(self, ids: np.ndarray, values: np.ndarray) -> None:
+        """Fold rows into ids already given out (:meth:`alloc`,
+        :meth:`open_ids`): the fast path for callers that keep their
+        own key→id mapping."""
+        with _flight.span("prep", rows=len(values)):
+            values = self._pick_dtype(np.asarray(values))
+            self._ensure_fields()
+            ids = np.asarray(ids, dtype=np.int32)
+        self._scatter(ids, values)
+
     def update_items(self, items: List[Any]):
         """One-pass itemized fast path: native ``kv_encode`` walks
         each ``(key, value)`` tuple exactly once (dict-encode + value
-        fill), then one gather maps dense ids to slots and one
+        fill), then one gather maps dense ids to ids here and one
         scatter folds the batch.  Returns the touched keys, or None
         when the native module is unavailable (caller falls back).
         Raises :class:`NonNumericValues` for rows the device tier
@@ -440,8 +516,8 @@ class DeviceAggState:
                     ]
                 )
             self._ensure_fields()
-            slots = self._id_to_slot[ids]
-        self._scatter(slots, vals)
+            row_ids = self._id_to_slot[ids]
+        self._scatter(row_ids, vals)
         counts = np.bincount(ids, minlength=len(self._id_keys))
         return [
             self._id_keys[i] for i in np.nonzero(counts)[0].tolist()
@@ -460,16 +536,238 @@ class DeviceAggState:
             raise NonNumericValues(msg)
         with _flight.span("prep", rows=len(values)):
             values = self._pick_dtype(values)
-        row_slots = self._enc.encode(
+        row_ids = self._enc.encode(
             keys, lambda ks: [self.alloc(k) for k in ks]
         )
         with _flight.span("prep"):
             self._ensure_fields()
-            slots = row_slots.astype(np.int32, copy=False)
-        self._scatter(slots, values)
-        return [
-            self.slot_keys[s] for s in np.unique(row_slots).tolist()
-        ]
+            ids = row_ids.astype(np.int32, copy=False)
+        self._scatter(ids, values)
+        return [self._id_key[i] for i in np.unique(row_ids).tolist()]
+
+    def _sync_vocab(self, ids: np.ndarray, vocab: np.ndarray) -> np.ndarray:
+        """Assign ids for newly-seen external vocabulary ids (alloc
+        reuses a recovery-resumed id if one exists); returns the
+        touched unique external ids (see :class:`VocabMap`)."""
+
+        def alloc_many(keys):
+            self._vocab_moved()
+            return [self.alloc(key) for key in keys]
+
+        return self._vocab.sync(ids, vocab, alloc_many)
+
+    def update_batch(self, batch: ArrayBatch) -> List[str]:
+        if "key_id" in batch.cols and batch.key_vocab is not None:
+            # Ledger: `prep` ends before the vocab sync (`encode`) and
+            # begins again after it, so no work span holds another.
+            with _flight.span("prep") as sp:
+                ids = batch.numpy("key_id")
+                values = batch.numpy("value")
+                sp.rows = len(values)
+                scale = batch.value_scale
+                if scale is None:
+                    values = self._pick_dtype(values)
+                elif self.dtype != jnp.float32:
+                    msg = (
+                        "fixed-point (value_scale) batches need a float "
+                        "accumulator, but earlier batches locked this "
+                        "step's state to an integer dtype"
+                    )
+                    raise TypeError(msg)
+            uniq = self._sync_vocab(ids, batch.key_vocab)
+            self._fold_encoded(ids, values, scale)
+            return [str(self._vocab.vocab[e]) for e in uniq.tolist()]
+        if "key" in batch.cols:
+            values = batch.numpy("value")
+            if batch.value_scale is not None:
+                values = (values * batch.value_scale).astype(np.float32)
+            return self.update(batch.numpy("key"), values)
+        msg = (
+            "columnar batch feeding an accelerated keyed aggregation "
+            "needs a 'key' or dictionary-encoded 'key_id' column"
+        )
+        raise TypeError(msg)
+
+    # -- recovery ----------------------------------------------------------
+
+    def load(self, key: str, state: Any) -> None:
+        """Install a resumed snapshot for a key (host-tier format).
+        The id comes from :meth:`alloc`, so freed (evicted/discarded)
+        ones are reused instead of growing the table."""
+        self._maybe_lock_int(state)
+        field_vals = _field_vals(self.kind_name, state)
+        id_ = self.alloc(key)
+        self._ensure_fields()
+        row = self._global_idx(id_)
+        for name, val in field_vals.items():
+            self._fields[name] = (
+                self._fields[name].at[row].set(jnp.asarray(val, self.dtype))
+            )
+
+    def load_many(self, items: List[Tuple[str, Any]]) -> None:
+        """Batched resume: ONE scatter per field for a whole page of
+        host-format snapshots.  A per-key :meth:`load` is a device
+        dispatch per key — resuming 10^6 keys that way is 10^6 jax
+        ops; this is O(fields) ops per page."""
+        if not items:
+            return
+        self._maybe_lock_int(items[0][1])
+        ids = np.fromiter(
+            (self.alloc(key) for key, _state in items),
+            dtype=np.int32,
+            count=len(items),
+        )
+        self.load_ids(ids, [state for _key, state in items])
+
+    def load_ids(self, ids: np.ndarray, states: List[Any]) -> None:
+        """Install host-format snapshots into ids already given out
+        (:meth:`alloc`, :meth:`open_ids`): one scatter per field.
+        Rows are resolved here, after every alloc, so a growth
+        mid-page can't skew them."""
+        n = len(states)
+        if not n:
+            return
+        self._maybe_lock_int(states[0])
+        padded = pad_len(n, floor_pow=3)
+        cols = _state_columns(self.kind, self.dtype, states, padded)
+        # Pending resets apply here, BEFORE the scatter installs the
+        # resumed values.
+        self._ensure_fields()
+        rows = np.empty(padded, dtype=np.int32)
+        rows[:n] = self._global_idx(ids.astype(np.int64))
+        rows[n:] = rows[0]
+        with _flight.span("h2d", rows=padded):
+            _flight.note_transfer(
+                "h2d",
+                rows.nbytes + sum(c.nbytes for c in cols.values()),
+            )
+            dev_rows = jax.device_put(rows)
+            for name, col in cols.items():
+                self._fields[name] = (
+                    self._fields[name].at[dev_rows].set(jax.device_put(col))
+                )
+
+    def _fetch(self) -> Dict[str, np.ndarray]:
+        """One stacked device→host transfer for all fields (one
+        round-trip instead of one per field)."""
+        names = list(self.kind.fields)
+        with _flight.span("fetch", rows=self.capacity):
+            stacked = np.asarray(
+                jnp.stack([self._fields[name] for name in names])
+            )
+            _flight.note_transfer("d2h", stacked.nbytes)
+        return {name: stacked[i] for i, name in enumerate(names)}
+
+    def snapshots_for(self, keys: List[str]) -> List[Tuple[str, Any]]:
+        """Host-format snapshots of specific keys (one device_get),
+        ``None`` where a key has no row."""
+        if self._fields is None or not keys:
+            return [(k, None) for k in keys]
+        host = self._fetch()
+        # Ledger: turning the fetched rows into host-format states
+        # is part of `close_emit` (its rows are counted by the close
+        # that asked).
+        with _flight.span("close_emit"):
+            ids = [self.key_to_slot.get(key) for key in keys]
+            have = np.array(
+                [i for i in ids if i is not None], dtype=np.int64
+            )
+            snaps = iter(
+                _snaps_of(self.kind_name, host, self._global_idx(have))
+            )
+            return [
+                (key, None if i is None else next(snaps))
+                for key, i in zip(keys, ids)
+            ]
+
+    def states_of(self, ids: np.ndarray) -> List[Any]:
+        """Host-format snapshots of the given ids, in order (one
+        device_get)."""
+        self._ensure_fields()
+        host = self._fetch()
+        with _flight.span("close_emit"):
+            return _snaps_of(
+                self.kind_name, host, self._global_idx(ids.astype(np.int64))
+            )
+
+    # -- finalization ------------------------------------------------------
+
+    def finalize(self) -> List[Tuple[str, Any]]:
+        """Emit ``(key, final_value)`` for every live key, sorted by
+        key (matching the host tier's EOF ordering), and clear."""
+        if not any(self._fill):
+            return []
+        self._ensure_fields()
+        host = self._fetch()
+        with _flight.span("close_emit", rows=len(self.key_to_slot)):
+            ids = self.key_to_slot
+            out = [
+                (key, _final_of(self.kind_name, host, self._global_idx(ids[key])))
+                for key in sorted(ids)
+            ]
+        self._init_slots(self.n_shards, self.cap_per_shard)
+        self._reset_ingest()
+        self._vocab_moved()
+        return out
+
+
+class DeviceAggState(_AggTable):
+    """Slot-table aggregation state for one stateful step on one
+    device: :class:`_AggTable` with the placement of a single block.
+    Rows are folded by the jitted scatter programs of
+    :mod:`bytewax_tpu.ops.segment`; a dictionary-encoded batch ships
+    raw ``(id, value)`` columns (one packed int16 array when the
+    values are fixed-point) and the device looks the slots up in its
+    own copy of the id→slot table.
+    """
+
+    def __init__(self, kind: str):
+        super().__init__(kind, 1, _MIN_CAPACITY)
+        self._dev_map = None
+
+    # -- the table ---------------------------------------------------------
+
+    def _make_fields(self):
+        return init_fields(self.kind, self.capacity, self.dtype)
+
+    def _reset_rows(self, slots: List[int]) -> None:
+        # Pad to a bucket (repeating the first slot — set is
+        # idempotent) so XLA sees few distinct shapes.
+        n = len(slots)
+        padded = pad_len(n, floor_pow=3)
+        slots_np = np.full(padded, slots[0], dtype=np.int32)
+        slots_np[:n] = slots
+        self._fields = reset_fields(
+            self.kind, self._fields, jax.device_put(slots_np)
+        )
+
+    def _resize(self, new_cap: int) -> None:
+        # The scratch slot moves to the new last index; any device
+        # id→slot table pointing at the old scratch is stale.
+        self._dev_map = None
+        if self._fields is not None:
+            # Reused slots are reset at the size they were freed at
+            # (each size and padded length is a program of its own).
+            self._ensure_fields()
+            grown = {}
+            for name, (init, _op) in self.kind.fields.items():
+                old = self._fields[name]
+                # Identities in the accumulator dtype (see
+                # segment.update_fields): a float identity does not
+                # cast safely into an integer table.
+                ident = identity_for(init, old.dtype)
+                # The old scratch slot becomes a real slot: clear it.
+                old = old.at[self.capacity - 1].set(ident)
+                pad = jnp.full(
+                    (new_cap - self.capacity,), ident, dtype=old.dtype
+                )
+                grown[name] = jnp.concatenate([old, pad])
+            self._fields = grown
+        # Else nothing is folded yet: the table is made at its final
+        # size (and dtype) by the first update or load.
+        self.cap_per_shard = new_cap
+
+    # -- placement ---------------------------------------------------------
 
     def _scatter(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
         from bytewax_tpu.ops.pallas_fold import maybe_update_fields
@@ -492,30 +790,11 @@ class DeviceAggState:
                 self.kind, self._fields, slots_d, vals_d
             )
 
-    def _fetch(self) -> Dict[str, np.ndarray]:
-        """One stacked device→host transfer for all fields (one
-        round-trip instead of one per field)."""
-        names = list(self.kind.fields)
-        with _flight.span("fetch", rows=self.capacity):
-            stacked = np.asarray(
-                jnp.stack([self._fields[name] for name in names])
-            )
-            _flight.note_transfer("d2h", stacked.nbytes)
-        return {name: stacked[i] for i, name in enumerate(names)}
+    def _vocab_moved(self) -> None:
+        self._dev_map = None
 
-    def _sync_vocab(self, ids: np.ndarray, vocab: np.ndarray) -> np.ndarray:
-        """Assign slots for newly-seen external ids (alloc reuses a
-        recovery-resumed slot if one exists) and refresh the on-device
-        id→slot table; returns the touched unique ids."""
-        had_new = []
-
-        def alloc_many(keys):
-            had_new.extend(keys)
-            # alloc reuses a recovery-resumed slot if one exists.
-            return [self.alloc(key) for key in keys]
-
-        uniq = self._vocab.sync(ids, vocab, alloc_many)
-        if had_new or self._dev_map is None:
+    def _fold_encoded(self, ids, values, scale) -> None:
+        if self._dev_map is None:
             # Rebuild the device table: unseen ids and the padding
             # sentinel (index len(vocab)) route to the scratch slot.
             with _flight.span("h2d") as sp:
@@ -526,249 +805,47 @@ class DeviceAggState:
                 sp.rows = len(table)
                 _flight.note_transfer("h2d", table.nbytes)
                 self._dev_map = jax.device_put(table)
-        return uniq
-
-    def update_batch(self, batch: ArrayBatch) -> List[str]:
-        if "key_id" in batch.cols and batch.key_vocab is not None:
-            # Ledger: `prep` ends before the vocab sync (`encode`) and
-            # begins again after it, so no work span holds another.
-            with _flight.span("prep") as sp:
-                ids = batch.numpy("key_id")
-                values = batch.numpy("value")
-                sp.rows = len(values)
-                quantized = (
-                    batch.value_scale is not None
-                    and values.dtype == np.int16
+        with _flight.span("prep"):
+            self._ensure_fields()
+            n = len(values)
+            sentinel = len(self._vocab.table)
+            padded = pad_len(n)
+            if scale is not None and (
+                values.dtype != np.int16 or sentinel >= 2**15
+            ):
+                # The packed carrier is int16, ids and values: what
+                # does not fit it is dequantized host-side into the
+                # (float) accumulator dtype.
+                values = (values * scale).astype(np.float32)
+                scale = None
+        if scale is not None:
+            # Fixed-point fast path: one int16 [2, n] transfer.
+            with _flight.span("h2d", rows=padded):
+                packed = np.full((2, padded), sentinel, dtype=np.int16)
+                packed[0, :n] = ids
+                packed[1, :n] = values
+                packed[1, n:] = 0
+                _flight.note_transfer("h2d", packed.nbytes)
+                packed_d = jax.device_put(packed)
+                scale_d = jnp.float32(scale)
+            with _flight.span("dispatch"):
+                self._fields = update_fields_packed(
+                    self.kind, self._fields, self._dev_map, packed_d, scale_d
                 )
-                if (
-                    batch.value_scale is not None
-                    and self.dtype != jnp.float32
-                ):
-                    msg = (
-                        "fixed-point (value_scale) batches need a float "
-                        "accumulator, but earlier batches locked this "
-                        "step's state to an integer dtype"
-                    )
-                    raise TypeError(msg)
-                if batch.value_scale is not None and not quantized:
-                    # Fixed-point values in a non-int16 carrier:
-                    # dequantize host-side into the (float)
-                    # accumulator dtype.
-                    values = (values * batch.value_scale).astype(
-                        np.float32
-                    )
-                elif not quantized:
-                    values = self._pick_dtype(values)
-            uniq = self._sync_vocab(ids, batch.key_vocab)
-            with _flight.span("prep"):
-                self._ensure_fields()
-                n = len(values)
-                sentinel = len(self._vocab.table)
-                padded = pad_len(n)
-            if quantized and sentinel < 2**15:
-                # Fixed-point fast path: one int16 [2, n] transfer.
-                with _flight.span("h2d", rows=padded):
-                    packed = np.full(
-                        (2, padded), sentinel, dtype=np.int16
-                    )
-                    packed[0, :n] = ids
-                    packed[1, :n] = values
-                    packed[1, n:] = 0
-                    _flight.note_transfer("h2d", packed.nbytes)
-                    packed_d = jax.device_put(packed)
-                    scale = jnp.float32(batch.value_scale)
-                with _flight.span("dispatch"):
-                    self._fields = update_fields_packed(
-                        self.kind,
-                        self._fields,
-                        self._dev_map,
-                        packed_d,
-                        scale,
-                    )
-            else:
-                with _flight.span("h2d", rows=padded):
-                    id_dtype = np.int16 if sentinel < 2**15 else np.int32
-                    ids_p = np.full(padded, sentinel, dtype=id_dtype)
-                    ids_p[:n] = ids
-                    vals_p = np.zeros(padded, dtype=np.dtype(self.dtype))
-                    vals_p[:n] = values
-                    _flight.note_transfer(
-                        "h2d", ids_p.nbytes + vals_p.nbytes
-                    )
-                    ids_d = jax.device_put(ids_p)
-                    vals_d = jax.device_put(vals_p)
-                with _flight.span("dispatch"):
-                    self._fields = update_fields_vocab(
-                        self.kind,
-                        self._fields,
-                        self._dev_map,
-                        ids_d,
-                        vals_d,
-                    )
-            return [str(self._vocab.vocab[e]) for e in uniq.tolist()]
-        if "key" in batch.cols:
-            values = batch.numpy("value")
-            if batch.value_scale is not None:
-                values = (values * batch.value_scale).astype(np.float32)
-            return self.update(batch.numpy("key"), values)
-        msg = (
-            "columnar batch feeding an accelerated keyed aggregation "
-            "needs a 'key' or dictionary-encoded 'key_id' column"
-        )
-        raise TypeError(msg)
-
-    # -- recovery ----------------------------------------------------------
-
-    def _maybe_lock_int(self, state: Any) -> None:
-        if (
-            self.kind_name in ("sum", "min", "max", "count")
-            and isinstance(state, int)
-            and self._fields is None
-        ):
-            self.dtype = jnp.int32
-
-    def load(self, key: str, state: Any) -> None:
-        """Install a resumed snapshot for a key (host-tier format).
-        Slot assignment goes through :meth:`alloc` so freed (evicted/
-        discarded) slots are reused instead of growing the table."""
-        self._maybe_lock_int(state)
-        field_vals = _field_vals(self.kind_name, state)
-        slot = self.alloc(key)
-        self._ensure_fields()
-        for name, val in field_vals.items():
-            self._fields[name] = (
-                self._fields[name].at[slot].set(jnp.asarray(val, self.dtype))
-            )
-
-    def load_many(self, items: List[Tuple[str, Any]]) -> None:
-        """Batched resume: ONE scatter per field for a whole page of
-        host-format snapshots.  A per-key :meth:`load` is a device
-        dispatch per key — resuming 10^6 keys that way is 10^6 jax
-        ops; this is O(fields) ops per page."""
-        if not items:
             return
-        self._maybe_lock_int(items[0][1])
-        # alloc reuses freed (evicted/discarded) slots and grows on
-        # demand.
-        slots = np.fromiter(
-            (self.alloc(key) for key, _state in items),
-            dtype=np.int32,
-            count=len(items),
-        )
-        self.load_ids(slots, [state for _key, state in items])
-
-    def load_ids(self, ids: np.ndarray, states: List[Any]) -> None:
-        """Install host-format snapshots into slots already given out
-        (:meth:`alloc`, :meth:`open_ids`): one scatter per field."""
-        n = len(states)
-        if not n:
-            return
-        self._maybe_lock_int(states[0])
-        padded = pad_len(n, floor_pow=3)
-        cols = _state_columns(self.kind, self.dtype, states, padded)
-        slots = np.empty(padded, dtype=np.int32)
-        slots[:n] = ids
-        slots[n:] = slots[0]
-        # Pending resets apply here, BEFORE the scatter installs the
-        # resumed values.
-        self._ensure_fields()
         with _flight.span("h2d", rows=padded):
-            _flight.note_transfer(
-                "h2d",
-                slots.nbytes + sum(c.nbytes for c in cols.values()),
+            id_dtype = np.int16 if sentinel < 2**15 else np.int32
+            ids_p = np.full(padded, sentinel, dtype=id_dtype)
+            ids_p[:n] = ids
+            vals_p = np.zeros(padded, dtype=np.dtype(self.dtype))
+            vals_p[:n] = values
+            _flight.note_transfer("h2d", ids_p.nbytes + vals_p.nbytes)
+            ids_d = jax.device_put(ids_p)
+            vals_d = jax.device_put(vals_p)
+        with _flight.span("dispatch"):
+            self._fields = update_fields_vocab(
+                self.kind, self._fields, self._dev_map, ids_d, vals_d
             )
-            dev_slots = jax.device_put(slots)
-            for name, col in cols.items():
-                self._fields[name] = (
-                    self._fields[name].at[dev_slots].set(jax.device_put(col))
-                )
-
-    def snapshots_for(self, keys: List[str]) -> List[Tuple[str, Any]]:
-        """Host-format snapshots of specific keys (one device_get)."""
-        if self._fields is None or not keys:
-            return [(k, None) for k in keys]
-        host = self._fetch()
-        # Ledger: turning the fetched slots into host-format states
-        # is part of `close_emit` (its rows are counted by the close
-        # that asked).
-        with _flight.span("close_emit"):
-            return _snaps_for(
-                self.kind_name,
-                host,
-                [self.key_to_slot.get(key) for key in keys],
-                keys,
-            )
-
-    # -- finalization ------------------------------------------------------
-
-    def finalize(self) -> List[Tuple[str, Any]]:
-        """Emit ``(key, final_value)`` for every live key, sorted by
-        key (matching the host tier's EOF ordering), and clear."""
-        if not self.slot_keys:
-            return []
-        self._ensure_fields()
-        host = self._fetch()
-        with _flight.span("close_emit", rows=len(self.key_to_slot)):
-            out = [
-                (
-                    key,
-                    _final_of(self.kind_name, host, self.key_to_slot[key]),
-                )
-                for key in sorted(self.key_to_slot)
-            ]
-        self.key_to_slot.clear()
-        self.slot_keys.clear()
-        self._fields = None
-        self._vocab = VocabMap(dtype=np.int32)
-        self._dev_map = None
-        self._enc.clear()
-        self._iddict = {}
-        self._id_keys = []
-        self._id_to_slot = np.empty(0, dtype=np.int32)
-        return out
-
-    def keys(self) -> List[str]:
-        return [k for k in self.slot_keys if k is not None]
-
-    def flush(self) -> None:
-        """Block until every dispatched fold has materialized on
-        device.  ``update*`` only enqueue under JAX async dispatch;
-        the engine's pipeline (``engine/pipeline.py``) defers all host
-        readbacks to drain points, and this is the state-level wait
-        those drain points (snapshot, demotion, EOF) rest on."""
-        if self._fields is not None:
-            jax.block_until_ready(self._fields)
-
-    def demotion_snapshots(self) -> List[Tuple[str, Any]]:
-        """Every live key's host-format snapshot — the full-state
-        drain the driver uses to demote this step to the host tier
-        after repeated device faults (host logics rebuild from these
-        exactly as a recovery resume would)."""
-        return self.snapshots_for(self.keys())
-
-    # -- residency (engine/residency.py) ------------------------------------
-
-    def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
-        """Snapshot AND release the given keys (one device_get for the
-        batch): the residency manager's eviction surface.  Released
-        slots reset lazily on reuse; keys with no folded state release
-        with no snapshot.  The vocab drop runs as ONE vectorized pass
-        over the whole victim batch (a per-key drop is an O(vocab)
-        scan each).  Callers own the drain-point scheduling — no fold
-        referencing these slots may be in flight."""
-        snaps = self.snapshots_for(keys)
-        slots = [
-            s for s in (self._release(key) for key in keys)
-            if s is not None
-        ]
-        if slots and self._vocab.drop_ids(slots):
-            self._dev_map = None
-        return [(k, s) for k, s in snaps if s is not None]
-
-    def inject_keys(self, items: List[Tuple[str, Any]]) -> None:
-        """Reinstall previously-extracted keys (host-format snapshots,
-        one scatter per field) — the residency-fault restore path."""
-        self.load_many(items)
 
 
 # -- global-exchange device merge (docs/performance.md "Overlapped

@@ -50,7 +50,7 @@ def anomaly_flow(
     is_anomaly))`` per item with per-key online mean/variance state.
 
     ``fmt`` optionally maps each scored item before the sink (the
-    human-facing example uses it for pretty printing) — benches and
+    human-facing example uses it for pretty printing) — `chip_smoke.py` and
     ``examples/anomaly_detector.py`` both run THIS flow, so the two
     can't drift.
     """

@@ -321,7 +321,7 @@ class ClusterSupervisor:
             "BYTEWAX_TPU_AUTOSCALE_LIVE", "1"
         ) not in ("", "0")
         #: Diagnostics of the most recent completed live move
-        #: (tests/bench): action, sizes, surviving pids, and a
+        #: (tests): action, sizes, surviving pids, and a
         #: surviving child's epoch sampled before/after — epochs
         #: advancing across the move proves the non-moving workers
         #: kept closing epochs while it happened.

@@ -5,7 +5,7 @@ tests run on a virtual 8-device CPU mesh."""
 
 # Force a deterministic virtual 8-device CPU mesh for all tests BEFORE
 # jax initializes a backend (overriding any inherited platform
-# setting); chip runs go through chip_smoke.py / bench.py / run.py.
+# setting); chip runs go through chip_smoke.py / benchmark.run / run.py.
 import os
 
 # The driver arms jax's persistent compile cache on every run.  Tier-1

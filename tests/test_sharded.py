@@ -192,54 +192,6 @@ def test_sharded_state_skewed_hot_key():
     assert sum(out.values()) == 9100
 
 
-def test_sharded_state_dict_encoded_batches():
-    from bytewax_tpu.engine.sharded_state import ShardedAggState
-
-    mesh = _mesh()
-    st = ShardedAggState("stats", mesh)
-    vocab = np.array([f"station{i}" for i in range(50)])
-    rng = np.random.RandomState(3)
-    rows = []
-    for _ in range(4):
-        ids = rng.randint(0, 50, size=500).astype(np.int32)
-        temps = rng.randint(-400, 400, size=500).astype(np.int16)
-        rows.append((ids, temps))
-        st.update_batch(
-            ArrayBatch(
-                {"key_id": ids, "value": temps},
-                key_vocab=vocab,
-                value_scale=0.1,
-            )
-        )
-    out = dict(st.finalize())
-    groups = collections.defaultdict(list)
-    for ids, temps in rows:
-        for i, t in zip(ids.tolist(), temps.tolist()):
-            groups[f"station{i}"].append(t * 0.1)
-    assert set(out) == set(groups)
-    for k, g in groups.items():
-        mn, mean, mx, cnt = out[k]
-        assert cnt == len(g)
-        np.testing.assert_allclose(mn, min(g), atol=1e-4)
-        np.testing.assert_allclose(mx, max(g), atol=1e-4)
-        np.testing.assert_allclose(mean, sum(g) / len(g), atol=1e-3)
-
-
-def test_sharded_state_growth_keeps_state():
-    # Keys folded before a capacity growth must keep their state after.
-    from bytewax_tpu.engine.sharded_state import ShardedAggState
-
-    mesh = _mesh()
-    st = ShardedAggState("sum", mesh, cap_per_shard=8)
-    st.update(np.array(["early"]), np.array([5.0]))
-    many = np.array([f"key{i:05d}" for i in range(1000)])
-    st.update(many, np.ones(1000))
-    st.update(np.array(["early"]), np.array([7.0]))
-    out = dict(st.finalize())
-    assert out["early"] == 12.0
-    assert len(out) == 1001
-
-
 # -- engine integration -----------------------------------------------------
 
 
@@ -689,3 +641,56 @@ def test_window_state_on_four_devices(monkeypatch, case):
         twa.test_snapshot_resumes_to_same_results(
             monkeypatch, "4", twa.SLIDING_10S_BY_4S
         )
+
+
+# -- GlobalAggState's durable baseline row ----------------------------------
+
+
+def test_global_baseline_row_keeps_its_stored_format(monkeypatch):
+    """A baseline row outlives the program that wrote it
+    (docs/recovery.md "Store-composable overlap"): the payload below
+    is spelled as the program at e492823 stored it, with the key map
+    under ``"key_to_kid"``.  It installs, and the row this program
+    writes from it is the same row."""
+    import types
+
+    from bytewax_tpu.engine.sharded_state import GlobalAggState
+
+    _mesh(8)
+    monkeypatch.delenv("BYTEWAX_TPU_GSYNC_OVERLAP", raising=False)
+    monkeypatch.delenv("BYTEWAX_TPU_GSYNC_QUANT", raising=False)
+    driver = types.SimpleNamespace(proc_count=1, worker_count=1, store=None)
+    st = GlobalAggState("sum", driver)
+    cap, n = st.cap_per_shard, st.n_shards
+    # kid = slot * n + shard: "a" and "c" on shard 0, "b" on shard 1.
+    kids = {"a": 0, "b": 1, "c": n}
+    blocks = {
+        "sum": {d * cap: np.zeros(cap, np.float32) for d in range(n)}
+    }
+    blocks["sum"][0][0] = 1.5
+    blocks["sum"][cap][0] = 2.5
+    blocks["sum"][0][1] = 4.0
+    stored = {
+        "round": 3,
+        "key_to_kid": dict(kids),
+        "shard_fill": [2, 1] + [0] * (n - 2),
+        "procs": 1,
+        "fmt": "exact",
+        "blocks": blocks,
+        "dtype": "float32",
+    }
+    st._install_baseline(stored)
+    assert st.key_to_slot == kids
+    assert st._shard_fill == stored["shard_fill"]
+    assert st._data_rounds == 3
+
+    again = st._capture_baseline()
+    assert set(again) == set(stored)
+    assert "key_to_slot" not in again
+    assert again["key_to_kid"] == kids
+    for key in ("round", "shard_fill", "procs", "fmt", "dtype"):
+        assert again[key] == stored[key], key
+    assert set(again["blocks"]) == {"sum"}
+    assert set(again["blocks"]["sum"]) == set(blocks["sum"])
+    for start, block in blocks["sum"].items():
+        np.testing.assert_array_equal(again["blocks"]["sum"][start], block)

@@ -479,7 +479,7 @@ def test_agg_codec_all_int_columns_exact_under_int8():
 
 
 def test_agg_codec_int8_shrinks_floats():
-    """The bytes win the bench reports: int8 frames for float-heavy
+    """The bytes win of the quantized exchange: int8 frames for float-heavy
     partial columns are well under half the exact framing."""
     cols = _partial_cols(n=8192)
     exact = sum(len(f) for f in wire.encode_agg(cols, "off"))

@@ -8,7 +8,6 @@ import bytewax_tpu.operators as op
 from bytewax_tpu import xla
 from bytewax_tpu.dataflow import Dataflow
 from bytewax_tpu.engine.arrays import ArrayBatch
-from bytewax_tpu.engine.xla import DeviceAggState
 from bytewax_tpu.inputs import DynamicSource, StatelessSourcePartition
 from bytewax_tpu.testing import TestingSink, TestingSource, run_main
 
@@ -169,19 +168,6 @@ def test_accelerated_recovery_cross_tier(tmp_path, monkeypatch):
     assert out == [("a", 40)]
 
 
-def test_device_agg_state_growth():
-    agg = DeviceAggState("sum")
-    n = 5000  # > initial capacity, forces growth
-    keys = np.array([f"k{i:05d}" for i in range(n)])
-    values = np.ones(n, dtype=np.float32)
-    agg.update(keys, values)
-    agg.update(keys, values)
-    results = dict(agg.finalize())
-    assert len(results) == n
-    assert results["k00000"] == 2.0
-    assert results[f"k{n - 1:05d}"] == 2.0
-
-
 def test_keyed_all_to_all_mesh():
     import jax
     import jax.numpy as jnp
@@ -249,38 +235,6 @@ def test_int64_overflow_falls_back_to_host():
     op.output("out", r, TestingSink(out))
     run_main(flow)
     assert out == [("k", 2 * big)]  # exact, via host fallback
-
-
-def test_value_scale_string_key_path():
-    ab = ArrayBatch(
-        {"key": np.array(["a", "a"]), "value": np.array([15, 23], np.int16)},
-        value_scale=0.1,
-    )
-    agg = DeviceAggState("sum")
-    agg.update_batch(ab)
-    results = dict(agg.finalize())
-    assert abs(results["a"] - 3.8) < 1e-5
-    # to_pylist honors the scale too
-    assert ab.to_pylist()[0] == ("a", 1.5)
-
-
-def test_vocab_must_be_append_only():
-    agg = DeviceAggState("sum")
-    v1 = np.array(["london", "paris"])
-    v2 = np.array(["paris", "london"])  # reordered — invalid
-    agg.update_batch(
-        ArrayBatch(
-            {"key_id": np.array([0], np.int16), "value": np.array([1.0])},
-            key_vocab=v1,
-        )
-    )
-    with pytest.raises(TypeError, match="append-only"):
-        agg.update_batch(
-            ArrayBatch(
-                {"key_id": np.array([0], np.int16), "value": np.array([1.0])},
-                key_vocab=v2,
-            )
-        )
 
 
 def test_redistributed_columnar_batch_reaches_accel(monkeypatch):
