@@ -466,6 +466,10 @@ class DeviceWindowAggState:
         # held an on-time row of the key.
         self._seq = 0
         self._seen = np.empty(0, dtype=np.int64)
+        # Scratch of the in-order clock pass, one entry a key id: a
+        # delivery writes its keys' entries and reads only those, so
+        # nothing initialises it.
+        self._last_row = np.empty(0, dtype=np.int64)
         # Open windows by integer composite, with the slot self.agg
         # gave each (the session tier keeps a table of its own).
         self.open = _OpenWindows()
@@ -531,6 +535,7 @@ class DeviceWindowAggState:
                 self._seen = np.concatenate(
                     [self._seen, np.zeros(grow, dtype=np.int64)]
                 )
+                self._last_row = np.empty(len(self._seen), dtype=np.int64)
             self.base_us[fresh] = -np.inf
             self.sys_at_base[fresh] = (
                 datetime.now(timezone.utc).timestamp() * _US
@@ -602,6 +607,7 @@ class DeviceWindowAggState:
         vals = batch.numpy("value")
         if batch.value_scale is not None:
             vals = (vals * batch.value_scale).astype(np.float32)
+            return self._ingest(kids, ts_us, vals, fold_vals=vals)
         return self._ingest(kids, ts_us, vals)
 
     @property
@@ -731,9 +737,13 @@ class DeviceWindowAggState:
         """Host phase of one delivery; returns ``(late_events,
         device_phase)``.
 
-        ``values`` is indexed per late row (original objects where
-        available); ``fold_vals`` optionally supplies the numeric fold
-        column when ``values`` is a lazy view rather than an array.
+        ``kids`` and ``ts_us`` are columns the entry point built for
+        this call.  ``values`` is indexed per late row (original
+        objects where available); ``fold_vals`` is the numeric fold
+        column where the entry point built one of its own (a lazy
+        ``values`` view, a ``value_scale`` product): it may go to the
+        deferred fold uncopied, a caller's own column may not (a
+        source may reuse its buffer once this call has returned).
         ``device_phase()`` — the fold, the due-window scan (against
         the clock as of THIS ingest), and window-event construction —
         returns ``(close_events, notify_hint, gone)`` and may run
@@ -746,93 +756,66 @@ class DeviceWindowAggState:
         now_us = datetime.now(timezone.utc).timestamp() * _US
         self._seq += 1
         seq = self._seq
-        self.touched.update(
-            self.keys[int(k)] for k in np.unique(kids)
-        )
+        n = len(ts_us)
 
         # Per-row watermark exactly as the host tier computes it per
         # item (post-item): the running per-key prefix max of
         # (ts - wait), floored by the carried base advanced with
-        # system time.  Group rows by key with one stable sort, then
-        # run one accumulate per contiguous segment — O(n log n), not
-        # O(keys × rows).
-        with _flight.span("watermark", rows=len(ts_us)):
+        # system time.  The delivery itself says which pass computes
+        # it: where no timestamp falls below the one before it, a
+        # key's running maximum at a row is the row's own value.
+        with _flight.span("watermark", rows=n):
             eff = ts_us - spec.wait_us
-            n = len(ts_us)
-            order = np.argsort(kids, kind="stable")
-            kids_sorted = kids[order]
-            eff_sorted = eff[order]
-            seg_kids, seg_starts = np.unique(kids_sorted, return_index=True)
-            seg_counts = np.diff(np.append(seg_starts, n))
-            n_seg = len(seg_kids)
-            carry = self.base_us[seg_kids] + (
-                now_us - self.sys_at_base[seg_kids]
-            )
-
-            # Segmented prefix max with no per-key Python: shift each
-            # key's rows into its own disjoint value band (band width >
-            # the value span), run ONE global cummax — later bands
-            # dominate earlier ones, so the running max never leaks
-            # across segments — and shift back.  Exact only in integer
-            # arithmetic below 2^53, which the hot columnar path
-            # (datetime64[us] timestamps) always is; fractional
-            # microseconds or astronomically-spread batches take the
-            # per-segment loop so watermark equality stays bit-exact.
-            lo_val = float(eff_sorted.min()) if n else 0.0
-            band = float(eff_sorted.max()) - lo_val + 1.0 if n else 1.0
-            integral = n == 0 or (
-                band == np.floor(band)
-                and not np.any(eff_sorted % 1.0)
-            )
-            if integral and n_seg * band < float(1 << 53):
-                seg_of_row = np.repeat(
-                    np.arange(n_seg, dtype=np.int64), seg_counts
+            if n < 2 or bool((ts_us[1:] >= ts_us[:-1]).all()):
+                _flight.RECORDER.count("window_clock_inorder")
+                seg_kids, seg_max, wm_rows = self._clock_inorder(
+                    kids, eff, now_us
                 )
-                off = seg_of_row * band
-                prefix = (
-                    np.maximum.accumulate((eff_sorted - lo_val) + off) - off
-                ) + lo_val
-                wm_sorted = np.maximum(prefix, carry[seg_of_row])
-                seg_max = np.maximum.reduceat(eff_sorted, seg_starts)
             else:
-                seg_ends = np.append(seg_starts[1:], n)
-                wm_sorted = np.empty(n, dtype=np.float64)
-                seg_max = np.empty(n_seg, dtype=np.float64)
-                for j, (lo, hi) in enumerate(
-                    zip(seg_starts.tolist(), seg_ends.tolist())
-                ):
-                    prefix = np.maximum.accumulate(eff_sorted[lo:hi])
-                    np.maximum(prefix, carry[j], out=wm_sorted[lo:hi])
-                    seg_max[j] = prefix[-1]
+                _flight.RECORDER.count("window_clock_sorted")
+                seg_kids, seg_max, wm_rows = self._clock_sorted(
+                    kids, eff, now_us
+                )
             advanced = seg_max > self.base_us[seg_kids]
             if advanced.any():
                 moved = seg_kids[advanced]
                 self.base_us[moved] = seg_max[advanced]
                 self.sys_at_base[moved] = now_us
-            wm_rows = np.empty(n, dtype=np.float64)
-            wm_rows[order] = wm_sorted
             late_mask = ts_us < wm_rows
+        any_late = bool(late_mask.any())
+        self.touched.update(map(self.keys.__getitem__, seg_kids.tolist()))
 
         events: List[Tuple[str, Tuple[int, str, Any]]] = []
-        if late_mask.any():
+        kids_ok = ts_ok = vals_ok = None
+        if not any_late:
+            # Nothing to drop: the engine's own columns go on as they
+            # are, and every key of the delivery has an on-time row.
+            if n:
+                kids_ok, ts_ok = kids, ts_us
+                self._seen[seg_kids] = seq
+                if spec.kind == "count":
+                    vals_ok = np.ones(n, dtype=np.float64)
+                elif fold_vals is not None:
+                    vals_ok = fold_vals
+                else:
+                    vals_ok = np.array(values)  # keep dtype for exact ints
+        else:
             events.extend(
                 self._late_events(
                     np.nonzero(late_mask)[0], kids, ts_us, values
                 )
             )
-
-        ok = ~late_mask
-        kids_ok = ts_ok = vals_ok = None
-        if ok.any():
-            kids_ok = kids[ok]
-            self._seen[kids_ok] = seq
-            ts_ok = ts_us[ok]
-            if spec.kind == "count":
-                vals_ok = np.ones(int(ok.sum()), dtype=np.float64)
-            elif fold_vals is not None:
-                vals_ok = fold_vals[ok]
-            else:
-                vals_ok = np.asarray(values)[ok]  # keep dtype for exact ints
+            ok = ~late_mask
+            if ok.any():
+                kids_ok = kids[ok]
+                self._seen[kids_ok] = seq
+                ts_ok = ts_us[ok]
+                if spec.kind == "count":
+                    vals_ok = np.ones(int(ok.sum()), dtype=np.float64)
+                elif fold_vals is not None:
+                    vals_ok = fold_vals[ok]
+                else:
+                    vals_ok = np.asarray(values)[ok]  # keep dtype for exact ints
 
         # The deferred phase judges window dues by the watermark as of
         # THIS ingest.
@@ -845,6 +828,93 @@ class DeviceWindowAggState:
             return closes, self.notify_at(clock=clock), (seq, gone)
 
         return events, device_phase
+
+    def _clock_inorder(
+        self, kids: np.ndarray, eff: np.ndarray, now_us: float
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The clock pass over a delivery whose timestamps do not
+        decrease: ``(seg_kids, seg_max, wm_rows)`` as
+        :meth:`_clock_sorted` gives them, with no sort of the rows.
+        A key's running maximum at a row is the row's own ``eff``, and
+        its new base candidate the value at its last row."""
+        n = len(eff)
+        carry_rows = self.base_us[kids] + (
+            now_us - self.sys_at_base[kids]
+        )
+        wm_rows = np.maximum(eff, carry_rows)
+        # Each key's last row from one scatter (of repeated indices
+        # the last assignment stays), then a sort of the distinct
+        # keys only.
+        rows = np.arange(n)
+        last_row = self._last_row
+        last_row[kids] = rows
+        last = np.flatnonzero(last_row[kids] == rows)
+        by_kid = np.argsort(kids[last])
+        last = last[by_kid]
+        return kids[last], eff[last], wm_rows
+
+    def _clock_sorted(
+        self, kids: np.ndarray, eff: np.ndarray, now_us: float
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The clock pass over any delivery of two rows or more: the
+        delivery's key ids ascending, each key's maximum of ``eff``
+        and the watermark after each row (its key's running maximum
+        of ``eff``, floored by the key's carried clock).  Rows are
+        grouped by key with one stable sort and one accumulate runs
+        over the contiguous segments — O(n log n), not O(keys × rows)."""
+        n = len(eff)
+        order = np.argsort(kids, kind="stable")
+        kids_sorted = kids[order]
+        eff_sorted = eff[order]
+        seg_starts = np.concatenate(
+            ([0], np.flatnonzero(kids_sorted[1:] != kids_sorted[:-1]) + 1)
+        )
+        seg_kids = kids_sorted[seg_starts]
+        n_seg = len(seg_kids)
+        carry = self.base_us[seg_kids] + (
+            now_us - self.sys_at_base[seg_kids]
+        )
+
+        # Segmented prefix max with no per-key Python: shift each
+        # key's rows into its own disjoint value band (band width >
+        # the value span), run ONE global cummax — later bands
+        # dominate earlier ones, so the running max never leaks
+        # across segments — and shift back.  Exact only in integer
+        # arithmetic below 2^53, which the hot columnar path
+        # (datetime64[us] timestamps) always is; fractional
+        # microseconds or astronomically-spread batches take the
+        # per-segment loop so watermark equality stays bit-exact (a
+        # value that is not finite makes the band infinite or NaN,
+        # and goes there too).
+        lo_val = float(eff_sorted.min())
+        band = float(eff_sorted.max()) - lo_val + 1.0
+        integral = band == np.floor(band) and bool(
+            (eff_sorted == np.floor(eff_sorted)).all()
+        )
+        if integral and n_seg * band < float(1 << 53):
+            seg_of_row = np.repeat(
+                np.arange(n_seg, dtype=np.int64),
+                np.diff(np.append(seg_starts, n)),
+            )
+            off = seg_of_row * band
+            prefix = (
+                np.maximum.accumulate((eff_sorted - lo_val) + off) - off
+            ) + lo_val
+            wm_sorted = np.maximum(prefix, carry[seg_of_row])
+            seg_max = np.maximum.reduceat(eff_sorted, seg_starts)
+        else:
+            seg_ends = np.append(seg_starts[1:], n)
+            wm_sorted = np.empty(n, dtype=np.float64)
+            seg_max = np.empty(n_seg, dtype=np.float64)
+            for j, (lo, hi) in enumerate(
+                zip(seg_starts.tolist(), seg_ends.tolist())
+            ):
+                prefix = np.maximum.accumulate(eff_sorted[lo:hi])
+                np.maximum(prefix, carry[j], out=wm_sorted[lo:hi])
+                seg_max[j] = prefix[-1]
+        wm_rows = np.empty(n, dtype=np.float64)
+        wm_rows[order] = wm_sorted
+        return seg_kids, seg_max, wm_rows
 
     def _late_events(
         self, late_rows: np.ndarray, kids: np.ndarray, ts_us: np.ndarray, values

@@ -23,6 +23,7 @@ from bytewax_tpu.operators.windowing import (
     SessionWindower,
 )
 from bytewax_tpu.testing import TestingSink, TestingSource, run_main
+from tests import test_window_accel as twa
 
 ALIGN = datetime(2022, 1, 1, tzinfo=timezone.utc)
 
@@ -252,3 +253,39 @@ def test_session_fold_custom_merger_stays_host(monkeypatch):
     plan = flatten(flow)
     stateful = [o for o in plan.ops if o.name == "stateful_batch"]
     assert stateful[0].conf.get("_accel") is None
+
+
+# -- the clock pass under sessions -------------------------------------------
+
+
+def _session_of(kind):
+    from bytewax_tpu import xla
+
+    gap = timedelta(seconds=10)
+    return (
+        SessionAccelSpec(kind, xla.column_ts, gap, timedelta(0)),
+        SessionWindower(gap=gap),
+    )
+
+
+@pytest.mark.parametrize("kind", ["count", "stats"])
+@pytest.mark.parametrize("case,entry", twa.clock_case_entries())
+def test_session_clock_passes_agree_with_each_other_and_the_host_tier(
+    monkeypatch, case, entry, kind
+):
+    """The session tier inherits ``_ingest``: the in-order pass, the
+    sorted pass over the same rows and the per-item host tier give the
+    same late events (under the sentinel id), sessions, clocks and
+    touched keys (cases and harness: tests/test_window_accel.py)."""
+    twa.check_clock_case(monkeypatch, *_session_of(kind), case, entry)
+
+
+@pytest.mark.parametrize("entry", ["columnar", "itemized", "host_format"])
+@pytest.mark.parametrize(
+    "case", ["in_order", "late_by_the_carried_clock_only", "out_of_order_within_a_key"]
+)
+def test_session_clock_passes_agree_on_a_resumed_clock(monkeypatch, case, entry):
+    """The same on session states loaded from snapshots."""
+    twa.check_clock_case(
+        monkeypatch, *_session_of("stats"), case, entry, resumed=True
+    )

@@ -1823,3 +1823,596 @@ def test_window_table_counters_are_on_status(monkeypatch):
     assert counted["window_table_rebuilds"] > before.get("window_table_rebuilds", 0)
     assert counted["window_table_rebuild_rows"] > before.get("window_table_rebuild_rows", 0)
     assert counted["window_opens"] - before.get("window_opens", 0) == 160
+
+
+# -- the clock pass: in order, sorted, and the host tier ---------------------
+#
+# ``_ingest`` computes a delivery's watermarks by one of two passes
+# and chooses by the delivery alone: no timestamp below the one before
+# it, and no row is sorted.  The cases below drive the same rows
+# through both (the second forced by input only: a ballast key's row,
+# far in the future, moved to the front) and through the per-item host
+# tier, all under one scripted system clock.
+
+_CLOCK_T0 = ALIGN + timedelta(days=400)
+_ALIGN_US = int(ALIGN.timestamp()) * 1_000_000
+_BALLAST = "zz"
+_BALLAST_S = 9_000_000.0
+
+#: name -> (wait in seconds, [(system seconds since the first
+#: delivery, [(key, event seconds since ALIGN), ...]), ...], does the
+#: delivery-by-delivery order put every delivery on the in-order pass)
+CLOCK_CASES = {
+    "in_order": (
+        5,
+        [
+            (0, [("a", 1), ("b", 2), ("a", 3), ("a", 14), ("b", 15), ("a", 27)]),
+            (1, [("b", 28), ("a", 29), ("c", 30), ("b", 41), ("c", 55)]),
+        ],
+        True,
+    ),
+    "in_order_with_ties": (
+        0,
+        [
+            (0, [("a", 1), ("b", 1), ("a", 1), ("a", 12), ("b", 12), ("b", 12)]),
+            (1, [("a", 12), ("b", 30), ("a", 30), ("a", 30)]),
+        ],
+        True,
+    ),
+    # base 90 after the first delivery, carried to 140 by 50 s of
+    # system time: 95-97 are late by the carried clock alone.
+    "late_by_the_carried_clock_only": (
+        10,
+        [
+            (0, [("a", 100), ("b", 100)]),
+            (50, [("a", 95), ("a", 96), ("b", 97), ("a", 150), ("b", 151)]),
+        ],
+        True,
+    ),
+    "one_row": (0, [(0, [("a", 5)]), (1, [("a", 3)]), (2, [("a", 27)])], True),
+    "empty_delivery": (
+        0,
+        [(0, [("a", 5), ("b", 6)]), (1, []), (2, [("a", 8), ("b", 19)])],
+        True,
+    ),
+    # "a" loses its windows to the notify after the second delivery:
+    # where the tier lets a key go, its clock starts over at -inf and
+    # 50 is on time; where it keeps the key (sessions), 50 is late.
+    "key_fresh_at_minus_inf": (
+        0,
+        [
+            (0, [("a", 1), ("b", 2)]),
+            (100, [("b", 200)]),
+            (101, [("a", 50), ("a", 51), ("b", 201), ("c", 202)]),
+        ],
+        True,
+    ),
+    "fractional_microseconds": (
+        0,
+        [
+            (0, [("a", 1.00000025), ("b", 1.0000005), ("a", 2.0000015), ("b", 13.00000075)]),
+            (1, [("a", 2.00000125), ("a", 14.5), ("b", 14.50000025)]),
+        ],
+        True,
+    ),
+    "in_order_for_each_key_not_across": (
+        2,
+        [
+            (0, [("a", 1), ("a", 2), ("a", 13), ("b", 1), ("b", 5), ("b", 14)]),
+            (1, [("b", 15), ("b", 26), ("a", 14), ("a", 30)]),
+        ],
+        False,
+    ),
+    "out_of_order_within_a_key": (
+        3,
+        [
+            (0, [("a", 10), ("a", 8), ("a", 2), ("b", 5), ("a", 20), ("b", 1), ("b", 30), ("a", 18)]),
+            (1, [("a", 31), ("b", 12), ("a", 19), ("b", 44), ("a", 45)]),
+        ],
+        False,
+    ),
+}
+
+
+def _scripted_now(monkeypatch):
+    """System time under the test's hand: the device tier reads it
+    through its module's ``datetime``, the host tier through the
+    clock's ``now_getter``."""
+    from bytewax_tpu.engine import window_accel as wa
+
+    at = [_CLOCK_T0]
+
+    class _Datetime(datetime):
+        @classmethod
+        def now(cls, tz=None):
+            return at[0]
+
+    monkeypatch.setattr(wa, "datetime", _Datetime)
+    return at
+
+
+def _case_rows(deliveries):
+    """A case's deliveries with each row's value: its number in the
+    case (whole, so float32 folds it exactly; distinct, so a late
+    event names its row)."""
+    out, n = [], 0
+    for elapsed, rows in deliveries:
+        out.append(
+            (elapsed, [(k, s, float(n + i + 1)) for i, (k, s) in enumerate(rows)])
+        )
+        n += len(rows)
+    return out
+
+
+def _with_ballast(deliveries):
+    """The same rows with a ballast key's far-future row ahead of them
+    and one a second older behind: each delivery now descends, which
+    is all that sends it to the sorted pass; clocks are per key, so
+    no other key's answers move."""
+    return [
+        (
+            elapsed,
+            [(_BALLAST, _BALLAST_S + elapsed, 0.0)]
+            + rows
+            + [(_BALLAST, _BALLAST_S + elapsed - 1, 0.0)],
+        )
+        for elapsed, rows in deliveries
+    ]
+
+
+def _ts_of(sec):
+    return ALIGN + timedelta(microseconds=round(sec * 1_000_000))
+
+
+def _enter(st, entry, rows):
+    """One delivery through one of the tier's three entry points."""
+    from bytewax_tpu.engine.arrays import ArrayBatch, TsValue
+
+    keys = [k for k, _s, _v in rows]
+    if entry == "columnar":
+        # Microseconds since the epoch; float64 carries quarters of
+        # one at this size, and whole ones go as int64.
+        us = np.asarray([s * 1_000_000 for _k, s, _v in rows], dtype=np.float64)
+        ts = _ALIGN_US + us
+        if (us == np.floor(us)).all():
+            ts = ts.astype(np.int64)
+        cols = {
+            "key": np.asarray(keys, dtype="U2"),
+            "ts": ts,
+            "value": np.asarray([v for _k, _s, v in rows], dtype=np.float64),
+        }
+        return st.on_batch_columnar(ArrayBatch(cols))
+    if st.spec.kind == "count":
+        values = [_ts_of(s) for _k, s, _v in rows]
+    else:
+        values = [TsValue(v, _ts_of(s)) for _k, s, v in rows]
+    if entry == "itemized":
+        got = st.on_batch_items(list(zip(keys, values)))
+        if got is None:
+            pytest.skip("native toolchain unavailable")
+        return got
+    return st.on_batch(keys, values)
+
+
+def _norm(event):
+    """A window event as plain comparable numbers."""
+    key, (wid, tag, payload) = event
+    if tag == "M":
+        payload = (payload.open_time.timestamp(), payload.close_time.timestamp())
+    elif isinstance(payload, datetime):
+        payload = payload.timestamp()
+    elif isinstance(payload, tuple):
+        payload = tuple(float(x) for x in payload)
+    elif payload is not None:
+        payload = float(payload)
+    return key, int(wid), tag, payload
+
+
+def _passes():
+    from bytewax_tpu.engine import flight
+
+    return tuple(
+        flight.RECORDER.counters.get(name, 0)
+        for name in ("window_clock_inorder", "window_clock_sorted")
+    )
+
+
+def _device_tier(at, spec, entry, deliveries, resume_from=None):
+    """Drive a device-tier state through the deliveries: its events
+    (late and closed, a notify after every delivery and end of input
+    last), and after each ingest the delivery's keys, their clocks
+    and the pass that ran.  Every delivery is also held to the rule
+    itself, item by item in plain Python."""
+    st = spec.make_state()
+    if resume_from is not None:
+        st.load_many(resume_from)
+        st.touched.clear()
+    wait_us = spec.wait_us
+    events, log = [], []
+    for elapsed, rows in deliveries:
+        at[0] = _CLOCK_T0 + timedelta(seconds=elapsed)
+        now_us = at[0].timestamp() * 1_000_000
+        held = {
+            key: (float(st.base_us[kid]), float(st.sys_at_base[kid]))
+            for key, kid in st.key_ids.items()
+        }
+        before = _passes()
+        seg = []
+        phase_clock = st._phase_clock
+        st._phase_clock = lambda kids: seg.append(kids) or phase_clock(kids)
+        late, phase = _enter(st, entry, rows)
+        del st._phase_clock
+        took = tuple(b - a for a, b in zip(before, _passes()))
+        # The delivery's key ids, ascending as ``np.unique`` gives them.
+        (seg_kids,) = seg
+        assert (np.diff(seg_kids) > 0).all()
+        assert {st.keys[kid] for kid in seg_kids.tolist()} == {
+            k for k, _s, _v in rows
+        }
+        clocks = {
+            key: (float(st.base_us[kid]), float(st.sys_at_base[kid]))
+            for key, kid in st.key_ids.items()
+        }
+        # The rule: after each row its key's watermark is the larger
+        # of the carried clock and the key's largest ts - wait so far.
+        want_late, top = set(), {}
+        for key, sec, value in rows:
+            base, sys_at = held.get(key, (-np.inf, now_us))
+            ts = _ALIGN_US + np.float64(sec * 1_000_000)
+            top[key] = max(top.get(key, -np.inf), ts - wait_us)
+            if ts < max(top[key], base + (now_us - sys_at)):
+                want_late.add(value)
+        for key, most in top.items():
+            base, sys_at = held.get(key, (-np.inf, now_us))
+            want = (most, now_us) if most > base else (base, sys_at)
+            assert clocks[key] == want, key
+        by_value = {v: (k, s) for k, s, v in rows}
+        got_late = set()
+        for key, (_wid, tag, payload) in late:
+            assert tag == "L"
+            if st.spec.kind == "count":
+                got_late.update(
+                    v for v, (k, s) in by_value.items()
+                    if k == key and _ts_of(s) == payload
+                )
+            else:
+                got_late.add(float(payload))
+        assert got_late == want_late
+        assert st.touched == {k for k, _s, _v in rows}
+        log.append((took, clocks, set(st.touched)))
+        st.touched.clear()
+        closes, _hint, gone = phase()
+        st.let_go(gone)
+        events += late + closes + st.on_notify()
+    events += st.on_eof()
+    return sorted(map(_norm, events)), log, st
+
+
+def _host_tier(at, kind, windower, wait_s, deliveries, resume_from=None):
+    """The per-item host tier over the same deliveries: one
+    ``_WindowLogic`` a key, discarded when empty as the driver does,
+    a notify after every delivery and end of input last."""
+    from bytewax_tpu import xla
+    from bytewax_tpu.engine.arrays import TsValue
+    from bytewax_tpu.operators.windowing import _FoldWindowLogic, _WindowLogic
+
+    clock = EventClock(
+        ts_getter=xla.column_ts,
+        wait_for_system_duration=timedelta(seconds=wait_s),
+        now_getter=lambda: at[0],
+    )
+
+    def builder(resume):
+        if kind == "count":
+            return _FoldWindowLogic(
+                lambda s, _v: s + 1, lambda s, t: s + t, resume or 0
+            )
+        state = resume if resume is not None else xla.STATS.make_acc()
+        return _FoldWindowLogic(xla.STATS, xla.STATS.merge, state)
+
+    def logic_of(snap):
+        if snap is None:
+            return _WindowLogic(
+                clock.build(None), windower.build(None), builder, False
+            )
+        return _WindowLogic(
+            clock.build(snap.clock_state),
+            windower.build(snap.windower_state),
+            builder,
+            False,
+            {wid: builder(s) for wid, s in snap.logic_states.items()},
+            list(snap.queue),
+        )
+
+    logics = {
+        key: logic_of(snap)
+        for key, snap in (resume_from or [])
+        if snap is not None
+    }
+    events = []
+
+    def step(key, call, *args):
+        got, empty = call(*args)
+        events.extend((key, ev) for ev in got)
+        if empty:
+            del logics[key]
+
+    for elapsed, rows in deliveries:
+        at[0] = _CLOCK_T0 + timedelta(seconds=elapsed)
+        by_key = {}
+        for key, sec, value in rows:
+            ts = _ts_of(sec)
+            by_key.setdefault(key, []).append(
+                ts if kind == "count" else TsValue(value, ts)
+            )
+        for key, values in by_key.items():
+            if key not in logics:
+                logics[key] = logic_of(None)
+            step(key, logics[key].on_batch, values)
+        for key in list(logics):
+            step(key, logics[key].on_notify)
+    for key in list(logics):
+        step(key, logics[key].on_eof)
+    return sorted(map(_norm, events))
+
+
+def _without_ballast(events):
+    return [ev for ev in events if ev[0] != _BALLAST]
+
+
+def check_clock_case(monkeypatch, spec, windower, case, entry, resumed=False):
+    """The body of the clock-pass tests, shared with the session
+    tier's (tests/test_session_accel.py)."""
+    wait_s, deliveries, in_order = CLOCK_CASES[case]
+    whole_us = case != "fractional_microseconds"
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", "0")
+    at = _scripted_now(monkeypatch)
+    spec.wait_us = wait_s * 1_000_000.0
+    deliveries = _case_rows(deliveries)
+    resume_from = None
+    if resumed:
+        # Everything but the first delivery runs on states resumed
+        # from the snapshots the first left: "a" comes back with its
+        # clock, a key the first delivery did not hold starts at -inf.
+        first, deliveries = deliveries[:1], deliveries[1:]
+        _events, _log, st0 = _device_tier(at, spec, entry, [])
+        at[0] = _CLOCK_T0 + timedelta(seconds=first[0][0])
+        _late, phase = _enter(st0, entry, first[0][1])
+        phase()
+        resume_from = [
+            (key, snap)
+            for key, snap in st0.snapshots_for(sorted(st0.key_ids))
+            if snap is not None
+        ]
+        assert resume_from
+
+    events, log, _st = _device_tier(at, spec, entry, deliveries, resume_from)
+    for (took, _clocks, _touched), (_e, rows) in zip(log, deliveries):
+        assert took == ((1, 0) if in_order or len(rows) < 2 else (0, 1))
+
+    if in_order:
+        # The same rows on the sorted pass: every answer the same.
+        twin, twin_log, _st = _device_tier(
+            at, spec, entry, _with_ballast(deliveries), resume_from
+        )
+        assert _without_ballast(twin) == events
+        for (took, clocks, touched), (took_2, clocks_2, touched_2) in zip(
+            log, twin_log
+        ):
+            assert took_2 == (0, 1)
+            assert touched_2 - {_BALLAST} == touched
+            clocks_2.pop(_BALLAST)
+            # Clocks of the delivery's keys; a key the delivery does
+            # not hold keeps what it had in both.
+            assert clocks_2 == clocks
+
+    if whole_us:
+        host = _host_tier(
+            at, spec.kind, windower, wait_s, deliveries, resume_from
+        )
+        if isinstance(windower, w.SessionWindower) and not in_order:
+            # Session ids follow timestamp order on the device tier
+            # and arrival order on the host tier (documented): the
+            # sessions and their values are the same.
+            def anonymous(evs):
+                return sorted((k, tag, p) for k, _wid, tag, p in evs)
+
+            assert anonymous(events) == anonymous(host)
+        else:
+            assert events == host
+    assert any(tag == "E" for _k, _wid, tag, _p in events) or not any(
+        rows for _e, rows in deliveries
+    )
+
+
+def _window_spec(kind, windower):
+    from bytewax_tpu import xla
+
+    offset = getattr(windower, "offset", windower.length)
+    return WindowAccelSpec(
+        kind, xla.column_ts, ALIGN, windower.length, offset, timedelta(0)
+    )
+
+
+def clock_case_entries():
+    """Every case through every entry point that can carry it: a
+    ``datetime`` holds whole microseconds, so fractions of one reach
+    the tier through a float column alone (and no host tier)."""
+    return [
+        pytest.param(case, entry, id=f"{case}-{entry}")
+        for case in sorted(CLOCK_CASES)
+        for entry in ("columnar", "itemized", "host_format")
+        if case != "fractional_microseconds" or entry == "columnar"
+    ]
+
+
+@pytest.mark.parametrize("kind", ["count", "stats"])
+@pytest.mark.parametrize(
+    "windower", [TUMBLING_10S, SLIDING_10S_BY_4S], ids=["tumbling", "sliding"]
+)
+@pytest.mark.parametrize("case,entry", clock_case_entries())
+def test_clock_passes_agree_with_each_other_and_the_host_tier(
+    monkeypatch, case, windower, kind, entry
+):
+    """Late events, emitted windows, ``base_us`` / ``sys_at_base`` and
+    ``touched`` of the in-order pass, of the sorted pass over the same
+    rows, and of the per-item host tier."""
+    check_clock_case(
+        monkeypatch, _window_spec(kind, windower), windower, case, entry
+    )
+
+
+@pytest.mark.parametrize("entry", ["columnar", "itemized", "host_format"])
+@pytest.mark.parametrize("kind", ["count", "stats"])
+@pytest.mark.parametrize(
+    "windower", [TUMBLING_10S, SLIDING_10S_BY_4S], ids=["tumbling", "sliding"]
+)
+@pytest.mark.parametrize(
+    "case", ["in_order", "late_by_the_carried_clock_only", "out_of_order_within_a_key"]
+)
+def test_clock_passes_agree_on_a_resumed_clock(
+    monkeypatch, case, windower, kind, entry
+):
+    """The same on states loaded from snapshots: a resumed key's clock
+    carries, a key new to the resumed state starts at minus infinity."""
+    check_clock_case(
+        monkeypatch, _window_spec(kind, windower), windower, case, entry,
+        resumed=True,
+    )
+
+
+def test_clock_pass_counters_are_on_the_recorder_and_on_status(monkeypatch, tmp_path):
+    """``window_clock_inorder`` / ``window_clock_sorted`` count the
+    deliveries each pass took, on the recorder and under it on ``GET
+    /status``: three deliveries in order, then one that descends."""
+    import json
+    import urllib.request
+
+    from bytewax_tpu.engine import flight
+    from bytewax_tpu.outputs import DynamicSink, StatelessSinkPartition
+
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", "0")
+    monkeypatch.setenv("BYTEWAX_TPU_ACCEL", "1")
+    monkeypatch.setenv("BYTEWAX_TPU_INGEST_TARGET_ROWS", "0")
+    monkeypatch.setenv("BYTEWAX_DATAFLOW_API_ENABLED", "1")
+    monkeypatch.setenv("BYTEWAX_DATAFLOW_API_PORT", "13063")
+    monkeypatch.chdir(tmp_path)
+    secs = list(range(0, 150, 5)) + [400, 390, 420, 410, 440, 430, 460, 450, 480, 470]
+    inp = [(ALIGN + timedelta(seconds=s), f"key{s % 3}") for s in secs]
+    seen = []
+
+    class _Part(StatelessSinkPartition):
+        def write_batch(self, items):
+            with urllib.request.urlopen(
+                "http://127.0.0.1:13063/status", timeout=5
+            ) as resp:
+                seen.append(json.loads(resp.read())["recorder"]["counters"])
+
+    class _Sink(DynamicSink):
+        def build(self, step_id, worker_index, worker_count):
+            return _Part()
+
+    clock = EventClock(
+        ts_getter=lambda item: item[0],
+        wait_for_system_duration=timedelta(seconds=30),
+    )
+    flow = Dataflow("test_df")
+    s = op.input("inp", flow, TestingSource(inp, batch_size=10))
+    wo = w.count_window("count", s, clock, TUMBLING_10S, key=lambda item: item[1])
+    op.output("out", wo.down, _Sink())
+    before = dict(flight.RECORDER.counters)
+    run_main(flow)
+    gained = {
+        name: flight.RECORDER.counters.get(name, 0) - before.get(name, 0)
+        for name in ("window_clock_inorder", "window_clock_sorted")
+    }
+    assert gained == {"window_clock_inorder": 3, "window_clock_sorted": 1}
+    assert seen, "no window closed before end of input"
+    last = seen[-1]
+    assert last["window_clock_inorder"] == flight.RECORDER.counters["window_clock_inorder"]
+    assert last["window_clock_sorted"] == flight.RECORDER.counters["window_clock_sorted"]
+
+
+def _late_and_on_time(rng, n):
+    """Rows of two keys in order, every seventh 500 s behind (late
+    under a wait of 0 from the second row of its key on)."""
+    secs = 1000 + np.arange(n) * 3
+    secs[6::7] -= 500
+    return rng.choice(["a", "b"], size=n), secs
+
+
+@pytest.mark.parametrize("late", [False, True], ids=["none_late", "some_late"])
+@pytest.mark.parametrize(
+    "column", ["callers", "value_scale", "itemized"],
+)
+def test_lane_folds_the_on_time_rows_as_they_were_at_the_call(
+    monkeypatch, column, late
+):
+    """The deferred phase is handed exactly the rows the mask kept,
+    and a source that reuses its columns' buffers once the call has
+    returned (before the lane runs the fold) changes nothing: with no
+    row late the engine's own columns go on uncopied, a caller's own
+    value column is copied."""
+    from bytewax_tpu.engine.arrays import ArrayBatch, TsValue
+
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", "0")
+    rng = np.random.RandomState(11)
+    n = 70
+    keys, secs = _late_and_on_time(rng, n)
+    if not late:
+        secs = np.sort(secs)
+    vals = rng.randint(1, 50, size=n)
+    scale = 0.5 if column == "value_scale" else 1.0
+    st = _spec_of("sum", TUMBLING_10S).make_state()
+    absorbed = []
+    absorb = st._absorb
+
+    def spy(kids_ok, ts_ok, vals_ok):
+        names = [st.keys[kid] for kid in kids_ok.tolist()]
+        absorbed.append((names, ts_ok.copy(), np.array(vals_ok)))
+        absorb(kids_ok, ts_ok, vals_ok)
+
+    monkeypatch.setattr(st, "_absorb", spy)
+
+    if column == "itemized":
+        items = [
+            (str(k), TsValue(float(v), ALIGN + timedelta(seconds=int(s))))
+            for k, s, v in zip(keys, secs, vals)
+        ]
+        got = st.on_batch_items(items)
+        if got is None:
+            pytest.skip("native toolchain unavailable")
+        late_events, phase = got
+        items[:] = [("a", TsValue(-1.0, ALIGN))] * n
+    else:
+        cols = {
+            "key": np.array(keys),
+            "ts": (_ALIGN_US + secs * 1_000_000).astype(np.float64),
+            "value": vals.astype(np.int16 if column == "value_scale" else np.float64),
+        }
+        late_events, phase = st.on_batch_columnar(
+            ArrayBatch(cols, value_scale=scale if column == "value_scale" else None)
+        )
+        # The source fills its buffers with the next poll's rows.
+        cols["key"][:] = "b"
+        cols["ts"][:] = _ALIGN_US
+        cols["value"][:] = -1
+    closes, _hint, gone = phase()
+    st.let_go(gone)
+    events = closes + st.on_eof()
+
+    top, on_time = {}, []
+    for k, s in zip(keys, secs):
+        top[k] = max(top.get(k, -np.inf), s)
+        on_time.append(s >= top[k])
+    on_time = np.asarray(on_time)
+    assert (~on_time).any() == late and len(late_events) == (~on_time).sum()
+    ((names_ok, ts_ok, vals_ok),) = absorbed
+    assert names_ok == keys[on_time].tolist()
+    np.testing.assert_array_equal(ts_ok, _ALIGN_US + secs[on_time] * 1_000_000.0)
+    np.testing.assert_array_equal(vals_ok, vals[on_time] * scale)
+    want = {}
+    for k, s, v in zip(keys[on_time], secs[on_time], vals[on_time]):
+        want[(str(k), int(s) // 10)] = want.get((str(k), int(s) // 10), 0.0) + v * scale
+    got = {(k, wid): v for k, (wid, tag, v) in events if tag == "E"}
+    assert got == want
