@@ -1671,6 +1671,23 @@ class _StatefulBatchRt(_OpRt):
             if page:
                 pager.load_many(page)
 
+    def placement(self) -> Optional[Dict[str, Any]]:
+        """Where this step's keyed device state lives, for ``GET
+        /graph``: ``{"blocks": n, "devices": [ids]}`` as the state
+        object the factory built reports it (the window tier's slot
+        table is its ``agg``).  None for a step with no such state:
+        host tier, demoted, the collective tier (its node says
+        ``collective``), ``op.infer``."""
+        if self.demoted:
+            return None
+        for state in (self.agg, self.wagg, self.sagg):
+            report = getattr(
+                getattr(state, "agg", state), "placement", None
+            )
+            if report is not None:
+                return report()
+        return None
+
     # -- dispatch pipeline -------------------------------------------------
 
     def _pipe_pending(self) -> bool:
@@ -4746,7 +4763,12 @@ class _Driver:
         # collective global-exchange state or runtime demotions.
         tiers: Dict[str, str] = {}
         lanes: Dict[str, Optional[Dict[str, int]]] = {}
+        placements: Dict[str, Dict[str, Any]] = {}
         for rt in self.rts:
+            if isinstance(rt, _StatefulBatchRt):
+                placed = rt.placement()
+                if placed is not None:
+                    placements[rt.op.step_id] = placed
             if isinstance(rt, _InferRt):
                 # Infer steps report the tier that actually scores
                 # (device until demotion/knob-off, host after).
@@ -4764,6 +4786,8 @@ class _Driver:
             node["tier"] = tiers.get(node["step_id"], node["tier"])
             if node["step_id"] in lanes:
                 node["collective_lane"] = lanes[node["step_id"]]
+            if node["step_id"] in placements:
+                node["placement"] = placements[node["step_id"]]
         sources: Dict[str, Any] = {}
         local = _flowmap.FLOWMAP.summary()
         if local is not None:

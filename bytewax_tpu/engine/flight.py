@@ -136,6 +136,7 @@ TRACED_PHASES = frozenset(
         "encode",
         "watermark",
         "prep",
+        "exchange",
         "h2d",
         "dispatch",
         "close_scan",
@@ -667,6 +668,30 @@ def note_transfer(direction: str, nbytes: int) -> None:
     child.inc(nbytes)
     RECORDER.count(f"device_transfer_bytes_{direction}", nbytes)
     RECORDER.record("transfer", direction=direction, bytes=int(nbytes))
+
+
+def note_exchange(
+    n_shards: int, capacity: int, max_block_rows: int
+) -> None:
+    """One mesh-sharded step's exchange as the host sized it (the
+    ``exchange`` span counts the step's real rows itself, as
+    ``exchange_rows``): ``n_shards² × capacity`` bucket slots, which
+    the ``all_to_all`` moves and the scatter walks, and the rows of
+    the fullest source block (rows are padded at the end, so the
+    first blocks fill first: against ``exchange_rows / n_shards`` it
+    says how unevenly the steps load the chips).  ``exchange_blocks``
+    is a gauge: the blocks of the state the last sharded step ran on
+    (absent, read it as 0, until one has run)."""
+    counters = RECORDER.counters
+    counters["exchange_steps"] = counters.get("exchange_steps", 0) + 1
+    counters["exchange_bucket_rows"] = (
+        counters.get("exchange_bucket_rows", 0)
+        + n_shards * n_shards * capacity
+    )
+    counters["exchange_rows_max_block"] = (
+        counters.get("exchange_rows_max_block", 0) + max_block_rows
+    )
+    counters["exchange_blocks"] = n_shards
 
 
 def note_comm(direction: str, peer: int, nbytes: int) -> None:
@@ -1437,6 +1462,7 @@ _FRACTION_BUCKETS = {
         "encode",
         "watermark",
         "prep",
+        "exchange",
         "h2d",
         "dispatch",
         "close_scan",

@@ -249,14 +249,51 @@ class _ShardedSlots(_SlotLayout):
     the columns :meth:`_iter_fields` names (see ``xla._SlotLayout``
     for where an id lives).
 
-    Hosts set ``_sharding``, call :meth:`_init_slots`, and implement
-    :meth:`_iter_fields` yielding ``(name, identity, dtype)`` per
-    state column.
+    Hosts set ``mesh`` and ``_sharding``, call :meth:`_init_slots`,
+    and implement :meth:`_iter_fields` yielding ``(name, identity,
+    dtype)`` per state column and ``_step_for(total_rows, capacity)``
+    returning the compiled step of that shape.
     """
 
     def _iter_fields(self):
         """``(name, identity, dtype)`` per state column."""
         raise NotImplementedError
+
+    def placement(self) -> Dict[str, Any]:
+        """Where this step's state lives (``GET /graph``): block *d*
+        on the mesh's device *d*."""
+        return {
+            "blocks": self.n_shards,
+            "devices": [int(d.id) for d in self.mesh.devices.flat],
+        }
+
+    def _plan_exchange(self, kids: np.ndarray) -> Tuple[int, Any]:
+        """The exchange's host half for one delivery: the padded row
+        count and the compiled step for it.  Rows are padded at the
+        end to ``n_shards`` equal source blocks, and the bucket
+        capacity is the exact per-(source block, destination shard)
+        maximum, so the exchange can never drop rows, however skewed
+        the key distribution."""
+        n = len(kids)
+        n_shards = self.n_shards
+        with _flight.span("exchange", rows=n):
+            rows_per_shard = _pow2(
+                -(-n // n_shards), int(math.log2(_MIN_ROWS_PER_SHARD))
+            )
+            total = rows_per_shard * n_shards
+            dest = kids % n_shards
+            block_of = np.arange(n) // rows_per_shard
+            pair_counts = np.bincount(
+                block_of * n_shards + dest, minlength=n_shards * n_shards
+            )
+            capacity = _pow2(int(pair_counts.max()), 4)
+            step = self._step_for(total, capacity)
+            _flight.note_exchange(
+                n_shards,
+                capacity,
+                int(pair_counts.reshape(n_shards, n_shards).sum(axis=1).max()),
+            )
+        return total, step
 
     def _make_fields(self):
         import jax
@@ -352,24 +389,7 @@ class ShardedAggState(_ShardedSlots, _AggTable):
         n = len(kids)
         if n == 0:
             return
-        with _flight.span("prep"):
-            rows_per_shard = _pow2(
-                -(-n // self.n_shards),
-                int(math.log2(_MIN_ROWS_PER_SHARD)),
-            )
-            total = rows_per_shard * self.n_shards
-
-            # Exact per-(source block, destination shard) bucket
-            # maximum: sized on host so the exchange can never drop
-            # rows, however skewed the key distribution.
-            dest = kids % self.n_shards
-            block_of = np.arange(n) // rows_per_shard
-            pair_counts = np.bincount(
-                block_of * self.n_shards + dest,
-                minlength=self.n_shards * self.n_shards,
-            )
-            capacity = _pow2(int(pair_counts.max()), 4)
-            step = self._step_for(total, capacity)
+        total, step = self._plan_exchange(kids)
         with _flight.span("h2d", rows=total):
             kids_p = np.zeros(total, dtype=np.int32)
             kids_p[:n] = kids
@@ -454,10 +474,7 @@ class ShardedScanState(_ShardedSlots, ScanUpdates):
         if n == 0:
             return tuple()
         self._ensure_fields()
-        rows_per_shard = _pow2(
-            -(-n // self.n_shards), int(math.log2(_MIN_ROWS_PER_SHARD))
-        )
-        total = rows_per_shard * self.n_shards
+        total, step = self._plan_exchange(kids)
 
         kids_p = np.zeros(total, dtype=np.int32)
         kids_p[:n] = kids
@@ -466,15 +483,6 @@ class ShardedScanState(_ShardedSlots, ScanUpdates):
         valid_p = np.zeros(total, dtype=bool)
         valid_p[:n] = True
 
-        dest = kids % self.n_shards
-        block_of = np.arange(n) // rows_per_shard
-        pair_counts = np.bincount(
-            block_of * self.n_shards + dest,
-            minlength=self.n_shards * self.n_shards,
-        )
-        capacity = _pow2(int(pair_counts.max()), 4)
-
-        step = self._step_for(total, capacity)
         outs, self._fields = step(
             self._fields,
             jax.device_put(kids_p, self._sharding),

@@ -79,6 +79,14 @@ def make_sharded_step(
     integer = jnp.issubdtype(dtype, jnp.integer)
 
     def body(fields, key_ids, values, valid):
+        # The two halves carry a ``jax.named_scope`` each, so the
+        # device trace's ``XLA Ops`` line tells them apart.
+        with jax.named_scope("exchange"):
+            recv_ids, recv_vals, mask = exchange(key_ids, values, valid)
+        with jax.named_scope("fold"):
+            return fold(fields, recv_ids, recv_vals, mask)
+
+    def exchange(key_ids, values, valid):
         # 1. Keyed exchange over ICI: ship each row to its owner.
         # Float payloads ride bitcast to int32 (a float32 payload
         # lane would corrupt ids above 2^24).
@@ -111,7 +119,9 @@ def make_sharded_step(
             recv_vals = rows[:, 1]
         else:
             recv_vals = jax.lax.bitcast_convert_type(rows[:, 1], jnp.float32)
+        return recv_ids, recv_vals, mask
 
+    def fold(fields, recv_ids, recv_vals, mask):
         # 2. Local scatter-combine into this device's state block.
         local_slot = jnp.where(
             mask, recv_ids // n_shards, cap_per_shard - 1
@@ -209,68 +219,72 @@ def make_sharded_scan_step(
 
     def body(fields, key_ids, values, valid):
         rows = key_ids.shape[0]
-        shard_ids = (key_ids % n_shards).astype(jnp.int32)
-        vbits = jax.lax.bitcast_convert_type(
-            values.astype(jnp.float32), jnp.int32
-        )
-        pos = jnp.arange(rows, dtype=jnp.int32)
-        payload = jnp.stack(
-            [key_ids.astype(jnp.int32), vbits, pos], axis=1
-        )
-        buckets, counts, _dropped = bucket_by_shard(
-            shard_ids, payload, valid, n_shards, cap
-        )
-        got = jax.lax.all_to_all(
-            buckets, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        got_counts = jax.lax.all_to_all(
-            counts, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        mask = (
-            jnp.arange(cap)[None, :] < got_counts[:, None]
-        ).reshape(-1)
-        recv = got.reshape(-1, 3)
-        recv_ids = recv[:, 0]
-        recv_vals = jax.lax.bitcast_convert_type(recv[:, 1], jnp.float32)
-        recv_pos = recv[:, 2]
-
-        # Group by slot with ONE stable sort: received buckets are
-        # ordered by source block and source order within each block,
-        # so the stable sort preserves each key's global arrival
-        # order.  Padding rows target the scratch slot (the block's
-        # last), which sorts to the tail — the kernel's contract.
-        local_slot = jnp.where(
-            mask, recv_ids // n_shards, cap_per_shard - 1
-        ).astype(jnp.int32)
-        order = jnp.argsort(local_slot, stable=True)
-        outs_s, new_fields = scan_kind.raw_run(
-            fields, local_slot[order], recv_vals[order]
-        )
-        # Un-sort back to received order, then ship outputs home.
-        outs_r = tuple(
-            jnp.zeros_like(o).at[order].set(o) for o in outs_s
-        )
-        ret = jnp.stack(
-            [*(_lane_encode(o) for o in outs_r), recv_pos], axis=1
-        ).reshape(n_shards, cap, -1)
-        back = jax.lax.all_to_all(
-            ret, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True
-        ).reshape(-1, len(outs_r) + 1)
-        # This device's send counts bound each returned bucket's
-        # valid prefix (bucket d of `back` holds shard d's outputs
-        # for the rows we sent it, in the order we sent them).
-        src_mask = (
-            jnp.arange(cap)[None, :] < counts[:, None]
-        ).reshape(-1)
-        back_pos = jnp.where(src_mask, back[:, -1], rows)
-        outs_local = []
-        for j, o in enumerate(outs_r):
-            buf = (
-                jnp.zeros((rows + 1,), dtype=jnp.int32)
-                .at[back_pos]
-                .set(back[:, j])
+        # ``exchange`` / ``fold`` scopes: see make_sharded_step.
+        with jax.named_scope("exchange"):
+            shard_ids = (key_ids % n_shards).astype(jnp.int32)
+            vbits = jax.lax.bitcast_convert_type(
+                values.astype(jnp.float32), jnp.int32
             )
-            outs_local.append(_lane_decode(buf[:rows], o))
+            pos = jnp.arange(rows, dtype=jnp.int32)
+            payload = jnp.stack(
+                [key_ids.astype(jnp.int32), vbits, pos], axis=1
+            )
+            buckets, counts, _dropped = bucket_by_shard(
+                shard_ids, payload, valid, n_shards, cap
+            )
+            got = jax.lax.all_to_all(
+                buckets, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True
+            )
+            got_counts = jax.lax.all_to_all(
+                counts, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True
+            )
+            mask = (
+                jnp.arange(cap)[None, :] < got_counts[:, None]
+            ).reshape(-1)
+            recv = got.reshape(-1, 3)
+            recv_ids = recv[:, 0]
+            recv_vals = jax.lax.bitcast_convert_type(recv[:, 1], jnp.float32)
+            recv_pos = recv[:, 2]
+
+        with jax.named_scope("fold"):
+            # Group by slot with ONE stable sort: received buckets are
+            # ordered by source block and source order within each block,
+            # so the stable sort preserves each key's global arrival
+            # order.  Padding rows target the scratch slot (the block's
+            # last), which sorts to the tail — the kernel's contract.
+            local_slot = jnp.where(
+                mask, recv_ids // n_shards, cap_per_shard - 1
+            ).astype(jnp.int32)
+            order = jnp.argsort(local_slot, stable=True)
+            outs_s, new_fields = scan_kind.raw_run(
+                fields, local_slot[order], recv_vals[order]
+            )
+            # Un-sort back to received order, then ship outputs home.
+            outs_r = tuple(
+                jnp.zeros_like(o).at[order].set(o) for o in outs_s
+            )
+        with jax.named_scope("exchange"):
+            ret = jnp.stack(
+                [*(_lane_encode(o) for o in outs_r), recv_pos], axis=1
+            ).reshape(n_shards, cap, -1)
+            back = jax.lax.all_to_all(
+                ret, SHARD_AXIS, split_axis=0, concat_axis=0, tiled=True
+            ).reshape(-1, len(outs_r) + 1)
+            # This device's send counts bound each returned bucket's
+            # valid prefix (bucket d of `back` holds shard d's outputs
+            # for the rows we sent it, in the order we sent them).
+            src_mask = (
+                jnp.arange(cap)[None, :] < counts[:, None]
+            ).reshape(-1)
+            back_pos = jnp.where(src_mask, back[:, -1], rows)
+            outs_local = []
+            for j, o in enumerate(outs_r):
+                buf = (
+                    jnp.zeros((rows + 1,), dtype=jnp.int32)
+                    .at[back_pos]
+                    .set(back[:, j])
+                )
+                outs_local.append(_lane_decode(buf[:rows], o))
         return tuple(outs_local), new_fields
 
     field_specs = {name: P(SHARD_AXIS) for name in scan_kind.fields}
