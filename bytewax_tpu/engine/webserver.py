@@ -62,6 +62,8 @@ host deployments keep the configured port on every pod.
 import json
 import logging
 import os
+import selectors
+import socket
 import sys
 import threading
 import traceback
@@ -242,16 +244,68 @@ class _Handler(BaseHTTPRequestHandler):
         pass
 
 
+class _WakeableServer(ThreadingHTTPServer):
+    """``ThreadingHTTPServer`` whose serving loop waits for a
+    connection *or* for a wake-up, and for nothing else.
+
+    The stdlib's ``serve_forever`` learns of a ``shutdown()`` by
+    polling a flag every half second, so stopping an idle server
+    costs what is left of that poll (and rounds every short job up
+    to it).  Here ``shutdown()`` writes to one end of a socket pair
+    whose other end sits in the loop's selector beside the listening
+    socket: the ``select`` takes no time-out, an idle plane never
+    wakes, and a stop costs a thread hand-over."""
+
+    def __init__(self, address, handler):
+        # Before the bind: a failed bind unwinds through
+        # ``server_close``, which closes the pair.
+        self._wake_r, self._wake_w = socket.socketpair()
+        super().__init__(address, handler)
+        # A peer that resets between ``select`` and ``accept`` must
+        # not park the loop in ``accept`` where no wake-up reaches it
+        # (accepted sockets stay blocking).
+        self.socket.setblocking(False)
+
+    def serve_forever(self) -> None:
+        with selectors.DefaultSelector() as selector:
+            selector.register(self, selectors.EVENT_READ)
+            selector.register(self._wake_r, selectors.EVENT_READ)
+            while True:
+                ready = [key.fileobj for key, _ in selector.select()]
+                if self._wake_r in ready:
+                    return
+                self._handle_request_noblock()
+
+    def shutdown(self) -> None:
+        """Ask the serving loop to return.  Does not wait for it: the
+        owner joins the serving thread (``_ApiServer.shutdown``).  A
+        wake-up raised before the loop first selects is not lost."""
+        self._wake_w.send(b"\0")
+
+    def server_close(self) -> None:
+        super().server_close()
+        self._wake_r.close()
+        self._wake_w.close()
+
+
 class _ApiServer:
-    def __init__(self, server: ThreadingHTTPServer, thread: threading.Thread):
+    def __init__(self, server: _WakeableServer, thread: threading.Thread):
         self._server = server
-        self._thread = thread
+        self._thread: Optional[threading.Thread] = thread
         #: The bound port (configured port may be 0 = ephemeral).
         self.port = server.server_address[1]
 
     def shutdown(self) -> None:
+        """Stop the plane.  On return the serving thread has left its
+        loop and the listening socket is closed, so the next
+        generation in this process (a supervised restart, a
+        reconfigure rebuild, a job after this one) binds the same
+        port at once.  Idempotent; safe before the loop has run."""
+        if self._thread is None:
+            return
         self._server.shutdown()
-        self._thread.join(timeout=5)
+        self._thread.join()
+        self._thread = None
         self._server.server_close()
 
 
@@ -354,7 +408,7 @@ def maybe_start_server(
         },
     )
     try:
-        server = ThreadingHTTPServer((host, port), handler)
+        server = _WakeableServer((host, port), handler)
     except OSError as ex:
         # An observability server must never take down the data
         # plane: a taken port (another process, co-located ranks with

@@ -531,6 +531,49 @@ def test_healthz_not_ready_is_503(monkeypatch, tmp_path):
         srv.shutdown()
 
 
+def test_back_to_back_runs_share_one_port_and_tear_down_at_once(
+    monkeypatch, tmp_path
+):
+    # A job ends when its work ends: with the API plane on, a tiny
+    # flow's "teardown" span no longer waits out serve_forever's poll
+    # (0.0-0.5 s a run), and the next run in the same process binds
+    # the same port (no "continuing without /dataflow, /metrics,
+    # /status") and answers on it.
+    monkeypatch.setenv("BYTEWAX_DATAFLOW_API_ENABLED", "1")
+    monkeypatch.setenv("BYTEWAX_DATAFLOW_API_PORT", "13067")
+    monkeypatch.chdir(tmp_path)
+    from bytewax_tpu.outputs import DynamicSink
+
+    graphs = []
+
+    class _ProbePartition:
+        def write_batch(self, items):
+            with urllib.request.urlopen(
+                "http://127.0.0.1:13067/graph", timeout=5
+            ) as resp:
+                graphs.append(json.loads(resp.read()))
+
+        def close(self):
+            pass
+
+    class _ProbeSink(DynamicSink):
+        def build(self, step_id, worker_index, worker_count):
+            return _ProbePartition()
+
+    teardowns = []
+    for n in range(2):
+        flow = Dataflow(f"job{n}_df")
+        s = op.input("inp", flow, TestingSource([1]))
+        op.output("out", s, _ProbeSink())
+        before = flight.RECORDER.phase_totals.get("teardown", 0.0)
+        run_main(flow)
+        teardowns.append(
+            flight.RECORDER.phase_totals.get("teardown", 0.0) - before
+        )
+    assert [g["flow_id"] for g in graphs] == ["job0_df", "job1_df"]
+    assert all(0.0 < t < 0.1 for t in teardowns), teardowns
+
+
 # -- crash post-mortems ------------------------------------------------
 
 

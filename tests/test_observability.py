@@ -8,6 +8,8 @@ import sys
 import time
 import urllib.request
 
+import pytest
+
 import bytewax_tpu.operators as op
 from bytewax_tpu.dataflow import Dataflow
 from bytewax_tpu.testing import TestingSink, TestingSource, run_main
@@ -676,3 +678,124 @@ def test_per_operator_spans_at_debug(caplog):
     finally:
         guard.shutdown()
         setup_tracing(None, "ERROR")
+
+
+# -- the API plane stops on a wake-up ----------------------------------
+
+
+def _api_flow(flow_id="stop_df"):
+    flow = Dataflow(flow_id)
+    s = op.input("inp", flow, TestingSource([1]))
+    op.output("out", s, TestingSink([]))
+    return flow
+
+
+def _get_json(port, path):
+    with urllib.request.urlopen(
+        f"http://127.0.0.1:{port}{path}", timeout=5
+    ) as resp:
+        return json.loads(resp.read())
+
+
+def _api_env(monkeypatch, tmp_path, port):
+    monkeypatch.setenv("BYTEWAX_DATAFLOW_API_ENABLED", "1")
+    monkeypatch.setenv("BYTEWAX_DATAFLOW_API_PORT", str(port))
+    monkeypatch.chdir(tmp_path)
+
+
+@pytest.mark.parametrize("idle_s", [0.05, 0.26, 0.45])
+def test_api_server_stops_on_a_wake_up(idle_s, monkeypatch, tmp_path):
+    # The serving loop waits for a connection or for shutdown()'s
+    # wake-up, never for a clock: a stop costs a thread hand-over
+    # wherever it falls in what was serve_forever's half-second poll
+    # (0.45 / 0.24 / 0.05 s left at these three), and the port is
+    # free for the next generation the moment shutdown() returns.
+    from bytewax_tpu.engine.webserver import maybe_start_server
+
+    _api_env(monkeypatch, tmp_path, 13064)
+    flow = _api_flow()
+    took = []
+    for _ in range(3):  # a loaded machine may preempt one hand-over
+        srv = maybe_start_server(flow, status_fn=lambda: {"gen": 0})
+        assert srv is not None and srv.port == 13064
+        time.sleep(idle_s)
+        t0 = time.perf_counter()
+        srv.shutdown()
+        took.append(time.perf_counter() - t0)
+        # No bind degraded to "continuing without": the second
+        # generation holds the same port at once and answers.
+        nxt = maybe_start_server(flow, status_fn=lambda: {"gen": 1})
+        try:
+            assert nxt is not None and nxt.port == 13064
+            assert _get_json(13064, "/status") == {"gen": 1}
+        finally:
+            nxt.shutdown()
+        if took[-1] < 0.05:
+            break
+    assert min(took) < 0.05, took
+
+
+def test_api_server_shutdown_is_idempotent_and_safe_unserved(
+    monkeypatch, tmp_path
+):
+    # The start-up unwind stops a plane whose loop may not have
+    # selected yet and that no request ever reached; teardown may
+    # follow it with a second shutdown().
+    import socket
+
+    from bytewax_tpu.engine.webserver import maybe_start_server
+
+    _api_env(monkeypatch, tmp_path, 13065)
+    srv = maybe_start_server(_api_flow())
+    assert srv is not None
+    thread = srv._thread
+    srv.shutdown()
+    srv.shutdown()
+    assert not thread.is_alive()
+    # The listening socket is closed, not merely idle.
+    with socket.socket() as probe:
+        probe.bind(("127.0.0.1", 13065))
+
+
+def test_api_server_serves_while_another_request_blocks(
+    monkeypatch, tmp_path
+):
+    # A thread a request, beside a select with no time-out: a slow
+    # /status neither stalls /healthz nor the stop, and the loop
+    # answers after sitting idle in its select.
+    import threading
+
+    from bytewax_tpu.engine.webserver import maybe_start_server
+
+    _api_env(monkeypatch, tmp_path, 13066)
+    entered, release = threading.Event(), threading.Event()
+
+    def slow_status():
+        entered.set()
+        release.wait(10)
+        return {"slow": True}
+
+    srv = maybe_start_server(
+        _api_flow(), status_fn=slow_status, health_fn=lambda: {"ready": True}
+    )
+    assert srv is not None
+    got = {}
+    slow = threading.Thread(
+        target=lambda: got.update(_get_json(13066, "/status"))
+    )
+    try:
+        time.sleep(0.2)
+        slow.start()
+        assert entered.wait(5)
+        assert _get_json(13066, "/healthz") == {"live": True, "ready": True}
+        assert _get_json(13066, "/dataflow")["flow_id"] == "stop_df"
+        # A request in flight does not hold the stop back (request
+        # threads are daemons, as they were).
+        t0 = time.perf_counter()
+        srv.shutdown()
+        assert time.perf_counter() - t0 < 1.0
+    finally:
+        release.set()
+        slow.join(5)
+        srv.shutdown()
+    assert got == {"slow": True}
