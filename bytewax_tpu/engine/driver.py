@@ -998,6 +998,9 @@ class _InputRt(_OpRt):
         #: accumulated before it must flow (and be processed) first,
         #: exactly as they would have without coalescing.
         self._deferred: Dict[str, BaseException] = {}
+        #: Partitions whose polls have brought columns: their polls
+        #: open no `read` span (see :meth:`poll`).
+        self._columnar_parts: Set[str] = set()
         # -- connector-edge resilience (docs/recovery.md) -----------------
         #: Consecutive transient poll failures per partition (the I/O
         #: retry ladder; reset by any successful poll).
@@ -1282,6 +1285,16 @@ class _InputRt(_OpRt):
                         self._absorb_poll_fault(name, deferred, now)
                         continue
                     _reraise(self.op.step_id, "`next_batch`", deferred)
+                # Ledger: an itemized poll and the coalescing polls
+                # that follow it are one `read` span, so one a
+                # delivery.  Whether a partition hands over items or
+                # columns shows only when a poll returns, so the span
+                # is dropped where it brought columns (the source's
+                # own `parse` times those) and that partition's later
+                # polls open none.
+                read = _flight.span("read", self.op.step_id)
+                if name not in self._columnar_parts:
+                    read.begin()
                 try:
                     # The pinned connector-edge fault site: fired
                     # before the poll touches the source, so an
@@ -1314,24 +1327,38 @@ class _InputRt(_OpRt):
                         self._absorb_poll_fault(name, ex, now)
                         continue
                     _reraise(self.op.step_id, "`next_batch`", ex)
-                self._io_heal(name)
-                emitted = len(batch) > 0
-                if emitted:
-                    if self.coalesce_rows > 1 and len(batch) < (
-                        self.coalesce_rows
+                else:
+                    self._io_heal(name)
+                    emitted = len(batch) > 0
+                    if (
+                        emitted
+                        and self.coalesce_rows > 1
+                        and len(batch) < self.coalesce_rows
                     ):
                         batches = self._coalesce(name, part, batch, now)
                     else:
                         batches = [batch]
+                    if isinstance(batch, ArrayBatch):
+                        self._columnar_parts.add(name)
+                    elif emitted:
+                        read.rows = sum(
+                            len(b)
+                            for b in batches
+                            if not isinstance(b, ArrayBatch)
+                        )
+                        read.end()
+                finally:
+                    read.drop()
+                if emitted:
                     w = self.part_worker[name]
+                    rec = _flight.RECORDER
                     for b in batches:
                         self.emit("down", (w, b))
-                        _flight.RECORDER.count(
-                            "ingest_rows_columnar"
-                            if isinstance(b, ArrayBatch)
-                            else "ingest_rows_itemized",
-                            len(b),
-                        )
+                        if isinstance(b, ArrayBatch):
+                            rec.count("ingest_rows_columnar", len(b))
+                        else:
+                            rec.count("ingest_rows_itemized", len(b))
+                            rec.count("ingest_deliveries_itemized")
                     progressed = True
                     lag = _batch_event_lag_s(batches[-1], now)
                     if lag is not None:
@@ -1399,6 +1426,12 @@ class _FlatMapBatchRt(_OpRt):
 
     def process(self, port: str, entries: List[Entry]) -> None:
         for w, items in entries:
+            # Ledger: the mapper's pass over a delivery of items (the
+            # per-item operators are all built on this one) is one
+            # `item_ops` span; a mapper over columns opens none.
+            work = _flight.span("item_ops", self.op.step_id, len(items))
+            if type(items) is list:
+                work.begin()
             try:
                 with self._timer("flat_map_batch", w).time():
                     out = self.mapper(items)
@@ -1406,6 +1439,8 @@ class _FlatMapBatchRt(_OpRt):
                     out = list(out)
             except BaseException as ex:  # noqa: BLE001
                 _reraise(self.op.step_id, "the mapper", ex, self.mapper)
+            finally:
+                work.end()
             self.emit("down", (w, out))
 
 
@@ -2294,6 +2329,9 @@ class _StatefulBatchRt(_OpRt):
                             # toolchain.
                             touched = agg.update_items(items)
                         if touched is None:
+                            _flight.RECORDER.count(
+                                "items_fallback_rows", len(items)
+                            )
                             keys = []
                             values = []
                             for item in items:

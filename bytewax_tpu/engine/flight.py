@@ -133,6 +133,9 @@ TRACED_PHASES = frozenset(
         "startup",
         "teardown",
         "parse",
+        "read",
+        "item_ops",
+        "promote",
         "encode",
         "watermark",
         "prep",
@@ -1279,9 +1282,11 @@ class span:
     name, since the same step runs on the worker in a delivery and on
     the main thread at a notify or a close.
 
-    :meth:`begin` and :meth:`end` serve the two spans that are not
-    lexical (``startup``, ``teardown``); both do nothing the second
-    time, so the owner can end the span again where a fault unwinds.
+    :meth:`begin` and :meth:`end` serve the spans that are not
+    lexical (``startup``, ``teardown``) or not always wanted (a span
+    never begun ends as nothing); both do nothing the second time, so
+    the owner can end the span again where a fault unwinds.
+    :meth:`drop` ends a span unrecorded.
     """
 
     __slots__ = (
@@ -1320,8 +1325,8 @@ class span:
     def begin(self) -> "span":
         if self._frame is not None:
             return self
-        lane = self._lane = getattr(_tls, "lane", None)
-        stack = RECORDER._phase_stack if lane is None else lane.stack
+        self._lane = getattr(_tls, "lane", None)
+        stack = self._stack()
         if self.step_id == "*" and stack:
             # A span deep in a state object knows no step: it is the
             # enclosing span's (the lane's task, the step's drain).
@@ -1336,17 +1341,22 @@ class span:
         self._t0 = frame.t0 = time.monotonic()
         return self
 
-    def end(self) -> None:
+    def _stack(self) -> List[_Frame]:
+        lane = self._lane
+        return RECORDER._phase_stack if lane is None else lane.stack
+
+    def _leave(self) -> Optional[float]:
+        """Take the span off its lane's stack and out of the trace;
+        its gross seconds, or None where the span is not open."""
         t0 = self._t0
         if t0 is None:
-            return
+            return None
         self._t0 = None
         gross = time.monotonic() - t0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
-        lane, frame = self._lane, self._frame
-        stack = RECORDER._phase_stack if lane is None else lane.stack
+        frame, stack = self._frame, self._stack()
         if stack and stack[-1] is frame:
             stack.pop()
         else:
@@ -1356,6 +1366,13 @@ class span:
                 if stack[i] is frame:
                     del stack[i]
                     break
+        return gross
+
+    def end(self) -> None:
+        gross = self._leave()
+        if gross is None:
+            return
+        lane, frame = self._lane, self._frame
         seconds = max(gross - frame.nested, 0.0)
         name = (
             self.phase
@@ -1363,14 +1380,35 @@ class span:
             else lane.phase + "/" + self.phase
         )
         if lane is None or lane.spans is None:
-            note_phase(name, self.step_id, seconds, gross=gross, t0=t0)
+            note_phase(
+                name, self.step_id, seconds, gross=gross, t0=frame.t0
+            )
             _count_span(self.phase, self.rows)
             return
-        if stack:
-            stack[-1].nested += gross
+        if lane.stack:
+            lane.stack[-1].nested += gross
         lane.spans.append(
-            (name, self.step_id, seconds, gross, t0, self.phase, self.rows)
+            (
+                name,
+                self.step_id,
+                seconds,
+                gross,
+                frame.t0,
+                self.phase,
+                self.rows,
+            )
         )
+
+    def drop(self) -> None:
+        """End the span and record nothing (the interval turned out
+        not to be what the span is for: a ``read`` whose poll brought
+        columns, or nothing).  Its seconds stay the enclosing span's;
+        what its own children took still comes out of that one.
+        Does nothing to a span that is not open."""
+        if self._leave() is not None:
+            stack = self._stack()
+            if stack:
+                stack[-1].nested += self._frame.nested
 
 
 def _count_span(phase: str, rows: Optional[int]) -> None:
@@ -1459,6 +1497,9 @@ _FRACTION_BUCKETS = {
         "host",
         "readback",
         "parse",
+        "read",
+        "item_ops",
+        "promote",
         "encode",
         "watermark",
         "prep",

@@ -185,6 +185,39 @@ def test_begin_and_end_do_nothing_the_second_time(ledger):
     assert "teardown_spans" not in ledger()[1]
 
 
+@pytest.mark.parametrize("on_lane", [False, True])
+def test_a_dropped_span_records_nothing_and_its_children_still_come_out(ledger, on_lane):
+    """``drop``: as if the span had never been opened.  Its own time
+    stays the enclosing span's; what a child took inside it is still
+    taken out of that one."""
+
+    def work():
+        with flight.span("ingest", "t"):
+            read = flight.span("read", "t", rows=9).begin()
+            time.sleep(0.01)
+            with flight.span("parse", rows=4):
+                time.sleep(0.02)
+            read.drop()
+            read.drop()
+            read.end()  # dropped: nothing left to end
+
+    prefix = ""
+    if on_lane:
+        flight.lane_run("device", "t", work, inline=True)
+        prefix = "device/"
+    else:
+        work()
+    totals, counters = ledger()
+    assert "read_spans" not in counters and "read_rows" not in counters
+    assert prefix + "read" not in totals
+    assert totals[prefix + "parse"] >= 0.02 and counters["parse_rows"] == 4
+    assert 0.01 <= totals[prefix + "ingest"] < 0.02
+    assert REC._phase_stack == []
+    unbegun = flight.span("read")
+    unbegun.drop()
+    assert "read_spans" not in ledger()[1]
+
+
 def test_a_span_that_raises_still_ends(ledger):
     with pytest.raises(ValueError):
         with flight.span("host", "t"):
