@@ -846,7 +846,6 @@ KNOBS: Dict[str, Tuple[str, str]] = {
     "BYTEWAX_TPU_MAX_RESTARTS": ("0", "docs/recovery.md"),
     "BYTEWAX_TPU_PAD_MAX_POW": ("24", "docs/performance.md"),
     "BYTEWAX_TPU_PAD_MIN_POW": ("5", "docs/performance.md"),
-    "BYTEWAX_TPU_PALLAS": ("0", "docs/configuration.md"),
     "BYTEWAX_TPU_PIPELINE_DEPTH": ("2", "docs/performance.md"),
     "BYTEWAX_TPU_PLATFORM": ("", "docs/profiling.md"),
     "BYTEWAX_TPU_POSTMORTEM_DIR": ("", "docs/observability.md"),

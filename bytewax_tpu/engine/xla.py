@@ -31,9 +31,11 @@ from bytewax_tpu.engine.batching import pad_len
 from bytewax_tpu.ops.segment import (
     AGG_KINDS,
     AggKind,
+    fold_is_dense,
     identity_for,
     init_fields,
     reset_fields,
+    update_fields,
     update_fields_packed,
     update_fields_vocab,
 )
@@ -787,9 +789,15 @@ class DeviceAggState(_AggTable):
 
     # -- placement ---------------------------------------------------------
 
-    def _scatter(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
-        from bytewax_tpu.ops.pallas_fold import maybe_update_fields
+    def _note_fold(self, padded: int, ext_to_slot=None) -> None:
+        """Count a dispatch's padded rows by the form its program
+        takes."""
+        dense = fold_is_dense(self._fields, ext_to_slot)
+        _flight.RECORDER.count(
+            "fold_dense_rows" if dense else "fold_scatter_rows", padded
+        )
 
+    def _scatter(self, slot_ids: np.ndarray, values: np.ndarray) -> None:
         n = len(values)
         # Bucketed padding (engine/batching.py) so XLA sees few
         # distinct shapes; padding rows target the scratch slot
@@ -804,7 +812,8 @@ class DeviceAggState(_AggTable):
             slots_d = jax.device_put(slots_p)
             vals_d = jax.device_put(vals_p)
         with _flight.span("dispatch"):
-            self._fields = maybe_update_fields(
+            self._note_fold(padded)
+            self._fields = update_fields(
                 self.kind, self._fields, slots_d, vals_d
             )
 
@@ -847,6 +856,7 @@ class DeviceAggState(_AggTable):
                 packed_d = jax.device_put(packed)
                 scale_d = jnp.float32(scale)
             with _flight.span("dispatch"):
+                self._note_fold(padded, self._dev_map)
                 self._fields = update_fields_packed(
                     self.kind, self._fields, self._dev_map, packed_d, scale_d
                 )
@@ -861,6 +871,7 @@ class DeviceAggState(_AggTable):
             ids_d = jax.device_put(ids_p)
             vals_d = jax.device_put(vals_p)
         with _flight.span("dispatch"):
+            self._note_fold(padded, self._dev_map)
             self._fields = update_fields_vocab(
                 self.kind, self._fields, self._dev_map, ids_d, vals_d
             )

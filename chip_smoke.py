@@ -13,8 +13,9 @@ Stages: ``brc`` (generated 1BRC file, native parser, packed int16
 fold), ``windows`` (event-time tumbling stats + sliding counts with a
 recovery store), ``resume`` (the same store stopped mid-stream and
 resumed, exactly once), ``scan_infer`` (z-score scan, ``op.infer`` and
-session windows against the host tier), ``kernels`` (the Pallas fold,
-compiled, against the XLA scatter) and, with more than one device,
+session windows against the host tier), ``kernels`` (the fold's dense
+form, the Pallas kernel compiled, and its scatter through the entry
+points on both sides of the threshold) and, with more than one device,
 ``mesh`` (``brc`` and ``windows`` again over the local mesh).
 
 Prints one JSON object per stage and, as the last line of standard
@@ -64,10 +65,11 @@ class Sizes:
     scan_rows: int = 2_000_000
     scan_batch_rows: int = 1 << 18
     oracle_keys: int = 2_000
-    #: Pallas fold: rows and the slot-table capacities it is held to
-    #: (the engine's smallest table and the kernel's largest).
-    kernel_rows: int = 1_000_000
-    kernel_caps: Tuple[int, ...] = (1024, 4096)
+    #: The fold's two forms: rows a fold (a 16 MiB 1BRC chunk, padded)
+    #: and the slot-table capacities they are held to (the engine's
+    #: smallest table, the largest the dense form takes, and the next).
+    kernel_rows: int = 1 << 21
+    kernel_caps: Tuple[int, ...] = (1024, 8192, 16384)
 
     def scaled(self, factor: float) -> "Sizes":
         def cut(n: int, floor: int) -> int:
@@ -972,52 +974,114 @@ def stage_scan_infer(ctx: Ctx) -> Dict[str, Any]:
 
 
 def stage_kernels(ctx: Ctx) -> Dict[str, Any]:
-    """The Pallas fold, compiled for the chip, against the XLA
-    scatter on the same rows.  Values are multiples of 1/8 in a small
-    range, so every partial sum is exact in float32 and the two
-    summation orders must agree to the tolerance
-    tests/test_pallas_fold.py uses however many rows fold."""
+    """The fold's two forms through its jitted entry points, on both
+    sides of the threshold: a table up to ``DENSE_MAX_SLOTS`` takes
+    the dense reduce (the Pallas kernel, compiled), a larger one the
+    XLA scatter, and either way the answer is the scatter's on the
+    same rows.  Values are multiples of 1/8 in a small range, so
+    every partial sum is exact in float32 and the two summation
+    orders must agree to the tolerance tests/test_pallas_fold.py uses
+    however many rows fold.  The milliseconds are smoke times."""
     import jax
     import jax.numpy as jnp
 
     from bytewax_tpu.engine import flight
     from bytewax_tpu.ops import pallas_fold
-    from bytewax_tpu.ops.segment import AGG_KINDS, init_fields, update_fields
+    from bytewax_tpu.ops.segment import (
+        AGG_KINDS,
+        fold_is_dense,
+        init_fields,
+        scatter_fields,
+        update_fields,
+        update_fields_packed,
+    )
 
     interpreted = pallas_fold._interpret()
     require(
         ctx.allow_cpu or not interpreted,
         "kernels: the Pallas fold would run interpreted, not compiled",
     )
-    for cap in ctx.sizes.kernel_caps:
-        require(pallas_fold.fits(cap), f"kernels: fits({cap}) is False")
     flight.ensure_compile_listener()
     before = dict(flight.RECORDER.counters)
     kind = AGG_KINDS["stats"]
+    scatter = jax.jit(scatter_fields, static_argnames=("kind",))
     rng = np.random.default_rng(ctx.seed + 3)
     n = ctx.sizes.kernel_rows
-    values = (rng.integers(-512, 512, size=n) / 8).astype(np.float32)
-    t0 = time.perf_counter()
-    for cap in ctx.sizes.kernel_caps:
-        slots = rng.integers(0, cap - 1, size=n).astype(np.int32)
-        want = update_fields(
-            kind, init_fields(kind, cap), jnp.asarray(slots), jnp.asarray(values)
-        )
-        got = pallas_fold.update_fields_pallas(
-            kind, init_fields(kind, cap), jnp.asarray(slots), jnp.asarray(values)
-        )
-        jax.block_until_ready((want, got))
+
+    def timed_ms(fold, cap):
+        """The second call's wall time: the first compiled."""
+        got = None
+        for _ in range(2):
+            state = init_fields(kind, cap)
+            jax.block_until_ready(state)
+            t0 = time.perf_counter()
+            got = fold(state)
+            jax.block_until_ready(got)
+        return got, round((time.perf_counter() - t0) * 1e3, 3)
+
+    def same(got, want, what):
         for name in kind.fields:
             g, w = np.asarray(got[name]), np.asarray(want[name])
             if name == "sum":
                 ok = np.allclose(g, w, rtol=1e-5, atol=1e-5)
             else:
                 ok = np.array_equal(g, w)
-            require(ok, f"kernels: capacity {cap}, field {name} differs")
+            require(ok, f"kernels: {what}, field {name} differs")
+
+    t0 = time.perf_counter()
+    forms = []
+    for cap in ctx.sizes.kernel_caps:
+        dense = fold_is_dense(init_fields(kind, cap))
+        require(
+            dense == (cap <= pallas_fold.DENSE_MAX_SLOTS),
+            f"kernels: capacity {cap} takes the wrong form",
+        )
+        slots = jnp.asarray(rng.integers(0, cap - 1, size=n).astype(np.int32))
+        values = jnp.asarray(
+            (rng.integers(-512, 512, size=n) / 8).astype(np.float32)
+        )
+        want, scatter_ms = timed_ms(
+            lambda st: scatter(kind, st, slots, values), cap
+        )
+        got, entry_ms = timed_ms(
+            lambda st: update_fields(kind, st, slots, values), cap
+        )
+        same(got, want, f"capacity {cap}")
         require(
             int(np.asarray(got["count"]).sum()) == n,
             f"kernels: capacity {cap} lost rows",
         )
+        forms.append(
+            {
+                "capacity": cap,
+                "form": "dense" if dense else "scatter",
+                "entry_ms": entry_ms,
+                "scatter_ms": scatter_ms,
+            }
+        )
+    # The packed entry as a 1BRC chunk calls it: int16 ids through an
+    # id->slot table (the last entry is the padding's), deci-degrees.
+    cap, n_ext = ctx.sizes.kernel_caps[0], ctx.sizes.brc_stations + 2
+    table = np.full(n_ext, cap - 1, dtype=np.int32)
+    table[: n_ext - 1] = rng.permutation(n_ext - 1)
+    packed = np.stack(
+        [
+            rng.integers(0, n_ext - 1, size=n).astype(np.int16),
+            (8 * rng.integers(-100, 100, size=n)).astype(np.int16),
+        ]
+    )
+    table_d, packed_d = jnp.asarray(table), jnp.asarray(packed)
+    scale = jnp.float32(0.125)
+    slots = jnp.asarray(table[packed[0]])
+    values = jnp.asarray(packed[1].astype(np.float32) * np.float32(0.125))
+    want, _ms = timed_ms(lambda st: scatter(kind, st, slots, values), cap)
+    got, packed_ms = timed_ms(
+        lambda st: update_fields_packed(kind, st, table_d, packed_d, scale), cap
+    )
+    same(got, want, "the packed entry")
+    forms.append(
+        {"capacity": cap, "form": "dense, packed entry", "entry_ms": packed_ms}
+    )
     run = {
         "wall_s": time.perf_counter() - t0,
         "counters": _counter_deltas(before),
@@ -1026,8 +1090,10 @@ def stage_kernels(ctx: Ctx) -> Dict[str, Any]:
         ctx,
         "kernels",
         [run],
-        rows_in=n * len(ctx.sizes.kernel_caps),
+        rows_in=n * (len(ctx.sizes.kernel_caps) + 1),
+        rows_a_fold=n,
         capacities=list(ctx.sizes.kernel_caps),
+        forms_ms_not_a_benchmark=forms,
         pallas_interpreted=interpreted,
     )
 

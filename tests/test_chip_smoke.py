@@ -81,7 +81,10 @@ def test_stage_scan_infer(ctx):
 
 def test_stage_kernels(ctx):
     doc = chip_smoke.stage_kernels(ctx)
-    assert doc["ok"] and doc["capacities"] == [1024, 4096]
+    assert doc["ok"] and doc["capacities"] == [1024, 8192, 16384]
+    assert [f["form"] for f in doc["forms_ms_not_a_benchmark"]] == [
+        "dense", "dense", "scatter", "dense, packed entry"
+    ]
     # Only the waiver lets an interpreted kernel through.
     assert doc["pallas_interpreted"]
     ctx.allow_cpu = False

@@ -552,7 +552,7 @@ def test_shared_state_inventory_is_pinned():
 
 
 def test_knob_catalog_is_pinned():
-    """The knob inventory: exactly today's 58 BYTEWAX_TPU_* knobs,
+    """The knob inventory: exactly today's 57 BYTEWAX_TPU_* knobs,
     each with a default and a doc anchor.  Adding a knob requires
     updating contracts.KNOBS, this list, docs/configuration.md, and
     the anchor doc — BTX-KNOB enforces the rest (literal reads,
@@ -635,7 +635,6 @@ def test_knob_catalog_is_pinned():
         "BYTEWAX_TPU_MAX_RESTARTS",
         "BYTEWAX_TPU_PAD_MAX_POW",
         "BYTEWAX_TPU_PAD_MIN_POW",
-        "BYTEWAX_TPU_PALLAS",
         "BYTEWAX_TPU_PIPELINE_DEPTH",
         "BYTEWAX_TPU_PLATFORM",
         "BYTEWAX_TPU_POSTMORTEM_DIR",
@@ -653,7 +652,7 @@ def test_knob_catalog_is_pinned():
         "BYTEWAX_TPU_TRACE_DIR",
         "BYTEWAX_TPU_WIRE",
     ]
-    assert len(contracts.KNOBS) == 58
+    assert len(contracts.KNOBS) == 57
     for name, (default, doc) in contracts.KNOBS.items():
         assert isinstance(default, str), name
         assert doc.startswith("docs/") and doc.endswith(".md"), name
