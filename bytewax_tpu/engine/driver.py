@@ -718,7 +718,9 @@ def _supervised(
             # Ledger: "startup" runs from here (flatten, plan, the
             # driver built) to the first pass of the run loop, where
             # the driver ends it; "teardown" from the loop's exit to
-            # the return.  Both are ended here, whatever unwinds.
+            # the return.  Both are ended here, whatever unwinds, and
+            # the run's wall clock (``run_wall_seconds``) spans both.
+            _flight.note_run_wall()
             lifecycle = (
                 _flight.span("startup").begin(),
                 _flight.span("teardown"),
@@ -729,6 +731,7 @@ def _supervised(
                 finally:
                     for sp in lifecycle:
                         sp.end()
+                    _flight.note_run_wall(stop=True)
                 if isinstance(result, _Reconfigure):
                     if proc_id >= max(len(result.addresses), 1):
                         # This process retires: the agreed close
@@ -898,24 +901,43 @@ class _OpRt:
         # evictions, readbacks, the work spans) subtract so the sums
         # stay disjoint.
         with _flight.span("host", self.op.step_id):
-            for port, q in self.queues.items():
-                if q:
-                    entries, self.queues[port] = q, []
-                    for w, items in entries:
-                        self._count_inp(w, len(items))
-                    if self.driver.trace_ops:
-                        # Per-activation spans, like the reference's
-                        # debug_span!("operator") (src/operators.rs:184) —
-                        # only when a backend/DEBUG logging wants them.
-                        with _span(
-                            "operator",
-                            step_id=self.op.step_id,
-                            port=port,
-                            entries=len(entries),
-                        ):
-                            self.process(port, entries)
-                    else:
+            # By key: an items() iterator would keep the drained
+            # list alive until its next step.
+            for port in self.queues:
+                entries = self.queues[port]
+                if not entries:
+                    continue
+                self.queues[port] = []
+                listed = self._count_entries(entries)
+                if self.driver.trace_ops:
+                    # Per-activation spans, like the reference's
+                    # debug_span!("operator") (src/operators.rs:184) —
+                    # only when a backend/DEBUG logging wants them.
+                    with _span(
+                        "operator",
+                        step_id=self.op.step_id,
+                        port=port,
+                        entries=len(entries),
+                    ):
                         self.process(port, entries)
+                else:
+                    self.process(port, entries)
+                if listed:
+                    # Ledger: `free` is the delivery's item lists let
+                    # go, where this step held the last reference: some
+                    # 10^4 objects a delivery, freed one by one.
+                    with _flight.span("free", self.op.step_id, listed):
+                        del entries
+
+    def _count_entries(self, entries: List[Entry]) -> int:
+        """Count a drained queue's deliveries in; the items among them
+        that came as lists (what ``free`` lets go)."""
+        listed = 0
+        for w, items in entries:
+            self._count_inp(w, len(items))
+            if type(items) is list:
+                listed += len(items)
+        return listed
 
     def process(self, port: str, entries: List[Entry]) -> None:
         raise NotImplementedError()
@@ -2090,21 +2112,25 @@ class _StatefulBatchRt(_OpRt):
         for _w, items in entries:
             if isinstance(items, ArrayBatch):
                 items = items.to_pylist()
-            groups: Optional[Dict[str, List[Any]]] = None
-            if type(items) is list:
-                try:
-                    # Native one-pass grouping (None when no toolchain).
-                    groups = _native_group_kv(items)
-                except TypeError:
-                    # Rows that are not exact str-keyed 2-tuples take
-                    # the general loop for its permissive unpacking
-                    # and step-qualified errors.
-                    groups = None
-            if groups is None:
-                groups = {}
-                for item in items:
-                    k, v = _extract_kv(item, self.op.step_id)
-                    groups.setdefault(k, []).append(v)
+            # Ledger: `group` is a delivery's items gathered by key
+            # for the host tier's logics.
+            with _flight.span("group", self.op.step_id, rows=len(items)):
+                groups: Optional[Dict[str, List[Any]]] = None
+                if type(items) is list:
+                    try:
+                        # Native one-pass grouping (None when no
+                        # toolchain).
+                        groups = _native_group_kv(items)
+                    except TypeError:
+                        # Rows that are not exact str-keyed 2-tuples
+                        # take the general loop for its permissive
+                        # unpacking and step-qualified errors.
+                        groups = None
+                if groups is None:
+                    groups = {}
+                    for item in items:
+                        k, v = _extract_kv(item, self.op.step_id)
+                        groups.setdefault(k, []).append(v)
             # Ledger: `logic` is the step's per-key logic calls of
             # one delivery (build, `on_batch`, reschedule).
             with _flight.span("logic", self.op.step_id, rows=len(items)):
@@ -3728,13 +3754,17 @@ class _Driver:
             # limited so epoch_interval=0 flows don't collect per
             # batch.  Plain refcounting still frees the (acyclic)
             # item churn immediately.
-            import gc
-            import time as _time
+            if time.monotonic() - self._last_gc >= 1.0:
+                self._collect()
 
-            now_m = _time.monotonic()
-            if now_m - self._last_gc >= 1.0:
-                gc.collect()
-                self._last_gc = _time.monotonic()
+    def _collect(self) -> None:
+        """One full collection at a point the engine chose, as the
+        work span ``gc`` (rows: the unreachable objects found)."""
+        import gc
+
+        with _flight.span("gc") as sp:
+            sp.rows = gc.collect()
+        self._last_gc = time.monotonic()
 
     def _flowmap_close(self, closing: int) -> None:
         """Sample the close-time flow-map gauges (device-resident
@@ -4776,6 +4806,7 @@ class _Driver:
                         lambda: dict(_flight.RECORDER.phase_totals), {}
                     ).items()
                 },
+                "phase_cpu_totals": _flight.phase_cpu_totals(),
                 "phase_fractions": _flight.ledger_fractions(),
                 "lag": _flight.RECORDER.ledger_lag(),
             },
@@ -5063,6 +5094,7 @@ class _Driver:
                 if startup is not None:
                     startup.end()
                     startup = None
+                _flight.note_run_wall()
                 self._progressed = False
                 now = _now()
 
@@ -5220,12 +5252,8 @@ class _Driver:
                     # (embedding hosts and other threads still make
                     # cyclic garbage): collect on a flat 10s wall
                     # clock between closes.
-                    now_m = time.monotonic()
-                    if now_m - self._last_gc >= 10.0:
-                        import gc as _gc
-
-                        _gc.collect()
-                        self._last_gc = time.monotonic()
+                    if time.monotonic() - self._last_gc >= 10.0:
+                        self._collect()
 
                 if not self._progressed:
                     waits = []
@@ -5260,7 +5288,15 @@ class _Driver:
                         if wait > 0 and self._pending_close is None:
                             self._pump(timeout=wait)
                     elif wait > 0:
+                        # Ledger: a pass with nothing to do waits for
+                        # input, a timer or a lane as `idle`, from its
+                        # duration (no interval: a thousand a second
+                        # would crowd the epoch's Perfetto dump).
+                        t_idle = time.monotonic()
                         time.sleep(wait)
+                        _flight.note_phase(
+                            "idle", "*", time.monotonic() - t_idle
+                        )
             # Clean exit (EOF, agreed stop, agreed reconfigure): the
             # final close's snapshot commit may still be riding the
             # committer lane — land it before teardown so the next

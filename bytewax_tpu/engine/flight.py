@@ -26,10 +26,16 @@ epoch protocol.  It keeps, per process:
   on each lane.  The main thread is one lane; a pipeline worker's
   task is another (:func:`lane_run`), recorded as ``device`` (its
   self time) and ``device/<phase>`` (its children), which overlap the
-  main thread's phases by design.  The work spans
+  main thread's phases by design.  A span also reads its thread's
+  CPU clock: the ``cpu:<phase>`` counters hold the same exclusive
+  seconds of CPU, and wall less CPU is what the thread spent
+  waiting for the interpreter, pre-empted, or blocked in the
+  runtime.  ``run_wall_seconds`` (:func:`note_run_wall`) is the wall
+  clock of the runs themselves: what the main thread's phases leave
+  of it ran under no span.  The work spans
   (:data:`TRACED_PHASES`) also enter the profiler's trace as
   ``btx.<phase>``.  Callers that hold a duration already (a stall, a
-  barrier, a sync round) use :func:`note_phase`.
+  barrier, a sync round) use :func:`note_phase`, and record no CPU.
   ``note_epoch_close`` seals
   the accumulating ledger into a per-epoch record carrying the
   full-epoch phase breakdown, the close-window breakdown (whose sum
@@ -38,10 +44,11 @@ epoch protocol.  It keeps, per process:
   epoch-close gsync piggyback, the rescale hint, and — with ``BYTEWAX_TPU_TRACE_DIR`` set — a
   Chrome/Perfetto ``trace_event`` JSON dump per completed epoch.
 
-XLA compiles are observed via ``jax.monitoring`` duration events
-(:func:`ensure_compile_listener`), so every jit in the engine —
-segment folds, window scans, the sharded exchange — is counted without
-per-call-site plumbing.
+Every stage of a program's way to the chip (trace, lowering, then a
+backend compile or a load from the persistent cache) is observed via
+``jax.monitoring`` duration events (:func:`ensure_compile_listener`),
+so every jit in the engine — segment folds, window scans, the sharded
+exchange — is counted, by function, without per-call-site plumbing.
 
 Thread-safety note: counters are GIL-atomic dict updates read racily
 by the API server thread; they are observability data, not an epoch
@@ -84,6 +91,7 @@ __all__ = [
     "note_reconfigure_requested",
     "note_rescale",
     "note_resident",
+    "note_run_wall",
     "note_residency_restore",
     "note_restart",
     "note_snapshot_lag",
@@ -93,6 +101,7 @@ __all__ = [
     "note_transfer",
     "note_unquarantine",
     "note_wire",
+    "phase_cpu_totals",
     "span",
     "wire_status",
     "write_postmortem",
@@ -132,12 +141,14 @@ TRACED_PHASES = frozenset(
     {
         "startup",
         "teardown",
+        "gc",
         "parse",
         "read",
         "item_ops",
         "promote",
         "encode",
         "watermark",
+        "touch",
         "prep",
         "exchange",
         "h2d",
@@ -147,8 +158,10 @@ TRACED_PHASES = frozenset(
         "close_emit",
         "retire",
         "emit",
+        "group",
         "logic",
         "sink",
+        "free",
     }
 )
 
@@ -176,17 +189,54 @@ def enabled() -> bool:
     )
 
 
+def _cpu_reuse_s() -> float:
+    """For how long a reading of a thread's CPU clock is used again,
+    on the reckoning that the thread ran meanwhile: forty times what
+    a read costs here, so that the reads take at most a fortieth of a
+    thread's time whatever the host.  ``time.thread_time()`` is a
+    system call: 0.4 us on a plain kernel (15 us of reuse), 6 us on
+    the chip's host (PERF.md §6, PR 35: 250 us), and spans end and
+    begin in clusters."""
+    costs = []
+    for _ in range(9):
+        t0 = time.monotonic()
+        time.thread_time()
+        costs.append(time.monotonic() - t0)
+    # The median: one read pre-empted, or one answered from a fast
+    # path, must not set the window.
+    return min(max(40.0 * sorted(costs)[4], 10e-6), 500e-6)
+
+
+_CPU_REUSE_S = _cpu_reuse_s()
+
+
+class _CpuClock:
+    """One thread's CPU clock as last read: ``cpu`` seconds at
+    ``wall`` (monotonic).  It lives where the thread's phase stack
+    does: on the recorder for the main thread, on the lane for a
+    worker's task."""
+
+    __slots__ = ("wall", "cpu")
+
+    def __init__(self) -> None:
+        self.wall = -1.0
+        self.cpu = 0.0
+
+
 class _Frame:
     """An open span's place on its lane's phase stack."""
 
-    __slots__ = ("nested", "step_id", "t0")
+    __slots__ = ("nested", "nested_cpu", "step_id", "t0", "c0")
 
     def __init__(self, step_id: str):
         #: Gross seconds of the spans that ended while this one was
-        #: open: what its exclusive time leaves out.
+        #: open: what its exclusive time leaves out.  ``nested_cpu``
+        #: is the same of their thread's CPU seconds.
         self.nested = 0.0
+        self.nested_cpu = 0.0
         self.step_id = step_id
         self.t0 = 0.0
+        self.c0 = 0.0
 
 
 class FlightRecorder:
@@ -215,6 +265,8 @@ class FlightRecorder:
         #: frame of each open span, so the parent records exclusive
         #: time.
         self._phase_stack: List[_Frame] = []
+        #: The main thread's CPU clock as the spans last read it.
+        self._clock = _CpuClock()
         #: Max pending tasks observed at each step's pipeline drain.
         self._flush_depth: Dict[str, int] = {}
         #: (step_id, kind) -> latest source-lag sample in seconds.
@@ -226,6 +278,9 @@ class FlightRecorder:
         self.last_ledger: Optional[Dict[str, Any]] = None
         self._ledgers: deque = deque(maxlen=_LEDGER_BUF)
         self._epoch_t0 = time.monotonic()
+        #: Up to when ``counters["run_wall_seconds"]`` has counted the
+        #: run in progress (:func:`note_run_wall`); None between runs.
+        self._run_t: Optional[float] = None
         self.trace_dir = (
             os.environ.get("BYTEWAX_TPU_TRACE_DIR", "").strip() or None
         )
@@ -1219,9 +1274,7 @@ def note_phase(
                 key, epoch_phase_seconds.labels(phase, step_id)
             )
     child.inc(seconds)
-    RECORDER.ledger_add(
-        phase, step_id, seconds, gross=gross, t0=t0, lane=lane
-    )
+    RECORDER.ledger_add(phase, step_id, seconds, gross, t0, lane)
 
 
 # -- the span primitive ----------------------------------------------------
@@ -1255,13 +1308,14 @@ class _Lane:
     and records at once (``spans`` is None), under the lane's name
     all the same."""
 
-    __slots__ = ("phase", "stack", "spans")
+    __slots__ = ("phase", "stack", "clock", "spans")
 
     def __init__(self, phase: str, inline: bool):
         self.phase = phase
         self.stack: List[_Frame] = (
             RECORDER._phase_stack if inline else []
         )
+        self.clock = RECORDER._clock if inline else _CpuClock()
         self.spans: Optional[List[tuple]] = None if inline else []
 
 
@@ -1274,13 +1328,18 @@ class span:
     children's gross time).  Adds 1 to ``counters["<phase>_spans"]``
     and ``rows`` (settable until the span ends) to
     ``counters["<phase>_rows"]``; both repeat exactly for a given
-    input.  A work span (:data:`TRACED_PHASES`) also enters
+    input.  Its thread's CPU seconds (``time.thread_time()``, read
+    where the wall clock is unless the thread's last reading is under
+    :data:`_CPU_REUSE_S` old), exclusive in the same way, go to
+    ``counters["cpu:<ledger phase>"]``.  A work span
+    (:data:`TRACED_PHASES`) also enters
     ``jax.profiler.TraceAnnotation("btx.<phase>", step_id=...)`` on
     the thread that does the work, so a profiler session holds it on
     the device trace's clock.  On a lane (:func:`lane_run`) the span
-    is recorded as ``<lane>/<phase>``; the counters keep the bare
-    name, since the same step runs on the worker in a delivery and on
-    the main thread at a notify or a close.
+    is recorded as ``<lane>/<phase>``; the ``_spans`` and ``_rows``
+    counters keep the bare name, since the same step runs on the
+    worker in a delivery and on the main thread at a notify or a
+    close.
 
     :meth:`begin` and :meth:`end` serve the spans that are not
     lexical (``startup``, ``teardown``) or not always wanted (a span
@@ -1315,18 +1374,14 @@ class span:
         self._t0: Optional[float] = None
         self._ann: Any = None
 
-    def __enter__(self) -> "span":
-        self.begin()
-        return self
-
-    def __exit__(self, *_exc: Any) -> None:
-        self.end()
-
     def begin(self) -> "span":
         if self._frame is not None:
             return self
-        self._lane = getattr(_tls, "lane", None)
-        stack = self._stack()
+        lane = self._lane = getattr(_tls, "lane", None)
+        if lane is None:
+            stack, clock = RECORDER._phase_stack, RECORDER._clock
+        else:
+            stack, clock = lane.stack, lane.clock
         if self.step_id == "*" and stack:
             # A span deep in a state object knows no step: it is the
             # enclosing span's (the lane's task, the step's drain).
@@ -1334,29 +1389,50 @@ class span:
         frame = self._frame = _Frame(self.step_id)
         stack.append(frame)
         if self.phase in TRACED_PHASES:
-            ann = self._ann = _trace_annotation()(
-                "btx." + self.phase, step_id=self.step_id
-            )
-            ann.__enter__()
-        self._t0 = frame.t0 = time.monotonic()
+            annotation = _trace_annotation()
+            # Only while a profiler session runs: a span that began
+            # before one is not in its trace, and outside one the
+            # annotation is a tenth of the span's cost for nothing.
+            if annotation.is_enabled():
+                ann = self._ann = annotation(
+                    "btx." + self.phase, step_id=self.step_id
+                )
+                ann.__enter__()
+        now = time.monotonic()
+        if now - clock.wall < _CPU_REUSE_S:
+            frame.c0 = clock.cpu + (now - clock.wall)
+        else:
+            # The read's own time is the enclosing span's, like the
+            # annotation's: the span's wall clock starts after it.
+            frame.c0 = clock.cpu = time.thread_time()
+            now = clock.wall = time.monotonic()
+        self._t0 = frame.t0 = now
         return self
 
-    def _stack(self) -> List[_Frame]:
-        lane = self._lane
-        return RECORDER._phase_stack if lane is None else lane.stack
-
-    def _leave(self) -> Optional[float]:
+    def _leave(self) -> Optional[Tuple[float, float, List[_Frame]]]:
         """Take the span off its lane's stack and out of the trace;
-        its gross seconds, or None where the span is not open."""
+        its gross wall and CPU seconds and the stack it was on, or
+        None where the span is not open."""
         t0 = self._t0
         if t0 is None:
             return None
         self._t0 = None
-        gross = time.monotonic() - t0
+        now = time.monotonic()
+        gross = now - t0
+        lane, frame = self._lane, self._frame
+        if lane is None:
+            stack, clock = RECORDER._phase_stack, RECORDER._clock
+        else:
+            stack, clock = lane.stack, lane.clock
+        if now - clock.wall < _CPU_REUSE_S:
+            cpu_gross = clock.cpu + (now - clock.wall) - frame.c0
+        else:
+            clock.cpu = time.thread_time()
+            clock.wall = time.monotonic()
+            cpu_gross = clock.cpu - frame.c0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
-        frame, stack = self._frame, self._stack()
         if stack and stack[-1] is frame:
             stack.pop()
         else:
@@ -1366,27 +1442,35 @@ class span:
                 if stack[i] is frame:
                     del stack[i]
                     break
-        return gross
+        return gross, cpu_gross, stack
 
-    def end(self) -> None:
-        gross = self._leave()
-        if gross is None:
+    def end(self, *_exc: Any) -> None:
+        left = self._leave()
+        if left is None:
             return
+        gross, cpu_gross, stack = left
         lane, frame = self._lane, self._frame
-        seconds = max(gross - frame.nested, 0.0)
+        seconds = gross - frame.nested
+        if seconds < 0.0:
+            seconds = 0.0
+        cpu = cpu_gross - frame.nested_cpu
+        if cpu < 0.0:
+            cpu = 0.0
         name = (
             self.phase
             if lane is None or self._root
             else lane.phase + "/" + self.phase
         )
+        if stack:
+            # The enclosing frame leaves this span's CPU out, as it
+            # does its wall seconds (``ledger_add``, or just below).
+            stack[-1].nested_cpu += cpu_gross
         if lane is None or lane.spans is None:
-            note_phase(
-                name, self.step_id, seconds, gross=gross, t0=frame.t0
-            )
-            _count_span(self.phase, self.rows)
+            note_phase(name, self.step_id, seconds, gross, frame.t0)
+            _count_span(name, self.phase, self.rows, cpu)
             return
-        if lane.stack:
-            lane.stack[-1].nested += gross
+        if stack:
+            stack[-1].nested += gross
         lane.spans.append(
             (
                 name,
@@ -1396,6 +1480,7 @@ class span:
                 frame.t0,
                 self.phase,
                 self.rows,
+                cpu,
             )
         )
 
@@ -1405,19 +1490,42 @@ class span:
         columns, or nothing).  Its seconds stay the enclosing span's;
         what its own children took still comes out of that one.
         Does nothing to a span that is not open."""
-        if self._leave() is not None:
-            stack = self._stack()
+        left = self._leave()
+        if left is not None:
+            _gross, _cpu_gross, stack = left
             if stack:
                 stack[-1].nested += self._frame.nested
+                stack[-1].nested_cpu += self._frame.nested_cpu
+
+    #: ``with span(...):`` is ``begin()`` and ``end()``.
+    __enter__ = begin
+    __exit__ = end
 
 
-def _count_span(phase: str, rows: Optional[int]) -> None:
+#: ledger phase -> its counters' keys (built once a name).
+_span_keys: Dict[str, Tuple[str, str, str]] = {}
+
+
+def _count_span(
+    name: str, phase: str, rows: Optional[int], cpu: float
+) -> None:
+    """One recorded span's counters: its thread's exclusive CPU
+    seconds under ``cpu:<ledger phase>`` (``name``: the key
+    ``phase_totals`` uses), the span and its rows under the bare
+    phase."""
+    keys = _span_keys.get(name)
+    if keys is None:
+        keys = _span_keys[name] = (
+            "cpu:" + name,
+            phase + "_spans",
+            phase + "_rows",
+        )
+    cpu_key, spans_key, rows_key = keys
     counters = RECORDER.counters
-    key = phase + "_spans"
-    counters[key] = counters.get(key, 0) + 1
+    counters[cpu_key] = counters.get(cpu_key, 0.0) + cpu
+    counters[spans_key] = counters.get(spans_key, 0) + 1
     if rows is not None:
-        key = phase + "_rows"
-        counters[key] = counters.get(key, 0) + rows
+        counters[rows_key] = counters.get(rows_key, 0) + rows
 
 
 def lane_run(
@@ -1453,9 +1561,27 @@ def lane_fold(spans: List[tuple]) -> None:
     """Main thread, at finalize: fold one worker task's spans into
     the ledger with the worker's own timing.  They overlap the main
     thread's phases and charge no frame of its stack."""
-    for name, step_id, seconds, gross, t0, phase, rows in spans:
-        note_phase(name, step_id, seconds, gross=gross, t0=t0, lane=1)
-        _count_span(phase, rows)
+    for name, step_id, seconds, gross, t0, phase, rows, cpu in spans:
+        note_phase(name, step_id, seconds, gross, t0, 1)
+        _count_span(name, phase, rows, cpu)
+
+
+def note_run_wall(stop: bool = False) -> None:
+    """Advance ``counters["run_wall_seconds"]``, the wall seconds of
+    this process's runs from ``startup``'s begin to ``teardown``'s
+    end, to now.  The first call of a run starts its clock, the run
+    loop calls once a pass (so a reader that samples the counters in
+    mid-run has the run's seconds to within a pass), ``stop`` ends
+    it.  What the main thread's ledger phases (``idle``, the loop's
+    own wait, among them) do not cover of it ran under no phase at
+    all."""
+    rec = RECORDER
+    now = time.monotonic()
+    if rec._run_t is not None:
+        rec.counters["run_wall_seconds"] = (
+            rec.counters.get("run_wall_seconds", 0.0) + now - rec._run_t
+        )
+    rec._run_t = None if stop else now
 
 
 def note_source_lag(step_id: str, kind: str, seconds: float) -> None:
@@ -1502,6 +1628,7 @@ _FRACTION_BUCKETS = {
         "promote",
         "encode",
         "watermark",
+        "touch",
         "prep",
         "exchange",
         "h2d",
@@ -1511,8 +1638,10 @@ _FRACTION_BUCKETS = {
         "close_emit",
         "retire",
         "emit",
+        "group",
         "logic",
         "sink",
+        "free",
     ),
     "device": ("device",),
     "flush": ("flush", "close_flush"),
@@ -1551,6 +1680,20 @@ def ledger_fractions(
     if denom <= 0:
         return None
     return {k: round(v / denom, 4) for k, v in buckets.items()}
+
+
+def phase_cpu_totals() -> Dict[str, float]:
+    """Lifetime CPU seconds by ledger phase, for ``GET /status``
+    beside ``phase_totals``: the ``cpu:<phase>`` counters the spans
+    write, not a second store.  A phase's wall seconds less these are
+    its off-CPU seconds: its thread waited for the interpreter, was
+    pre-empted, or blocked in the runtime."""
+    counters = RECORDER._copied(lambda: dict(RECORDER.counters), {})
+    return {
+        k[4:]: round(v, 6)
+        for k, v in counters.items()
+        if k.startswith("cpu:")
+    }
 
 
 def write_postmortem(
@@ -1603,16 +1746,54 @@ def write_postmortem(
 
 
 _compile_listener_on = False
+#: Functions ``jit_stage_seconds[<fun_name>]`` names before the rest
+#: go under ``jit_stage_seconds[other]``.
+_JIT_NAMES_CAP = 64
+#: A trace shorter than this leaves no ring event: a cold process
+#: traces some hundreds of eager operations in under a millisecond
+#: each, and the ring is for the events around a fault.
+_TRACE_RING_FLOOR_S = 1e-3
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+#: The functions named so far.
+_jit_names: set = set()
+
+
+def _note_jit_stage(fun_name: Any, secs: float) -> None:
+    """``secs`` of one stage of ``fun_name``'s way to the chip, by
+    function (jax names a lowered module ``jit(<function>)``)."""
+    fun = str(fun_name or "?")
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]
+    if fun not in _jit_names:
+        if len(_jit_names) >= _JIT_NAMES_CAP:
+            fun = "other"
+        else:
+            _jit_names.add(fun)
+    RECORDER.count(f"jit_stage_seconds[{fun}]", secs)
 
 
 def ensure_compile_listener() -> None:
     """Register ``jax.monitoring`` listeners (once per process) that
-    count backend compiles and their seconds.  Safe to call before
-    any backend is up — ``jax.monitoring`` imports without
-    initializing devices.  A program loaded from the persistent
-    compilation cache is not a compile: jax reports the cache hit
-    just before the duration event of the same request, on the same
-    thread, and that duration (the load) is left out."""
+    count every stage of a program's way to the chip, with jax's own
+    durations: the trace (``jit_trace_count`` / ``jit_trace_seconds``:
+    a new ``jax.jit`` object, or a new signature of an old one), the
+    lowering (``jit_lower_seconds``), and then either a backend
+    compile (``xla_compile_count`` / ``xla_compile_seconds``) or a
+    load from the persistent compilation cache
+    (``xla_cache_load_count`` / ``xla_cache_load_seconds``); all of
+    them by function under ``jit_stage_seconds[<fun_name>]``.  Safe
+    to call before any backend is up — ``jax.monitoring`` imports
+    without initializing devices.  A program loaded from the cache is
+    not a compile: jax reports the cache hit just before the duration
+    event of the same request, on the same thread, and that duration
+    is the load.  A function traced inside another's trace reports
+    its own duration inside the outer one: the outer's seconds leave
+    it out (jax marks a trace's start with a scalar of the same
+    name), so the seconds add up to wall time."""
     global _compile_listener_on
     if _compile_listener_on:
         return
@@ -1620,24 +1801,51 @@ def ensure_compile_listener() -> None:
 
     from bytewax_tpu._metrics import xla_compile_count, xla_compile_seconds
 
-    cache_hit = threading.local()
+    tls = threading.local()
 
     def _on_event(name: str, **_kw: Any) -> None:
         if name.endswith("compilation_cache/cache_hits"):
-            cache_hit.pending = True
+            tls.cache_hit = True
 
-    def _on_duration(name: str, secs: float, **_kw: Any) -> None:
-        if not name.endswith("backend_compile_duration"):
+    def _on_scalar(name: str, _value: Any, **_kw: Any) -> None:
+        if name == _TRACE_EVENT:
+            # A trace begins: its frame gathers the traces inside it.
+            tls.__dict__.setdefault("traces", []).append(0.0)
+
+    def _on_duration(name: str, secs: float, **kw: Any) -> None:
+        fun = kw.get("fun_name")
+        if name == _TRACE_EVENT:
+            traces = getattr(tls, "traces", None)
+            inner = traces.pop() if traces else 0.0
+            if traces:
+                traces[-1] += secs
+            secs = max(secs - inner, 0.0)
+            RECORDER.count("jit_trace_count")
+            RECORDER.count("jit_trace_seconds", secs)
+            if not traces and secs >= _TRACE_RING_FLOOR_S:
+                # One ring event a program traced, as a compile has;
+                # the functions traced inside it, and an eager
+                # operation's first call, stay counters.
+                RECORDER.record(
+                    "jit_trace", fun=str(fun), seconds=round(secs, 6)
+                )
+        elif name == _LOWER_EVENT:
+            RECORDER.count("jit_lower_seconds", secs)
+        elif name != _COMPILE_EVENT:
             return
-        if getattr(cache_hit, "pending", False):
-            cache_hit.pending = False
-            return
-        xla_compile_count.inc()
-        xla_compile_seconds.inc(secs)
-        RECORDER.count("xla_compile_count")
-        RECORDER.count("xla_compile_seconds", secs)
-        RECORDER.record("xla_compile", seconds=round(secs, 6))
+        elif getattr(tls, "cache_hit", False):
+            tls.cache_hit = False
+            RECORDER.count("xla_cache_load_count")
+            RECORDER.count("xla_cache_load_seconds", secs)
+        else:
+            xla_compile_count.inc()
+            xla_compile_seconds.inc(secs)
+            RECORDER.count("xla_compile_count")
+            RECORDER.count("xla_compile_seconds", secs)
+            RECORDER.record("xla_compile", seconds=round(secs, 6))
+        _note_jit_stage(fun, secs)
 
     monitoring.register_event_listener(_on_event)
+    monitoring.register_scalar_listener(_on_scalar)
     monitoring.register_event_duration_secs_listener(_on_duration)
     _compile_listener_on = True

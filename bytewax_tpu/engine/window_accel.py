@@ -783,7 +783,12 @@ class DeviceWindowAggState:
                 self.sys_at_base[moved] = now_us
             late_mask = ts_us < wm_rows
         any_late = bool(late_mask.any())
-        self.touched.update(map(self.keys.__getitem__, seg_kids.tolist()))
+        # Ledger: `touch` is the delivery's keys noted for the epoch's
+        # close (one set update a key, by name).
+        with _flight.span("touch", rows=len(seg_kids)):
+            self.touched.update(
+                map(self.keys.__getitem__, seg_kids.tolist())
+            )
 
         events: List[Tuple[str, Tuple[int, str, Any]]] = []
         kids_ok = ts_ok = vals_ok = None
