@@ -18,6 +18,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from bytewax_tpu.engine import flight as _flight
+
 __all__ = [
     "BrcParser",
     "any_isinstance",
@@ -283,15 +285,20 @@ def _configure(cdll: ctypes.CDLL) -> None:
         ctypes.c_int32,
     ]
     cdll.brc_vocab_get.restype = ctypes.c_int32
-    cdll.last_line_end.argtypes = [ctypes.c_char_p, ctypes.c_int64]
+    # Text buffers go in by address (`_address`): a `bytes`, or a
+    # buffer the caller reuses.
+    cdll.last_line_end.argtypes = [ctypes.c_void_p, ctypes.c_int64]
     cdll.last_line_end.restype = ctypes.c_int64
+    cdll.count_newlines.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    cdll.count_newlines.restype = ctypes.c_int64
     cdll.brc_parse_chunk.argtypes = [
         ctypes.c_void_p,
-        ctypes.c_char_p,
+        ctypes.c_void_p,
         ctypes.c_int64,
-        ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int16),
+        ctypes.c_void_p,
+        ctypes.c_void_p,
         ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64),
     ]
     cdll.brc_parse_chunk.restype = ctypes.c_int64
     cdll.line_offsets.argtypes = [
@@ -322,6 +329,18 @@ def _configure(cdll: ctypes.CDLL) -> None:
     cdll.wc_tokenize.restype = ctypes.c_int64
 
 
+def _address(buf, length: Optional[int]):
+    """``(address, length)`` of a contiguous buffer's first ``length``
+    bytes for a native call; the caller keeps ``buf`` alive over it."""
+    view = np.frombuffer(buf, dtype=np.uint8)
+    if length is None:
+        length = len(view)
+    elif not 0 <= length <= len(view):
+        msg = f"length {length} outside a buffer of {len(view)} bytes"
+        raise ValueError(msg)
+    return view.ctypes.data, length
+
+
 class BrcParser:
     """Streaming 1BRC text parser: bytes in, dictionary-encoded
     ``(key_id int32, deci-degrees int16)`` columns out.
@@ -341,24 +360,36 @@ class BrcParser:
             self._cdll.brc_parser_free(parser)
             self._parser = None
 
-    def parse(self, chunk: bytes):
-        """Parse a chunk ending on a line boundary; returns
-        ``(ids int32[n], temps int16[n])``."""
-        # Worst-case rows: one per 5 bytes ("a;0\n" minimum ~4).
-        cap = len(chunk) // 4 + 1
+    def parse(self, chunk, length: Optional[int] = None):
+        """Parse the first ``length`` bytes of ``chunk`` (all of it by
+        default; ``bytes`` or any contiguous buffer), which end on a
+        line boundary; returns ``(ids int32[n], temps int16[n])``.
+
+        The columns are new arrays every call (the caller may hold
+        them while the next chunk is parsed, and may reuse ``chunk``),
+        sized from the chunk's newlines: a row ends at one or at the
+        chunk's end."""
+        addr, length = _address(chunk, length)
+        cap = self._cdll.count_newlines(addr, length) + 1
         ids = np.empty(cap, dtype=np.int32)
         temps = np.empty(cap, dtype=np.int16)
+        general = ctypes.c_int64(0)
         n = self._cdll.brc_parse_chunk(
             self._parser,
-            chunk,
-            len(chunk),
-            ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            temps.ctypes.data_as(ctypes.POINTER(ctypes.c_int16)),
+            addr,
+            length,
+            ids.ctypes.data,
+            temps.ctypes.data,
             cap,
+            ctypes.byref(general),
         )
         if n < 0:
             msg = "malformed 1BRC input (expected `station;temp` lines)"
             raise ValueError(msg)
+        # Which way the rows' readings went: the fixed 1BRC shapes or
+        # the general loop (`GET /status`).
+        _flight.RECORDER.count("parse_rows_fixed", n - general.value)
+        _flight.RECORDER.count("parse_rows_general", general.value)
         return ids[:n], temps[:n]
 
     def vocab(self) -> np.ndarray:
@@ -368,9 +399,13 @@ class BrcParser:
             i = len(self._vocab_cache)
             buf = ctypes.create_string_buffer(256)
             n = self._cdll.brc_vocab_get(self._parser, i, buf, 256)
+            if n < 0:  # longer than the buffer: -n is its length
+                buf = ctypes.create_string_buffer(-n)
+                n = self._cdll.brc_vocab_get(self._parser, i, buf, -n)
             self._vocab_cache.append(buf.raw[:n].decode("utf-8"))
         return np.array(self._vocab_cache)
 
-    def split_point(self, chunk: bytes) -> int:
-        """Largest prefix length of ``chunk`` ending on a newline."""
-        return self._cdll.last_line_end(chunk, len(chunk))
+    def split_point(self, chunk, length: Optional[int] = None) -> int:
+        """Largest prefix length of ``chunk``'s first ``length`` bytes
+        (all of it by default) ending on a newline."""
+        return self._cdll.last_line_end(*_address(chunk, length))
