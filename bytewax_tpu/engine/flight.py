@@ -157,6 +157,8 @@ TRACED_PHASES = frozenset(
         "fetch",
         "close_emit",
         "retire",
+        "session_place",
+        "session_close",
         "emit",
         "group",
         "logic",
@@ -1637,6 +1639,8 @@ _FRACTION_BUCKETS = {
         "fetch",
         "close_emit",
         "retire",
+        "session_place",
+        "session_close",
         "emit",
         "group",
         "logic",

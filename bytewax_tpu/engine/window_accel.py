@@ -39,8 +39,9 @@ pass over one column each, not a recomputation of every open
 window's watermark.  "M" events (two ``datetime``s and a
 ``WindowMetadata`` a window) are built only while the plan keeps the
 step's ``meta`` tap (``WindowAccelSpec.meta_live``, set at flatten
-time).  The session tier keeps string slot keys and the scalar
-``alloc`` / ``discard`` surface.
+time).  The session tier keeps its open sessions the same way, in
+:class:`_OpenSessions` (the arena with each session's bounds beside
+it), and builds "M" events under the same rule.
 
 Key note: a tumbling/sliding step holds a key (its id, its clock,
 its encoder entries) only while the key has an open window, as the
@@ -51,7 +52,10 @@ lets it go (:meth:`DeviceWindowAggState.let_go`) and gives its id to
 the next new key, which starts a clock at minus infinity.  A key with
 an on-time row in a delivery taken in after the one whose close found
 it stays, clock and all (it has a window again before anything could
-tell).  The session tier keeps its keys.
+tell).  The session tier lets a key go one delivery after its last
+session closed and keeps, by name, the clock and next session id the
+host tier's never-empty session logic would still hold
+(:class:`DeviceSessionAggState`).
 
 Pipeline note (docs/performance.md): each ``on_batch*`` call returns
 ``(late_events, device_phase)`` — the host phase (vocab sync,
@@ -66,6 +70,7 @@ pre-pipeline engine.  ``on_notify``/``on_eof``/``snapshots_for``
 remain synchronous and may only run with the pipeline drained.
 """
 
+from collections import deque
 from datetime import datetime, timedelta, timezone
 from itertools import repeat
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -154,6 +159,9 @@ class _OpenWindows:
         "_big", "_big_rows", "_small", "_small_rows",
     )  # fmt: skip
 
+    #: The arena's columns, each carried over by a rebuild.
+    _COLUMNS = ("_comp", "_ids", "_at")
+
     def __init__(self):
         self._comp = np.empty(0, dtype=np.int64)
         self._ids = np.empty(0, dtype=np.int32)
@@ -192,6 +200,14 @@ class _OpenWindows:
         """Slot ids of sorted unique composites; those not yet open
         are given slots by ``agg`` in one call (in ascending
         composite order) and join the table."""
+        return self._find_or_open(uniq, agg)[0]
+
+    def _find_or_open(
+        self, uniq: np.ndarray, agg, counter: str = "window_opens"
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """:meth:`ids_for`, and the arena row of each composite (they
+        hold until the next call that opens or removes); the opens
+        are counted under ``counter``."""
         if (
             self._n + len(uniq) > len(self._comp)
             or len(self._small) > _RECENT_SHARE * len(self._big)
@@ -208,9 +224,9 @@ class _OpenWindows:
         out = self._ids[row_of]
         new = fresh | (out < 0)
         if not new.any():
-            return out
+            return out, row_of
         opened = agg.open_ids(uniq[new])
-        _flight.RECORDER.count("window_opens", len(opened))
+        _flight.RECORDER.count(counter, len(opened))
         out[new] = opened
         rows = np.arange(self._n, self._n + len(opened))
         self._comp[rows] = uniq[new]
@@ -233,7 +249,7 @@ class _OpenWindows:
         self._small_rows = np.insert(
             self._small_rows, pos_small[fresh], row_of[fresh]
         )
-        return out
+        return out, row_of
 
     def _of_keys(self, kids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Arena rows of the open windows of ``kids``, and for each
@@ -266,8 +282,13 @@ class _OpenWindows:
         clocks (``base``, ``sys_at``, parallel to ``kids``)."""
         rows, of_key = self._of_keys(kids)
         self._at[rows] = sys_at[of_key] + (
-            closes_of(self._comp[rows]) - base[of_key]
+            self._closes(rows, closes_of) - base[of_key]
         )
+
+    def _closes(self, rows: np.ndarray, closes_of) -> np.ndarray:
+        """Event times (us) at which the windows at ``rows`` fall due:
+        their close times, arithmetic on their composites."""
+        return closes_of(self._comp[rows])
 
     def shift(self, delta_us: float) -> None:
         """Move every due instant by ``delta_us``, as if the system
@@ -340,12 +361,78 @@ class _OpenWindows:
             np.take(old, kept, out=col[:live], mode="clip")
             return col
 
-        self._comp, self._ids, self._at = map(
-            carried, (self._comp, self._ids, self._at)
-        )
+        for name in self._COLUMNS:
+            setattr(self, name, carried(getattr(self, name)))
         self._n = live
         _flight.RECORDER.count("window_table_rebuilds")
         _flight.RECORDER.count("window_table_rebuild_rows", live)
+
+
+class _OpenSessions(_OpenWindows):
+    """The open sessions of a session step: :class:`_OpenWindows` with
+    two more columns, the session's open and close times (us), which
+    move as the session grows and so are stored.  The composite is
+    ``kid << 32 | wid + 2**31`` as a window's; ``at`` is the system
+    time at which the session falls due (its close + gap under its
+    key's clock), and a session is due strictly after it, as the host
+    tier closes a session once ``close < watermark - gap``."""
+
+    __slots__ = ("_lo", "_hi", "gap_us")
+
+    _COLUMNS = _OpenWindows._COLUMNS + ("_lo", "_hi")
+
+    def __init__(self, gap_us: float):
+        super().__init__()
+        self._lo = np.empty(0, dtype=np.float64)
+        self._hi = np.empty(0, dtype=np.float64)
+        self.gap_us = gap_us
+
+    def _closes(self, rows: np.ndarray, closes_of) -> np.ndarray:
+        return self._hi[rows] + self.gap_us
+
+    def due(self, now_us: float) -> np.ndarray:
+        if now_us == np.inf:  # end of input: every open session
+            return np.flatnonzero(self._ids[: self._n] >= 0)
+        return np.flatnonzero(self._at[: self._n] < now_us)
+
+    def bounds(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """Open and close times (us) at arena rows."""
+        return self._lo[rows], self._hi[rows]
+
+    def grow(self, rows: np.ndarray, lo: np.ndarray, hi: np.ndarray) -> None:
+        """Widen the sessions at ``rows`` (repeats allowed) to take in
+        ``[lo, hi]``."""
+        np.minimum.at(self._lo, rows, lo)
+        np.maximum.at(self._hi, rows, hi)
+
+    def set_bounds(self, rows: np.ndarray, lo: np.ndarray, hi: np.ndarray) -> None:
+        self._lo[rows] = lo
+        self._hi[rows] = hi
+
+    def open_sessions(
+        self, comp: np.ndarray, lo: np.ndarray, hi: np.ndarray, agg
+    ) -> np.ndarray:
+        """Open new sessions (sorted unique composites, none open
+        now) with their bounds; their slot ids."""
+        ids, rows = self._find_or_open(comp, agg, "session_opens")
+        self.set_bounds(rows, lo, hi)
+        return ids
+
+
+def _clock_state(base_us: float, sys_at_us: float):
+    """A key's clock as the host tier's ``_EventClockState``."""
+    from bytewax_tpu.operators.windowing import _EventClockState
+
+    return _EventClockState(
+        system_time_of_max_event=datetime.fromtimestamp(
+            sys_at_us / _US, tz=timezone.utc
+        ),
+        watermark_base=(
+            datetime.fromtimestamp(base_us / _US, tz=timezone.utc)
+            if np.isfinite(base_us)
+            else datetime.min.replace(tzinfo=timezone.utc)
+        ),
+    )
 
 
 class _LateTs:
@@ -536,11 +623,13 @@ class DeviceWindowAggState:
                     [self._seen, np.zeros(grow, dtype=np.int64)]
                 )
                 self._last_row = np.empty(len(self._seen), dtype=np.int64)
-            self.base_us[fresh] = -np.inf
-            self.sys_at_base[fresh] = (
-                datetime.now(timezone.utc).timestamp() * _US
-            )
+            self._start_keys(fresh)
         return out
+
+    def _start_keys(self, fresh: List[int]) -> None:
+        """Clocks of keys just given an id: minus infinity."""
+        self.base_us[fresh] = -np.inf
+        self.sys_at_base[fresh] = datetime.now(timezone.utc).timestamp() * _US
 
     def let_go(self, gone: Tuple[int, np.ndarray]) -> None:
         """Retire the keys a close left without an open window
@@ -556,6 +645,7 @@ class DeviceWindowAggState:
         with _flight.span("retire", rows=len(kids)):
             ids = kids.tolist()
             names = [self.keys[kid] for kid in ids]
+            self._left_behind(kids, names)
             for name, kid in zip(names, ids):
                 del self.key_ids[name]
                 self.keys[kid] = None
@@ -564,6 +654,9 @@ class DeviceWindowAggState:
             self._enc.drop_many(names)
             self._item_iddict = None
             _flight.RECORDER.count("window_keys_retired", len(ids))
+
+    def _left_behind(self, kids: np.ndarray, names: List[str]) -> None:
+        """Hook: what a key let go leaves behind (nothing here)."""
 
     def _watermarks(self, kids: np.ndarray, now_us: float) -> np.ndarray:
         return self.base_us[kids] + (now_us - self.sys_at_base[kids])
@@ -1123,7 +1216,6 @@ class DeviceWindowAggState:
         with no open windows snapshots as a discard (the host tier
         discards empty window logics the same way)."""
         from bytewax_tpu.operators.windowing import (
-            _EventClockState,
             _SlidingWindowerState,
             _WindowSnapshot,
         )
@@ -1152,22 +1244,11 @@ class DeviceWindowAggState:
                 out.append((key, None))
                 continue
             opened, folded = open_of[kid]
-            base = self.base_us[kid]
-            clock_state = _EventClockState(
-                system_time_of_max_event=datetime.fromtimestamp(
-                    self.sys_at_base[kid] / _US, tz=timezone.utc
-                ),
-                watermark_base=(
-                    datetime.fromtimestamp(base / _US, tz=timezone.utc)
-                    if np.isfinite(base)
-                    else datetime.min.replace(tzinfo=timezone.utc)
-                ),
-            )
             out.append(
                 (
                     key,
                     _WindowSnapshot(
-                        clock_state,
+                        _clock_state(self.base_us[kid], self.sys_at_base[kid]),
                         _SlidingWindowerState(opened=opened),
                         folded,
                         [],
@@ -1299,21 +1380,24 @@ class DeviceWindowAggState:
         self.load_many(items)
 
 
+
+
 class DeviceSessionAggState(DeviceWindowAggState):
     """Session windows on the device tier: key-local gap merges.
 
-    The heavy per-row work stays vectorized/on-device: rows are
-    lexsorted by (key, timestamp), contiguous runs (consecutive
-    timestamps within ``gap``) are found with one vectorized diff,
-    each run folds into ONE device slot via the same scatter-combine
-    as sliding windows, and only per-RUN work (session create /
-    extend / gap-merge bookkeeping, ``WindowMetadata.merged_ids``)
-    runs in host Python — O(runs + open sessions), not O(rows).
-
-    A session's accumulator is the combine of its slot set; merging
-    two sessions is list concatenation (no device roundtrip), and
-    the combine happens host-side at close/snapshot over a handful
-    of scalars.
+    A delivery's on-time rows are sorted by (key, timestamp) and cut
+    into runs (consecutive timestamps of a key within ``gap``) with one
+    vectorized diff; each run folds into ONE device slot through the
+    same scatter-combine as sliding windows.  The open sessions are
+    rows of :class:`_OpenSessions` (composite, slot id, due instant,
+    open and close times), so placing a delivery's runs is array work:
+    each run is matched against its key's open sessions, and a run
+    that touches none opens a session, a run that touches one widens
+    it.  Only the keys where a run bridges two sessions (a merge) are
+    placed run by run in Python.  A merged session keeps the slots of
+    the sessions it absorbed beside its own (``_extra``) and their ids
+    (``_merged``); its accumulator is their combine, taken host-side
+    at close or snapshot over a handful of scalars.
 
     Documented deviations from the host tier (cosmetic — the merged
     intervals, membership, and values are identical):
@@ -1324,12 +1408,24 @@ class DeviceSessionAggState(DeviceWindowAggState):
       the host tier's can differ when a single value extends several
       sessions downward at once.
 
-    Keys are kept: a session key's id, clock and ``next_wid`` stay
-    after its last session closes (session ids must never be reused,
-    so the key's state is never empty), where the tumbling/sliding
-    tier lets a key go with its last window
-    (:meth:`DeviceWindowAggState.let_go`); its closes hand no key
-    back.
+    How session ids stay unique: a key is let go with its last open
+    session (:meth:`let_go`: its id, clock slot, vocabulary and
+    encoder entries), as the tumbling/sliding tier lets a key go with
+    its last window, one delivery later (a key that comes straight
+    back keeps its id).  The host tier never discards
+    a session logic (reused ids would give downstream joins wrong
+    metadata), so what it would still hold of such a key, its clock
+    and its next session id, stays behind in ``_retired`` by name:
+    three numbers a key and nothing in the table, but the record grows
+    by every key that goes and never returns (counter
+    ``session_keys_remembered``; about 200 bytes of host memory a
+    key), as the host tier's logics do.  A key that returns
+    takes them up again, so it is judged late by the clock it had and
+    its session ids go on from where they stopped.  The snapshot of a
+    key let go is the host tier's snapshot of a session logic with no
+    session (its clock and ``next_id``), so ids stay unique across a
+    resume in either direction; a key resumed with no open session is
+    let go again at the next delivery.
 
     Reference session semantics:
     ``/root/reference/pysrc/bytewax/operators/windowing.py:688-806``.
@@ -1337,123 +1433,96 @@ class DeviceSessionAggState(DeviceWindowAggState):
 
     def __init__(self, spec: SessionAccelSpec):
         super().__init__(spec)
-        #: kid -> wid -> [open_us, close_us, merged_ids set]
-        self.sessions: Dict[int, Dict[int, list]] = {}
-        #: kid -> next session id (never reset: session ids must not
-        #: be reused, matching the host windower's never-empty state)
-        self.next_wid: Dict[int, int] = {}
-        #: (kid, wid) -> device slot keys whose combine is the
-        #: session's accumulator
-        self.session_slots: Dict[Tuple[int, int], List[str]] = {}
-        self._slot_seq = 0
-        #: (kid, wid) -> the session's DUE time (close + gap), which
-        #: moves as the session grows and so is stored, unlike a
-        #: sliding window's; ``notify_at`` reads it through
-        #: :meth:`_open_arrays` as it reads the base class's table.
-        self.open_close_us: Dict[Tuple[int, int], float] = {}
-        # Cached (kids, wids, dues) arrays over open_close_us;
-        # invalidated whenever the open-session set changes.
-        self._open_cache = None
-        # Deferred device phases read the per-key clock as of their
-        # own ingest, so the ingest snapshots it; at pipeline depth 1
-        # the phase runs inline before the clock can move again and
-        # the copy is skipped.
-        from bytewax_tpu.engine.pipeline import pipeline_depth
+        self.open = _OpenSessions(spec.gap_us)
+        #: Per key id, the next session id.  The placement (the
+        #: dispatch lane) owns it; a key given an id on the main thread
+        #: queues its first value in ``_wid_starts``, which the
+        #: placement takes in before it reads the column.
+        self._next_wid = np.zeros(0, dtype=np.int64)
+        self._wid_starts: deque = deque()
+        #: What the host tier would still hold of a key let go, by
+        #: name: ``(watermark base, system time at base, next id)``.
+        self._retired: Dict[str, Tuple[float, float, int]] = {}
+        #: By composite, for merged sessions only: the ids they
+        #: absorbed, and the slots besides their own that hold parts
+        #: of their accumulator.
+        self._merged: Dict[int, set] = {}
+        self._extra: Dict[int, List[int]] = {}
+        #: Keys of the delivery being taken in with a late row (one
+        #: with no on-time row may be left with no session).
+        self._late_kids = _NO_KIDS
+        #: ``(delivery, key ids)`` found without an open session by
+        #: the closes of that delivery, to let go at a later one's.
+        self._parked: Tuple[int, np.ndarray] = (0, _NO_KIDS)
 
-        self._clock_copies = pipeline_depth() > 1
+    def is_empty(self) -> bool:
+        return super().is_empty() and not self._retired
 
-    @property
-    def open_count(self) -> int:
-        return len(self.open_close_us)
+    # -- keys ----------------------------------------------------------------
 
-    def _open_arrays(self):
-        """Cached parallel arrays of the open-session table so the
-        per-batch due check is vectorized (a Python loop here is
-        O(keys × sessions) per batch at high cardinality)."""
-        if self._open_cache is None:
-            items = list(self.open_close_us.items())
-            kids = np.fromiter(
-                (k for (k, _w), _c in items), dtype=np.int64, count=len(items)
+    def _start_keys(self, fresh: List[int]) -> None:
+        """A key given an id starts at minus infinity with session id
+        0, or where it stopped when it was let go."""
+        super()._start_keys(fresh)
+        starts = np.zeros(len(fresh), dtype=np.int64)
+        retired = self._retired
+        if retired:
+            back = 0
+            for i, kid in enumerate(fresh):
+                held = retired.pop(self.keys[kid], None)
+                if held is not None:
+                    self.base_us[kid], self.sys_at_base[kid], starts[i] = held
+                    back += 1
+            _flight.RECORDER.count("session_keys_remembered", -back)
+        self._wid_starts.append((np.asarray(fresh, dtype=np.int64), starts))
+
+    def _take_wid_starts(self) -> None:
+        """Take the queued first session ids into ``_next_wid``."""
+        pending = self._wid_starts
+        while pending:
+            kids, starts = pending.popleft()
+            grow = len(self.keys) - len(self._next_wid)
+            if grow > 0:
+                self._next_wid = np.concatenate(
+                    [self._next_wid, np.zeros(grow, dtype=np.int64)]
+                )
+            self._next_wid[kids] = starts
+
+    def let_go(self, gone: Tuple[int, np.ndarray]) -> None:
+        """Park the keys a close left without an open session; let go
+        those parked by an earlier delivery's close that took in no
+        on-time row since.  A key is held one delivery past its last
+        session, so one that comes straight back keeps its id."""
+        seq, kids = gone
+        parked_seq, parked = self._parked
+        if seq > parked_seq:
+            super().let_go(self._parked)
+            # A key found again has had late rows alone since: it went
+            # just now.
+            self._parked = (seq, np.setdiff1d(kids, parked))
+        else:
+            self._parked = (seq, np.union1d(parked, kids))
+
+    def _left_behind(self, kids: np.ndarray, names: List[str]) -> None:
+        _flight.RECORDER.count("session_keys_remembered", len(names))
+        self._retired.update(
+            zip(
+                names,
+                zip(
+                    self.base_us[kids].tolist(),
+                    self.sys_at_base[kids].tolist(),
+                    self._next_wid[kids].tolist(),
+                ),
             )
-            wids = np.fromiter(
-                (w for (_k, w), _c in items), dtype=np.int64, count=len(items)
-            )
-            dues = np.fromiter(
-                (c for _kw, c in items), dtype=np.float64, count=len(items)
-            )
-            self._open_cache = (kids, wids, dues)
-        return self._open_cache
+        )
 
     def _phase_clock(self, kids: np.ndarray):
-        """The whole clock as of this ingest (a session's due time is
-        stored, not its due instant: the scan reads every key's
-        clock)."""
-        if not self._clock_copies:
-            return None
-        return self.base_us.copy(), self.sys_at_base.copy()
+        """The base class's, and the delivery's keys with a late row."""
+        late, self._late_kids = self._late_kids, _NO_KIDS
+        return super()._phase_clock(kids) + (late,)
 
     def _retime(self, kids: np.ndarray) -> None:
-        """Nothing to retime: the scan reads the clock itself."""
-
-    def notify_at(self, clock=None) -> Optional[datetime]:
-        """System time of the earliest session close: the instant the
-        key's watermark reaches the due time."""
-        if not self.open_count:
-            return None
-        kids_arr, _wids_arr, closes_arr = self._open_arrays()
-        base, sys_at = clock if clock is not None else (
-            self.base_us,
-            self.sys_at_base,
-        )
-        bases = base[kids_arr]
-        finite = np.isfinite(bases)
-        if not finite.any():
-            return None
-        ats = sys_at[kids_arr][finite] + (
-            closes_arr[finite] - bases[finite]
-        )
-        return datetime.fromtimestamp(float(ats.min()) / _US, tz=timezone.utc)
-
-    # -- session bookkeeping (per run, host Python) ------------------------
-
-    def _place_run(self, kid: int, lo_us: float, hi_us: float) -> int:
-        """Create/extend/merge sessions for one run of rows; returns
-        the session id the run folds into."""
-        gap = self.spec.gap_us
-        sess = self.sessions.setdefault(kid, {})
-        overlapping = [
-            wid
-            for wid, s in sess.items()
-            if not (hi_us < s[0] - gap or lo_us > s[1] + gap)
-        ]
-        if not overlapping:
-            wid = self.next_wid.get(kid, 0)
-            self.next_wid[kid] = wid + 1
-            sess[wid] = [lo_us, hi_us, set()]
-            self.session_slots[(kid, wid)] = []
-            self.open_close_us[(kid, wid)] = hi_us + gap
-            self._open_cache = None
-            return wid
-        winner = min(overlapping, key=lambda w: sess[w][0])
-        s = sess[winner]
-        s[0] = min(s[0], lo_us)
-        s[1] = max(s[1], hi_us)
-        for other in overlapping:
-            if other == winner:
-                continue
-            o = sess.pop(other)
-            s[0] = min(s[0], o[0])
-            s[1] = max(s[1], o[1])
-            # The host records only the absorbed window's id (its own
-            # merged_ids are dropped): windowing.py _merge_overlapping.
-            s[2].add(other)
-            self.session_slots[(kid, winner)].extend(
-                self.session_slots.pop((kid, other))
-            )
-            del self.open_close_us[(kid, other)]
-        self.open_close_us[(kid, winner)] = s[1] + gap
-        self._open_cache = None
-        return winner
+        self.open.retime(kids, self.base_us[kids], self.sys_at_base[kids], None)
 
     # -- hook overrides -----------------------------------------------------
 
@@ -1464,6 +1533,7 @@ class DeviceSessionAggState(DeviceWindowAggState):
         # can't name a specific session (host: late_for -> sentinel).
         from bytewax_tpu.operators.windowing import LATE_SESSION_ID
 
+        self._late_kids = np.unique(kids[late_rows])
         return [
             (
                 self.keys[int(kids[row])],
@@ -1478,16 +1548,14 @@ class DeviceSessionAggState(DeviceWindowAggState):
         n = len(ts_ok)
         if not n:
             return
+        self._take_wid_starts()
         with _flight.span("prep", rows=n):
             order = np.lexsort((ts_ok, kids_ok))
             k = kids_ok[order]
             t = ts_ok[order]
             v = np.asarray(vals_ok)[order]
             # Runs: maximal (key, ts-sorted) stretches with consecutive
-            # gaps <= gap.  Runs are disjoint and processed in ts order
-            # per key, so a run that bridges two existing sessions via
-            # transitive extension is handled by _place_run seeing the
-            # already-extended interval.
+            # gaps <= gap, in (key, time) order.
             new_run = np.empty(n, dtype=bool)
             new_run[0] = True
             np.logical_or(
@@ -1496,29 +1564,154 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 out=new_run[1:],
             )
             run_of_row = np.cumsum(new_run) - 1
-            starts = np.nonzero(new_run)[0]
+            starts = np.flatnonzero(new_run)
             ends = np.append(starts[1:], n) - 1
-            slot_of_run = np.empty(len(starts), dtype=np.int32)
-            for r in range(len(starts)):
-                kid = int(k[starts[r]])
-                wid = self._place_run(kid, float(t[starts[r]]), float(t[ends[r]]))
-                # Fold into the session's existing slot when it has one:
-                # a continuously-active session must stay O(1) state, not
-                # accumulate a slot per batch.  (Extra slots only ever
-                # come from merges, which concatenate lists.)
-                slots = self.session_slots[(kid, wid)]
-                if slots:
-                    slot_key = slots[0]
-                else:
-                    slot_key = f"{self.keys[kid]}\x00{wid}\x00{self._slot_seq}"
-                    self._slot_seq += 1
-                    slots.append(slot_key)
-                slot_of_run[r] = self.agg.alloc(slot_key)
-            _flight.RECORDER.record(
-                "device_dispatch", tier="session", rows=len(v)
-            )
+        with _flight.span("session_place", rows=len(starts)):
+            slot_of_run = self._place(k[starts], t[starts], t[ends])
+            _flight.RECORDER.record("device_dispatch", tier="session", rows=n)
             slots_rep = slot_of_run[run_of_row]
         self.agg.update_ids(slots_rep, v)
+
+    # -- placement ------------------------------------------------------------
+
+    def _place(
+        self, r_kid: np.ndarray, r_lo: np.ndarray, r_hi: np.ndarray
+    ) -> np.ndarray:
+        """Create, widen and merge sessions for a delivery's runs (in
+        (key, time) order); the slot each run folds into.  Two runs of
+        a key lie more than ``gap`` apart, so a run touches a session
+        that the delivery's other runs widened only where it touched
+        it before: the runs are matched against the sessions as they
+        stood, all at once."""
+        table, gap = self.open, self.spec.gap_us
+        n_runs = len(r_kid)
+        uk, r_key = np.unique(r_kid, return_inverse=True)
+        rows, of_key = table._of_keys(uk)
+        # Every (run, open session of its key) pair.
+        by_key = rows[np.argsort(of_key, kind="stable")]
+        per_key = np.bincount(of_key, minlength=len(uk))
+        n_of_run = per_key[r_key]
+        ahead = np.cumsum(n_of_run) - n_of_run - (np.cumsum(per_key) - per_key)[r_key]
+        pair_run = np.repeat(np.arange(n_runs), n_of_run)
+        pair_row = by_key[np.arange(len(pair_run)) - np.repeat(ahead, n_of_run)]
+        lo, hi = table.bounds(pair_row)
+        touch = (r_hi[pair_run] >= lo - gap) & (r_lo[pair_run] <= hi + gap)
+        pair_run, pair_row = pair_run[touch], pair_row[touch]
+        touches = np.bincount(pair_run, minlength=n_runs)
+        merging = np.isin(r_kid, r_kid[touches > 1])
+
+        slot = np.empty(n_runs, dtype=np.int32)
+        # A run that touches one session widens it.
+        widen = ((touches == 1) & ~merging)[pair_run]
+        runs, at = pair_run[widen], pair_row[widen]
+        table.grow(at, r_lo[runs], r_hi[runs])
+        slot[runs] = table.read(at)[1]
+        # The keys where a run bridges two sessions, run by run.
+        created, owner_of = (
+            self._place_merging(np.flatnonzero(merging), r_kid, r_lo, r_hi)
+            if merging.any()
+            else ([], {})
+        )
+        # A run that touches none opens a session: ids in time order
+        # a key.
+        fresh = np.flatnonzero((touches == 0) & ~merging)
+        kids = r_kid[fresh]
+        head = np.flatnonzero(np.diff(kids, prepend=-1))
+        count = np.diff(np.append(head, len(kids)))
+        wids = self._next_wid[kids] + (np.arange(len(kids)) - np.repeat(head, count))
+        self._next_wid[kids[head]] += count
+        kid_of = np.append(kids, [kid for kid, _s in created]).astype(np.int64)
+        wid_of = np.append(wids, [s[2] for _kid, s in created]).astype(np.int64)
+        comp = (kid_of << 32) + (wid_of + _WID_BIAS)
+        order = np.argsort(comp)
+        opened = np.empty(len(comp), dtype=np.int32)
+        opened[order] = table.open_sessions(
+            comp[order],
+            np.append(r_lo[fresh], [s[0] for _kid, s in created])[order],
+            np.append(r_hi[fresh], [s[1] for _kid, s in created])[order],
+            self.agg,
+        )
+        slot[fresh] = opened[: len(fresh)]
+        for (_kid, s), id_ in zip(created, opened[len(fresh) :].tolist()):
+            s[4] = id_
+        for run, s in owner_of.items():
+            while s[5] is not None:
+                s = s[5]
+            slot[run] = s[4]
+        return slot
+
+    def _place_merging(
+        self, runs: np.ndarray, r_kid: np.ndarray, r_lo: np.ndarray, r_hi: np.ndarray
+    ):
+        """The runs of the keys where a run bridges two sessions, one
+        after another in time order, as the host tier places values: a
+        run that touches no session opens one, else the sessions it
+        touches merge into the earliest open.  A session here is
+        ``[open, close, wid, arena row or -1, slot id or -1, merged
+        into]``.  Returns the sessions opened here that nothing merged
+        away ``(key id, session)``, and each run's session."""
+        table, gap = self.open, self.spec.gap_us
+        created, owner_of, dead = [], {}, []
+        for kid in np.unique(r_kid[runs]).tolist():
+            rows = table.rows_of(np.array([kid], dtype=np.int64))
+            lo, hi = table.bounds(rows)
+            comp, ids = table.read(rows)
+            sess = [
+                [a, b, w, r, i, None]
+                for a, b, w, r, i in zip(
+                    lo.tolist(),
+                    hi.tolist(),
+                    ((comp & _WID_MASK) - _WID_BIAS).tolist(),
+                    rows.tolist(),
+                    ids.tolist(),
+                )
+            ]
+            next_wid = int(self._next_wid[kid])
+            for run in runs[r_kid[runs] == kid].tolist():
+                a, b = float(r_lo[run]), float(r_hi[run])
+                over = [s for s in sess if b >= s[0] - gap and a <= s[1] + gap]
+                if not over:
+                    s = [a, b, next_wid, -1, -1, None]
+                    next_wid += 1
+                    sess.append(s)
+                    created.append((kid, s))
+                else:
+                    s = min(over, key=lambda x: x[0])
+                    s[0], s[1] = min(s[0], a), max(s[1], b)
+                    for other in over:
+                        if other is not s:
+                            sess.remove(other)
+                            self._merge(kid, s, other, dead)
+                owner_of[run] = s
+            self._next_wid[kid] = next_wid
+            kept = [s for s in sess if s[3] >= 0]
+            table.set_bounds(
+                np.array([s[3] for s in kept], dtype=np.int64),
+                np.array([s[0] for s in kept]),
+                np.array([s[1] for s in kept]),
+            )
+        table.remove(np.asarray(dead, dtype=np.int64))
+        return [(kid, s) for kid, s in created if s[5] is None], owner_of
+
+    def _merge(self, kid: int, keep: list, other: list, dead: List[int]) -> None:
+        """Session ``other`` of key ``kid`` merges into ``keep``: its
+        bounds, its id (the host records only the absorbed session's
+        id; its own merged ids go), its slots and its row."""
+        keep[0], keep[1] = min(keep[0], other[0]), max(keep[1], other[1])
+        other[5] = keep
+        into = (kid << 32) + keep[2] + _WID_BIAS
+        gone = (kid << 32) + other[2] + _WID_BIAS
+        self._merged.pop(gone, None)
+        self._merged.setdefault(into, set()).add(other[2])
+        parts = self._extra.pop(gone, [])
+        if other[3] >= 0:
+            parts.append(other[4])
+            dead.append(other[3])
+        if parts:
+            self._extra.setdefault(into, []).extend(parts)
+        _flight.RECORDER.count("session_merges")
+
+    # -- close ----------------------------------------------------------------
 
     def _combine(self, snaps: List[Any]) -> Any:
         """Combine slot accumulators host-side (kind algebra over a
@@ -1546,199 +1739,229 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 )
         return acc
 
-    def _session_accs(
-        self, sessions: List[Tuple[int, int]], discard: bool
-    ) -> List[Any]:
-        """Accumulators of the given ``(kid, wid)`` sessions, in
-        order, from ONE device fetch over all their slots (a fetch
-        per session reads the whole table back each time)."""
-        slot_lists = [self.session_slots[kw] for kw in sessions]
-        snap_of = dict(
-            self.agg.snapshots_for(
-                [sk for slot_keys in slot_lists for sk in slot_keys]
+    def _parts(self, comp: np.ndarray, take: bool) -> Dict[int, List[int]]:
+        """By position in ``comp``, the slots a merged session holds
+        besides its own; ``take``: forget the sessions' merge records."""
+        parts: Dict[int, List[int]] = {}
+        if self._extra:
+            get = self._extra.pop if take else self._extra.get
+            for i, c in enumerate(comp.tolist()):
+                held = get(c, None)
+                if held:
+                    parts[i] = held
+        if take and self._merged:
+            for c in comp.tolist():
+                self._merged.pop(c, None)
+        return parts
+
+    @staticmethod
+    def _with_parts(ids: np.ndarray, parts: Dict[int, List[int]]) -> np.ndarray:
+        """The sessions' own slots, then the parts' in order."""
+        if not parts:
+            return ids
+        more = [s for held in parts.values() for s in held]
+        return np.append(ids, np.asarray(more, dtype=ids.dtype))
+
+    def _accs(self, ids: np.ndarray, parts: Dict[int, List[int]]) -> List[Any]:
+        """Accumulators of the sessions with slots ``ids`` and
+        ``parts`` (:meth:`_parts`), from ONE device fetch, a merged
+        session's slots combined."""
+        slots = self._with_parts(ids, parts)
+        # bytewax: allow[BTX-DRAIN] — the session windower's .agg is its own slot table (never residency-wrapped), fetched inside the deferred device phase the pipeline worker owns, or with the pipeline drained
+        states = list(self.agg.states_of(slots)) if len(slots) else []
+        at = len(ids)
+        for i, held in parts.items():
+            states[i] = self._combine([states[i]] + states[at : at + len(held)])
+            at += len(held)
+        return states[: len(ids)]
+
+    def _metas(self, comp: np.ndarray, lo: np.ndarray, hi: np.ndarray) -> List[Any]:
+        """``WindowMetadata`` of sessions: their bounds and merged ids."""
+        from bytewax_tpu.operators.windowing import WindowMetadata
+
+        merged = self._merged
+        return [
+            WindowMetadata(
+                datetime.fromtimestamp(a / _US, tz=timezone.utc),
+                datetime.fromtimestamp(b / _US, tz=timezone.utc),
+                set(merged.get(c, ())),
             )
-        )
-        accs = [
-            self._combine([snap_of[sk] for sk in slot_keys])
-            for slot_keys in slot_lists
+            for c, a, b in zip(comp.tolist(), lo.tolist(), hi.tolist())
         ]
-        if discard:
-            for kw, slot_keys in zip(sessions, slot_lists):
-                for sk in slot_keys:
-                    self.agg.discard(sk)
-                del self.session_slots[kw]
-        return accs
 
     def _close_due(
         self, now_us: float, clock=None
     ) -> Tuple[List[Tuple[str, Tuple[int, str, Any]]], np.ndarray]:
-        if not self.open_close_us:
-            return [], _NO_KIDS
-        with _flight.span("close_scan") as scan:
-            kids_arr, wids_arr, dues_arr = self._open_arrays()
-            scan.rows = len(dues_arr)
-            base, sys_at = clock if clock is not None else (
-                self.base_us,
-                self.sys_at_base,
-            )
-            wm = base[kids_arr] + (now_us - sys_at[kids_arr])
-            # Strict: a session closes when the watermark passes close
-            # + gap (host: close_time < watermark - gap), not at
-            # equality.
-            due_rows = np.nonzero(dues_arr < wm)[0]
-            if not len(due_rows):
-                return [], _NO_KIDS
-            due = [(int(kids_arr[i]), int(wids_arr[i])) for i in due_rows]
-        from bytewax_tpu.operators.windowing import WindowMetadata
-
-        events = []
-        accs = self._session_accs(due, discard=True)
-        with _flight.span("close_emit", rows=len(due)):
-            for (kid, wid), acc in zip(due, accs):
-                key = self.keys[kid]
-                s = self.sessions[kid].pop(wid)
-                del self.open_close_us[(kid, wid)]
-                events.append((key, (wid, "E", self._finalize_one(acc))))
-                meta = WindowMetadata(
-                    datetime.fromtimestamp(s[0] / _US, tz=timezone.utc),
-                    datetime.fromtimestamp(s[1] / _US, tz=timezone.utc),
-                    set(s[2]),
-                )
-                events.append((key, (wid, "M", meta)))
-            self._open_cache = None
-        return events, _NO_KIDS
+        """Close the sessions that are due: their events, and the ids
+        of the keys this leaves without an open session (with those
+        of the delivery's keys whose every row was late)."""
+        table = self.open
+        late = clock[3] if clock is not None else _NO_KIDS
+        # (A key left with late rows alone has its first id queued.)
+        self._take_wid_starts()
+        with _flight.span("session_close", rows=len(table)):
+            if clock is not None:
+                table.retime(*clock[:3], None)
+            due = table.due(now_us) if len(table) else _NO_KIDS
+            if not len(due):
+                return [], table.without_window(late) if len(late) else _NO_KIDS
+            comp, ids = table.read(due)
+            lo, hi = table.bounds(due)
+            metas = self._metas(comp, lo, hi) if self.spec.meta_live else None
+            parts = self._parts(comp, take=True)
+        states = self._accs(ids, parts)
+        with _flight.span("session_close", rows=len(due)):
+            table.remove(due)
+            self.agg.release_ids(self._with_parts(ids, parts))
+            kids_due = comp >> 32
+            keys = list(map(self.keys.__getitem__, kids_due.tolist()))
+            wids = ((comp & _WID_MASK) - _WID_BIAS).tolist()
+            events = list(zip(keys, zip(wids, repeat("E"), map(self._finalize_one, states))))
+            if metas is not None:
+                _flight.RECORDER.count("window_meta_events", len(metas))
+                # "E" then "M" per session, as the host tier emits.
+                both = [None] * (2 * len(events))
+                both[0::2] = events
+                both[1::2] = zip(keys, zip(wids, repeat("M"), metas))
+                events = both
+            _flight.RECORDER.count("session_closes", len(due))
+            gone = table.without_window(np.unique(np.append(kids_due, late)))
+        return events, gone
 
     # -- recovery -----------------------------------------------------------
 
     def snapshots_for(self, keys: List[str]):
         """Host-tier ``_WindowSnapshot``-compatible snapshots with
-        session windower state.  Session state is never discarded
-        once a key exists (ids must not be reused — host parity)."""
+        session windower state: a held key's open sessions, a key let
+        go as the host tier's logic with no session (clock and
+        ``next_id``), ``None`` for a key never seen."""
         from bytewax_tpu.operators.windowing import (
-            WindowMetadata,
-            _EventClockState,
             _SessionWindowerState,
             _WindowSnapshot,
         )
 
-        open_sessions = [
-            (kid, wid)
-            for kid in (self.key_ids.get(key) for key in keys)
-            if kid is not None
-            for wid in self.sessions.get(kid, {})
-        ]
-        acc_of = dict(
-            zip(
-                open_sessions,
-                self._session_accs(open_sessions, discard=False),
-            )
-        )
+        self._take_wid_starts()
+        rows = self._rows_of(keys)
+        comp, ids = self.open.read(rows)
+        lo, hi = self.open.bounds(rows)
+        states = self._accs(ids, self._parts(comp, take=False))
+        of_kid: Dict[int, Tuple[dict, dict]] = {}
+        for kid, wid, meta, state in zip(
+            (comp >> 32).tolist(),
+            ((comp & _WID_MASK) - _WID_BIAS).tolist(),
+            self._metas(comp, lo, hi),
+            states,
+        ):
+            sessions, folded = of_kid.setdefault(kid, ({}, {}))
+            sessions[wid] = meta
+            folded[wid] = state
         out = []
         for key in keys:
             kid = self.key_ids.get(key)
-            if kid is None:
-                out.append((key, None))
-                continue
-            sess = self.sessions.get(kid, {})
-            metas = {
-                wid: WindowMetadata(
-                    datetime.fromtimestamp(s[0] / _US, tz=timezone.utc),
-                    datetime.fromtimestamp(s[1] / _US, tz=timezone.utc),
-                    set(s[2]),
-                )
-                for wid, s in sess.items()
-            }
-            states = {wid: acc_of[(kid, wid)] for wid in sess}
-            base = self.base_us[kid]
-            clock_state = _EventClockState(
-                system_time_of_max_event=datetime.fromtimestamp(
-                    self.sys_at_base[kid] / _US, tz=timezone.utc
-                ),
-                watermark_base=(
-                    datetime.fromtimestamp(base / _US, tz=timezone.utc)
-                    if np.isfinite(base)
-                    else datetime.min.replace(tzinfo=timezone.utc)
-                ),
-            )
+            if kid is not None:
+                held = (self.base_us[kid], self.sys_at_base[kid], self._next_wid[kid])
+            else:
+                held = self._retired.get(key)
+                if held is None:
+                    out.append((key, None))
+                    continue
+            base, sys_at, next_id = held
+            sessions, folded = of_kid.get(kid, ({}, {}))
             out.append(
                 (
                     key,
                     _WindowSnapshot(
-                        clock_state,
+                        _clock_state(base, sys_at),
                         _SessionWindowerState(
-                            next_id=self.next_wid.get(kid, 0),
-                            sessions=metas,
-                            merge_queue=[],
+                            next_id=int(next_id), sessions=sessions, merge_queue=[]
                         ),
-                        states,
+                        folded,
                         [],
                     ),
                 )
             )
         return out
 
+    def demotion_snapshots(self):
+        """Every key held and every key let go: the host tier keeps
+        both."""
+        return self.snapshots_for(sorted(self.key_ids.keys() | self._retired.keys()))
+
+    def load_many(self, items: List[Tuple[str, Any]]) -> None:
+        """The base class's resume; a key resumed with no open session
+        is let go at once."""
+        super().load_many(items)
+        kids = np.unique(
+            np.fromiter(
+                (self.key_ids[key] for key, _snap in items),
+                dtype=np.int64,
+                count=len(items),
+            )
+        )
+        self._seen[kids] = self._seq
+        self.let_go((self._seq, self.open.without_window(kids)))
+
     def _load_windows(
         self, kids: List[int], items: List[Tuple[str, Any]]
     ) -> None:
         """Session variant: reopen the page's sessions from host-tier
-        session ``_WindowSnapshot``s, one install for the page."""
-        slot_states: List[Tuple[str, Any]] = []
-        gap = self.spec.gap_us
-        for kid, (key, snap) in zip(kids, items):
+        session ``_WindowSnapshot``s, one open and one install for the
+        page."""
+        self._take_wid_starts()
+        comps, los, his, states = [], [], [], []
+        for kid, (_key, snap) in zip(kids, items):
             st = snap.windower_state
-            self.next_wid[kid] = st.next_id
-            sess = self.sessions.setdefault(kid, {})
-            for wid, meta in st.sessions.items():
-                sess[wid] = [
-                    _to_us(meta.open_time),
-                    _to_us(meta.close_time),
-                    set(meta.merged_ids),
-                ]
-                self.session_slots[(kid, wid)] = []
-                self.open_close_us[(kid, wid)] = (
-                    _to_us(meta.close_time) + gap
-                )
+            self._next_wid[kid] = st.next_id
             # A snapshot taken between a windower merge and the logic
             # merge has the sessions dict merged but logic states
             # still split per pre-merge id; resolve each state to its
             # surviving session (chasing chained merges).
             into = dict(st.merge_queue)
+            parts: Dict[int, List[Any]] = {}
             for wid, state in snap.logic_states.items():
-                target = wid
-                seen = set()
+                target, seen = wid, set()
                 while target in into and target not in seen:
                     seen.add(target)
                     target = into[target]
-                if target not in sess:
-                    continue
-                slot_key = f"{key}\x00{target}\x00{self._slot_seq}"
-                self._slot_seq += 1
-                slot_states.append((slot_key, state))
-                self.session_slots[(kid, target)].append(slot_key)
-        self._open_cache = None
-        self.agg.load_many(slot_states)
+                parts.setdefault(target, []).append(state)
+            for wid, meta in st.sessions.items():
+                comp = (kid << 32) + wid + _WID_BIAS
+                comps.append(comp)
+                los.append(_to_us(meta.open_time))
+                his.append(_to_us(meta.close_time))
+                states.append(self._combine(parts.get(wid, [])))
+                if meta.merged_ids:
+                    self._merged[comp] = set(meta.merged_ids)
+        if not comps:
+            return
+        comp = np.asarray(comps, dtype=np.int64)
+        order = np.argsort(comp)
+        ids = np.empty(len(comp), dtype=np.int32)
+        ids[order] = self.open.open_sessions(
+            comp[order], np.asarray(los)[order], np.asarray(his)[order], self.agg
+        )
+        held = [i for i, state in enumerate(states) if state is not None]
+        self.agg.load_ids(ids[held], [states[i] for i in held])
 
     def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
-        """Session variant of the residency extract: open sessions
-        drain into the snapshot (which carries ``next_id``, so session
-        ids stay unique across an extract/inject round trip) and their
-        device slots are released."""
-        out = []
-        for key, snap in self.snapshots_for(keys):
-            kid = self.key_ids.get(key)
-            if snap is None or kid is None:
-                continue
-            # Keys with ZERO open sessions still extract: their
-            # snapshot carries next_id/clock state (session state is
-            # never discarded once a key exists), and skipping them
-            # would leave a residency manager believing it evicted a
-            # key that released nothing.
-            for wid in list(self.sessions.get(kid, {})):
-                for slot_key in self.session_slots.pop((kid, wid), []):
-                    self.agg.discard(slot_key)
-                self.open_close_us.pop((kid, wid), None)
-            self.sessions[kid] = {}
-            self._open_cache = None
-            self.touched.discard(key)
-            out.append((key, snap))
+        """Session variant of the residency extract: the keys' open
+        sessions drain into their snapshots (which carry ``next_id``,
+        so session ids stay unique across an extract/inject round
+        trip), their device slots are released and the keys go, what
+        they left behind with them."""
+        out = [(key, snap) for key, snap in self.snapshots_for(keys) if snap is not None]
+        names = [key for key, _snap in out]
+        rows = self._rows_of(names)
+        comp, ids = self.open.read(rows)
+        self.agg.release_ids(self._with_parts(ids, self._parts(comp, take=True)))
+        self.open.remove(rows)
+        held = np.asarray(
+            [self.key_ids[key] for key in names if key in self.key_ids], dtype=np.int64
+        )
+        parked_seq, parked = self._parked
+        self._parked = (parked_seq, np.setdiff1d(parked, held))
+        super().let_go((self._seq, held))
+        forgot = sum(self._retired.pop(key, None) is not None for key in names)
+        _flight.RECORDER.count("session_keys_remembered", -forgot)
+        self.touched.difference_update(names)
         return out
