@@ -38,6 +38,25 @@ class TsValue(float):
         return (TsValue, (float(self), self.ts))
 
 
+def grow_column(col: np.ndarray, n: int, fill: Any = None) -> np.ndarray:
+    """``col`` when it holds ``n`` entries, else a copy with room for
+    twice as many (or ``n``), its new entries set to ``fill`` (left
+    as they come when None).  A key-indexed column grown this way
+    costs O(1) a key over its life, not a copy of every key each time
+    one is added; its ``len`` is its capacity.  Counts the entries
+    copied (``vocab_walked``) and the growth (``vocab_grows``)."""
+    cap = len(col)
+    if n <= cap:
+        return col
+    out = np.empty(max(n, 2 * cap), dtype=col.dtype)
+    out[:cap] = col
+    if fill is not None:
+        out[cap:] = fill
+    _flight.RECORDER.count("vocab_walked", cap)
+    _flight.RECORDER.count("vocab_grows")
+    return out
+
+
 class VocabMap:
     """Append-only mapping from a batch's external ``key_id`` space to
     engine-internal ids.
@@ -56,16 +75,49 @@ class VocabMap:
     rewrite raises, while a rewrite that dodges every sampled entry
     of a large vocabulary is undefined behavior (the contract was
     always append-only).
+
+    A batch costs O(its rows + the keys it touches), not O(the
+    vocabulary), for an ndarray vocabulary: the id table grows by
+    doubling (``table`` is a view of exactly the vocabulary's
+    length), the touched ids come from a pass over the batch's own
+    id range, and :meth:`drop_ids` finds its entries through a
+    reverse index.  A list vocabulary is converted and re-validated
+    by equality as it always was, which walks all of it.  Counters:
+    ``vocab_rows`` (rows synced), ``vocab_walked`` (key-indexed
+    entries read, zeroed or copied beyond those rows), ``vocab_sorted``
+    (batches whose ids were sorted instead of counted) and
+    ``vocab_grows`` (:func:`grow_column`).
     """
 
-    __slots__ = ("vocab", "table", "_ref", "_ref_probe", "_dtype")
+    __slots__ = (
+        "vocab",
+        "table",
+        "_buf",
+        "_rev",
+        "_rev_more",
+        "_ref",
+        "_ref_probe",
+        "_dtype",
+    )
 
     #: How many entries the identity fast path spot-checks per batch.
     _PROBE_N = 16
 
+    #: A batch whose ids span more than this many entries a row takes
+    #: a sort of its rows instead of a count over the span: past about
+    #: twice the rows, zeroing and scanning the counts costs more than
+    #: the sort.
+    _COUNT_SPAN_PER_ROW = 2
+
     def __init__(self, dtype=np.int32):
         self.vocab: Optional[np.ndarray] = None
         self.table: Optional[np.ndarray] = None
+        # The table's capacity: ``table`` is its first len(vocab).
+        self._buf = np.empty(0, dtype=dtype)
+        # Internal id -> an external id mapped to it (-1: none), and
+        # the further ones where a vocabulary names one key twice.
+        self._rev = np.empty(0, dtype=np.int64)
+        self._rev_more: Dict[int, List[int]] = {}
         self._ref: Any = None
         self._ref_probe: Any = None
         self._dtype = dtype
@@ -98,15 +150,16 @@ class VocabMap:
             return self._sync(ids, vocab, alloc_many)
 
     def _sync(self, ids: np.ndarray, vocab: Any, alloc_many) -> np.ndarray:
-        same = vocab is self._ref and (
+        same = vocab is self._ref
+        if same and not isinstance(vocab, np.ndarray):
             # Identity only short-circuits full validation for
             # ndarrays (spot-checked below) — a list mutated in place
             # keeps its identity, so equal-length lists re-validate
             # every batch (in-place growth revalidates by probe).
-            isinstance(vocab, np.ndarray)
-            or len(vocab) == len(self.table)
-            and vocab == self.vocab.tolist()
-        )
+            same = len(vocab) == len(self.table)
+            if same:
+                _flight.RECORDER.count("vocab_walked", len(vocab))
+                same = vocab == self.vocab.tolist()
         if same and isinstance(vocab, np.ndarray):
             if self._probe_of(vocab) != self._ref_probe:
                 msg = (
@@ -117,7 +170,8 @@ class VocabMap:
                 raise TypeError(msg)
         if self.vocab is None:
             self.vocab = np.asarray(vocab)
-            self.table = np.full(len(self.vocab), -1, dtype=self._dtype)
+            self._buf = grow_column(self._buf, len(self.vocab), -1)
+            self.table = self._buf[: len(self.vocab)]
             self._ref = vocab
             self._ref_probe = self._probe_of(self.vocab)
         elif not same:
@@ -148,50 +202,101 @@ class VocabMap:
                     self.vocab = vocab
                 else:
                     # Convert only the new suffix; the validated
-                    # prefix is already installed.
+                    # prefix is already installed (and copied here).
+                    _flight.RECORDER.count("vocab_walked", prev)
                     self.vocab = np.concatenate(
                         [self.vocab, np.asarray(vocab[prev:])]
                     )
-                pad = np.full(n - prev, -1, self._dtype)
-                self.table = np.concatenate([self.table, pad])
+                self._buf = grow_column(self._buf, n, -1)
+                self.table = self._buf[:n]
             self._ref = vocab
             self._ref_probe = self._probe_of(self.vocab)
-        if len(ids):
-            mx, mn = int(ids.max()), int(ids.min())
-            if mx >= len(self.table) or mn < 0:
-                bad = mx if mx >= len(self.table) else mn
-                msg = (
-                    f"key_id {bad} is out of range for a "
-                    f"{len(self.table)}-entry key_vocab"
-                )
-                raise TypeError(msg)
-        # bincount + nonzero beats np.unique's sort by ~20x here.
-        counts = np.bincount(ids, minlength=len(self.table))
-        uniq = np.nonzero(counts)[0]
+        uniq = self._touched(ids)
         new = uniq[self.table[uniq] < 0]
         if len(new):
-            self.table[new] = np.asarray(
-                alloc_many([str(self.vocab[e]) for e in new.tolist()]),
-                dtype=self._dtype,
-            )
+            if self.vocab.dtype.kind == "U":  # its entries are str
+                names = self.vocab[new].tolist()
+            else:
+                names = [str(self.vocab[e]) for e in new.tolist()]
+            got = np.asarray(alloc_many(names), dtype=self._dtype)
+            self.table[new] = got
+            self._note_owners(new, got.astype(np.int64))
         return uniq
+
+    def _touched(self, ids: np.ndarray) -> np.ndarray:
+        """The distinct ids of a batch, ascending: a count over the
+        batch's own id range (from 0 where that is at most twice as
+        long, with no copy of the rows), or a sort of the rows where
+        the range is sparse."""
+        n = len(ids)
+        _flight.RECORDER.count("vocab_rows", n)
+        if not n:
+            return np.empty(0, dtype=np.intp)
+        mx, mn = int(ids.max()), int(ids.min())
+        if mx >= len(self.table) or mn < 0:
+            bad = mx if mx >= len(self.table) else mn
+            msg = (
+                f"key_id {bad} is out of range for a "
+                f"{len(self.table)}-entry key_vocab"
+            )
+            raise TypeError(msg)
+        lo = 0 if mn <= mx - mn else mn
+        width = mx - lo + 1
+        if width > self._COUNT_SPAN_PER_ROW * n:
+            _flight.RECORDER.count("vocab_sorted")
+            return np.unique(ids)
+        _flight.RECORDER.count("vocab_walked", width)
+        if not lo:
+            return np.flatnonzero(np.bincount(ids))
+        return np.flatnonzero(np.bincount(ids - lo)) + lo
+
+    def _note_owners(self, ext: np.ndarray, owner: np.ndarray) -> None:
+        """Record in the reverse index that externals ``ext`` now map
+        to internal ids ``owner``.  An id that already names a live
+        external (one key under two external ids, or an id given out
+        again without :meth:`drop_ids`), or that appears twice in the
+        batch, keeps the others in ``_rev_more``."""
+        self._rev = grow_column(self._rev, int(owner.max()) + 1, -1)
+        prev = self._rev[owner]
+        self._rev[owner] = ext  # of repeated ids the last one stays
+        more = self._rev[owner] != ext
+        live = prev >= 0
+        live[live] = self.table[prev[live]] == owner[live]
+        if more.any() or live.any():
+            pairs = set(zip(owner[more].tolist(), ext[more].tolist()))
+            pairs.update(zip(owner[live].tolist(), prev[live].tolist()))
+            for i, e in pairs:
+                self._rev_more.setdefault(i, []).append(e)
 
     def drop_ids(self, internal_ids) -> int:
         """Forget the external entries mapped to these *internal* ids
         (back to unassigned): the next :meth:`sync` re-allocs them, so
         a released internal id can be reused by another key without a
         stale external mapping folding rows into the wrong slot.
-        Returns how many entries were dropped."""
+        Returns how many entries were dropped.  O(ids), through the
+        reverse index: no pass over the table."""
         if self.table is None or not len(self.table):
             return 0
-        mask = np.isin(
-            self.table,
-            np.asarray(list(internal_ids), dtype=self.table.dtype),
-        )
-        n = int(mask.sum())
-        if n:
-            self.table[mask] = -1
-        return n
+        ids = np.unique(np.asarray(list(internal_ids), dtype=np.int64))
+        ids = ids[(ids >= 0) & (ids < len(self._rev))]
+        ext, owner = self._rev[ids], ids
+        self._rev[ids] = -1
+        if self._rev_more:
+            more = [
+                (i, e)
+                for i in ids.tolist()
+                if i in self._rev_more
+                for e in self._rev_more.pop(i)
+            ]
+            if more:
+                extra_owner, extra_ext = np.asarray(more, dtype=np.int64).T
+                ext = np.concatenate([ext, extra_ext])
+                owner = np.concatenate([owner, extra_owner])
+        held = ext >= 0
+        ext, owner = ext[held], owner[held]
+        ext = np.unique(ext[self.table[ext] == owner])
+        self.table[ext] = -1
+        return len(ext)
 
 
 _factorize = None

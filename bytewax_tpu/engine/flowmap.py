@@ -384,8 +384,10 @@ def watermark_lag_s(wagg: Any) -> Optional[float]:
     sys_at = getattr(wagg, "sys_at_base", None)
     if base is None or sys_at is None:
         return None
-    b = np.asarray(base, dtype=np.float64)
-    s = np.asarray(sys_at, dtype=np.float64)
+    # The columns' len is their capacity: read the ids given out.
+    n = len(getattr(wagg, "keys", base))
+    b = np.asarray(base[:n], dtype=np.float64)
+    s = np.asarray(sys_at[:n], dtype=np.float64)
     if b.shape != s.shape or b.size == 0:
         return None
     mask = np.isfinite(b) & np.isfinite(s)

@@ -78,7 +78,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from bytewax_tpu.engine import flight as _flight
-from bytewax_tpu.engine.arrays import KeyEncoder, VocabMap
+from bytewax_tpu.engine.arrays import KeyEncoder, VocabMap, grow_column
 
 __all__ = ["DeviceWindowAggState", "WindowAccelSpec"]
 
@@ -543,7 +543,9 @@ class DeviceWindowAggState:
         self.expand = max(1, int(np.ceil(spec.length_us / spec.offset_us)))
         # Per-key clock state, indexed by key id.  A key holds an id
         # while it has an open window (:meth:`let_go`); ``keys`` is
-        # None at an id that is free.
+        # None at an id that is free.  The key-indexed columns grow by
+        # doubling (``grow_column``): their ``len`` is their capacity,
+        # ``len(self.keys)`` the ids given out.
         self.keys: List[Optional[str]] = []
         self.key_ids: Dict[str, int] = {}
         self._free_kids: List[int] = []
@@ -611,17 +613,11 @@ class DeviceWindowAggState:
             out[i] = kid
         if fresh:
             _flight.RECORDER.count("window_keys_opened", len(fresh))
-            grow = len(self.keys) - len(self.base_us)
-            if grow > 0:
-                self.base_us = np.concatenate(
-                    [self.base_us, np.empty(grow)]
-                )
-                self.sys_at_base = np.concatenate(
-                    [self.sys_at_base, np.empty(grow)]
-                )
-                self._seen = np.concatenate(
-                    [self._seen, np.zeros(grow, dtype=np.int64)]
-                )
+            n = len(self.keys)
+            if n > len(self.base_us):
+                self.base_us = grow_column(self.base_us, n)
+                self.sys_at_base = grow_column(self.sys_at_base, n)
+                self._seen = grow_column(self._seen, n, 0)
                 self._last_row = np.empty(len(self._seen), dtype=np.int64)
             self._start_keys(fresh)
         return out
@@ -1481,11 +1477,7 @@ class DeviceSessionAggState(DeviceWindowAggState):
         pending = self._wid_starts
         while pending:
             kids, starts = pending.popleft()
-            grow = len(self.keys) - len(self._next_wid)
-            if grow > 0:
-                self._next_wid = np.concatenate(
-                    [self._next_wid, np.zeros(grow, dtype=np.int64)]
-                )
+            self._next_wid = grow_column(self._next_wid, len(self.keys), 0)
             self._next_wid[kids] = starts
 
     def let_go(self, gone: Tuple[int, np.ndarray]) -> None:
