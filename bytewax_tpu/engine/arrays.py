@@ -74,7 +74,10 @@ class VocabMap:
     batch, never a full re-scan of the vocabulary — so a detected
     rewrite raises, while a rewrite that dodges every sampled entry
     of a large vocabulary is undefined behavior (the contract was
-    always append-only).
+    always append-only).  A shorter vocabulary that agrees with the
+    held one's prefix is an older view of it (a merge can deliver one
+    stream's earlier batch after another's later one) and is read
+    through the vocabulary held.
 
     A batch costs O(its rows + the keys it touches), not O(the
     vocabulary), for an ndarray vocabulary: the id table grows by
@@ -177,13 +180,14 @@ class VocabMap:
         elif not same:
             prev = len(self.table)
             n = len(vocab)
-            ok = n >= prev
-            if ok and prev:
+            shared = min(n, prev)
+            ok = True
+            if shared:
                 # Spot-check the already-validated prefix at sampled
                 # indices instead of re-scanning all of it: O(probes),
                 # not O(vocabulary), per batch.
                 idx = np.linspace(
-                    0, prev - 1, min(prev, self._PROBE_N)
+                    0, shared - 1, min(shared, self._PROBE_N)
                 ).astype(np.intp)
                 if isinstance(vocab, np.ndarray):
                     ok = np.array_equal(vocab[idx], self.vocab[idx])
@@ -197,20 +201,32 @@ class VocabMap:
                     "vocabulary used by earlier batches of this step"
                 )
                 raise TypeError(msg)
-            if n > prev:
-                if isinstance(vocab, np.ndarray):
-                    self.vocab = vocab
-                else:
-                    # Convert only the new suffix; the validated
-                    # prefix is already installed (and copied here).
-                    _flight.RECORDER.count("vocab_walked", prev)
-                    self.vocab = np.concatenate(
-                        [self.vocab, np.asarray(vocab[prev:])]
+            if n < prev:
+                # An older view of the vocabulary held (a merge can
+                # hand one stream's earlier batch in after another's
+                # later one): read through the one held, which keeps
+                # every entry of it.
+                if len(ids) and int(ids.max()) >= n:
+                    msg = (
+                        f"key_id {int(ids.max())} is out of range for "
+                        f"a {n}-entry key_vocab"
                     )
-                self._buf = grow_column(self._buf, n, -1)
-                self.table = self._buf[:n]
-            self._ref = vocab
-            self._ref_probe = self._probe_of(self.vocab)
+                    raise TypeError(msg)
+            else:
+                if n > prev:
+                    if isinstance(vocab, np.ndarray):
+                        self.vocab = vocab
+                    else:
+                        # Convert only the new suffix; the validated
+                        # prefix is already installed (and copied here).
+                        _flight.RECORDER.count("vocab_walked", prev)
+                        self.vocab = np.concatenate(
+                            [self.vocab, np.asarray(vocab[prev:])]
+                        )
+                    self._buf = grow_column(self._buf, n, -1)
+                    self.table = self._buf[:n]
+                self._ref = vocab
+                self._ref_probe = self._probe_of(self.vocab)
         uniq = self._touched(ids)
         new = uniq[self.table[uniq] < 0]
         if len(new):
@@ -596,6 +612,18 @@ class ArrayBatch:
     def numpy(self, name: str) -> np.ndarray:
         return np.asarray(self.cols[name])
 
+    #: The keyed windowed-event conventions (with ``"value"`` or
+    #: without), which :meth:`to_pylist` turns into keyed items.
+    _KEYED_TS = ({"key", "ts"}, {"key_id", "ts"}, {"key", "ts", "value"}, {"key_id", "ts", "value"})
+
+    def is_keyed_ts(self) -> bool:
+        """Whether the columns are a keyed windowed-event convention
+        (a ``value`` column numeric, as :meth:`to_pylist` needs)."""
+        return set(self.cols) in self._KEYED_TS and (
+            "value" not in self.cols
+            or np.issubdtype(self.numpy("value").dtype, np.number)
+        )
+
     def _key_strings(self) -> List[str]:
         """The key column as Python strings, decoding ``key_id``
         through ``key_vocab`` when dictionary-encoded."""
@@ -638,6 +666,13 @@ class ArrayBatch:
         per-row dicts.
         """
         names = set(self.cols)
+        if "side" in names and (names - {"side"}) in self._KEYED_TS:
+            # A join's side tagged as a column: the items the host
+            # tier's tagging makes, ``(key, (side, value))``.
+            cols = {name: col for name, col in self.cols.items() if name != "side"}
+            rows = ArrayBatch(cols, self.key_vocab, self.value_scale).to_pylist()
+            sides = self.numpy("side").tolist()
+            return [(k, (s, v)) for (k, v), s in zip(rows, sides)]
         # A column named key_id invokes the dictionary-encoded keyed
         # convention; _key_strings raises a clear error when the
         # vocab is missing rather than silently mis-keying rows.

@@ -26,6 +26,7 @@ import time
 import zlib
 from collections import deque
 from datetime import datetime, timedelta, timezone
+from operator import itemgetter
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -1969,8 +1970,15 @@ class _StatefulBatchRt(_OpRt):
 
     def _emit_window_events(self, events: List[Tuple[str, Any]]) -> None:
         with _flight.span("emit", self.op.step_id, rows=len(events)):
-            out: Dict[int, List[Any]] = {}
             w_count = self.driver.worker_count
+            if w_count == 1:
+                # One worker owns every key: nothing to route, and the
+                # keys are noted with no Python a row.
+                self.awoken.update(map(itemgetter(0), events))
+                if events:
+                    self._flush({0: events})
+                return
+            out: Dict[int, List[Any]] = {}
             for key, ev in events:
                 out.setdefault(_route_hash(key) % w_count, []).append(
                     (key, ev)
@@ -2013,6 +2021,16 @@ class _StatefulBatchRt(_OpRt):
         else:
             self._pipe.push(task, finalize)
 
+    def _join_to_host(self, reason: str, rest: List[Entry]) -> None:
+        """Rows a join's device tier cannot hold (itemized sides,
+        values that are not numbers): the step moves to the host tier,
+        with its state where it has some, and takes ``rest`` there."""
+        if self._wagg_empty() and not self.logics:
+            self._host_fallback(reason)
+        else:
+            self._demote(reason)
+        self.process("up", rest)
+
     def _process_window_accel(self, entries: List[Entry]) -> None:
         assert self.wagg is not None
         for i, (_w, items) in enumerate(entries):
@@ -2027,6 +2045,13 @@ class _StatefulBatchRt(_OpRt):
                 try:
                     with self._timer("stateful_batch_on_batch").time():
                         late, phase = self.wagg.on_batch_columnar(items)
+                except NonNumericValues as ex:
+                    if self.wagg.spec.kind != "join":
+                        _reraise(
+                            self.op.step_id, "the device window fold", ex
+                        )
+                    self._join_to_host(str(ex), entries[i:])
+                    return
                 except BaseException as ex:  # noqa: BLE001
                     _reraise(
                         self.op.step_id, "the device window fold", ex
@@ -2049,6 +2074,9 @@ class _StatefulBatchRt(_OpRt):
                     with self._timer("stateful_batch_on_batch").time():
                         ingest = self.wagg.on_batch_items(items)
                 except NonNumericValues as ex:
+                    if self.wagg.spec.kind == "join":
+                        self._join_to_host(str(ex), entries[i:])
+                        return
                     if (
                         self.wagg.spec.kind != "count"
                         and self._wagg_empty()

@@ -61,6 +61,8 @@ def _annotate_accel(op: Operator) -> None:
         )
     elif op.name in ("count_window", "fold_window", "reduce_window"):
         spec = _window_accel_spec(op)
+    elif op.name == "join_window":
+        spec = _join_accel_spec(op)
     if spec is not None:
         inner = _find_core_stateful(op)
         if inner is not None:
@@ -87,13 +89,7 @@ def _window_accel_spec(op: Operator):
         SessionAccelSpec,
         WindowAccelSpec,
     )
-    from bytewax_tpu.operators import _get_system_utc, _identity
-    from bytewax_tpu.operators.windowing import (
-        EventClock,
-        SessionWindower,
-        SlidingWindower,
-        TumblingWindower,
-    )
+    from bytewax_tpu.operators.windowing import SessionWindower
     from bytewax_tpu.xla import Reducer, WindowFold
 
     from bytewax_tpu.ops.segment import AGG_KINDS
@@ -148,18 +144,13 @@ def _window_accel_spec(op: Operator):
             return None
     else:
         return None
-    clock = op.conf.get("clock")
+    clock = _system_event_clock(op)
     windower = op.conf.get("windower")
-    if not isinstance(clock, EventClock):
+    if clock is None:
         return None
-    if clock.now_getter is not _get_system_utc or clock.to_system_utc is not _identity:
-        # Custom/fake clocks (tests) need the host tier's exact
-        # per-item semantics.
-        return None
-    if isinstance(windower, TumblingWindower):
-        length, offset = windower.length, windower.length
-    elif isinstance(windower, SlidingWindower):
-        length, offset = windower.length, windower.offset
+    fixed = _fixed_windows(windower)
+    if fixed is not None:
+        length, offset = fixed
     elif isinstance(windower, SessionWindower):
         # Sessions merge, so the device tier's slot-set combine must
         # be the kind's own merge: require the operator's merger to
@@ -195,6 +186,56 @@ def _window_accel_spec(op: Operator):
         offset,
         clock.wait_for_system_duration,
     )
+
+
+def _system_event_clock(op: Operator):
+    """The step's ``EventClock`` where it runs on the system clock, else
+    None: custom and fake clocks (tests) need the host tier's exact
+    per-item semantics."""
+    from bytewax_tpu.operators import _get_system_utc, _identity
+    from bytewax_tpu.operators.windowing import EventClock
+
+    clock = op.conf.get("clock")
+    if not isinstance(clock, EventClock):
+        return None
+    if clock.now_getter is not _get_system_utc or clock.to_system_utc is not _identity:
+        return None
+    return clock
+
+
+def _fixed_windows(windower):
+    """``(length, offset)`` of a tumbling or sliding windower, else None."""
+    from bytewax_tpu.operators.windowing import SlidingWindower, TumblingWindower
+
+    if isinstance(windower, TumblingWindower):
+        return windower.length, windower.length
+    if isinstance(windower, SlidingWindower):
+        return windower.length, windower.offset
+    return None
+
+
+def _join_accel_spec(op: Operator):
+    """Device lowering for ``join_window``: every row of every side
+    kept until its window closes, then the product of the sides
+    written once (``insert_mode="product"``, ``emit_mode="final"``),
+    over ``EventClock`` with the system clock and tumbling or sliding
+    windows.  Every other form stays on the host tier: ``first`` /
+    ``last`` / ``complete`` / ``running`` decide row by row as values
+    arrive, sessions merge tables, and a custom clock needs the host
+    tier's per-item semantics."""
+    from bytewax_tpu.engine.window_accel import JoinAccelSpec
+
+    if op.conf.get("insert_mode") != "product" or op.conf.get("emit_mode") != "final":
+        return None
+    clock = _system_event_clock(op)
+    fixed = _fixed_windows(op.conf.get("windower"))
+    if clock is None or fixed is None:
+        return None
+    return JoinAccelSpec(
+        len(op.ups["sides"]), clock.ts_getter, op.conf["windower"].align_to, *fixed,
+        clock.wait_for_system_duration,
+    )
+
 
 CORE_OPS = frozenset(
     {

@@ -159,6 +159,8 @@ TRACED_PHASES = frozenset(
         "retire",
         "session_place",
         "session_close",
+        "join_place",
+        "join_close",
         "emit",
         "group",
         "logic",
@@ -1641,6 +1643,8 @@ _FRACTION_BUCKETS = {
         "retire",
         "session_place",
         "session_close",
+        "join_place",
+        "join_close",
         "emit",
         "group",
         "logic",

@@ -57,6 +57,12 @@ session closed and keeps, by name, the clock and next session id the
 host tier's never-empty session logic would still hold
 (:class:`DeviceSessionAggState`).
 
+Join note: ``join_window`` with product inserts and final emits runs
+here too (:class:`DeviceJoinState`): a slot a (key, window, side) in
+the tumbling/sliding tier's table, the rows themselves in a row store
+on the device (``ops/join.py``), and a close that expands its windows'
+products there and reads back the output rows' values alone.
+
 Pipeline note (docs/performance.md): each ``on_batch*`` call returns
 ``(late_events, device_phase)`` — the host phase (vocab sync,
 watermark math, late classification) runs on the caller's thread and
@@ -80,7 +86,7 @@ import numpy as np
 from bytewax_tpu.engine import flight as _flight
 from bytewax_tpu.engine.arrays import KeyEncoder, VocabMap, grow_column
 
-__all__ = ["DeviceWindowAggState", "WindowAccelSpec"]
+__all__ = ["DeviceJoinState", "DeviceWindowAggState", "JoinAccelSpec", "WindowAccelSpec"]
 
 _US = 1_000_000.0
 
@@ -295,12 +301,21 @@ class _OpenWindows:
         clock had (tests age a table with it)."""
         self._at[: self._n] += delta_us
 
-    def due(self, now_us: float) -> np.ndarray:
+    def due(self, now_us: float, most: Optional[int] = None) -> np.ndarray:
         """Arena rows of the windows due by ``now_us``, in the order
-        they were opened."""
-        rows = np.nonzero(self._at[: self._n] <= now_us)[0]
+        they were opened; with ``most``, at most that many, the
+        earliest due first and whole instants at a time (rows that
+        share a due instant, as a key's rows of one window do, are
+        all taken or all left)."""
+        at = self._at[: self._n]
+        rows = np.nonzero(at <= now_us)[0]
         if now_us == np.inf:  # end of input: the dead hold inf too
             rows = rows[self._ids[rows] >= 0]
+        elif most is not None and len(rows) > most:
+            at_due = at[rows]
+            cut = np.partition(at_due, most)[most]
+            first = at_due < cut
+            rows = rows[first if first.any() else at_due == cut]
         return rows
 
     def next_due(self) -> float:
@@ -419,6 +434,15 @@ class _OpenSessions(_OpenWindows):
         return ids
 
 
+def _ts_us_of(batch) -> np.ndarray:
+    """A columnar batch's ``ts`` column as float64 microseconds since
+    the epoch (``np.datetime64`` or int64 microseconds)."""
+    ts_col = batch.numpy("ts")
+    if np.issubdtype(ts_col.dtype, np.datetime64):
+        return ts_col.astype("datetime64[us]").astype(np.int64).astype(np.float64)
+    return ts_col.astype(np.float64)
+
+
 def _clock_state(base_us: float, sys_at_us: float):
     """A key's clock as the host tier's ``_EventClockState``."""
     from bytewax_tpu.operators.windowing import _EventClockState
@@ -531,14 +555,8 @@ class DeviceWindowAggState:
     """
 
     def __init__(self, spec: WindowAccelSpec):
-        from bytewax_tpu.engine.sharded_state import make_agg_state
-
         self.spec = spec
-        # Mesh-sharded slot table when >1 local device: the window
-        # bookkeeping (watermarks, open/close) stays host-side; the
-        # per-(key, window) fold rides the same all_to_all exchange
-        # as keyed aggregations.
-        self.agg = make_agg_state(spec.kind)
+        self.agg = self._slot_table(spec)
         # windows_per_ts is static for a sliding windower.
         self.expand = max(1, int(np.ceil(spec.length_us / spec.offset_us)))
         # Per-key clock state, indexed by key id.  A key holds an id
@@ -576,6 +594,19 @@ class DeviceWindowAggState:
         # ids.  Both start over when a key is let go.
         self._item_iddict: Optional[Dict[str, int]] = None
         self._item_kids = _NO_KIDS
+
+    def _slot_table(self, spec: WindowAccelSpec):
+        """The per-(key, window) fold's table: mesh-sharded when >1
+        local device (the window bookkeeping, watermarks and
+        open/close, stays host-side; the fold rides the same
+        all_to_all exchange as keyed aggregations)."""
+        from bytewax_tpu.engine.sharded_state import make_agg_state
+
+        return make_agg_state(spec.kind)
+
+    def _release_slots(self, ids: np.ndarray) -> None:
+        """Give back the slots of windows that closed or left."""
+        self.agg.release_ids(ids)
 
     # -- clock -------------------------------------------------------------
 
@@ -666,6 +697,15 @@ class DeviceWindowAggState:
         self._vocab.sync(ids, vocab, self._key_ids_for)
         return self._vocab.table[ids]
 
+    def _key_ids_of(self, batch) -> np.ndarray:
+        """Key ids of a columnar batch's rows (``key_id`` through its
+        vocabulary, or ``key`` strings)."""
+        if "key_id" in batch.cols and batch.key_vocab is not None:
+            return self._sync_vocab(
+                batch.numpy("key_id").astype(np.int64), batch.key_vocab
+            )
+        return self._enc.encode(batch.numpy("key"), self._key_ids_for)
+
     def on_batch_columnar(self, batch):
         """Columnar fast path: a batch with ``"key"`` (strings) or
         dictionary-encoded ``"key_id"`` + ``key_vocab`` and ``"ts"``
@@ -674,21 +714,8 @@ class DeviceWindowAggState:
         no per-row Python.  Late rows are reported with their value
         (counting: their timestamp).  Returns ``(late_events,
         device_phase)`` — see :meth:`_ingest`."""
-        if "key_id" in batch.cols and batch.key_vocab is not None:
-            kids = self._sync_vocab(
-                batch.numpy("key_id").astype(np.int64), batch.key_vocab
-            )
-        else:
-            kids = self._enc.encode(
-                batch.numpy("key"), self._key_ids_for
-            )
-        ts_col = batch.numpy("ts")
-        if np.issubdtype(ts_col.dtype, np.datetime64):
-            ts_us = ts_col.astype("datetime64[us]").astype(np.int64).astype(
-                np.float64
-            )
-        else:
-            ts_us = ts_col.astype(np.float64)
+        kids = self._key_ids_of(batch)
+        ts_us = _ts_us_of(batch)
         if self.spec.kind == "count":
             return self._ingest(kids, ts_us, _LateTs(ts_us))
         # Keep the column's dtype: integer folds stay exact (the slot
@@ -1365,7 +1392,7 @@ class DeviceWindowAggState:
             if snap is not None
         ]
         rows = self._rows_of([key for key, _snap in out])
-        self.agg.release_ids(self.open.read(rows)[1])
+        self._release_slots(self.open.read(rows)[1])
         self.open.remove(rows)
         self.touched.difference_update(key for key, _snap in out)
         return out
@@ -1957,3 +1984,437 @@ class DeviceSessionAggState(DeviceWindowAggState):
         _flight.RECORDER.count("session_keys_remembered", -forgot)
         self.touched.difference_update(names)
         return out
+
+
+# -- the windowed product join ----------------------------------------------
+
+
+class JoinAccelSpec(WindowAccelSpec):
+    """Flatten-time annotation: lower this ``join_window`` (product
+    inserts, final emits) to the device tier."""
+
+    def __init__(
+        self,
+        sides: int,
+        ts_getter: Callable[[Any], datetime],
+        align_to: datetime,
+        length: timedelta,
+        offset: timedelta,
+        wait: timedelta,
+    ):
+        super().__init__("join", ts_getter, align_to, length, offset, wait)
+        self.sides = sides
+
+    def make_state(self) -> "DeviceJoinState":
+        return DeviceJoinState(self)
+
+    def __repr__(self) -> str:
+        return f"JoinAccelSpec({self.sides} sides)"
+
+
+class _JoinRows:
+    """A delivery's join columns beside its keys and timestamps: each
+    row's side and its value as 64 carrier bits."""
+
+    __slots__ = ("side", "bits")
+
+    def __init__(self, side: np.ndarray, bits: np.ndarray):
+        self.side = side
+        self.bits = bits
+
+    def __getitem__(self, rows) -> "_JoinRows":
+        return _JoinRows(self.side[rows], self.bits[rows])
+
+
+class _JoinLate:
+    """Late-value view of a columnar join delivery: row index → the
+    ``(side, value)`` the host tier's tagging gives the row
+    (``ArrayBatch.to_pylist``: the value a :class:`TsValue`)."""
+
+    __slots__ = ("_side", "_values", "_ts")
+
+    def __init__(self, side: np.ndarray, values: np.ndarray, ts_us: np.ndarray):
+        self._side, self._values, self._ts = side, values, _LateTs(ts_us)
+
+    def __getitem__(self, row: int):
+        from bytewax_tpu.engine.arrays import TsValue
+
+        return (int(self._side[row]), TsValue(self._values[row].item(), self._ts[row]))
+
+
+#: A side's carrier: integers (and bools) as int64, floats as float64.
+_CARRIERS = {"i": np.int64, "b": np.int64, "f": np.float64}
+
+#: The most slots one close of the join takes before end of input (the
+#: earliest due first; the rest stay due for the next).  A close's
+#: output is Python an output row downstream, and while it runs no row
+#: comes in: a backlog closed at once (a flood's first closes hold
+#: millions of rows) would stall the input past the clock's wait, and
+#: rows that came on time by the data would come late by the wall
+#: clock.
+_CLOSE_SLOTS = 1 << 16
+
+
+class DeviceJoinState(DeviceWindowAggState):
+    """``join_window`` with product inserts and final emits on the
+    device tier: the tumbling/sliding tier's clock, keys, open-window
+    table and due scan, over one slot a (key, window, side).
+
+    A slot counts its rows in the slot table (the ``count`` fold), and
+    its rows lie in one region of a row store on the device
+    (:class:`~bytewax_tpu.ops.join.RowStore`): a row is its value
+    alone, 64 carrier bits, an integer column exact whatever its
+    width.  The composite of a slot is ``kid << 32 | wid * sides +
+    side + 2**31``, so a window's sides are neighbours in the
+    table's order and close together (they share a key clock and a
+    close time).  A close expands its windows on the device
+    (:func:`~bytewax_tpu.ops.join.join_expand`: each window's product
+    of ``max(count, 1)`` over its sides, the prefix sum, the stored row
+    of each side of each output row) and reads back only the output
+    rows' values; a side with no row reads ``None``, as
+    ``_SideTable.rows`` writes it.  The events are built from those
+    columns with one ``tolist()`` a side.
+
+    Snapshots are the host tier's (``_WindowSnapshot`` with a
+    ``_SideTable`` a window), so a resume crosses tiers both ways.
+    The tier takes columnar sides only (``ArrayBatch.is_keyed_ts``
+    with the ``side`` column the join's tagging adds): the driver
+    hands an itemized delivery to the host tier, state and all."""
+
+    def __init__(self, spec: JoinAccelSpec):
+        from bytewax_tpu.ops.join import RowStore
+
+        super().__init__(spec)
+        self.store = RowStore()
+        #: Per side: its carrier (``_CARRIERS``), fixed by its first
+        #: rows, and whether any of its integers needed 64 bits.
+        self._carrier: List[Optional[str]] = [None] * spec.sides
+        self._wide = [False] * spec.sides
+
+    def _slot_table(self, spec):
+        from bytewax_tpu.engine.xla import DeviceAggState
+
+        # One device: the close reads the count field beside the
+        # store on the device that holds both.
+        return DeviceAggState("count")
+
+    def _release_slots(self, ids: np.ndarray) -> None:
+        self.agg.release_ids(ids)
+        self.store.release(ids.astype(np.int64))
+
+    # -- composites -----------------------------------------------------------
+
+    def _windows_of(self, comp: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(kids, wids, sides)`` of slot composites."""
+        low = (comp & _WID_MASK) - _WID_BIAS
+        wids = low // self.spec.sides
+        return comp >> 32, wids, low - wids * self.spec.sides
+
+    def _closes_of(self, comp: np.ndarray) -> np.ndarray:
+        spec = self.spec
+        wids = self._windows_of(comp)[1]
+        return spec.align_us + wids * spec.offset_us + spec.length_us
+
+    # -- values -----------------------------------------------------------------
+
+    def _carried(self, side: np.ndarray, values: np.ndarray) -> np.ndarray:
+        """The values as carrier bits (int64), each side in the
+        carrier its first rows fixed; raises :class:`NonNumericValues`
+        for a value the device tier cannot hold exactly, before any
+        state is touched."""
+        from bytewax_tpu.engine.xla import NonNumericValues
+
+        if values.dtype == np.bool_:
+            kind = "b"
+        elif np.issubdtype(values.dtype, np.integer):
+            kind = "i"
+            if values.dtype == np.uint64 and len(values) and values.max() > np.iinfo(np.int64).max:
+                raise NonNumericValues("the join's device tier holds integers of 64 bits")
+        elif np.issubdtype(values.dtype, np.floating):
+            kind = "f"
+        else:
+            msg = f"the join's device tier holds numbers, not {values.dtype}"
+            raise NonNumericValues(msg)
+        carriers = {}
+        for s in np.unique(side).tolist():
+            have = self._carrier[s] or kind
+            if have != kind and not (have == "f" or {have, kind} == {"i", "b"}):
+                msg = (
+                    f"side {s} of the join took {have!r} values and now "
+                    f"{kind!r}: the device tier keeps one carrier a side"
+                )
+                raise NonNumericValues(msg)
+            carriers[s] = have
+        bits = np.empty(len(values), dtype=np.int64)
+        for s, have in carriers.items():
+            mine = side == s if len(carriers) > 1 else slice(None)
+            col = values[mine].astype(_CARRIERS[have])
+            if have == "f":
+                bits[mine] = col.view(np.int64)
+            else:
+                bits[mine] = col
+                if len(col) and (
+                    col.min() < np.iinfo(np.int32).min or col.max() > np.iinfo(np.int32).max
+                ):
+                    self._wide[s] = True
+            self._carrier[s] = have
+        return bits
+
+    def _values_of(self, side: int, low: np.ndarray, high: Optional[np.ndarray]) -> np.ndarray:
+        """A side's values back from their carrier words."""
+        carrier = self._carrier[side]
+        if high is None:
+            return low.astype(bool) if carrier == "b" else low
+        pair = np.empty((len(low), 2), dtype=np.int32)
+        pair[:, 0] = low
+        pair[:, 1] = high
+        return pair.view(np.float64 if carrier == "f" else np.int64).ravel()
+
+    def _fetches_high(self, side: int) -> bool:
+        return self._carrier[side] == "f" or self._wide[side]
+
+    # -- ingest -----------------------------------------------------------------
+
+    def on_batch_columnar(self, batch):
+        """A delivery of tagged columnar sides: ``key`` or ``key_id``,
+        ``ts``, ``value`` and ``side``.  Returns ``(late_events,
+        device_phase)`` — see :meth:`_ingest`."""
+        if "side" not in batch.cols or "value" not in batch.cols:
+            msg = "a columnar join delivery needs 'side' and 'value' columns"
+            raise TypeError(msg)
+        side = batch.numpy("side").astype(np.int64)
+        values = batch.numpy("value")
+        if batch.value_scale is not None:
+            values = values * batch.value_scale
+        bits = self._carried(side, values)
+        kids = self._key_ids_of(batch)
+        ts_us = _ts_us_of(batch)
+        return self._ingest(
+            kids, ts_us, _JoinLate(side, values, ts_us), fold_vals=_JoinRows(side, bits)
+        )
+
+    def on_batch_items(self, items: List[Any]):
+        from bytewax_tpu.engine.xla import NonNumericValues
+
+        raise NonNumericValues("the join's device tier takes columnar sides only")
+
+    def _absorb(self, kids_ok: np.ndarray, ts_ok: np.ndarray, rows: _JoinRows) -> None:
+        """Each on-time row into every window that holds it: its slot
+        counts it, its region of the store keeps its value."""
+        with _flight.span("prep", rows=len(kids_ok)):
+            spec = self.spec
+            hi = np.floor((ts_ok - spec.align_us) / spec.offset_us).astype(np.int64)
+            if len(hi) and int(np.abs(hi).max()) * spec.sides >= (1 << 31) - self.expand * spec.sides:
+                msg = (
+                    "window ids exceed the composite encoding range; "
+                    "move align_to closer to the event times or use a "
+                    "larger window offset"
+                )
+                raise ValueError(msg)
+            if self.expand == 1 and spec.offset_us == spec.length_us:
+                kid_rep, wid_rep = kids_ok, hi
+            else:
+                e = np.arange(self.expand, dtype=np.int64)
+                wids = hi[:, None] - e[None, :]
+                in_window = ts_ok[:, None] < spec.align_us + wids * spec.offset_us + spec.length_us
+                kid_rep = np.broadcast_to(kids_ok[:, None], wids.shape)[in_window]
+                wid_rep = wids[in_window]
+                rows = rows[np.nonzero(in_window)[0]]
+            comp = (kid_rep << 32) + (wid_rep * spec.sides + rows.side + _WID_BIAS)
+        self._place(comp, rows.bits)
+
+    def _place(self, comp: np.ndarray, bits: np.ndarray) -> None:
+        """Rows with slot composites ``comp``: their slots (opened where
+        new), their places in the store in arrival order within a slot,
+        the store written and the slots' counts folded."""
+        n = len(comp)
+        if not n:
+            return
+        with _flight.span("join_place", rows=n):
+            order = np.argsort(comp, kind="stable")
+            in_order = comp[order]
+            head = np.empty(n, dtype=bool)
+            head[0] = True
+            np.not_equal(in_order[1:], in_order[:-1], out=head[1:])
+            first = np.flatnonzero(head)
+            group = np.cumsum(head) - 1
+            slots = self.open.ids_for(in_order[first], self.agg)
+            base = self.store.place(
+                slots.astype(np.int64), np.diff(np.append(first, n))
+            )
+            pos = np.empty(n, dtype=np.int64)
+            pos[order] = base[group] + (np.arange(n) - first[group])
+            self.store.write(pos, bits.view(np.int32).reshape(n, 2).T)
+            slot_of_row = np.empty(n, dtype=np.int32)
+            slot_of_row[order] = slots[group]
+            _flight.RECORDER.count("join_rows_stored", n)
+            _flight.RECORDER.record("device_dispatch", tier="join", rows=n)
+        self.agg.update_ids(slot_of_row, np.ones(n, dtype=np.float32))
+
+    # -- close ------------------------------------------------------------------
+
+    def _close_due(
+        self, now_us: float, clock=None
+    ) -> Tuple[List[Tuple[str, Tuple[int, str, Any]]], np.ndarray]:
+        """Close the windows that are due: each window's product of its
+        sides' rows, expanded and read back on the device, as events;
+        and the keys left without an open window."""
+        if not self.open_count:
+            return [], _NO_KIDS
+        with _flight.span("join_close", rows=len(self.open)):
+            if clock is not None:
+                self.open.retime(*clock, self._closes_of)
+            due = self.open.due(now_us, None if now_us == np.inf else _CLOSE_SLOTS)
+            if not len(due):
+                return [], _NO_KIDS
+            comp, ids = self.open.read(due)
+            order = np.argsort(comp)
+            comp, ids = comp[order], ids[order]
+            kids, wids, sides = self._windows_of(comp)
+            window = (kids << 32) + (wids + _WID_BIAS)
+            head = np.empty(len(comp), dtype=bool)
+            head[0] = True
+            np.not_equal(window[1:], window[:-1], out=head[1:])
+            at = np.cumsum(head) - 1
+            n_windows = int(at[-1]) + 1
+            slots = np.full((self.spec.sides, n_windows), -1, dtype=np.int64)
+            slots[sides, at] = ids
+            starts = np.zeros_like(slots)
+            counts = np.zeros_like(slots)
+            starts[sides, at], counts[sides, at] = self.store.regions(ids.astype(np.int64))
+            sizes = np.maximum(counts, 1).prod(axis=0)
+            columns = self._expand(slots, starts, sizes)
+            events = self._join_events(
+                kids[head], wids[head], sizes, counts, columns, comp[head]
+            )
+            self.open.remove(due)
+            self._release_slots(ids)
+            gone = self.open.without_window(np.unique(kids))
+        return events, gone
+
+    def _expand(
+        self, slots: np.ndarray, starts: np.ndarray, sizes: np.ndarray
+    ) -> List[np.ndarray]:
+        """Each side's values of the close's output rows, expanded on
+        the device from the slot table's counts (``RowStore.expand``)."""
+        sides = self.spec.sides
+        wide = tuple(s for s in range(sides) if self._fetches_high(s))
+        self.agg._ensure_fields()
+        low, high = self.store.expand(self.agg._fields["count"], slots, starts, sizes, wide)
+        return [
+            self._values_of(s, low[s], high[wide.index(s)] if s in wide else None)
+            for s in range(sides)
+        ]
+
+    def _join_events(self, kids, wids, sizes, counts, columns, comp):
+        """The close's events: a row a combination (``"E"``), then a
+        ``WindowMetadata`` a window (``"M"``) while the meta stream is
+        read."""
+        total = int(sizes.sum())
+        _flight.RECORDER.count("join_rows_emitted", total)
+        keys = list(map(self.keys.__getitem__, kids.tolist()))
+        values = []
+        for s, col in enumerate(columns):
+            absent = counts[s] == 0
+            if absent.any():
+                col = col.astype(object)
+                col[np.repeat(absent, sizes)] = None
+            values.append(col.tolist())
+        events = list(
+            zip(
+                np.repeat(np.asarray(keys, dtype=object), sizes).tolist(),
+                zip(np.repeat(wids, sizes).tolist(), repeat("E"), zip(*values)),
+            )
+        )
+        if self.spec.meta_live:
+            metas = self._metas(self._closes_of(comp).tolist())
+            _flight.RECORDER.count("window_meta_events", len(metas))
+            events.extend(zip(keys, zip(wids.tolist(), repeat("M"), metas)))
+        return events
+
+    # -- recovery -----------------------------------------------------------------
+
+    def snapshots_for(self, keys: List[str]):
+        """Host-tier ``_WindowSnapshot``s: per open window its metadata
+        and a ``_SideTable`` of every stored row, in arrival order a
+        side; a key with no open window snapshots as a discard."""
+        from bytewax_tpu.operators import _SideTable
+        from bytewax_tpu.operators.windowing import (
+            _SlidingWindowerState,
+            _WindowSnapshot,
+        )
+
+        comp, ids = self.open.read(self._rows_of(keys))
+        kids, wids, sides = self._windows_of(comp)
+        slot_ids = ids.astype(np.int64)
+        low, high = self.store.read(slot_ids)
+        lengths = self.store.regions(slot_ids)[1]
+        side_of_row = np.repeat(sides, lengths)
+        values = np.empty(len(low), dtype=object)
+        for s in range(self.spec.sides):
+            mine = side_of_row == s
+            if mine.any():
+                got = self._values_of(s, low[mine], high[mine] if self._fetches_high(s) else None)
+                values[mine] = got.astype(object)
+        metas = self._metas(self._closes_of(comp).tolist())
+        open_of: Dict[int, Tuple[dict, dict]] = {}
+        at = 0
+        for kid, wid, side, meta, n in zip(
+            kids.tolist(), wids.tolist(), sides.tolist(), metas, lengths.tolist()
+        ):
+            opened, tables = open_of.setdefault(kid, ({}, {}))
+            opened[wid] = meta
+            table = tables.setdefault(wid, _SideTable.empty(self.spec.sides))
+            table.pools[side] = values[at : at + n].tolist()
+            at += n
+        out = []
+        for key in keys:
+            kid = self.key_ids.get(key)
+            if kid not in open_of:
+                out.append((key, None))
+                continue
+            opened, tables = open_of[kid]
+            out.append(
+                (
+                    key,
+                    _WindowSnapshot(
+                        _clock_state(self.base_us[kid], self.sys_at_base[kid]),
+                        _SlidingWindowerState(opened=opened),
+                        tables,
+                        [],
+                    ),
+                )
+            )
+        return out
+
+    def _load_windows(self, kids: List[int], items: List[Tuple[str, Any]]) -> None:
+        """Reopen the page's windows from host-tier ``_SideTable``s and
+        put every pooled row in the store."""
+        kid_rep, wid_rep, side_rep, values = [], [], [], []
+        for kid, (_key, snap) in zip(kids, items):
+            for wid, table in snap.logic_states.items():
+                for side, pool in enumerate(table.pools):
+                    kid_rep += [kid] * len(pool)
+                    wid_rep += [wid] * len(pool)
+                    side_rep += [side] * len(pool)
+                    values += pool
+        if not values:
+            return
+        side = np.asarray(side_rep, dtype=np.int64)
+        bits = self._carried(side, np.asarray(values))
+        comp = (np.asarray(kid_rep, dtype=np.int64) << 32) + (
+            np.asarray(wid_rep, dtype=np.int64) * self.spec.sides + side + _WID_BIAS
+        )
+        self._place(comp, bits)
+
+    def _replay_queue(self, kid: int, snap: Any) -> None:
+        """A host-tier logic's queued ``((side, value), ts)`` entries
+        (ordered mode keeps on-time values until the watermark passes
+        them) go into their windows now."""
+        queue = getattr(snap, "queue", None)
+        if not queue:
+            return
+        ts_q = np.fromiter((_to_us(ts) for _v, ts in queue), dtype=np.float64, count=len(queue))
+        side = np.asarray([v[0] for v, _ts in queue], dtype=np.int64)
+        bits = self._carried(side, np.asarray([v[1] for v, _ts in queue]))
+        self._absorb(np.full(len(queue), kid, dtype=np.int64), ts_q, _JoinRows(side, bits))

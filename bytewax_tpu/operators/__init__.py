@@ -1574,10 +1574,47 @@ def _tag_sides(
     step_id: str,
     *ups: KeyedStream[Any],
 ) -> KeyedStream[Tuple[int, Any]]:
-    """Tag each upstream's values with their side index and merge."""
+    """Tag each upstream's values with their side index and merge.
+
+    A keyed columnar side (``key`` or ``key_id``, ``ts`` and
+    ``value`` columns) stays columnar: it gains a ``side`` column,
+    which the join's device tier reads and which ``to_pylist`` turns
+    into the ``(key, (side, value))`` items the host tier takes."""
+
+    def tag(side: int):
+        side_id = f"{step_id}.side_{side}"
+
+        def shim(xs):
+            import numpy as _np
+
+            from bytewax_tpu.engine.arrays import ArrayBatch
+
+            if isinstance(xs, ArrayBatch) and xs.is_keyed_ts():
+                side_col = _np.full(len(xs), side, _np.int8)
+                return ArrayBatch(
+                    {**xs.cols, "side": side_col},
+                    key_vocab=xs.key_vocab,
+                    value_scale=xs.value_scale,
+                )
+            if isinstance(xs, ArrayBatch):
+                xs = xs.to_pylist()
+            out = []
+            for k_v in xs:
+                try:
+                    k, v = k_v
+                except TypeError as ex:
+                    msg = (
+                        f"step {side_id!r} requires (key, value) 2-tuple "
+                        f"from upstream; got a {type(k_v)!r} instead"
+                    )
+                    raise TypeError(msg) from ex
+                out.append((k, (side, v)))
+            return out
+
+        return shim
+
     tagged = [
-        map_value(f"side_{i}", up, lambda v, _i=i: (_i, v))
-        for i, up in enumerate(ups)
+        flat_map_batch(f"side_{i}", up, tag(i)) for i, up in enumerate(ups)
     ]
     return merge("merge", *tagged)
 

@@ -31,8 +31,12 @@ _OFF_MAIN = ("device", "collective_lane", "snapshot_lane")
 
 
 @pytest.fixture
-def gained():
-    """What ``phase_totals`` and the counters gained since."""
+def gained(monkeypatch):
+    """What ``phase_totals`` and the counters gained since.  The
+    functions named under ``jit_stage_seconds[...]`` start afresh: a
+    process that ran other tests first may have named as many as the
+    cap allows, and a probe's stage would read as ``other``."""
+    monkeypatch.setattr(flight, "_jit_names", set())
     REC._ledger = {}
     REC._ledger_pre_close = None
     assert REC._phase_stack == []

@@ -142,7 +142,8 @@ def test_a_vocabulary_that_is_not_append_only_raises(vocab_kind, change):
     vocab = np.array(first) if vocab_kind == "ndarray" else list(first)
     vm.sync(np.arange(100), vocab, lambda keys: list(range(len(keys))))
     if change == "shrink":
-        bad = vocab[:50]
+        # Shorter, and not a prefix of the vocabulary held.
+        bad = vocab[50:]
     elif change == "rewritten_prefix":
         bad = vocab.copy() if vocab_kind == "ndarray" else list(vocab)
         bad[0] = "other"
@@ -151,6 +152,28 @@ def test_a_vocabulary_that_is_not_append_only_raises(vocab_kind, change):
         bad[0] = "other"
     with pytest.raises(TypeError, match="key_vocab"):
         vm.sync(np.arange(3), bad, lambda keys: list(range(len(keys))))
+
+
+@pytest.mark.parametrize("vocab_kind", ["ndarray", "list"])
+def test_an_older_view_of_the_vocabulary_reads_through_the_one_held(vocab_kind):
+    """A merge can deliver one stream's earlier batch, with a prefix of
+    the vocabulary, after another's later one: its ids map as the held
+    vocabulary maps them, the held vocabulary stays, and an id past the
+    older view's end raises."""
+    names = [f"k{i}" for i in range(100)]
+    ids = _Ids()
+    vm = VocabMap()
+    full = np.array(names) if vocab_kind == "ndarray" else list(names)
+    older = full[:40]
+    vm.sync(np.array([5, 70]), full, ids.alloc_many)
+    assert vm.sync(np.array([5, 30]), older, ids.alloc_many).tolist() == [5, 30]
+    assert vm.table[[5, 30, 70]].tolist() == [ids.of["k5"], ids.of["k30"], ids.of["k70"]]
+    assert len(vm.table) == 100
+    # The longer vocabulary is still the one held: no revalidation.
+    vm.sync(np.array([99]), full, ids.alloc_many)
+    assert vm.table[99] == ids.of["k99"]
+    with pytest.raises(TypeError, match="out of range for a 40-entry key_vocab"):
+        vm.sync(np.array([40]), older, ids.alloc_many)
 
 
 def _isin_drop(table, ids):
