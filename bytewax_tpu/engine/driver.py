@@ -26,7 +26,6 @@ import time
 import zlib
 from collections import deque
 from datetime import datetime, timedelta, timezone
-from operator import itemgetter
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -54,6 +53,7 @@ from bytewax_tpu.errors import (
 from bytewax_tpu.engine.flatten import Plan, flatten
 from bytewax_tpu.engine.recovery_store import RecoveryStore, ResumeFrom
 from bytewax_tpu.engine.residency import ResidentKeyState, maybe_wrap
+from bytewax_tpu.engine.window_accel import WindowEvents
 from bytewax_tpu.engine.xla import AccelSpec, DeviceAggState, NonNumericValues
 from bytewax_tpu.inputs import (
     AbortExecution,
@@ -1968,22 +1968,28 @@ class _StatefulBatchRt(_OpRt):
                     driver.ship_deliver(self.idx, "up", (w, group))
         return local
 
-    def _emit_window_events(self, events: List[Tuple[str, Any]]) -> None:
+    def _emit_window_events(self, events: WindowEvents) -> None:
+        """A device tier's output of one delivery downstream: its keys
+        noted (one a window, not one a row), its rows routed to their
+        keys' workers part by part."""
         with _flight.span("emit", self.op.step_id, rows=len(events)):
+            self.awoken.update(events.keys)
             w_count = self.driver.worker_count
             if w_count == 1:
-                # One worker owns every key: nothing to route, and the
-                # keys are noted with no Python a row.
-                self.awoken.update(map(itemgetter(0), events))
+                # One worker owns every key: nothing to route.
                 if events:
                     self._flush({0: events})
                 return
-            out: Dict[int, List[Any]] = {}
-            for key, ev in events:
-                out.setdefault(_route_hash(key) % w_count, []).append(
-                    (key, ev)
-                )
-                self.awoken.add(key)
+            out: Dict[int, WindowEvents] = {}
+            home: Dict[str, int] = {}
+            for part in ("late", "down", "meta"):
+                for row in getattr(events, part):
+                    w = home.get(row[0])
+                    if w is None:
+                        w = home[row[0]] = _route_hash(row[0]) % w_count
+                    if w not in out:
+                        out[w] = WindowEvents()
+                    getattr(out[w], part).append(row)
             self._flush(out)
 
     def _wagg_empty(self) -> bool:

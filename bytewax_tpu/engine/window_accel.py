@@ -36,12 +36,19 @@ time at which the window falls due under its key's clock (``at``); a
 delivery's phase retimes the windows of the delivery's own keys (the
 only clocks it moved), so the due scan and the notify hint are one
 pass over one column each, not a recomputation of every open
-window's watermark.  "M" events (two ``datetime``s and a
+window's watermark.  Metadata rows (two ``datetime``s and a
 ``WindowMetadata`` a window) are built only while the plan keeps the
 step's ``meta`` tap (``WindowAccelSpec.meta_live``, set at flatten
 time).  The session tier keeps its open sessions the same way, in
 :class:`_OpenSessions` (the arena with each session's bounds beside
-it), and builds "M" events under the same rule.
+it), and builds metadata rows under the same rule.
+
+Output note: every tier here hands a delivery's output on as one
+:class:`WindowEvents`, its ``down``, ``late`` and ``meta`` rows each
+built once in the form its stream carries, so the window operator's
+unwrap taps pass them on with no pass over the rows; the host tier's
+``_WindowLogic`` emits tagged ``(key, (window_id, type, obj))`` rows,
+which the taps still take apart.
 
 Key note: a tumbling/sliding step holds a key (its id, its clock,
 its encoder entries) only while the key has an open window, as the
@@ -78,7 +85,6 @@ remain synchronous and may only run with the pipeline drained.
 
 from collections import deque
 from datetime import datetime, timedelta, timezone
-from itertools import repeat
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -86,7 +92,13 @@ import numpy as np
 from bytewax_tpu.engine import flight as _flight
 from bytewax_tpu.engine.arrays import KeyEncoder, VocabMap, grow_column
 
-__all__ = ["DeviceJoinState", "DeviceWindowAggState", "JoinAccelSpec", "WindowAccelSpec"]
+__all__ = [
+    "DeviceJoinState",
+    "DeviceWindowAggState",
+    "JoinAccelSpec",
+    "WindowAccelSpec",
+    "WindowEvents",
+]
 
 _US = 1_000_000.0
 
@@ -457,6 +469,49 @@ def _clock_state(base_us: float, sys_at_us: float):
             else datetime.min.replace(tzinfo=timezone.utc)
         ),
     )
+
+
+class WindowEvents:
+    """What one delivery of a device-tier window step writes, already
+    split by the stream each row goes to: ``down`` (closed windows'
+    values), ``late`` (late rows' values) and ``meta`` (closed
+    windows' ``WindowMetadata``, only while the ``meta`` tap is live),
+    each a list of ``(key, (window_id, obj))`` rows built once, and
+    ``keys``, the keys those rows belong to (one a window or a late
+    row, not one an output row).  The window operator's unwrap taps
+    hand a part on as it is; the host tier's tagged
+    ``(key, (window_id, type, obj))`` lists still go through their
+    comprehension.  Its length is its rows, and iterating it gives the
+    tagged form: late rows, then ``"E"``, then ``"M"``."""
+
+    __slots__ = ("down", "late", "meta", "keys")
+
+    def __init__(self, down=None, keys=None, late=None, meta=None):
+        self.down: List[Any] = [] if down is None else down
+        self.keys: List[str] = [] if keys is None else keys
+        self.late: List[Any] = [] if late is None else late
+        self.meta: List[Any] = [] if meta is None else meta
+
+    def __len__(self) -> int:
+        return len(self.down) + len(self.late) + len(self.meta)
+
+    def __add__(self, other: "WindowEvents") -> "WindowEvents":
+        """Two deliveries' output in order, part by part."""
+        if not other:
+            return self
+        if not self:
+            return other
+        return WindowEvents(
+            self.down + other.down,
+            self.keys + other.keys,
+            self.late + other.late,
+            self.meta + other.meta,
+        )
+
+    def __iter__(self):
+        for typ, rows in (("L", self.late), ("E", self.down), ("M", self.meta)):
+            for key, (wid, obj) in rows:
+                yield key, (wid, typ, obj)
 
 
 class _LateTs:
@@ -834,9 +889,9 @@ class DeviceWindowAggState:
                 raise NonNumericValues(msg)
 
     def on_batch(self, keys: List[str], values: List[Any]):
-        """Fold a batch; window events are tagged like the host tier's
-        ``_WindowLogic`` ("E" emit / "L" late / "M" meta).  Returns
-        ``(late_events, device_phase)`` — see :meth:`_ingest`."""
+        """Fold a batch; window events come as :class:`WindowEvents`,
+        split by stream.  Returns ``(late_events, device_phase)`` —
+        see :meth:`_ingest`."""
         spec = self.spec
         with _flight.span("encode", rows=len(keys)):
             kids = self._key_ids_for(keys)
@@ -906,7 +961,7 @@ class DeviceWindowAggState:
                 map(self.keys.__getitem__, seg_kids.tolist())
             )
 
-        events: List[Tuple[str, Tuple[int, str, Any]]] = []
+        events = WindowEvents()
         kids_ok = ts_ok = vals_ok = None
         if not any_late:
             # Nothing to drop: the engine's own columns go on as they
@@ -921,11 +976,10 @@ class DeviceWindowAggState:
                 else:
                     vals_ok = np.array(values)  # keep dtype for exact ints
         else:
-            events.extend(
-                self._late_events(
-                    np.nonzero(late_mask)[0], kids, ts_us, values
-                )
+            events.late = self._late_events(
+                np.nonzero(late_mask)[0], kids, ts_us, values
             )
+            events.keys = [key for key, _ev in events.late]
             ok = ~late_mask
             if ok.any():
                 kids_ok = kids[ok]
@@ -1039,9 +1093,10 @@ class DeviceWindowAggState:
 
     def _late_events(
         self, late_rows: np.ndarray, kids: np.ndarray, ts_us: np.ndarray, values
-    ) -> List[Tuple[str, Tuple[int, str, Any]]]:
-        """Window-id attribution for late rows (sliding arithmetic;
-        the session subclass reports the late-session sentinel)."""
+    ) -> List[Tuple[str, Tuple[int, Any]]]:
+        """The ``late`` rows: window-id attribution for late rows
+        (sliding arithmetic; the session subclass reports the
+        late-session sentinel)."""
         spec = self.spec
         events = []
         wid_hi = np.floor(
@@ -1062,7 +1117,7 @@ class DeviceWindowAggState:
                     + wid * spec.offset_us
                     + spec.length_us
                 ):
-                    events.append((key, (wid, "L", values[row])))
+                    events.append((key, (wid, values[row])))
         return events
 
     def _absorb(
@@ -1140,13 +1195,13 @@ class DeviceWindowAggState:
 
     def _close_due(
         self, now_us: float, clock=None
-    ) -> Tuple[List[Tuple[str, Tuple[int, str, Any]]], np.ndarray]:
+    ) -> Tuple[WindowEvents, np.ndarray]:
         """Close the windows that are due: their events, and the ids
         of the keys this leaves without an open window (for
         :meth:`let_go`).  ``clock`` (:meth:`_phase_clock`, from a
         delivery's own phase) first retimes the delivery's keys."""
         if not self.open_count:
-            return [], _NO_KIDS
+            return WindowEvents(), _NO_KIDS
         # Ledger: `close_scan` (the due scan over the open windows),
         # `fetch` (inside ``states_of``), `close_emit` (columns to
         # events for the windows that close), `retire` (which keys
@@ -1156,7 +1211,7 @@ class DeviceWindowAggState:
                 self.open.retime(*clock, self._closes_of)
             due = self.open.due(now_us)
             if not len(due):
-                return [], _NO_KIDS
+                return WindowEvents(), _NO_KIDS
             comp_due, ids = self.open.read(due)
             kids_due = comp_due >> 32
         # bytewax: allow[BTX-DRAIN] — the windower's .agg is its own slot table (never residency-wrapped; the driver evicts only the keyed-agg/scan tiers), and this due-window fetch runs inside the deferred device phase the pipeline worker owns
@@ -1167,15 +1222,11 @@ class DeviceWindowAggState:
             keys = list(map(self.keys.__getitem__, kids_due.tolist()))
             wids = ((comp_due & _WID_MASK) - _WID_BIAS).tolist()
             values = map(self._finalize_one, states)
-            events = list(zip(keys, zip(wids, repeat("E"), values)))
+            events = WindowEvents(list(zip(keys, zip(wids, values))), keys)
             if self.spec.meta_live:
                 metas = self._metas(self._closes_of(comp_due).tolist())
                 _flight.RECORDER.count("window_meta_events", len(metas))
-                # "E" then "M" per window, as the host tier emits.
-                both = [None] * (2 * len(events))
-                both[0::2] = events
-                both[1::2] = zip(keys, zip(wids, repeat("M"), metas))
-                events = both
+                events.meta = list(zip(keys, zip(wids, metas)))
         with _flight.span("retire", rows=len(due)):
             gone = self.open.without_window(np.unique(kids_due))
         return events, gone
@@ -1209,13 +1260,13 @@ class DeviceWindowAggState:
         # stats_window append it).
         return snap
 
-    def on_notify(self) -> List[Tuple[str, Tuple[int, str, Any]]]:
+    def on_notify(self) -> WindowEvents:
         return self._close_now(datetime.now(timezone.utc).timestamp() * _US)
 
-    def on_eof(self) -> List[Tuple[str, Tuple[int, str, Any]]]:
+    def on_eof(self) -> WindowEvents:
         return self._close_now(np.inf)
 
-    def _close_now(self, now_us: float):
+    def _close_now(self, now_us: float) -> WindowEvents:
         """A close on the main thread with the pipeline drained: the
         keys it leaves without a window go at once."""
         events, gone = self._close_due(now_us)
@@ -1547,17 +1598,14 @@ class DeviceSessionAggState(DeviceWindowAggState):
 
     def _late_events(
         self, late_rows: np.ndarray, kids: np.ndarray, ts_us: np.ndarray, values
-    ) -> List[Tuple[str, Tuple[int, str, Any]]]:
+    ) -> List[Tuple[str, Tuple[int, Any]]]:
         # Session membership depends on other values, so a late value
         # can't name a specific session (host: late_for -> sentinel).
         from bytewax_tpu.operators.windowing import LATE_SESSION_ID
 
         self._late_kids = np.unique(kids[late_rows])
         return [
-            (
-                self.keys[int(kids[row])],
-                (LATE_SESSION_ID, "L", values[row]),
-            )
+            (self.keys[int(kids[row])], (LATE_SESSION_ID, values[row]))
             for row in late_rows
         ]
 
@@ -1810,7 +1858,7 @@ class DeviceSessionAggState(DeviceWindowAggState):
 
     def _close_due(
         self, now_us: float, clock=None
-    ) -> Tuple[List[Tuple[str, Tuple[int, str, Any]]], np.ndarray]:
+    ) -> Tuple[WindowEvents, np.ndarray]:
         """Close the sessions that are due: their events, and the ids
         of the keys this leaves without an open session (with those
         of the delivery's keys whose every row was late)."""
@@ -1823,7 +1871,8 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 table.retime(*clock[:3], None)
             due = table.due(now_us) if len(table) else _NO_KIDS
             if not len(due):
-                return [], table.without_window(late) if len(late) else _NO_KIDS
+                gone = table.without_window(late) if len(late) else _NO_KIDS
+                return WindowEvents(), gone
             comp, ids = table.read(due)
             lo, hi = table.bounds(due)
             metas = self._metas(comp, lo, hi) if self.spec.meta_live else None
@@ -1835,14 +1884,11 @@ class DeviceSessionAggState(DeviceWindowAggState):
             kids_due = comp >> 32
             keys = list(map(self.keys.__getitem__, kids_due.tolist()))
             wids = ((comp & _WID_MASK) - _WID_BIAS).tolist()
-            events = list(zip(keys, zip(wids, repeat("E"), map(self._finalize_one, states))))
+            values = map(self._finalize_one, states)
+            events = WindowEvents(list(zip(keys, zip(wids, values))), keys)
             if metas is not None:
                 _flight.RECORDER.count("window_meta_events", len(metas))
-                # "E" then "M" per session, as the host tier emits.
-                both = [None] * (2 * len(events))
-                both[0::2] = events
-                both[1::2] = zip(keys, zip(wids, repeat("M"), metas))
-                events = both
+                events.meta = list(zip(keys, zip(wids, metas)))
             _flight.RECORDER.count("session_closes", len(due))
             gone = table.without_window(np.unique(np.append(kids_due, late)))
         return events, gone
@@ -2255,18 +2301,18 @@ class DeviceJoinState(DeviceWindowAggState):
 
     def _close_due(
         self, now_us: float, clock=None
-    ) -> Tuple[List[Tuple[str, Tuple[int, str, Any]]], np.ndarray]:
+    ) -> Tuple[WindowEvents, np.ndarray]:
         """Close the windows that are due: each window's product of its
         sides' rows, expanded and read back on the device, as events;
         and the keys left without an open window."""
         if not self.open_count:
-            return [], _NO_KIDS
+            return WindowEvents(), _NO_KIDS
         with _flight.span("join_close", rows=len(self.open)):
             if clock is not None:
                 self.open.retime(*clock, self._closes_of)
             due = self.open.due(now_us, None if now_us == np.inf else _CLOSE_SLOTS)
             if not len(due):
-                return [], _NO_KIDS
+                return WindowEvents(), _NO_KIDS
             comp, ids = self.open.read(due)
             order = np.argsort(comp)
             comp, ids = comp[order], ids[order]
@@ -2306,10 +2352,10 @@ class DeviceJoinState(DeviceWindowAggState):
             for s in range(sides)
         ]
 
-    def _join_events(self, kids, wids, sizes, counts, columns, comp):
-        """The close's events: a row a combination (``"E"``), then a
-        ``WindowMetadata`` a window (``"M"``) while the meta stream is
-        read."""
+    def _join_events(self, kids, wids, sizes, counts, columns, comp) -> WindowEvents:
+        """The close's events: a ``down`` row a combination, and a
+        ``WindowMetadata`` a window while the meta stream is read; the
+        keys one a window."""
         total = int(sizes.sum())
         _flight.RECORDER.count("join_rows_emitted", total)
         keys = list(map(self.keys.__getitem__, kids.tolist()))
@@ -2320,16 +2366,17 @@ class DeviceJoinState(DeviceWindowAggState):
                 col = col.astype(object)
                 col[np.repeat(absent, sizes)] = None
             values.append(col.tolist())
-        events = list(
+        down = list(
             zip(
                 np.repeat(np.asarray(keys, dtype=object), sizes).tolist(),
-                zip(np.repeat(wids, sizes).tolist(), repeat("E"), zip(*values)),
+                zip(np.repeat(wids, sizes).tolist(), zip(*values)),
             )
         )
+        events = WindowEvents(down, keys)
         if self.spec.meta_live:
             metas = self._metas(self._closes_of(comp).tolist())
             _flight.RECORDER.count("window_meta_events", len(metas))
-            events.extend(zip(keys, zip(wids.tolist(), repeat("M"), metas)))
+            events.meta = list(zip(keys, zip(wids.tolist(), metas)))
         return events
 
     # -- recovery -----------------------------------------------------------------

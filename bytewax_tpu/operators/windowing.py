@@ -1212,55 +1212,40 @@ def window(
 
     events = op.stateful_batch("stateful_batch", up, shim_builder)
 
-    # Batch-level taps (one comprehension per delivery, not a Python
-    # call per event): the events stream is engine-internal, so the
-    # (key, (window_id, type, obj)) shape is guaranteed.
-    def unwrap_emit(k_evs: List) -> List[Tuple[str, Tuple[int, W]]]:
-        return [
-            (k, (window_id, obj))
-            for k, (window_id, typ, obj) in k_evs
-            if typ == "E"
-        ]
-
-    def unwrap_late(k_evs: List) -> List[Tuple[str, Tuple[int, V]]]:
-        return [
-            (k, (window_id, obj))
-            for k, (window_id, typ, obj) in k_evs
-            if typ == "L"
-        ]
-
-    def unwrap_meta(
-        k_evs: List,
-    ) -> List[Tuple[str, Tuple[int, WindowMetadata]]]:
-        return [
-            (k, (window_id, obj))
-            for k, (window_id, typ, obj) in k_evs
-            if typ == "M"
-        ]
-
     # The unwrap taps are pure fan-out shims; `_prunable` lets the
     # flatten pass drop any whose output stream is never consumed
-    # (most flows ignore `late`/`meta`, and each live tap costs a
-    # per-event Python pass).
-    downs = cast(
-        KeyedStream,
-        op.flat_map_batch(
-            "unwrap_down", events, unwrap_emit, _prunable=True
-        ),
-    )
-    lates = cast(
-        KeyedStream,
-        op.flat_map_batch(
-            "unwrap_late", events, unwrap_late, _prunable=True
-        ),
-    )
-    metas = cast(
-        KeyedStream,
-        op.flat_map_batch(
-            "unwrap_meta", events, unwrap_meta, _prunable=True
-        ),
-    )
+    # (most flows ignore `late`/`meta`).
+    downs = cast(KeyedStream, _unwrap_tap("unwrap_down", events, "down", "E"))
+    lates = cast(KeyedStream, _unwrap_tap("unwrap_late", events, "late", "L"))
+    metas = cast(KeyedStream, _unwrap_tap("unwrap_meta", events, "meta", "M"))
     return WindowOut(downs, lates, metas)
+
+
+def _unwrap_tap(step_id: str, events: Stream, part: str, typ: str) -> Stream:
+    """One of the window step's batch-level taps: its stream's rows
+    ``(key, (window_id, obj))`` of each delivery.  A device tier's
+    delivery is a ``WindowEvents`` whose ``part`` holds those rows
+    already built, handed on as they are (``window_rows_direct``); the
+    host tier's is a list of tagged ``(key, (window_id, type, obj))``
+    events (the stream is engine-internal, so the shape is
+    guaranteed), taken apart in one comprehension
+    (``window_rows_tapped``: the events walked)."""
+    from bytewax_tpu.engine.flight import RECORDER
+    from bytewax_tpu.engine.window_accel import WindowEvents
+
+    def unwrap(k_evs: Any) -> List[Tuple[str, Tuple[int, Any]]]:
+        if type(k_evs) is WindowEvents:
+            rows = getattr(k_evs, part)
+            RECORDER.count("window_rows_direct", len(rows))
+            return rows
+        RECORDER.count("window_rows_tapped", len(k_evs))
+        return [
+            (k, (window_id, obj))
+            for k, (window_id, t, obj) in k_evs
+            if t == typ
+        ]
+
+    return op.flat_map_batch(step_id, events, unwrap, _prunable=True)
 
 
 # --------------------------------------------------------------------------

@@ -243,7 +243,7 @@ def _deliver(st, keys, secs):
     late, phase = st.on_batch_columnar(batch)
     closes, _hint, gone = phase()
     st.let_go(gone)
-    return late + closes
+    return list(late + closes)
 
 
 def _age(st, seconds):
@@ -325,7 +325,7 @@ def test_every_ingest_path_forgets_a_key_that_was_let_go(path):
         late, phase = ingest
         closes, _hint, gone = phase()
         st.let_go(gone)
-        return late + closes
+        return list(late + closes)
 
     assert deliver(["a", "b", "a"], [1, 2, 3]) == []
     kid_b = st.key_ids["b"]
@@ -360,14 +360,14 @@ def test_a_key_with_rows_in_flight_is_not_let_go():
     _late, first = ingest(["a"], [1])
     closes, _hint, gone = first()
     st.let_go(gone)
-    assert closes == [] and list(st.key_ids) == ["a"]
+    assert list(closes) == [] and list(st.key_ids) == ["a"]
     _age(st, 120)
     # Two deliveries taken in before either phase runs (a pipeline
     # deeper than 2): the first one's close takes a's window.
     _late, second = ingest(["b"], [200])
     _late, third = ingest(["a"], [150])
     closes, _hint, gone = second()
-    assert closes == [("a", (0, "E", 1))] and gone[1].tolist() == [st.key_ids["a"]]
+    assert list(closes) == [("a", (0, "E", 1))] and gone[1].tolist() == [st.key_ids["a"]]
     st.let_go(gone)
     assert sorted(st.key_ids) == ["a", "b"]
     closes, _hint, gone = third()
@@ -397,7 +397,7 @@ def test_resume_between_a_keys_death_and_its_return(monkeypatch, shard):
     resumed.load_many(snaps[1:])
     assert sorted(resumed.key_ids) == sorted(straight.key_ids) == ["b", "stay"]
     back = (["a", "stay", "a"], [3.0, 3.0, 4.0])
-    events = [_deliver(st, *back) + st.on_eof() for st in (straight, resumed)]
+    events = [_deliver(st, *back) + list(st.on_eof()) for st in (straight, resumed)]
     assert sorted(events[0], key=repr) == sorted(events[1], key=repr)
     assert ("a", (0, "E", 2)) in events[0]
     late = [e for e in events[0] if e[1][1] == "L"]
@@ -471,7 +471,7 @@ def test_due_instants_follow_the_keys_clocks(windower):
     for phase in (first, second):
         closes, _hint, gone = phase()
         st.let_go(gone)
-        assert closes == []
+        assert list(closes) == []
     np.testing.assert_array_equal(st.open.at, _due_from_clock(st))
     # Part of the table falls due: exactly those windows close.
     _age(st, 14)

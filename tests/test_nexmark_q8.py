@@ -62,6 +62,26 @@ def test_flow_matches_its_reference(monkeypatch, cfg, seed):
     assert gained("window_keys_retired") == gained("window_keys_opened") > 0
 
 
+def test_the_tail_is_an_item_pass_and_the_taps_are_none(monkeypatch, cfg):
+    """The join's rows reach the flow's tail through the window's
+    ``down`` tap unwalked: the tail's mapper opens an ``item_ops`` span
+    over exactly the rows the join wrote, the taps open none, and
+    every row is counted as handed on directly."""
+    monkeypatch.setenv("BYTEWAX_TPU_ACCEL", "1")
+    before = dict(flight.RECORDER.counters)
+    _data, out = _run_q8(cfg, 20_000, seed=5)
+
+    def gained(name):
+        return flight.RECORDER.counters.get(name, 0) - before.get(name, 0)
+
+    emitted = gained("join_rows_emitted")
+    assert 0 < len(out) < emitted  # the tail drops the rows with no person
+    assert gained("item_ops_spans") >= 1
+    assert gained("item_ops_rows") == emitted
+    assert gained("window_rows_direct") == emitted
+    assert gained("window_rows_tapped") == 0
+
+
 def test_the_tiers_write_the_same_rows(monkeypatch, cfg):
     outs = []
     for accel in ("1", "0"):

@@ -1092,7 +1092,7 @@ def test_itemized_promotion_unit_matches_per_item_path():
         # the deferred phase to get the full event stream.
         late, phase = ingest
         closes, _hint, _gone = phase()
-        return late + closes
+        return list(late + closes)
 
     # Count shape: values ARE the timestamps.
     items = [
@@ -1378,7 +1378,7 @@ def test_reused_slot_starts_from_identity(monkeypatch, kind, shard="0"):
     monkeypatch.setenv("BYTEWAX_TPU_SHARD", shard)
     st = _spec_of(kind, TUMBLING_10S).make_state()
     first = _deliver(st, ["a"] * 3 + ["b"], [1, 2, 3, 4], [100, -100, 7, 9])
-    assert first == []
+    assert list(first) == []
     held = sorted(st.open.ids.tolist())
     # 30 s on: two new windows open, then both old ones close (wait
     # 0) and hand their slots back.
@@ -1525,12 +1525,146 @@ def test_delivery_makes_constant_calls_into_agg(monkeypatch, shard, meta):
         "release_ids": 1,
     }
     assert gained["window_opens"] == 1000
-    assert [e[1][1] for e in many].count("E") == 1000
+    assert len(many.down) == 1000 and not many.late
     assert gained["window_meta_events"] == (1000 if meta else 0)
-    assert [e[1][1] for e in many[:4]] == (
-        ["E", "M", "E", "M"] if meta else ["E"] * 4
+    # A metadata row for each window closed, in the same order.
+    assert [(k, wid) for k, (wid, _m) in many.meta] == (
+        [(k, wid) for k, (wid, _v) in many.down] if meta else []
     )
     assert len(few) == (16 if meta else 8)
+
+
+def _carrier_rows(n=400):
+    """``(key, second)`` rows a second apart in event time with a jump
+    of 40 s every 60 rows (sessions of a 10 s gap close between them),
+    every 37th row 200 s behind (late on both tiers under a wait of
+    100 s, whatever the wall clock does); on-time rows in order."""
+    rng = np.random.RandomState(11)
+    rows = []
+    for i in range(n):
+        sec = 300 + i + 40 * (i // 60)
+        if i % 37 == 36:
+            sec -= 200
+        rows.append((f"k{rng.randint(0, 3)}", sec))
+    return rows
+
+
+def _carrier_flow(tier, rows, late, meta):
+    """The window step of ``tier`` over ``rows`` in deliveries of 50
+    (a join's two sides from two inputs, a delivery each in turn: a
+    side's rows lie up to 90 s behind the other's, inside the wait),
+    with the taps the case reads; the lists the sinks fill."""
+    from bytewax_tpu import xla
+    from bytewax_tpu.engine.arrays import ArrayBatch
+    from tests.test_xla import ArraySource
+
+    taps = {"down": [], "late": [], "meta": []}
+    flow = Dataflow("carrier_df")
+    at = np.datetime64(ALIGN.replace(tzinfo=None), "s")
+    if tier == "join":
+        # Two columnar sides: even rows on the first, odd on the second.
+        sides = []
+        for side in (0, 1):
+            batches = []
+            for lo in range(0, len(rows), 50):
+                part = rows[lo : lo + 50][side::2]
+                batches.append(
+                    ArrayBatch(
+                        {
+                            "key": np.asarray([k for k, _s in part]),
+                            "ts": at + np.asarray([s for _k, s in part]).astype("timedelta64[s]"),
+                            "value": np.arange(lo, lo + len(part), dtype=np.int32),
+                        }
+                    )
+                )
+            sides.append(op.input(f"in{side}", flow, ArraySource(batches)))
+        clock = EventClock(
+            ts_getter=xla.column_ts, wait_for_system_duration=timedelta(seconds=100)
+        )
+        wo = w.join_window("win", clock, TUMBLING_10S, *sides, insert_mode="product")
+    else:
+        items = [(k, ALIGN + timedelta(seconds=s)) for k, s in rows]
+        s = op.input("inp", flow, TestingSource(items, batch_size=50))
+        clock = EventClock(
+            ts_getter=lambda kv: kv[1], wait_for_system_duration=timedelta(seconds=100)
+        )
+        windower = {
+            "tumbling": TUMBLING_10S,
+            "sliding": SLIDING_10S_BY_4S,
+            "session": w.SessionWindower(gap=timedelta(seconds=10)),
+        }[tier]
+        wo = w.count_window("win", s, clock, windower, key=lambda kv: kv[0])
+    op.output("down", wo.down, TestingSink(taps["down"]))
+    if late:
+        op.output("late", wo.late, TestingSink(taps["late"]))
+    if meta:
+        op.output("meta", wo.meta, TestingSink(taps["meta"]))
+    return flow, taps
+
+
+@pytest.mark.parametrize("meta", [False, True], ids=["meta_off", "meta_read"])
+@pytest.mark.parametrize("late", [False, True], ids=["late_pruned", "late_read"])
+@pytest.mark.parametrize("workers", [1, 2], ids=["one_worker", "two_workers"])
+@pytest.mark.parametrize("tier", ["tumbling", "sliding", "session", "join"])
+def test_device_tiers_hand_the_taps_their_rows_built(
+    monkeypatch, tier, workers, late, meta
+):
+    """Each device tier hands its output on as one ``WindowEvents`` a
+    delivery, and the taps pass its parts on: ``down``, ``late`` and
+    ``meta`` are the host tier's, item for item (one key's rows in the
+    same order; across keys the tiers take turns differently), with
+    no row walked by a tap on the device tier and every row written
+    counted as handed on directly; among the deliveries, one carries
+    late rows and closes together."""
+    from bytewax_tpu.engine import driver as drv
+    from bytewax_tpu.engine import flight
+    from bytewax_tpu.engine.window_accel import WindowEvents
+    from bytewax_tpu.testing import cluster_main
+
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", "0")
+    # Deliveries as the sources cut them on both tiers (the device
+    # tier's inputs would otherwise merge them).
+    monkeypatch.setenv("BYTEWAX_TPU_INGEST_TARGET_ROWS", "0")
+    rows = _carrier_rows()
+    carried = []
+    emit = drv._StatefulBatchRt._emit_window_events
+
+    def noted(self, events):
+        carried.append((type(events), len(events.late), len(events.down)))
+        return emit(self, events)
+
+    monkeypatch.setattr(drv._StatefulBatchRt, "_emit_window_events", noted)
+
+    def run(accel):
+        monkeypatch.setenv("BYTEWAX_TPU_ACCEL", accel)
+        flow, taps = _carrier_flow(tier, rows, late, meta)
+        before = dict(flight.RECORDER.counters)
+        if workers == 1:
+            run_main(flow)
+        else:
+            cluster_main(flow, [], 0, worker_count_per_proc=2)
+        gained = {
+            name: flight.RECORDER.counters.get(name, 0) - before.get(name, 0)
+            for name in ("window_rows_direct", "window_rows_tapped")
+        }
+        return taps, gained
+
+    (device, dev_counted), (host, host_counted) = run("1"), run("0")
+    assert len(device["down"]) > 20
+    assert (len(device["late"]) >= 5) is late
+    assert (len(device["meta"]) > 0) is meta
+    for tap in ("down", "late", "meta"):
+        assert len(device[tap]) == len(host[tap]), tap
+        for key in ("k0", "k1", "k2"):
+            assert [e for e in device[tap] if e[0] == key] == [
+                e for e in host[tap] if e[0] == key
+            ], (tap, key)
+    written = sum(len(got) for got in device.values())
+    assert dev_counted == {"window_rows_direct": written, "window_rows_tapped": 0}
+    assert host_counted["window_rows_direct"] == 0
+    assert host_counted["window_rows_tapped"] >= written
+    assert carried and all(t is WindowEvents for t, _l, _d in carried)
+    assert any(n_late and n_down for _t, n_late, n_down in carried)
 
 
 # -- the open-window table against a plain dict model -----------------------
