@@ -12,7 +12,9 @@ Which region a slot holds is the host's to know (three numbers a
 slot), so a delivery's rows are written straight to their places and
 a close finds its slots' rows with no search: :func:`join_expand`
 turns a close's slots into its output rows on the device and reads
-back only their values.
+back only their values.  An output row finds its window by one prefix
+sum over the windows' ends (no search a row) and reads that window's
+numbers with one gather from a small table.
 
 Regions grow by doubling (a slot that gains rows past its room is
 copied to a region twice its size), closed slots leave their regions
@@ -98,30 +100,37 @@ def join_expand(
     sum of the sizes, counted from the chunk's first row (so the first
     window may begin before it), and the padding windows repeat the
     last end.  Output row ``j`` falls in the window whose end first
-    passes it, and is that window's combination number ``j - begin``,
-    split into one row index a side as ``itertools.product`` numbers
-    them (side 0 slowest).  Returns each side's low word and the high
-    words of the sides in ``wide``, ``rows`` entries each; entries past
-    the chunk's end, and those of a side with no row, read the scratch
-    row.  Every gather takes a flat index (a gather by a 2-D index
-    takes the chip's compiler seconds at these sizes)."""
+    passes it: the number of ends at or below ``j``, read from one
+    prefix sum over a histogram of the ends (clipped to the chunk), so
+    no row searches.  The row then reads its window's numbers (end,
+    size, each side's count and start) with one gather, a row of a
+    ``[windows, 2 + 2 * sides]`` table, and is that window's
+    combination number ``j - begin``, split into one row index a side
+    as ``itertools.product`` numbers them (side 0 slowest).  Returns
+    each side's low word and the high words of the sides in ``wide``,
+    ``rows`` entries each; entries past the chunk's end, and those of
+    a side with no row, read the scratch row.  Every gather takes a
+    flat index (a gather by a 2-D index takes the chip's compiler
+    seconds at these sizes)."""
     scratch = words.shape[1] - 1
     n_sides, n_windows = count.shape
-    size = jnp.maximum(count, 1)
-    total = size[0]
+    total = jnp.maximum(count[0], 1)
     for s in range(1, n_sides):
-        total = total * size[s]
+        total = total * jnp.maximum(count[s], 1)
     j = jnp.arange(rows, dtype=jnp.int32)
-    at = jnp.minimum(jnp.searchsorted(ends, j, side="right"), n_windows - 1)
+    hist = jnp.zeros(rows + 1, dtype=jnp.int32).at[jnp.clip(ends, 0, rows)].add(1)
+    at = jnp.minimum(jnp.cumsum(hist[:rows]), n_windows - 1)
+    window = jnp.stack([ends, total, *count, *starts], axis=1)[at]
     live = j < ends[-1]
-    loc = j - (ends[at] - total[at])
+    loc = j - (window[:, 0] - window[:, 1])
     pos = [None] * n_sides
     for side in reversed(range(n_sides)):
-        side_size = size[side][at]
+        side_count = window[:, 2 + side]
+        side_size = jnp.maximum(side_count, 1)
         index = loc % side_size
         loc = loc // side_size
-        here = live & (count[side][at] > 0)
-        pos[side] = jnp.where(here, starts[side][at] + index, scratch)
+        here = live & (side_count > 0)
+        pos[side] = jnp.where(here, window[:, 2 + n_sides + side] + index, scratch)
     low = jnp.stack([words[0, p] for p in pos])
     hi = [words[1, pos[side]] for side in wide]
     high = jnp.stack(hi) if hi else jnp.zeros((0, rows), dtype=words.dtype)

@@ -527,3 +527,142 @@ def test_the_row_store_keeps_each_slots_rows_through_growth_and_compaction():
         assert length.tolist() == [len(ref[s]) for s in live.tolist()]
     assert _gained(before, "join_store_moved") > 0
     assert store.cap > 1024
+
+
+# -- the expansion ------------------------------------------------------------------
+
+
+def _expand_chunk(rng, rows, sides, cap, first_inside, last_past):
+    """A chunk of a close's expansion as ``RowStore.expand`` hands it to
+    ``join_expand``: ``(count, starts, ends)``, padded to ``rows``
+    windows.  Counts 0-3 a side (a side with none reads the scratch
+    row), now and then a window of many rows; ``first_inside``: the
+    chunk begins inside its first window, which began in the chunk
+    before; ``last_past``: the last window runs past the chunk's end,
+    else the close's rows end inside the chunk."""
+    counts = []
+    total = 0
+    while total < 2 * rows + 64:
+        c = rng.randint(0, 4, sides)
+        c[rng.rand(sides) < 0.3] = 0
+        if rng.rand() < 0.02:
+            c[rng.randint(sides)] = 40
+        if not c.any():
+            c[rng.randint(sides)] = 1
+        counts.append(c)
+        total += int(np.prod(np.maximum(c, 1)))
+    count = np.array(counts, dtype=np.int64).T
+    sizes = np.prod(np.maximum(count, 1), axis=0)
+    ends = np.cumsum(sizes)
+    begins = ends - sizes
+    # The chunk's first row: inside a window of two rows or more, or at
+    # a window's first; then its last row inside a window, or not.
+    big = np.flatnonzero(sizes > 1)
+    while True:
+        w0 = int(big[rng.randint(len(big) // 4)])
+        t0 = int(begins[w0]) + (rng.randint(1, sizes[w0]) if first_inside else 0)
+        cut = int(np.searchsorted(ends, t0 + rows, side="left"))
+        if begins[cut] < t0 + rows < ends[cut]:
+            break
+    if not last_past:
+        keep = int(np.searchsorted(ends, t0 + rows - rng.randint(1, rows // 2), side="right"))
+        count, sizes, ends, begins = count[:, :keep], sizes[:keep], ends[:keep], begins[:keep]
+    d0 = int(np.searchsorted(ends, t0, side="right"))
+    d1 = int(np.searchsorted(begins, t0 + rows, side="left"))
+    assert (begins[d0] < t0) == first_inside and (ends[d1 - 1] > t0 + rows) == last_past
+    n = d1 - d0
+    starts = rng.randint(0, cap - 64, count.shape)
+    out_count = np.zeros((sides, rows), dtype=np.int32)
+    out_count[:, :n] = count[:, d0:d1]
+    out_starts = np.zeros((sides, rows), dtype=np.int32)
+    out_starts[:, :n] = starts[:, d0:d1]
+    out_ends = np.full(rows, ends[d1 - 1] - t0, dtype=np.int32)
+    out_ends[:n] = ends[d0:d1] - t0
+    return out_count, out_starts, out_ends
+
+
+def _expand_oracle(words, count, starts, ends, rows, wide):
+    """``join_expand`` in numpy: each window's combinations numbered as
+    ``itertools.product`` numbers them (side 0 slowest)."""
+    import itertools
+
+    sides = count.shape[0]
+    scratch = words.shape[1] - 1
+    pos = np.full((sides, rows), scratch, dtype=np.int64)
+    last = -1
+    for w in range(count.shape[1]):
+        if ends[w] <= last:
+            break  # the padding windows repeat the last end
+        last = int(ends[w])
+        shape = [max(int(c), 1) for c in count[:, w]]
+        begin = last - int(np.prod(shape))
+        for k, combo in enumerate(itertools.product(*map(range, shape))):
+            if 0 <= begin + k < rows:
+                for s in range(sides):
+                    if count[s, w] > 0:
+                        pos[s, begin + k] = starts[s, w] + combo[s]
+    return words[0][pos], words[1][pos[list(wide)]].reshape(len(wide), rows)
+
+
+@pytest.mark.parametrize("full", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("sides", [2, 3])
+@pytest.mark.parametrize("rows", [256, 4096, 65536])
+def test_the_expansion_numbers_each_windows_rows_as_the_product_does(rows, sides, full):
+    """``join_expand`` against a numpy oracle at each of the output
+    ladder's sizes: a first window that began in the chunk before, a
+    last window that runs past the chunk (or a close that ends inside
+    it, the padding windows repeating its end), sides with no row."""
+    from bytewax_tpu.ops.join import OUTPUT_LADDER, join_expand
+
+    assert rows in OUTPUT_LADDER
+    rng = np.random.RandomState(rows + sides + full)
+    cap = 1 << 12
+    words = rng.randint(-(2**31), 2**31 - 1, (2, cap)).astype(np.int32)
+    wide = tuple(range(sides)) if full else ()
+    for first_inside in (True, False):
+        for last_past in (True, False):
+            count, starts, ends = _expand_chunk(rng, rows, sides, cap, first_inside, last_past)
+            low, high = join_expand(words, count, starts, ends, rows=rows, wide=wide)
+            want_low, want_high = _expand_oracle(words, count, starts, ends, rows, wide)
+            assert np.array_equal(np.asarray(low), want_low)
+            assert np.array_equal(np.asarray(high), want_high)
+            scratch = words[:, cap - 1]
+            assert (np.asarray(low) == scratch[0]).any(axis=1).all()
+
+
+def test_a_close_compiles_no_expansion_the_walk_did_not(now):
+    """Once ``join_expand`` ran at the set-up walk's argument shapes
+    (``benchmark/flows/nexmark_q8.py`` ``warm_join_programs``) for an
+    arena size and every ladder size, a real close at that arena size
+    compiles no expansion of its own."""
+    import jax.numpy as jnp
+
+    from bytewax_tpu.ops import join
+
+    st = DeviceJoinState(_spec(wait_s=0))
+    # Two keys of 40 x 40 rows and 60 of 3 x 3: 3,740 output rows from
+    # 520 stored, in the store's second arena; then one row alone.
+    rows = [
+        (f"k{k}", 3, side, 1000 * k + r)
+        for k in range(62)
+        for side in (0, 1)
+        for r in range(40 if k < 2 else 3)
+    ]
+    _deliver(st, rows, [])
+    arena = st.store.cap
+    assert arena == 4 * join._MIN_ROWS
+    words = jnp.zeros((2, arena), dtype=jnp.int32)
+    for ladder in join.OUTPUT_LADDER:
+        zeros = jnp.zeros((2, ladder), dtype=jnp.int32)
+        join.join_expand(words, zeros, zeros, jnp.zeros(ladder, dtype=jnp.int32), rows=ladder, wide=())
+    compiled = join.join_expand._cache_size()
+    before = dict(flight.RECORDER.counters)
+    events = []
+    now[0] = T0 + timedelta(seconds=100)
+    _deliver(st, [("z", 400, 0, 1)], events)
+    events += st.on_eof()
+    assert st.store.cap == arena
+    assert _gained(before, "join_expand_rows") == 4096 + 256
+    assert join.join_expand._cache_size() == compiled
+    got = Counter((k, (wid, row)) for k, (wid, tag, row) in events if tag == "E")
+    assert got == _want(rows + [("z", 400, 0, 1)])
