@@ -1638,9 +1638,9 @@ class _StatefulBatchRt(_OpRt):
         # evicting cold keys to host snapshots / the disk spill store.
         # Unset budget returns the state unchanged (byte-identical
         # engine).  The collective global-exchange tier is excluded
-        # inside maybe_wrap, exactly like demotion; the window tier
-        # exposes extract/inject but is not driver-evicted yet.  The
-        # worker count stamps spilled rows' route column (recovery
+        # inside maybe_wrap, exactly like demotion; the window tiers
+        # have no residency surface and are not wrapped.  The worker
+        # count stamps spilled rows' route column (recovery
         # snaps-format parity).
         self.agg = maybe_wrap(
             op.step_id, self.agg, worker_count=driver.worker_count

@@ -70,6 +70,18 @@ the tumbling/sliding tier's table, the rows themselves in a row store
 on the device (``ops/join.py``), and a close that expands its windows'
 products there and reads back the output rows' values alone.
 
+Tier note: the three tiers (tumbling/sliding, session, join) share
+one close (:meth:`DeviceWindowAggState._close_due`: retime, due scan,
+take out, give the slots back, one :class:`WindowEvents`, the keys
+left without a window) and one snapshot
+(:meth:`DeviceWindowAggState.snapshots_for`: one pass and one device
+read for all the keys, a ``_WindowSnapshot`` a key).  A tier gives
+only what differs, through hooks: ``_close_begins``, ``_due``,
+``_closing`` / ``_fetch_closed`` / ``_emit_closed`` (what a close
+takes, reads back and writes down), ``_bounds``, ``_window_states``,
+``_windower_of`` and ``_known_keys``; ``_CLOSE_SPANS`` names a
+close's spans stage by stage.
+
 Pipeline note (docs/performance.md): each ``on_batch*`` call returns
 ``(late_events, device_phase)`` — the host phase (vocab sync,
 watermark math, late classification) runs on the caller's thread and
@@ -514,6 +526,32 @@ class WindowEvents:
                 yield key, (wid, typ, obj)
 
 
+class _CloseSpans:
+    """The spans of one close as it goes from stage to stage (a tier's
+    ``_CLOSE_SPANS``): a stage named as the span still open goes on in
+    it, and ``None`` opens none."""
+
+    __slots__ = ("_names", "_open")
+
+    def __init__(self, names: Tuple[Optional[str], ...]):
+        self._names = names
+        self._open: Optional[_flight.span] = None
+
+    def to(self, stage: int, rows: int) -> None:
+        name = self._names[stage]
+        if self._open is not None:
+            if self._open.phase == name:
+                return
+            self._open.end()
+            self._open = None
+        if name is not None:
+            self._open = _flight.span(name, rows=rows).begin()
+
+    def end(self) -> None:
+        if self._open is not None:
+            self._open.end()
+
+
 class _LateTs:
     """Late-value view for columnar batches: row index → timestamp."""
 
@@ -649,6 +687,10 @@ class DeviceWindowAggState:
         # ids.  Both start over when a key is let go.
         self._item_iddict: Optional[Dict[str, int]] = None
         self._item_kids = _NO_KIDS
+        #: Keys of the delivery being taken in that a close must look
+        #: at for their late rows (:meth:`_late_events` notes them
+        #: where a key with no on-time row may be left with no window).
+        self._late_kids = _NO_KIDS
 
     def _slot_table(self, spec: WindowAccelSpec):
         """The per-(key, window) fold's table: mesh-sharded when >1
@@ -670,14 +712,19 @@ class DeviceWindowAggState:
         its own ingest (the next ingest moves the clock on the host
         thread while the phase may still be in flight): the
         delivery's keys with their clocks, from which the phase
-        retimes their open windows."""
-        return kids, self.base_us[kids], self.sys_at_base[kids]
+        retimes their open windows, and its keys with late rows that
+        :meth:`_late_events` noted."""
+        late, self._late_kids = self._late_kids, _NO_KIDS
+        return kids, self.base_us[kids], self.sys_at_base[kids], late
+
+    def _wids_of(self, comp: np.ndarray) -> np.ndarray:
+        """Window ids of composites."""
+        return (comp & _WID_MASK) - _WID_BIAS
 
     def _closes_of(self, comp: np.ndarray) -> np.ndarray:
         """Close times (us) of composites."""
         spec = self.spec
-        wids = (comp & _WID_MASK) - _WID_BIAS
-        return spec.align_us + wids * spec.offset_us + spec.length_us
+        return spec.align_us + self._wids_of(comp) * spec.offset_us + spec.length_us
 
     def _key_ids_for(self, keys: List[str]) -> np.ndarray:
         """Key ids of ``keys``; a key not held takes a free id (or a
@@ -1184,14 +1231,18 @@ class DeviceWindowAggState:
             )
         self.agg.update_ids(slots_rep, val_rep)
 
-    def _split(self, comp: np.ndarray):
-        """Parallel ``(kids, wids, closes)`` arrays of composites."""
-        return comp >> 32, (comp & _WID_MASK) - _WID_BIAS, self._closes_of(comp)
-
     def _open_arrays(self):
         """Parallel ``(kids, wids, closes)`` arrays over the open
         windows (table order: the order they were opened in)."""
-        return self._split(self.open.comp)
+        comp = self.open.comp
+        return comp >> 32, self._wids_of(comp), self._closes_of(comp)
+
+    # -- close ---------------------------------------------------------------
+
+    #: The spans of a close, stage by stage: the due scan, the closed
+    #: windows read back (``None``: the slot table's read times
+    #: itself), the events built, the keys left without a window.
+    _CLOSE_SPANS: Tuple[Optional[str], ...] = ("close_scan", None, "close_emit", "retire")
 
     def _close_due(
         self, now_us: float, clock=None
@@ -1199,51 +1250,94 @@ class DeviceWindowAggState:
         """Close the windows that are due: their events, and the ids
         of the keys this leaves without an open window (for
         :meth:`let_go`).  ``clock`` (:meth:`_phase_clock`, from a
-        delivery's own phase) first retimes the delivery's keys."""
-        if not self.open_count:
+        delivery's own phase) first retimes the delivery's keys and
+        names those with late rows, which may be left with no window
+        too."""
+        if not self._close_begins():
             return WindowEvents(), _NO_KIDS
-        # Ledger: `close_scan` (the due scan over the open windows),
-        # `fetch` (inside ``states_of``), `close_emit` (columns to
-        # events for the windows that close), `retire` (which keys
-        # the close left without a window).
-        with _flight.span("close_scan", rows=len(self.open)):
+        late = _NO_KIDS if clock is None else clock[3]
+        spans = _CloseSpans(self._CLOSE_SPANS)
+        try:
+            spans.to(0, len(self.open))
             if clock is not None:
-                self.open.retime(*clock, self._closes_of)
-            due = self.open.due(now_us)
+                self.open.retime(*clock[:3], self._closes_of)
+            due = self._due(now_us)
             if not len(due):
-                return WindowEvents(), _NO_KIDS
-            comp_due, ids = self.open.read(due)
-            kids_due = comp_due >> 32
-        # bytewax: allow[BTX-DRAIN] — the windower's .agg is its own slot table (never residency-wrapped; the driver evicts only the keyed-agg/scan tiers), and this due-window fetch runs inside the deferred device phase the pipeline worker owns
-        states = self.agg.states_of(ids)
-        with _flight.span("close_emit", rows=len(due)):
-            self.open.remove(due)
-            self.agg.release_ids(ids)
-            keys = list(map(self.keys.__getitem__, kids_due.tolist()))
-            wids = ((comp_due & _WID_MASK) - _WID_BIAS).tolist()
-            values = map(self._finalize_one, states)
-            events = WindowEvents(list(zip(keys, zip(wids, values))), keys)
-            if self.spec.meta_live:
-                metas = self._metas(self._closes_of(comp_due).tolist())
+                gone = self.open.without_window(late) if len(late) else _NO_KIDS
+                return WindowEvents(), gone
+            rows, comp, slots, held = self._closing(due, *self.open.read(due))
+            spans.to(1, len(due))
+            got = self._fetch_closed(held)
+            spans.to(2, len(due))
+            kids = comp >> 32
+            keys = list(map(self.keys.__getitem__, kids.tolist()))
+            wids = self._wids_of(comp)
+            # (Before :meth:`_emit_closed`, which may forget what the
+            # bounds read, and before the rows are removed.)
+            metas = self._metas(*self._bounds(rows, comp)) if self.spec.meta_live else None
+            events = WindowEvents(self._emit_closed(held, got, keys, wids), keys)
+            if metas is not None:
                 _flight.RECORDER.count("window_meta_events", len(metas))
-                events.meta = list(zip(keys, zip(wids, metas)))
-        with _flight.span("retire", rows=len(due)):
-            gone = self.open.without_window(np.unique(kids_due))
+                events.meta = list(zip(keys, zip(wids.tolist(), metas)))
+            self.open.remove(due)
+            self._release_slots(slots)
+            spans.to(3, len(due))
+            gone = self.open.without_window(
+                np.unique(np.append(kids, late) if len(late) else kids)
+            )
+        finally:
+            spans.end()
         return events, gone
 
-    def _metas(self, closes_us: List[float]) -> List[Any]:
-        """``WindowMetadata`` per close time."""
+    def _close_begins(self) -> bool:
+        """Hook: whether a close looks at the table at all."""
+        return bool(self.open_count)
+
+    def _due(self, now_us: float) -> np.ndarray:
+        """Hook: arena rows of the windows due by ``now_us``."""
+        return self.open.due(now_us)
+
+    def _closing(self, due: np.ndarray, comp: np.ndarray, ids: np.ndarray):
+        """Hook, in the due scan: what closing the arena rows ``due``
+        (composites ``comp``, slots ``ids``) takes, ``(rows, comp,
+        slots, held)``: the arena row and composite of each window
+        (one a window), the slots to give back, and what the next two
+        hooks read.  Here a row is a window."""
+        return due, comp, ids, ids
+
+    def _fetch_closed(self, held) -> Any:
+        """Hook: the closed windows' output, one read from the device;
+        here their fold states."""
+        # bytewax: allow[BTX-DRAIN] — the windower's .agg is its own slot table (never residency-wrapped; the driver evicts only the keyed-agg/scan tiers), and this due-window fetch runs inside the deferred device phase the pipeline worker owns
+        return self.agg.states_of(held)
+
+    def _emit_closed(self, held, got, keys: List[str], wids: np.ndarray) -> List[Any]:
+        """Hook: the ``down`` rows from what :meth:`_fetch_closed` read
+        (``keys`` and ``wids`` one a window); here one a window."""
+        return list(zip(keys, zip(wids.tolist(), map(self._finalize_one, got))))
+
+    def _bounds(self, rows: np.ndarray, comp: np.ndarray):
+        """Hook: open and close times (us) of the windows at arena
+        ``rows`` (composites ``comp``) and the ids each absorbed (None:
+        none); here arithmetic on the composites."""
+        closes = self._closes_of(comp)
+        return closes - self.spec.length_us, closes, None
+
+    def _metas(self, lo_us: np.ndarray, hi_us: np.ndarray, merged=None) -> List[Any]:
+        """``WindowMetadata`` of windows from their open and close
+        times (us), with the ids each absorbed (``merged``, a set a
+        window; none where not given)."""
         from bytewax_tpu.operators.windowing import WindowMetadata
 
-        length_us = self.spec.length_us
+        if merged is None:
+            merged = (set() for _ in range(len(lo_us)))
         return [
             WindowMetadata(
-                datetime.fromtimestamp(
-                    (close_us - length_us) / _US, tz=timezone.utc
-                ),
-                datetime.fromtimestamp(close_us / _US, tz=timezone.utc),
+                datetime.fromtimestamp(a / _US, tz=timezone.utc),
+                datetime.fromtimestamp(b / _US, tz=timezone.utc),
+                m,
             )
-            for close_us in closes_us
+            for a, b, m in zip(lo_us.tolist(), hi_us.tolist(), merged)
         ]
 
     def _finalize_one(self, snap: Any) -> Any:
@@ -1286,27 +1380,25 @@ class DeviceWindowAggState:
     # -- recovery ----------------------------------------------------------
 
     def snapshots_for(self, keys: List[str]):
-        """Host-tier ``_WindowSnapshot``-compatible snapshots; a key
-        with no open windows snapshots as a discard (the host tier
-        discards empty window logics the same way)."""
-        from bytewax_tpu.operators.windowing import (
-            _SlidingWindowerState,
-            _WindowSnapshot,
-        )
+        """Host-tier ``_WindowSnapshot``-compatible snapshots: a key's
+        open windows with their ``WindowMetadata`` and states
+        (:meth:`_window_states`), its clock and windower state
+        (:meth:`_windower_of`; None: the key snapshots as a discard)."""
+        from bytewax_tpu.operators import windowing
 
         # One pass over the open-window table and ONE device fetch
         # for all requested keys: a scan and a fetch per key is
         # O(keys x open windows) host work plus a whole-table
         # readback per key, which an epoch close over 10^5 touched
         # keys never finishes.
-        comp, ids = self.open.read(self._rows_of(keys))
-        kids_arr, wids_arr, closes_arr = self._split(comp)
-        # bytewax: allow[BTX-DRAIN] — snapshots run with the pipeline drained (module docstring); the windower's .agg is its own slot table
-        states = self.agg.states_of(ids) if len(ids) else []
-        metas = self._metas(closes_arr.tolist())
+        rows = self._rows_of(keys)
+        rows, comp, states = self._window_states(rows, *self.open.read(rows))
         open_of: Dict[int, Tuple[dict, dict]] = {}
         for kid, wid, meta, state in zip(
-            kids_arr.tolist(), wids_arr.tolist(), metas, states
+            (comp >> 32).tolist(),
+            self._wids_of(comp).tolist(),
+            self._metas(*self._bounds(rows, comp)),
+            states,
         ):
             opened, folded = open_of.setdefault(kid, ({}, {}))
             opened[wid] = meta
@@ -1314,22 +1406,42 @@ class DeviceWindowAggState:
         out = []
         for key in keys:
             kid = self.key_ids.get(key)
-            if kid not in open_of:
+            opened, folded = open_of.get(kid) or ({}, {})
+            held = self._windower_of(windowing, key, kid, opened)
+            if held is None:
                 out.append((key, None))
                 continue
-            opened, folded = open_of[kid]
+            base_us, sys_at_us, windower = held
             out.append(
                 (
                     key,
-                    _WindowSnapshot(
-                        _clock_state(self.base_us[kid], self.sys_at_base[kid]),
-                        _SlidingWindowerState(opened=opened),
-                        folded,
-                        [],
+                    windowing._WindowSnapshot(
+                        _clock_state(base_us, sys_at_us), windower, folded, []
                     ),
                 )
             )
         return out
+
+    def _window_states(self, rows: np.ndarray, comp: np.ndarray, ids: np.ndarray):
+        """Hook: the open windows at arena ``rows`` (composites
+        ``comp``, slots ``ids``) as ``(rows, comp, states)``, one a
+        window, states in the host tier's form; here the folds'."""
+        # bytewax: allow[BTX-DRAIN] — snapshots run with the pipeline drained (module docstring); the windower's .agg is its own slot table
+        return rows, comp, self.agg.states_of(ids) if len(ids) else []
+
+    def _windower_of(self, windowing, key: str, kid: Optional[int], opened: dict):
+        """Hook: a key's ``(watermark base, system time at base,
+        windower state)`` (us; a state class of the host tier's module
+        ``windowing``), None where the key snapshots as a discard: here
+        one with no open window, as the host tier discards an empty
+        window logic."""
+        if not opened:
+            return None
+        return (
+            self.base_us[kid],
+            self.sys_at_base[kid],
+            windowing._SlidingWindowerState(opened=opened),
+        )
 
     def _rows_of(self, keys: List[str]) -> np.ndarray:
         """Table rows of the given keys' open windows, in the order
@@ -1341,11 +1453,14 @@ class DeviceWindowAggState:
 
     def demotion_snapshots(self):
         """Full-state drain for device→host demotion: host-format
-        window snapshots for every key this windower has ever seen
-        (keys with no open windows drain as None — the host tier
-        rebuilds them on demand, matching its own discard of empty
-        window logics)."""
-        return self.snapshots_for(sorted(self.key_ids))
+        window snapshots for every key the tier knows
+        (:meth:`_known_keys`); a key that snapshots as a discard
+        drains as None, and the host tier rebuilds it on demand."""
+        return self.snapshots_for(sorted(self._known_keys()))
+
+    def _known_keys(self):
+        """Hook: the keys a demotion drains; here those held."""
+        return self.key_ids.keys()
 
     def _load_clock(self, kid: int, snap: Any) -> None:
         cs = snap.clock_state
@@ -1356,7 +1471,9 @@ class DeviceWindowAggState:
     def _retime(self, kids: np.ndarray) -> None:
         """Due instants of the keys' open windows from the live clock
         (main thread, pipeline drained)."""
-        self.open.retime(*self._phase_clock(kids), self._closes_of)
+        self.open.retime(
+            kids, self.base_us[kids], self.sys_at_base[kids], self._closes_of
+        )
 
     def _replay_queue(self, kid: int, snap: Any) -> None:
         """A host-tier ordered=True logic keeps on-time values whose
@@ -1421,39 +1538,6 @@ class DeviceWindowAggState:
             np.asarray(comps, dtype=np.int64), return_inverse=True
         )
         self.agg.load_ids(self.open.ids_for(uniq, self.agg)[inverse], states)
-
-    # -- residency (engine/residency.py) ------------------------------------
-    #
-    # The extract/inject surface for window state: a key drains to its
-    # host-tier ``_WindowSnapshot`` and its device fold slots are
-    # released.  NOTE the scheduling caveat: an extracted key's open
-    # windows stop closing by wall clock until the key is reinstated,
-    # so callers must route snapshot reads AND notify scheduling
-    # through a residency cache — the driver does not evict window
-    # state yet (docs/state-residency.md).
-
-    def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
-        """Snapshot AND release the given keys: open windows close
-        their device slots (one release for the batch); the per-key
-        clock entries stay (a later ``inject_keys`` restores the
-        snapshotted clock)."""
-        out = [
-            (key, snap)
-            for key, snap in self.snapshots_for(keys)
-            if snap is not None
-        ]
-        rows = self._rows_of([key for key, _snap in out])
-        self._release_slots(self.open.read(rows)[1])
-        self.open.remove(rows)
-        self.touched.difference_update(key for key, _snap in out)
-        return out
-
-    def inject_keys(self, items: List[Tuple[str, Any]]) -> None:
-        """Reinstate previously-extracted keys from their host-tier
-        ``_WindowSnapshot``s."""
-        self.load_many(items)
-
-
 
 
 class DeviceSessionAggState(DeviceWindowAggState):
@@ -1522,9 +1606,6 @@ class DeviceSessionAggState(DeviceWindowAggState):
         #: of their accumulator.
         self._merged: Dict[int, set] = {}
         self._extra: Dict[int, List[int]] = {}
-        #: Keys of the delivery being taken in with a late row (one
-        #: with no on-time row may be left with no session).
-        self._late_kids = _NO_KIDS
         #: ``(delivery, key ids)`` found without an open session by
         #: the closes of that delivery, to let go at a later one's.
         self._parked: Tuple[int, np.ndarray] = (0, _NO_KIDS)
@@ -1586,14 +1667,6 @@ class DeviceSessionAggState(DeviceWindowAggState):
             )
         )
 
-    def _phase_clock(self, kids: np.ndarray):
-        """The base class's, and the delivery's keys with a late row."""
-        late, self._late_kids = self._late_kids, _NO_KIDS
-        return super()._phase_clock(kids) + (late,)
-
-    def _retime(self, kids: np.ndarray) -> None:
-        self.open.retime(kids, self.base_us[kids], self.sys_at_base[kids], None)
-
     # -- hook overrides -----------------------------------------------------
 
     def _late_events(
@@ -1603,6 +1676,8 @@ class DeviceSessionAggState(DeviceWindowAggState):
         # can't name a specific session (host: late_for -> sentinel).
         from bytewax_tpu.operators.windowing import LATE_SESSION_ID
 
+        # A key with late rows alone may be left with no session: the
+        # delivery's close looks at these keys.
         self._late_kids = np.unique(kids[late_rows])
         return [
             (self.keys[int(kids[row])], (LATE_SESSION_ID, values[row]))
@@ -1808,7 +1883,7 @@ class DeviceSessionAggState(DeviceWindowAggState):
 
     def _parts(self, comp: np.ndarray, take: bool) -> Dict[int, List[int]]:
         """By position in ``comp``, the slots a merged session holds
-        besides its own; ``take``: forget the sessions' merge records."""
+        besides its own; ``take``: forget them."""
         parts: Dict[int, List[int]] = {}
         if self._extra:
             get = self._extra.pop if take else self._extra.get
@@ -1816,9 +1891,6 @@ class DeviceSessionAggState(DeviceWindowAggState):
                 held = get(c, None)
                 if held:
                     parts[i] = held
-        if take and self._merged:
-            for c in comp.tolist():
-                self._merged.pop(c, None)
         return parts
 
     @staticmethod
@@ -1842,115 +1914,77 @@ class DeviceSessionAggState(DeviceWindowAggState):
             at += len(held)
         return states[: len(ids)]
 
-    def _metas(self, comp: np.ndarray, lo: np.ndarray, hi: np.ndarray) -> List[Any]:
-        """``WindowMetadata`` of sessions: their bounds and merged ids."""
-        from bytewax_tpu.operators.windowing import WindowMetadata
+    #: Two spans a close: the due scan, and the events with the keys
+    #: left without a session (the slot table's read times itself).
+    _CLOSE_SPANS = ("session_close", None, "session_close", "session_close")
 
-        merged = self._merged
-        return [
-            WindowMetadata(
-                datetime.fromtimestamp(a / _US, tz=timezone.utc),
-                datetime.fromtimestamp(b / _US, tz=timezone.utc),
-                set(merged.get(c, ())),
-            )
-            for c, a, b in zip(comp.tolist(), lo.tolist(), hi.tolist())
-        ]
-
-    def _close_due(
-        self, now_us: float, clock=None
-    ) -> Tuple[WindowEvents, np.ndarray]:
-        """Close the sessions that are due: their events, and the ids
-        of the keys this leaves without an open session (with those
-        of the delivery's keys whose every row was late)."""
-        table = self.open
-        late = clock[3] if clock is not None else _NO_KIDS
-        # (A key left with late rows alone has its first id queued.)
+    def _close_begins(self) -> bool:
+        """Always: a key with late rows alone may be left with no
+        session whatever is open (its first id, queued, comes in
+        first)."""
         self._take_wid_starts()
-        with _flight.span("session_close", rows=len(table)):
-            if clock is not None:
-                table.retime(*clock[:3], None)
-            due = table.due(now_us) if len(table) else _NO_KIDS
-            if not len(due):
-                gone = table.without_window(late) if len(late) else _NO_KIDS
-                return WindowEvents(), gone
-            comp, ids = table.read(due)
-            lo, hi = table.bounds(due)
-            metas = self._metas(comp, lo, hi) if self.spec.meta_live else None
-            parts = self._parts(comp, take=True)
-        states = self._accs(ids, parts)
-        with _flight.span("session_close", rows=len(due)):
-            table.remove(due)
-            self.agg.release_ids(self._with_parts(ids, parts))
-            kids_due = comp >> 32
-            keys = list(map(self.keys.__getitem__, kids_due.tolist()))
-            wids = ((comp & _WID_MASK) - _WID_BIAS).tolist()
-            values = map(self._finalize_one, states)
-            events = WindowEvents(list(zip(keys, zip(wids, values))), keys)
-            if metas is not None:
-                _flight.RECORDER.count("window_meta_events", len(metas))
-                events.meta = list(zip(keys, zip(wids, metas)))
-            _flight.RECORDER.count("session_closes", len(due))
-            gone = table.without_window(np.unique(np.append(kids_due, late)))
-        return events, gone
+        return True
+
+    def _closing(self, due: np.ndarray, comp: np.ndarray, ids: np.ndarray):
+        """A session is a row; a merged one gives back the slots of
+        the sessions it absorbed too."""
+        parts = self._parts(comp, take=True)
+        return due, comp, self._with_parts(ids, parts), (comp, ids, parts)
+
+    def _fetch_closed(self, held) -> List[Any]:
+        _comp, ids, parts = held
+        return self._accs(ids, parts)
+
+    def _emit_closed(self, held, got, keys: List[str], wids: np.ndarray) -> List[Any]:
+        """The base class's rows; the sessions' merge records go, their
+        metadata built."""
+        comp, ids, _parts = held
+        if self._merged:
+            for c in comp.tolist():
+                self._merged.pop(c, None)
+        _flight.RECORDER.count("session_closes", len(ids))
+        return super()._emit_closed(held, got, keys, wids)
+
+    def _bounds(self, rows: np.ndarray, comp: np.ndarray):
+        """A session's bounds are stored, and it carries the ids of the
+        sessions it absorbed."""
+        lo, hi = self.open.bounds(rows)
+        merged = self._merged
+        return lo, hi, (set(merged.get(c, ())) for c in comp.tolist())
 
     # -- recovery -----------------------------------------------------------
 
-    def snapshots_for(self, keys: List[str]):
-        """Host-tier ``_WindowSnapshot``-compatible snapshots with
-        session windower state: a held key's open sessions, a key let
-        go as the host tier's logic with no session (clock and
-        ``next_id``), ``None`` for a key never seen."""
-        from bytewax_tpu.operators.windowing import (
-            _SessionWindowerState,
-            _WindowSnapshot,
+    def _window_states(self, rows: np.ndarray, comp: np.ndarray, ids: np.ndarray):
+        """A session's state is its parts' accumulators combined (the
+        keys' next ids, queued, come in first)."""
+        self._take_wid_starts()
+        return rows, comp, self._accs(ids, self._parts(comp, take=False))
+
+    def _windower_of(self, windowing, key: str, kid: Optional[int], opened: dict):
+        """A held key's open sessions (none, too), a key let go as the
+        host tier's logic with no session (clock and ``next_id``);
+        None for a key never seen."""
+        if kid is not None:
+            base_us, sys_at_us, next_id = (
+                self.base_us[kid], self.sys_at_base[kid], self._next_wid[kid]
+            )  # fmt: skip
+        else:
+            held = self._retired.get(key)
+            if held is None:
+                return None
+            base_us, sys_at_us, next_id = held
+        return (
+            base_us,
+            sys_at_us,
+            windowing._SessionWindowerState(
+                next_id=int(next_id), sessions=opened, merge_queue=[]
+            ),
         )
 
-        self._take_wid_starts()
-        rows = self._rows_of(keys)
-        comp, ids = self.open.read(rows)
-        lo, hi = self.open.bounds(rows)
-        states = self._accs(ids, self._parts(comp, take=False))
-        of_kid: Dict[int, Tuple[dict, dict]] = {}
-        for kid, wid, meta, state in zip(
-            (comp >> 32).tolist(),
-            ((comp & _WID_MASK) - _WID_BIAS).tolist(),
-            self._metas(comp, lo, hi),
-            states,
-        ):
-            sessions, folded = of_kid.setdefault(kid, ({}, {}))
-            sessions[wid] = meta
-            folded[wid] = state
-        out = []
-        for key in keys:
-            kid = self.key_ids.get(key)
-            if kid is not None:
-                held = (self.base_us[kid], self.sys_at_base[kid], self._next_wid[kid])
-            else:
-                held = self._retired.get(key)
-                if held is None:
-                    out.append((key, None))
-                    continue
-            base, sys_at, next_id = held
-            sessions, folded = of_kid.get(kid, ({}, {}))
-            out.append(
-                (
-                    key,
-                    _WindowSnapshot(
-                        _clock_state(base, sys_at),
-                        _SessionWindowerState(
-                            next_id=int(next_id), sessions=sessions, merge_queue=[]
-                        ),
-                        folded,
-                        [],
-                    ),
-                )
-            )
-        return out
-
-    def demotion_snapshots(self):
+    def _known_keys(self):
         """Every key held and every key let go: the host tier keeps
         both."""
-        return self.snapshots_for(sorted(self.key_ids.keys() | self._retired.keys()))
+        return self.key_ids.keys() | self._retired.keys()
 
     def load_many(self, items: List[Tuple[str, Any]]) -> None:
         """The base class's resume; a key resumed with no open session
@@ -2007,29 +2041,6 @@ class DeviceSessionAggState(DeviceWindowAggState):
         )
         held = [i for i, state in enumerate(states) if state is not None]
         self.agg.load_ids(ids[held], [states[i] for i in held])
-
-    def extract_keys(self, keys: List[str]) -> List[Tuple[str, Any]]:
-        """Session variant of the residency extract: the keys' open
-        sessions drain into their snapshots (which carry ``next_id``,
-        so session ids stay unique across an extract/inject round
-        trip), their device slots are released and the keys go, what
-        they left behind with them."""
-        out = [(key, snap) for key, snap in self.snapshots_for(keys) if snap is not None]
-        names = [key for key, _snap in out]
-        rows = self._rows_of(names)
-        comp, ids = self.open.read(rows)
-        self.agg.release_ids(self._with_parts(ids, self._parts(comp, take=True)))
-        self.open.remove(rows)
-        held = np.asarray(
-            [self.key_ids[key] for key in names if key in self.key_ids], dtype=np.int64
-        )
-        parked_seq, parked = self._parked
-        self._parked = (parked_seq, np.setdiff1d(parked, held))
-        super().let_go((self._seq, held))
-        forgot = sum(self._retired.pop(key, None) is not None for key in names)
-        _flight.RECORDER.count("session_keys_remembered", -forgot)
-        self.touched.difference_update(names)
-        return out
 
 
 # -- the windowed product join ----------------------------------------------
@@ -2156,10 +2167,8 @@ class DeviceJoinState(DeviceWindowAggState):
         wids = low // self.spec.sides
         return comp >> 32, wids, low - wids * self.spec.sides
 
-    def _closes_of(self, comp: np.ndarray) -> np.ndarray:
-        spec = self.spec
-        wids = self._windows_of(comp)[1]
-        return spec.align_us + wids * spec.offset_us + spec.length_us
+    def _wids_of(self, comp: np.ndarray) -> np.ndarray:
+        return ((comp & _WID_MASK) - _WID_BIAS) // self.spec.sides
 
     # -- values -----------------------------------------------------------------
 
@@ -2299,50 +2308,41 @@ class DeviceJoinState(DeviceWindowAggState):
 
     # -- close ------------------------------------------------------------------
 
-    def _close_due(
-        self, now_us: float, clock=None
-    ) -> Tuple[WindowEvents, np.ndarray]:
-        """Close the windows that are due: each window's product of its
-        sides' rows, expanded and read back on the device, as events;
-        and the keys left without an open window."""
-        if not self.open_count:
-            return WindowEvents(), _NO_KIDS
-        with _flight.span("join_close", rows=len(self.open)):
-            if clock is not None:
-                self.open.retime(*clock, self._closes_of)
-            due = self.open.due(now_us, None if now_us == np.inf else _CLOSE_SLOTS)
-            if not len(due):
-                return WindowEvents(), _NO_KIDS
-            comp, ids = self.open.read(due)
-            order = np.argsort(comp)
-            comp, ids = comp[order], ids[order]
-            kids, wids, sides = self._windows_of(comp)
-            window = (kids << 32) + (wids + _WID_BIAS)
-            head = np.empty(len(comp), dtype=bool)
-            head[0] = True
-            np.not_equal(window[1:], window[:-1], out=head[1:])
-            at = np.cumsum(head) - 1
-            n_windows = int(at[-1]) + 1
-            slots = np.full((self.spec.sides, n_windows), -1, dtype=np.int64)
-            slots[sides, at] = ids
-            starts = np.zeros_like(slots)
-            counts = np.zeros_like(slots)
-            starts[sides, at], counts[sides, at] = self.store.regions(ids.astype(np.int64))
-            sizes = np.maximum(counts, 1).prod(axis=0)
-            columns = self._expand(slots, starts, sizes)
-            events = self._join_events(
-                kids[head], wids[head], sizes, counts, columns, comp[head]
-            )
-            self.open.remove(due)
-            self._release_slots(ids)
-            gone = self.open.without_window(np.unique(kids))
-        return events, gone
+    #: One span around the whole close.
+    _CLOSE_SPANS = ("join_close",) * 4
 
-    def _expand(
-        self, slots: np.ndarray, starts: np.ndarray, sizes: np.ndarray
-    ) -> List[np.ndarray]:
+    def _due(self, now_us: float) -> np.ndarray:
+        """Before end of input, at most ``_CLOSE_SLOTS`` slots, the
+        earliest due first."""
+        return self.open.due(now_us, _CLOSE_SLOTS)
+
+    def _closing(self, due: np.ndarray, comp: np.ndarray, ids: np.ndarray):
+        """A window's slots (a side each) as one column of ``[sides,
+        windows]`` arrays: their slots, the starts and counts of their
+        regions in the store, and each window's output rows (the
+        product of ``max(count, 1)`` over its sides); the window's
+        first slot stands for it."""
+        order = np.argsort(comp)
+        comp, ids = comp[order], ids[order]
+        kids, wids, sides = self._windows_of(comp)
+        window = (kids << 32) + (wids + _WID_BIAS)
+        head = np.empty(len(comp), dtype=bool)
+        head[0] = True
+        np.not_equal(window[1:], window[:-1], out=head[1:])
+        at = np.cumsum(head) - 1
+        n_windows = int(at[-1]) + 1
+        slots = np.full((self.spec.sides, n_windows), -1, dtype=np.int64)
+        slots[sides, at] = ids
+        starts = np.zeros_like(slots)
+        counts = np.zeros_like(slots)
+        starts[sides, at], counts[sides, at] = self.store.regions(ids.astype(np.int64))
+        sizes = np.maximum(counts, 1).prod(axis=0)
+        return due[order[head]], comp[head], ids, (slots, starts, counts, sizes)
+
+    def _fetch_closed(self, held) -> List[np.ndarray]:
         """Each side's values of the close's output rows, expanded on
         the device from the slot table's counts (``RowStore.expand``)."""
+        slots, starts, _counts, sizes = held
         sides = self.spec.sides
         wide = tuple(s for s in range(sides) if self._fetches_high(s))
         self.agg._ensure_fields()
@@ -2352,13 +2352,11 @@ class DeviceJoinState(DeviceWindowAggState):
             for s in range(sides)
         ]
 
-    def _join_events(self, kids, wids, sizes, counts, columns, comp) -> WindowEvents:
-        """The close's events: a ``down`` row a combination, and a
-        ``WindowMetadata`` a window while the meta stream is read; the
-        keys one a window."""
-        total = int(sizes.sum())
-        _flight.RECORDER.count("join_rows_emitted", total)
-        keys = list(map(self.keys.__getitem__, kids.tolist()))
+    def _emit_closed(self, held, columns, keys: List[str], wids: np.ndarray) -> List[Any]:
+        """A ``down`` row a combination, built from the columns with
+        one ``tolist()`` a side; a side with no row reads ``None``."""
+        _slots, _starts, counts, sizes = held
+        _flight.RECORDER.count("join_rows_emitted", int(sizes.sum()))
         values = []
         for s, col in enumerate(columns):
             absent = counts[s] == 0
@@ -2366,33 +2364,21 @@ class DeviceJoinState(DeviceWindowAggState):
                 col = col.astype(object)
                 col[np.repeat(absent, sizes)] = None
             values.append(col.tolist())
-        down = list(
+        return list(
             zip(
                 np.repeat(np.asarray(keys, dtype=object), sizes).tolist(),
                 zip(np.repeat(wids, sizes).tolist(), zip(*values)),
             )
         )
-        events = WindowEvents(down, keys)
-        if self.spec.meta_live:
-            metas = self._metas(self._closes_of(comp).tolist())
-            _flight.RECORDER.count("window_meta_events", len(metas))
-            events.meta = list(zip(keys, zip(wids.tolist(), metas)))
-        return events
 
     # -- recovery -----------------------------------------------------------------
 
-    def snapshots_for(self, keys: List[str]):
-        """Host-tier ``_WindowSnapshot``s: per open window its metadata
-        and a ``_SideTable`` of every stored row, in arrival order a
-        side; a key with no open window snapshots as a discard."""
+    def _window_states(self, rows: np.ndarray, comp: np.ndarray, ids: np.ndarray):
+        """A window's state is a ``_SideTable`` of every stored row, in
+        arrival order a side; the window's first slot stands for it."""
         from bytewax_tpu.operators import _SideTable
-        from bytewax_tpu.operators.windowing import (
-            _SlidingWindowerState,
-            _WindowSnapshot,
-        )
 
-        comp, ids = self.open.read(self._rows_of(keys))
-        kids, wids, sides = self._windows_of(comp)
+        sides = self._windows_of(comp)[2]
         slot_ids = ids.astype(np.int64)
         low, high = self.store.read(slot_ids)
         lengths = self.store.regions(slot_ids)[1]
@@ -2403,36 +2389,20 @@ class DeviceJoinState(DeviceWindowAggState):
             if mine.any():
                 got = self._values_of(s, low[mine], high[mine] if self._fetches_high(s) else None)
                 values[mine] = got.astype(object)
-        metas = self._metas(self._closes_of(comp).tolist())
-        open_of: Dict[int, Tuple[dict, dict]] = {}
+        tables: Dict[int, Any] = {}
+        first = []
         at = 0
-        for kid, wid, side, meta, n in zip(
-            kids.tolist(), wids.tolist(), sides.tolist(), metas, lengths.tolist()
+        for i, (window, side, n) in enumerate(
+            zip((comp - sides).tolist(), sides.tolist(), lengths.tolist())
         ):
-            opened, tables = open_of.setdefault(kid, ({}, {}))
-            opened[wid] = meta
-            table = tables.setdefault(wid, _SideTable.empty(self.spec.sides))
+            table = tables.get(window)
+            if table is None:
+                table = tables[window] = _SideTable.empty(self.spec.sides)
+                first.append(i)
             table.pools[side] = values[at : at + n].tolist()
             at += n
-        out = []
-        for key in keys:
-            kid = self.key_ids.get(key)
-            if kid not in open_of:
-                out.append((key, None))
-                continue
-            opened, tables = open_of[kid]
-            out.append(
-                (
-                    key,
-                    _WindowSnapshot(
-                        _clock_state(self.base_us[kid], self.sys_at_base[kid]),
-                        _SlidingWindowerState(opened=opened),
-                        tables,
-                        [],
-                    ),
-                )
-            )
-        return out
+        first = np.asarray(first, dtype=np.int64)
+        return rows[first], comp[first], list(tables.values())
 
     def _load_windows(self, kids: List[int], items: List[Tuple[str, Any]]) -> None:
         """Reopen the page's windows from host-tier ``_SideTable``s and

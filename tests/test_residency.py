@@ -346,42 +346,3 @@ def test_scan_extract_inject_round_trip():
     st.inject_keys(items)
     (resumed,) = [s for _k, s in st.snapshots_for(["a"])]
     assert resumed == pytest.approx(snap)
-
-
-def test_window_extract_inject_round_trip():
-    """The window tier's residency surface: extraction drains a key's
-    open windows to its host-format _WindowSnapshot and frees the
-    fold slots; injection reinstates them bit-for-bit."""
-    from datetime import datetime, timedelta, timezone
-
-    from bytewax_tpu.engine.window_accel import WindowAccelSpec
-
-    align = datetime(2024, 1, 1, tzinfo=timezone.utc)
-    spec = WindowAccelSpec(
-        "sum",
-        lambda v: v.ts,
-        align,
-        timedelta(seconds=10),
-        timedelta(seconds=10),
-        timedelta(seconds=0),
-    )
-    st = spec.make_state()
-    from bytewax_tpu.engine.arrays import TsValue
-
-    ts = align + timedelta(seconds=1)
-    _late, phase = st.on_batch(
-        ["a", "b"], [TsValue(2.0, ts), TsValue(5.0, ts)]
-    )
-    phase()
-    before = dict(st.snapshots_for(["a"]))
-    items = st.extract_keys(["a"])
-    assert [k for k, _s in items] == ["a"]
-    assert st.key_ids["a"] not in (st.open.comp >> 32).tolist()
-    assert st.open_count == 1
-    st.inject_keys(items)
-    after = dict(st.snapshots_for(["a"]))
-    assert after["a"].logic_states == before["a"].logic_states
-    assert (
-        after["a"].windower_state.opened
-        == before["a"].windower_state.opened
-    )

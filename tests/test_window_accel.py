@@ -1468,6 +1468,107 @@ def test_snapshot_resumes_to_same_results(monkeypatch, shard, windower):
     assert len(ev_resumed) == len(ev_straight)
 
 
+#: Per tier: the spans a close records (the slot table's read of the
+#: fold states times itself as ``fetch`` and ``close_emit``), and the
+#: keys the tier knows once ``c`` was let go.
+_CLOSE_SPAN_NAMES = {
+    "tumbling": ({"close_scan", "fetch", "close_emit", "retire"}, "ab"),
+    "session": ({"session_close", "fetch", "close_emit"}, "abc"),
+    "join": ({"join_close"}, "ab"),
+}
+
+
+@pytest.mark.parametrize("tier", sorted(_CLOSE_SPAN_NAMES))
+def test_a_tier_closes_and_snapshots_through_the_one_form(monkeypatch, tier):
+    """Each device tier closes and snapshots through the base class's
+    one form: a close records the tier's spans and no other, with the
+    rows the metrics read; the snapshots of every key load into a fresh
+    state that snapshots the same; and a demotion drains every key the
+    tier knows, a session key let go among them."""
+    from bytewax_tpu import xla
+    from bytewax_tpu.engine import flight
+    from bytewax_tpu.engine import window_accel as wa
+    from bytewax_tpu.engine.arrays import ArrayBatch
+
+    monkeypatch.setenv("BYTEWAX_TPU_SHARD", "0")
+    t0 = ALIGN + timedelta(days=400)
+    now = [t0]
+
+    class _Datetime(datetime):
+        @classmethod
+        def now(cls, tz=None):
+            return now[0]
+
+    monkeypatch.setattr(wa, "datetime", _Datetime)
+    ten = timedelta(seconds=10)
+    if tier == "tumbling":
+        spec = wa.WindowAccelSpec("sum", xla.column_ts, ALIGN, ten, ten, ten)
+    elif tier == "session":
+        spec = wa.SessionAccelSpec("sum", xla.column_ts, ten, ten)
+    else:
+        spec = wa.JoinAccelSpec(2, xla.column_ts, ALIGN, ten, ten, ten)
+    st = spec.make_state()
+
+    def deliver(at_s, rows):
+        """``rows``: ``(key, second, side)``; the system clock at
+        ``at_s`` seconds."""
+        now[0] = t0 + timedelta(seconds=at_s)
+        cols = {
+            "key": np.asarray([k for k, _s, _side in rows]),
+            "ts": np.datetime64(ALIGN.replace(tzinfo=None), "s")
+            + np.asarray([s for _k, s, _side in rows]).astype("timedelta64[s]"),
+            "value": np.arange(len(rows), dtype=np.int32 if tier == "join" else np.float64),
+        }
+        if tier == "join":
+            cols["side"] = np.asarray([side for _k, _s, side in rows], dtype=np.int8)
+        _late, phase = st.on_batch_columnar(ArrayBatch(cols))
+        _closes, _hint, gone = phase()
+        st.let_go(gone)
+
+    # "a" holds two windows (sessions); at 8 s the first is due (the
+    # watermark at 13 s), the second not, and no key goes.
+    deliver(0, [("a", 1, 0), ("a", 2, 1), ("a", 15, 0)])
+    held = len(st.open)
+    before = dict(flight.RECORDER.counters)
+    now[0] = t0 + timedelta(seconds=8)
+    closed = st.on_notify()
+    moved = {
+        name[: -len("_spans")]: flight.RECORDER.counters[name] - before.get(name, 0)
+        for name in flight.RECORDER.counters
+        if name.endswith("_spans") and flight.RECORDER.counters[name] != before.get(name, 0)
+    }
+    names, known = _CLOSE_SPAN_NAMES[tier]
+    assert set(moved) == names
+
+    def gained(name):
+        return flight.RECORDER.counters.get(name, 0) - before.get(name, 0)
+
+    assert len(closed.down) == len(closed.meta) == gained("window_meta_events") == 1
+    if tier == "tumbling":
+        assert gained("close_scan_rows") == held == 2
+        assert gained("close_emit_rows") == gained("retire_rows") == 1
+    elif tier == "session":
+        assert gained("session_close_rows") == held + 1 == 3
+        assert gained("session_closes") == 1
+    else:
+        assert gained("join_close_rows") == held == 3
+        assert gained("join_rows_emitted") == 1
+
+    # "c" comes and goes (a session key one delivery later); "a" and
+    # "b" keep windows open.
+    deliver(10, [("c", 20, 0), ("b", 21, 1)])
+    deliver(40, [("b", 50, 0), ("b", 51, 1)])
+    deliver(41, [("a", 52, 0)])
+    assert "c" not in st.key_ids and st.open_count
+    keys = ["a", "b", "c", "never"]
+    snaps = st.snapshots_for(keys)
+    assert [k for k, s in snaps if s is not None] == list(known)
+    fresh = spec.make_state()
+    fresh.load_many([(k, s) for k, s in snaps if s is not None])
+    assert fresh.snapshots_for(keys) == snaps
+    assert st.demotion_snapshots() == [(k, s) for k, s in snaps if k in known]
+
+
 class _CountedCalls:
     """Wraps an aggregate state and counts the calls made into it."""
 
